@@ -1,12 +1,14 @@
 """shardmap-vjp: custom_vjp x shard_map islands, the PR-9 rule.
 
-On jax 0.4.x the AD machinery cannot transpose a ``shard_map`` whose
-specs mix sharded and replicated operands (the psum'd replicated
-outputs confuse its transpose rules), so the fused mesh ops keep
-``custom_vjp`` OUTSIDE the islands — fwd and bwd are each their own
-shard_map (ops/fused_norm.py, fused_epilogue.py). Until now the rule
-lived only in code comments and a memory note; this pass mechanizes
-it, including its two sanctioned shapes:
+The fused mesh ops keep ``custom_vjp`` OUTSIDE their shard_map islands
+— fwd and bwd are each their own shard_map (ops/fused_norm.py,
+fused_epilogue.py) — so autodiff never transposes an island whose
+specs mix sharded and replicated operands: every cross-shard sum such
+an op needs is the explicit psum its backward writes, not one the
+transpose has to infer (interpreted islands run without shard_map's
+varying-axes check, ops/fused.py:island, where an inferred transpose
+sums over every unmentioned axis). This pass mechanizes the rule,
+including its two sanctioned shapes:
 
 * **all-batch-sharded islands** may wrap a custom_vjp op directly
   (``island(..., in_batch=(True, ...all True), out_batch=True)``):
@@ -51,8 +53,8 @@ def _all_true(node: Optional[ast.AST]) -> bool:
 class ShardmapVjpPass(LintPass):
     name = "shardmap-vjp"
     description = ("custom_vjp defined or invoked lexically inside a "
-                   "shard_map island (0.4.x cannot transpose a "
-                   "mixed-spec shard_map)")
+                   "shard_map island (a mixed-spec island must not "
+                   "be transposed by autodiff)")
 
     def run(self, project: Project) -> List[Finding]:
         out: List[Finding] = []
@@ -137,8 +139,8 @@ class ShardmapVjpPass(LintPass):
                     msg = ("custom_vjp defined inside shard_map island "
                            f"'{bname}' — define the vjp OUTSIDE the "
                            "island and wrap only the kernels (PR-9 "
-                           "rule: 0.4.x cannot transpose a mixed-spec "
-                           "shard_map)")
+                           "rule: autodiff must not transpose a "
+                           "mixed-spec shard_map)")
                 elif isinstance(n, ast.Call):
                     if _last(call_chain(n)) == "defvjp":
                         msg = ("defvjp() called inside shard_map "

@@ -524,11 +524,9 @@ def rendezvous_jax_distributed(plan: Dict[str, Any], worker: int,
     DCN-mode rendezvous after a generation bump. Returns True when the
     runtime came back up at the new process count.
 
-    Degrades honestly: jax builds whose CPU backend cannot run
-    cross-process computations (this session's 0.4.x pin — see
-    doc/elastic_runbook.md) get an explicit SKIP print and False, the
-    same degrade-don't-die contract the multichip dryrun uses; the
-    driver's capture env re-proves the path."""
+    Degrades honestly: a rendezvous that cannot complete (coordinator
+    unreachable, peers missing) gets an explicit SKIP print and False —
+    the worker continues on its local mesh (degrade, don't die)."""
     import jax
     try:
         if jax.process_count() > 1 or getattr(
@@ -544,6 +542,5 @@ def rendezvous_jax_distributed(plan: Dict[str, Any], worker: int,
         if not silent:
             print(f"elastic: SKIP jax.distributed rendezvous "
                   f"({type(e).__name__}: {e}) — continuing on the "
-                  "local mesh; DCN-mode elasticity needs a backend "
-                  "with multiprocess support", flush=True)
+                  "local mesh", flush=True)
         return False

@@ -28,9 +28,14 @@ from .augment import (AugmentParams, ImageAugmenter, MeanStore,
                       mean_cache_path, pack_label)
 
 
+#: the "which decoder" line prints once per process
+_DECODER_SAID = False
+
+
 def decode_image(data: bytes, want_channels: int = 3) -> np.ndarray:
     """Decode jpeg/png bytes to HWC uint8 (RGB, or single-channel luma when
-    ``want_channels == 1``) via the native decoder if built, else cv2/PIL.
+    ``want_channels == 1``) via the native decoder (io/native.py builds it
+    on first use), else cv2/PIL where it cannot be built.
     Raw float tensors (flag==1 records) skip this."""
     from . import native
     arr = native.try_decode(data, want_channels)
@@ -214,6 +219,14 @@ class ImageRecordIterator(DataIter):
                 print(f"imgrec: device_normalize auto-resolved to "
                       f"{self.aug.device_normalize} "
                       f"({'uint8 device path' if exact else 'host float path'})")
+        global _DECODER_SAID
+        if not self.silent and not _DECODER_SAID:
+            # once per process: a run that quietly lost its native
+            # decoder decodes several times slower
+            _DECODER_SAID = True
+            from . import native
+            print(f"imgrec: decoding with {native.decoder_name()}",
+                  flush=True)
         self._pool = futures.ThreadPoolExecutor(self.nthread)
         self._rng = np.random.RandomState(self.seed + 7 * self.rank)
         # monotonically increasing per-item augmentation counter, hashed

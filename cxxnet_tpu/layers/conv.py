@@ -22,18 +22,6 @@ from jax import lax
 
 from .base import ApplyCtx, Layer, Shape3, is_flat, register_layer
 
-# optimization_barrier gained its differentiation rule after jax 0.4.x;
-# probe once (eval_shape traces the vjp without compiling) and skip the
-# fence where it cannot be differentiated — it is a perf-only fusion
-# hint, numerics are identical without it
-try:
-    jax.eval_shape(jax.grad(lambda x: lax.optimization_barrier(x)),
-                   jax.ShapeDtypeStruct((), jnp.float32))
-    _BARRIER_DIFFERENTIABLE = True
-except NotImplementedError:
-    _BARRIER_DIFFERENTIABLE = False
-
-
 @register_layer("conv")
 class ConvolutionLayer(Layer):
     """2-D convolution with groups (convolution_layer-inl.hpp:13-231).
@@ -392,7 +380,6 @@ class LRNLayer(Layer):
         # AlexNet's lrn->grouped-conv pairs), recomputing the LRN once per
         # kernel tap — measured 894 ms/step vs 15 ms with the barrier on a
         # v5e. The barrier only pins the one intermediate; everything else
-        # still fuses.
-        if _BARRIER_DIFFERENTIABLE:
-            out = lax.optimization_barrier(out)
+        # still fuses (a perf-only hint: numerics are identical without).
+        out = lax.optimization_barrier(out)
         return [out], state

@@ -214,38 +214,39 @@ class MultiHeadAttentionLayer(Layer, _SeqLinearMixin):
                 "o": {"wmat": ("model", None, None), "bias": None}}
 
     def _attend(self, q, k, v, ctx):
+        from ..ops.fused import note_attention
+        block = self.attn_block
         if ctx.seq_axis is not None:
             if ctx.seq_gather_kv:
                 # pipeline-parallel stage: one k/v all-gather (safe inside
                 # the stage's switch branch) instead of the ring
                 from ..ops.attention import gather_kv_attention
+                note_attention("gather_kv")
                 return gather_kv_attention(q, k, v, axis_name=ctx.seq_axis,
                                            causal=self.causal)
             # sequence-parallel step (shard_map): q/k/v are local sequence
             # shards; the ring carries k/v around the mesh axis
             from ..parallel.ring import ring_attention
+            note_attention("ring")
             return ring_attention(q, k, v, axis_name=ctx.seq_axis,
                                   causal=self.causal)
-        if self.attn_impl == "ref":
+        impl = self.attn_impl
+        if impl == "auto":
+            # flash on TPU when the sequence tiles evenly, plain reference
+            # for short sequences, chunked otherwise
+            S = q.shape[1]
+            if jax.default_backend() == "tpu" and S % block == 0:
+                impl = "flash"
+            else:
+                impl = "ref" if S <= 512 else "chunked"
+        note_attention(impl)
+        if impl == "ref":
             return attention_reference(q, k, v, causal=self.causal)
-        if self.attn_impl == "chunked":
+        if impl == "chunked":
             return chunked_attention(q, k, v, causal=self.causal,
-                                     block_k=self.attn_block)
-        if self.attn_impl == "flash":
-            return flash_attention(q, k, v, causal=self.causal,
-                                   block_q=self.attn_block,
-                                   block_k=self.attn_block)
-        # auto: flash on TPU when the sequence tiles evenly, plain reference
-        # for short sequences, chunked otherwise
-        S = q.shape[1]
-        if jax.default_backend() == "tpu" and S % self.attn_block == 0:
-            return flash_attention(q, k, v, causal=self.causal,
-                                   block_q=self.attn_block,
-                                   block_k=self.attn_block)
-        if S <= 512:
-            return attention_reference(q, k, v, causal=self.causal)
-        return chunked_attention(q, k, v, causal=self.causal,
-                                 block_k=self.attn_block)
+                                     block_k=block)
+        return flash_attention(q, k, v, causal=self.causal,
+                               block_q=block, block_k=block)
 
     def apply(self, params, state, inputs, ctx):
         x = _seq(inputs[0]).astype(ctx.compute_dtype)

@@ -23,6 +23,7 @@ from .config import ConfigPairs, Policy
 from .graph import NetGraph, global_param, policy_from_config
 from .layers import ApplyCtx, Layer, create_layer
 from .layers.base import Shape3, is_flat, to_nhwc
+from .ops.fused import selection_site
 
 Params = Dict[str, Dict[str, jax.Array]]
 NetState = Dict[str, Any]
@@ -71,6 +72,9 @@ class Network:
         # multi-device meshes: fused ops then run as shard_map islands
         # with per-op collectives instead of being cleared wholesale
         self.fused_spmd = None
+        # site -> which implementation it took (ops.fused.SelectionLog):
+        # written while apply() is traced, printed once by the trainer
+        self.fused_log: Dict[str, Tuple[str, str]] = {}
         self._tp_plan_logged = False
         # rule-driven sharding (parallel/rules.py): the validated
         # config namespace (partition_rules / fsdp_*), custom rules
@@ -235,21 +239,26 @@ class Network:
             inputs = [nodes[ni] for ni in spec.nindex_in]
             lparams = params.get(layer.name, {})
             lstate = new_state.get(layer.name, {})
-            if self.remat and layer.has_params:
-                def _fn(lp, ls, rng_, *ins, _layer=layer, _ctx=ctx):
-                    c = ApplyCtx(train=_ctx.train, rng=rng_,
-                                 compute_dtype=_ctx.compute_dtype,
-                                 seq_axis=_ctx.seq_axis,
-                                 data_axis=_ctx.data_axis,
-                                 fused=_ctx.fused,
-                                 fused_spmd=_ctx.fused_spmd,
-                                 fuse_act=_ctx.fuse_act,
-                                 cin_pad=_ctx.cin_pad)
-                    return _layer.apply(lp, ls, list(ins), c)
-                outputs, lstate_out = jax.checkpoint(_fn)(
-                    lparams, lstate, ctx.rng, *inputs)
-            else:
-                outputs, lstate_out = layer.apply(lparams, lstate, inputs, ctx)
+            # every fused-kernel choice this layer makes lands in
+            # fused_log under its name (tracing is synchronous, so the
+            # binding covers remat's inner trace too)
+            with selection_site(self.fused_log, spec.name):
+                if self.remat and layer.has_params:
+                    def _fn(lp, ls, rng_, *ins, _layer=layer, _ctx=ctx):
+                        c = ApplyCtx(train=_ctx.train, rng=rng_,
+                                     compute_dtype=_ctx.compute_dtype,
+                                     seq_axis=_ctx.seq_axis,
+                                     data_axis=_ctx.data_axis,
+                                     fused=_ctx.fused,
+                                     fused_spmd=_ctx.fused_spmd,
+                                     fuse_act=_ctx.fuse_act,
+                                     cin_pad=_ctx.cin_pad)
+                        return _layer.apply(lp, ls, list(ins), c)
+                    outputs, lstate_out = jax.checkpoint(_fn)(
+                        lparams, lstate, ctx.rng, *inputs)
+                else:
+                    outputs, lstate_out = layer.apply(lparams, lstate,
+                                                      inputs, ctx)
             if lstate_out:
                 new_state[layer.name] = lstate_out
                 # auxiliary regularizers (e.g. MoE load-balancing loss)
@@ -483,8 +492,8 @@ class Network:
             Specs come from the RULE TABLE (param_pspecs), not the
             layer declaration directly — a config ``partition_rules``
             override changes the manual plan the same way it changes
-            GSPMD placement, keeping the 0.4.x execution fallback
-            derived from the one declarative source."""
+            GSPMD placement, keeping the manual execution plan derived
+            from the one declarative source."""
             if getattr(layer, "tp_manual_axis", None) is None:
                 return "no tp_manual_axis"
             pspecs = self.param_pspecs().get(layer.name) or {}

@@ -17,8 +17,9 @@ taking (batch, seq, heads, head_dim) arrays:
   and streaming k/v blocks through VMEM. The forward also emits the
   per-row logsumexp; the backward is FUSED (dq and dk/dv kernels that
   rebuild the softmax from that statistic — no second online pass, no
-  chunked recompute). ``interpret=True`` runs the same kernels on CPU
-  for tests. Not twice-differentiable (the fused backward is a kernel,
+  chunked recompute). On the CPU backend the same kernels run under
+  the Pallas interpreter (``fused.use_interpret``); on a TPU backend
+  they are compiled. Not twice-differentiable (the fused backward is a kernel,
   not traced jnp); differentiate ``chunked_attention`` for higher-order
   uses.
 
@@ -35,6 +36,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .fused import out_struct, use_interpret
 
 _NEG = -1e30
 
@@ -306,14 +311,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(l_fin)
 
 
-try:  # pallas import kept lazy-safe: CPU-only installs still get chunked
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    with_lse: bool = False):
     B, Sq, H, D = q.shape
@@ -346,8 +343,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
+            out_struct((B * H, Sq, D), q.dtype, qt, kt, vt),
+            out_struct((B * H, Sq, 1), jnp.float32, qt, kt, vt),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
@@ -365,17 +362,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Flash attention (B,S,H,D): Pallas forward, chunked-recompute backward.
+    """Flash attention (B,S,H,D): Pallas forward, fused Pallas backward.
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the same
-    kernel is exercised in CPU tests (the pairtest spirit, SURVEY §4).
+    ``interpret=None``: compiled on a TPU backend, interpreted on the
+    CPU backend — the same kernel is exercised in CPU tests (the
+    pairtest spirit, SURVEY §4).
     """
-    if not _HAVE_PALLAS:   # promised fallback for pallas-less installs
-        return chunked_attention(q, k, v, causal=causal, scale=scale,
-                                 block_k=block_k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                          use_interpret(interpret))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -493,7 +487,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         grid=(B * H, Sq // block_q, Sk // block_k),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_shape=out_struct((B * H, Sq, D), q.dtype, qt, kt, vt, dot),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)
@@ -508,8 +502,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         grid=(B * H, Sk // block_k, Sq // block_q),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
         out_specs=[k_spec2, k_spec2],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype)],
+        out_shape=[out_struct((B * H, Sk, D), k.dtype, qt, kt, vt, dot),
+                   out_struct((B * H, Sk, D), v.dtype, qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
@@ -520,30 +514,15 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
-    if not _HAVE_PALLAS:
-        out = chunked_attention(q, k, v, causal=causal, scale=scale,
-                                block_k=block_k)
-        return out, (q, k, v, None, None)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret, with_lse=True)
+                              use_interpret(interpret), with_lse=True)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    if not _HAVE_PALLAS:
-        # fall back to differentiating the chunked implementation
-        def f(q_, k_, v_):
-            return chunked_attention(q_, k_, v_, causal=causal, scale=scale,
-                                     block_k=block_k)
-        _, vjp = jax.vjp(f, q, k, v)
-        return vjp(g)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                           block_q, block_k, interpret)
+                           block_q, block_k, use_interpret(interpret))
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

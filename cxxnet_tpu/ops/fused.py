@@ -5,13 +5,14 @@ Selection contract — the one rule every fused op follows:
 
 * ``fused_kernels = auto`` (default): kernels are selected on TPU
   backends only; every other backend runs the jnp reference the layer
-  already shipped. This is the production setting — the flagship bench
-  is HBM-bound (BENCH_r02–r04: ~100–105% of the bandwidth roofline at
-  MFU ~28%), and the fused kernels exist to move fewer HBM bytes per
-  step, which only a real TPU pays for.
-* ``fused_kernels = 1``: kernels are selected everywhere; off-TPU they
-  run under ``interpret=True`` (the flash-attention testing pattern —
-  the SAME kernel code is exercised by CPU tests and smokes).
+  already shipped. This is the production setting — the fused kernels
+  exist to move fewer HBM bytes per step, which only a real TPU pays
+  for.
+* ``fused_kernels = 1``: kernels are selected everywhere; on the CPU
+  backend they run under ``interpret=True`` (the SAME kernel code is
+  exercised by CPU tests and smokes). On a TPU backend they are always
+  compiled: a kernel the chip's compiler refuses raises, it never
+  gives way to its reference.
 * ``fused_kernels = 0``: jnp references everywhere — the escape hatch.
 * env ``CXXNET_FUSED_KERNELS`` overrides the config knob with the same
   values (ops-level kill switch that needs no config edit).
@@ -31,26 +32,24 @@ now with a one-time warning and a
 instead of a silent slow path.
 
 Every fused op returns ``None`` for unsupported shapes/dtypes and the
-caller falls back to its reference implementation, so selection is
-always safe — never an error.
+caller falls back to its reference implementation — counted by reason
+(:func:`note_fallback`), as a selected kernel is by op
+(:func:`note_fused`), into the model's selection log
+(:func:`selection_site`), which the trainer prints once.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import dataclasses
 import os
-from typing import Any, Optional, Sequence, Union
+from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 
 from ..config import parse_fused_mode
-
-try:  # same lazy-import guard as ops/attention.py: CPU-only installs
-    from jax.experimental import pallas as pl           # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu    # noqa: F401
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 #: dtypes the fused kernels accept as activation inputs; everything is
 #: accumulated in f32 inside the kernels regardless.
@@ -67,7 +66,7 @@ def kernels_active(mode: str) -> bool:
     env = os.environ.get("CXXNET_FUSED_KERNELS", "")
     if env:
         mode = resolve_mode(env)
-    if mode == "off" or not HAVE_PALLAS:
+    if mode == "off":
         return False
     if mode == "on":
         return True
@@ -89,26 +88,43 @@ class FusedSpmd:
 
 
 def island(spmd: FusedSpmd, fn, in_batch: Sequence[bool],
-           out_batch: Union[bool, Sequence[bool]]):
-    """Wrap ``fn`` in a fully-manual shard_map over EVERY mesh axis
-    (via parallel/compat.py, so jax-0.4.x spells it the same way):
+           out_batch: Union[bool, Sequence[bool]], interpret: bool):
+    """Wrap ``fn`` in a fully-manual shard_map over EVERY mesh axis:
     args flagged True in ``in_batch`` shard their leading dim over
     ``spmd.batch_axis``, the rest replicate; ``out_batch`` likewise
     for the outputs (a bare bool for a single output). Inside the
     island GSPMD never sees the pallas_call — the body is manual —
-    and any cross-shard reduction is the body's own explicit psum."""
-    from jax.sharding import PartitionSpec as P
+    and any cross-shard reduction is the body's own explicit psum.
 
-    from ..parallel.compat import shard_map
+    ``interpret``: whether the body's kernels run under the Pallas
+    interpreter. Compiled kernels are opaque calls whose outputs
+    declare their varying axes (:func:`out_struct`), so the island
+    keeps shard_map's ``check_vma``. The interpreter instead evaluates
+    the kernel body op by op on the shard's values, where its own
+    unvarying scratch meets varying blocks and the check refuses the
+    mix — interpreted islands run unchecked (same numerics; transposes
+    psum over the unmentioned axes instead of tracking them)."""
+    from jax.sharding import PartitionSpec as P
     bspec = P(spmd.batch_axis)
     in_specs = tuple(bspec if b else P() for b in in_batch)
     if isinstance(out_batch, bool):
         out_specs: Any = bspec if out_batch else P()
     else:
         out_specs = tuple(bspec if b else P() for b in out_batch)
-    return shard_map(fn, mesh=spmd.mesh, in_specs=in_specs,
-                     out_specs=out_specs,
-                     axis_names=set(spmd.mesh.axis_names))
+    return jax.shard_map(fn, mesh=spmd.mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(spmd.mesh.axis_names),
+                         check_vma=not interpret)
+
+
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """One ``out_shape`` entry of a ``pallas_call`` that may sit inside
+    an :func:`island`: under shard_map's ``check_vma`` the struct must
+    say over which mesh axes the output varies, and a kernel's output
+    varies wherever any of its ``operands`` does (the empty set outside
+    a shard_map)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def batch_divisible(spmd: Optional[FusedSpmd], leading: int) -> bool:
@@ -121,30 +137,93 @@ def batch_divisible(spmd: Optional[FusedSpmd], leading: int) -> bool:
 #: reasons already warned about (print once per process, count always)
 _FALLBACK_WARNED = set()
 
+#: the (log, site name) the fused ops called right now report into —
+#: bound by :func:`selection_site` around one layer's apply
+_SITE: contextvars.ContextVar = contextvars.ContextVar(
+    "cxxnet_fused_site", default=None)
+
+#: site name -> ("fused", op) | ("reference", reason) |
+#: ("attention", implementation)
+SelectionLog = Dict[str, Tuple[str, str]]
+
+
+@contextlib.contextmanager
+def selection_site(log: SelectionLog, name: str):
+    """Route :func:`note_fused` / :func:`note_fallback` calls made while
+    tracing site ``name`` (a layer, or an optimizer tag group) into
+    ``log``, which the model owns. Keyed by site, so a retrace
+    overwrites its own entry instead of counting twice."""
+    token = _SITE.set((log, name))
+    try:
+        yield
+    finally:
+        _SITE.reset(token)
+
+
+def _record(kind: str, what: str) -> None:
+    site = _SITE.get()
+    if site is not None:
+        site[0][site[1]] = (kind, what)
+
+
+def note_fused(op: str) -> None:
+    """Record that the site being traced took fused kernel ``op``."""
+    _record("fused", op)
+
+
+def note_attention(impl: str) -> None:
+    """Record which attention implementation the mha layer being
+    traced selected (``attn_impl = auto`` decides per backend and
+    sequence length)."""
+    _record("attention", impl)
+
 
 def note_fallback(reason: str, warn: Optional[str] = None) -> None:
-    """Record a fused-path fallback: always bumps
+    """Record a fused-path fallback: the site being traced took its
+    reference for ``reason``. Always bumps
     ``cxxnet_fused_fallback_total{reason}`` in the telemetry registry
     (visible in /metrics and fleet snapshots), and prints ``warn``
     once per process — a mesh run that silently loses its fused hot
     path is exactly the quiet misconfiguration telemetry exists for."""
-    try:
-        from ..telemetry.registry import get_registry
-        get_registry().counter(
-            "cxxnet_fused_fallback_total",
-            "fused kernel suite fallbacks to the reference path, "
-            "by reason", labels=("reason",)).labels(reason).inc()
-    except Exception:
-        pass
+    _record("reference", reason)
+    from ..telemetry.registry import get_registry
+    get_registry().counter(
+        "cxxnet_fused_fallback_total",
+        "fused kernel suite fallbacks to the reference path, "
+        "by reason", labels=("reason",)).labels(reason).inc()
     if warn and reason not in _FALLBACK_WARNED:
         _FALLBACK_WARNED.add(reason)
         print(f"fused_kernels: {warn} (reason={reason}; counted in "
               "cxxnet_fused_fallback_total)", flush=True)
 
 
+def selection_counts(log: SelectionLog):
+    """{kind: Counter(what)} over the log's sites (kind: ``fused`` /
+    ``reference`` / ``attention``)."""
+    by = collections.defaultdict(collections.Counter)
+    for kind, what in log.values():
+        by[kind][what] += 1
+    return by
+
+
+def selection_summary(log: SelectionLog) -> str:
+    """One line: how many sites took a fused kernel (by op) and how
+    many their reference (by reason)."""
+    by = selection_counts(log)
+    part = lambda c: ", ".join(f"{k}={v}" for k, v in sorted(c.items()))
+    line = (f"fused_kernels: {sum(by['fused'].values())} sites fused "
+            f"({part(by['fused'])}); {sum(by['reference'].values())} "
+            f"took the reference ({part(by['reference'])})")
+    if by["attention"]:
+        line += f"; attention: {part(by['attention'])}"
+    return line
+
+
 def use_interpret(interpret: Optional[bool]) -> bool:
-    """interpret=None auto-selects interpreter mode off-TPU — the same
-    kernel is exercised in CPU tests (flash_attention's contract)."""
+    """The one place a kernel's ``interpret`` flag is decided: ``None``
+    means compiled on a TPU backend and the Pallas interpreter on the
+    CPU backend (``dev = cpu``, the tests) — the same kernel code runs
+    either way."""
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret

@@ -24,13 +24,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, sublane_mult,
-                    supported_dtype, use_interpret)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, sublane_mult,
+                    supported_dtype, use_interpret)
 
 
 def bias_act_reference(x: jax.Array, bias: Optional[jax.Array],
@@ -64,15 +63,17 @@ def _epi_bwd_kernel(*refs, act, has_bias, nb):
         y_ref, dy_ref, dx_ref = refs
         db_ref = acc = None
     j = pl.program_id(0)
-    dyb = dy_ref[...]
+    # masked in f32 whatever the storage dtype: the v5e has no bf16
+    # vector compare (the widening and the cast back are exact)
+    dyb = dy_ref[...].astype(jnp.float32)
     if act == "relu":
-        dyb = jnp.where(y_ref[...] > 0, dyb, 0)
-    dx_ref[...] = dyb
+        dyb = jnp.where(y_ref[...].astype(jnp.float32) > 0.0, dyb, 0.0)
+    dx_ref[...] = dyb.astype(dx_ref.dtype)
     if has_bias:
         @pl.when(j == 0)
         def _init():
             acc[...] = jnp.zeros_like(acc)
-        acc[...] += jnp.sum(dyb.astype(jnp.float32), axis=0, keepdims=True)
+        acc[...] += jnp.sum(dyb, axis=0, keepdims=True)
 
         @pl.when(j == nb - 1)
         def _finish():
@@ -88,7 +89,7 @@ def _epi_act_2d(x2, act, interpret, bn):
         grid=(n // bn,),
         in_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), x2.dtype),
+        out_shape=out_struct((n, c), x2.dtype, x2),
         interpret=interpret,
     )(x2)
 
@@ -107,7 +108,7 @@ def _epi_act_bwd(act, interpret, bn, y, dy):
         in_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0)),
                   pl.BlockSpec((bn, c), lambda j: (j, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), y.dtype),
+        out_shape=out_struct((n, c), y.dtype, y, dy),
         interpret=interpret,
     )(y, dy)
     return (dx,)
@@ -125,7 +126,7 @@ def _epi_bias_2d(x2, bias, act, interpret, bn):
         in_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0)),
                   pl.BlockSpec((1, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), x2.dtype),
+        out_shape=out_struct((n, c), x2.dtype, x2),
         interpret=interpret,
     )(x2, bias.reshape(1, c))
 
@@ -146,8 +147,8 @@ def _epi_bias_bwd(act, interpret, bn, res, dy):
                   pl.BlockSpec((bn, c), lambda j: (j, 0))],
         out_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0)),
                    pl.BlockSpec((1, c), lambda j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, c), y.dtype),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
+        out_shape=[out_struct((n, c), y.dtype, y, dy),
+                   out_struct((1, c), jnp.float32, y, dy)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)],
         interpret=interpret,
     )(y, dy)
@@ -175,7 +176,8 @@ def _epi_bias_mesh(x, bias, act, interpret, bn, spmd):
         spmd, lambda xl, bl: _epi_bias_2d(
             xl.reshape(-1, c), bl, act, interpret, bn
         ).reshape(xl.shape),
-        in_batch=(True, False), out_batch=True)(x, bias)
+        in_batch=(True, False), out_batch=True,
+        interpret=interpret)(x, bias)
 
 
 def _epi_bias_mesh_fwd(x, bias, act, interpret, bn, spmd):
@@ -197,15 +199,15 @@ def _epi_bias_mesh_bwd(act, interpret, bn, spmd, res, dy):
                       pl.BlockSpec((bn, c), lambda j: (j, 0))],
             out_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0)),
                        pl.BlockSpec((1, c), lambda j: (0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((n, c), yl.dtype),
-                       jax.ShapeDtypeStruct((1, c), jnp.float32)],
+            out_shape=[out_struct((n, c), yl.dtype, yl, dyl),
+                       out_struct((1, c), jnp.float32, yl, dyl)],
             scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)],
             interpret=interpret,
         )(yl.reshape(n, c), dyl.reshape(n, c))
         db = jax.lax.psum(db, spmd.batch_axis)
         return dx2.reshape(yl.shape), db
     dx, db = island(spmd, local, in_batch=(True, True),
-                    out_batch=(True, False))(y, dy)
+                    out_batch=(True, False), interpret=interpret)(y, dy)
     return dx, db.reshape(bias.shape).astype(bias.dtype)
 
 
@@ -220,12 +222,11 @@ def fused_bias_act(x: jax.Array, bias: Optional[jax.Array],
     Returns y (x.dtype) or ``None`` when unsupported / nothing to
     fuse. With ``spmd`` the kernels run as shard_map islands on the
     mesh (dbias psum'd over the data axis in the backward)."""
-    if not HAVE_PALLAS or not supported_dtype(x):
-        return None
-    if x.ndim != 4 or act not in ("none", "relu"):
-        return None
     if bias is None and act == "none":
         return None                      # nothing to fuse
+    if not supported_dtype(x) or x.ndim != 4 or act not in ("none", "relu"):
+        note_fallback("epilogue_unsupported")
+        return None
     c = x.shape[-1]
     n = x.size // c
     if spmd is not None:
@@ -238,16 +239,16 @@ def fused_bias_act(x: jax.Array, bias: Optional[jax.Array],
     target = max(8, min(block_rows, (1 << 20) // max(4 * c, 1) // 8 * 8))
     bn = row_block(n_local, target, mult=sublane_mult(x))
     if bn is None or (bias is not None and bias.shape != (c,)):
-        if spmd is not None:
-            note_fallback("epilogue_shape")
+        note_fallback("epilogue_shape")
         return None
+    note_fused("bias_act")
     itp = use_interpret(interpret)
     if spmd is not None:
         if bias is None:
             return island(
                 spmd, lambda xl: _epi_act_2d(
                     xl.reshape(-1, c), act, itp, bn).reshape(xl.shape),
-                in_batch=(True,), out_batch=True)(x)
+                in_batch=(True,), out_batch=True, interpret=itp)(x)
         return _epi_bias_mesh(x, bias, act, itp, bn, spmd)
     x2 = x.reshape(n, c)
     if bias is None:

@@ -10,7 +10,7 @@ final product in a single pass — one streaming read of x, one write
 of y. The backward fuses the whole dx formula (including the
 transposed-window term) into one kernel of its own, recomputing norm
 from x in VMEM instead of saving it (HBM bytes are the scarce
-resource, BENCH_r02–r04).
+resource of this step).
 
 The channel window-sum is expressed as a matmul against a static
 (C, C) band matrix — MXU-friendly, supported everywhere, and exact:
@@ -31,12 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, sublane_mult,
-                    supported_dtype, use_interpret)
+from jax.experimental import pallas as pl
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, sublane_mult,
+                    supported_dtype, use_interpret)
 
 
 def lrn_reference(x: jax.Array, nsize: int, alpha: float, beta: float,
@@ -99,7 +98,7 @@ def _lrn_2d(x2, band, bandt, ab, beta, knorm, interpret, bn):
         in_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0)),
                   pl.BlockSpec((c, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), x2.dtype),
+        out_shape=out_struct((n, c), x2.dtype, x2),
         interpret=interpret,
     )(x2, band)
 
@@ -121,7 +120,7 @@ def _lrn_bwd(ab, beta, knorm, interpret, bn, res, dy):
                   pl.BlockSpec((c, c), lambda j: (0, 0)),
                   pl.BlockSpec((c, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), x2.dtype),
+        out_shape=out_struct((n, c), x2.dtype, x2, dy),
         interpret=interpret,
     )(x2, dy, band, bandt)
     # band/bandt are trace-time constants; zero cotangents (DCE'd)
@@ -141,13 +140,13 @@ def fused_lrn(x: jax.Array, nsize: int, alpha: float, beta: float,
     row-local (the window runs over channels), so the island needs no
     collectives and its shard_map transpose is exact; the band
     matrices ride as closed-over constants."""
-    if not HAVE_PALLAS or not supported_dtype(x):
-        return None
-    if x.ndim != 4 or knorm <= 0:
+    if not supported_dtype(x) or x.ndim != 4 or knorm <= 0:
+        note_fallback("lrn_unsupported")
         return None
     c = x.shape[-1]
     n = x.size // c
     if c > 1024:          # (C, C) band must stay comfortably in VMEM
+        note_fallback("lrn_channels")
         return None
     if spmd is not None:
         if not batch_divisible(spmd, x.shape[0]):
@@ -159,16 +158,17 @@ def fused_lrn(x: jax.Array, nsize: int, alpha: float, beta: float,
     target = max(8, min(block_rows, (1 << 20) // max(4 * c, 1) // 8 * 8))
     bn = row_block(n_local, target, mult=sublane_mult(x))
     if bn is None:
-        if spmd is not None:
-            note_fallback("lrn_shape")
+        note_fallback("lrn_shape")
         return None
+    note_fused("lrn")
     band = jnp.asarray(band_matrix(c, nsize))
+    itp = use_interpret(interpret)
     args = (band, band.T, float(alpha) / nsize, float(beta),
-            float(knorm), use_interpret(interpret), bn)
+            float(knorm), itp, bn)
     if spmd is not None:
         return island(
             spmd, lambda xl: _lrn_2d(xl.reshape(-1, c),
                                      *args).reshape(xl.shape),
-            in_batch=(True,), out_batch=True)(x)
+            in_batch=(True,), out_batch=True, interpret=itp)(x)
     y = _lrn_2d(x.reshape(n, c), *args)
     return y.reshape(x.shape)

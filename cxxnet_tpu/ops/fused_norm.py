@@ -1,8 +1,7 @@
 """Fused BatchNorm + activation: Pallas TPU kernels + jnp reference.
 
-The flagship Inception-BN step is memory-bound (BENCH_r02–r04:
-roofline_pct ~100–105% at arith_intensity ~64), and its dominant
-non-conv HBM traffic is the conv -> batch_norm -> relu chain: the jnp
+The dominant non-conv HBM traffic of the flagship Inception-BN step
+is the conv -> batch_norm -> relu chain: the jnp
 path reads the conv output for the moments, again for the normalize,
 and writes the normalized activation, with the relu riding a fourth
 logical pass XLA must fuse back in. The fused kernel does moments,
@@ -42,13 +41,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, sublane_mult,
-                    supported_dtype, use_interpret)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, sublane_mult,
+                    supported_dtype, use_interpret)
 
 
 def bn_act_reference(x: jax.Array, gamma: jax.Array, beta: jax.Array,
@@ -153,9 +151,9 @@ def _bn_forward(x2, gamma, beta, eps, act, two_pass, interpret, bn):
         grid=(sweeps * nb,),
         in_specs=[row_spec, vec_spec, vec_spec],
         out_specs=[row_spec, vec_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, c), x2.dtype),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
+        out_shape=[out_struct((n, c), x2.dtype, x2),
+                   out_struct((1, c), jnp.float32, x2),
+                   out_struct((1, c), jnp.float32, x2)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32),
                         pltpu.VMEM((1, c), jnp.float32)],
         interpret=interpret,
@@ -226,9 +224,9 @@ def _bn_backward(x2, gamma, mean, rstd, y2, dy2, act, interpret, bn):
         grid=(2 * nb,),
         in_specs=in_specs,
         out_specs=[row_spec, vec_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, c), x2.dtype),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
+        out_shape=[out_struct((n, c), x2.dtype, x2),
+                   out_struct((1, c), jnp.float32, x2),
+                   out_struct((1, c), jnp.float32, x2)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32),
                         pltpu.VMEM((1, c), jnp.float32)],
         interpret=interpret,
@@ -285,8 +283,7 @@ _bn_act_2d.defvjp(_bn_act_fwd, _bn_act_bwd)
 # backward's cross-shard reductions (dgamma/dbeta and the dx formula's
 # sum terms) psum the same way. custom_vjp sits OUTSIDE the islands —
 # fwd and bwd are each their own shard_map — so autodiff never
-# transposes a shard_map (whose 0.4.x transpose rules the psum'd
-# replicated outputs would confuse).
+# transposes a shard_map.
 
 def _bn_sums_kernel(x_ref, s1_ref, s2_ref, acc1, acc2, *, nb):
     """One streaming read: per-channel local (sum, sum of squares)."""
@@ -382,7 +379,7 @@ def _mesh_fwd_local(x, gamma, beta, *, c, eps, act, interpret, bn, axis,
     s1, s2 = pl.pallas_call(
         functools.partial(_bn_sums_kernel, nb=nb),
         grid=(nb,), in_specs=[row], out_specs=[vec, vec],
-        out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32)] * 2,
+        out_shape=[out_struct((1, c), jnp.float32, x2)] * 2,
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)] * 2,
         interpret=interpret)(x2)
     s1 = jax.lax.psum(s1, axis)
@@ -394,7 +391,7 @@ def _mesh_fwd_local(x, gamma, beta, *, c, eps, act, interpret, bn, axis,
     y2 = pl.pallas_call(
         functools.partial(_bn_norm_kernel, act=act),
         grid=(nb,), in_specs=[row, vec, vec, vec, vec], out_specs=row,
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+        out_shape=out_struct(x2.shape, x2.dtype, x2),
         interpret=interpret)(x2, gamma.reshape(1, c),
                              beta.reshape(1, c), mean, rstd)
     return (y2.reshape(x.shape), mean.reshape(c), var.reshape(c),
@@ -417,7 +414,7 @@ def _mesh_bwd_local(x, dy, y, gamma, mean, rstd, *, c, act, interpret,
     sb, sxh = pl.pallas_call(
         functools.partial(_bn_bwd_sums_kernel, nb=nb, act=act),
         grid=(nb,), in_specs=in_specs, out_specs=[vec, vec],
-        out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32)] * 2,
+        out_shape=[out_struct((1, c), jnp.float32, x2)] * 2,
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)] * 2,
         interpret=interpret)(*ins)
     sb = jax.lax.psum(sb, axis)
@@ -430,7 +427,7 @@ def _mesh_bwd_local(x, dy, y, gamma, mean, rstd, *, c, act, interpret,
     dx2 = pl.pallas_call(
         functools.partial(_bn_bwd_dx_kernel, act=act),
         grid=(nb,), in_specs=in_specs2, out_specs=row,
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+        out_shape=out_struct(x2.shape, x2.dtype, x2),
         interpret=interpret)(*ins2)
     return dx2.reshape(x.shape), sxh.reshape(c), sb.reshape(c)
 
@@ -443,7 +440,8 @@ def _bn_act_mesh(x, gamma, beta, eps, act, interpret, bn, spmd, n_total):
             interpret=interpret, bn=bn, axis=spmd.batch_axis,
             n_total=n_total),
         in_batch=(True, False, False),
-        out_batch=(True, False, False, False))(x, gamma, beta)
+        out_batch=(True, False, False, False),
+        interpret=interpret)(x, gamma, beta)
     return y, mean, var
 
 
@@ -455,7 +453,8 @@ def _bn_act_mesh_fwd(x, gamma, beta, eps, act, interpret, bn, spmd,
             interpret=interpret, bn=bn, axis=spmd.batch_axis,
             n_total=n_total),
         in_batch=(True, False, False),
-        out_batch=(True, False, False, False))(x, gamma, beta)
+        out_batch=(True, False, False, False),
+        interpret=interpret)(x, gamma, beta)
     res = (x, gamma, mean, rstd, y if act == "relu" else None)
     return (y, mean, var), res
 
@@ -472,7 +471,8 @@ def _bn_act_mesh_bwd(eps, act, interpret, bn, spmd, n_total, res, cts):
             _mesh_bwd_local, c=x.shape[-1], act=act, interpret=interpret,
             bn=bn, axis=spmd.batch_axis, n_total=n_total),
         in_batch=(True, True, True, False, False, False),
-        out_batch=(True, False, False))(x, dy, y, gamma, mean, rstd)
+        out_batch=(True, False, False),
+        interpret=interpret)(x, dy, y, gamma, mean, rstd)
     return (dx, dgamma.reshape(gamma.shape).astype(gamma.dtype),
             dbeta.reshape(gamma.shape).astype(gamma.dtype))
 
@@ -491,9 +491,8 @@ def fused_bn_act(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     (caller falls back to the jnp reference). With ``spmd`` the op
     runs as a shard_map island on the mesh — moments are psum'd over
     the data axis (sync-BN) so the math matches the GSPMD jnp path."""
-    if not HAVE_PALLAS or not supported_dtype(x):
-        return None
-    if x.ndim != 4 or act not in ("none", "relu"):
+    if not supported_dtype(x) or x.ndim != 4 or act not in ("none", "relu"):
+        note_fallback("bn_unsupported")
         return None
     c = x.shape[-1]
     n = x.size // c
@@ -514,9 +513,9 @@ def fused_bn_act(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     target = max(8, min(block_rows, (1 << 20) // max(4 * c, 1) // 8 * 8))
     bn = row_block(n_local, target, mult=sublane_mult(x))
     if bn is None or gamma.shape != (c,) or beta.shape != (c,):
-        if spmd is not None:
-            note_fallback("bn_shape")
+        note_fallback("bn_shape")
         return None
+    note_fused("bn_act")
     if spmd is not None:
         y, mean, var = _bn_act_mesh(x, gamma, beta, float(eps), act,
                                     use_interpret(interpret), bn, spmd,

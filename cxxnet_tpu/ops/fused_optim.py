@@ -40,10 +40,9 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .fused import HAVE_PALLAS, FusedSpmd, island, use_interpret
+from jax.experimental import pallas as pl
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
+from .fused import FusedSpmd, island, out_struct, use_interpret
 
 _LANES = 128
 
@@ -115,7 +114,7 @@ def _run(kern, scalars, mats, n_out, block_rows, interpret):
     grid = (rows // block_rows,)
     row_spec = pl.BlockSpec((block_rows, _LANES), lambda j: (j, 0))
     s_spec = pl.BlockSpec((1, 2), lambda j: (0, 0))
-    shape = jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)
+    shape = out_struct((rows, _LANES), jnp.float32, *mats)
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -146,7 +145,8 @@ def fused_sgd_apply(ws: List[jax.Array], gs: List[jax.Array],
             spmd, lambda w_, g_, m_, lr_, mom_: fused_sgd_apply(
                 w_, g_, m_, lr_, mom_, wd=wd, clip=clip, nag=nag,
                 interpret=interpret, block_rows=block_rows),
-            in_batch=(False,) * 5, out_batch=(False, False)
+            in_batch=(False,) * 5, out_batch=(False, False),
+            interpret=use_interpret(interpret)
         )(list(ws), list(gs), list(ms), jnp.asarray(lr, jnp.float32),
           jnp.asarray(momentum, jnp.float32))
     shapes = [w.shape for w in ws]
@@ -178,7 +178,8 @@ def fused_adam_apply(ws: List[jax.Array], gs: List[jax.Array],
             spmd, lambda w_, g_, a_, b_, lr_: fused_adam_apply(
                 w_, g_, a_, b_, lr_, wd=wd, clip=clip, d1=d1, d2=d2,
                 interpret=interpret, block_rows=block_rows),
-            in_batch=(False,) * 5, out_batch=(False, False, False)
+            in_batch=(False,) * 5, out_batch=(False, False, False),
+            interpret=use_interpret(interpret)
         )(list(ws), list(gs), list(m1s), list(m2s),
           jnp.asarray(lr_t, jnp.float32))
     shapes = [w.shape for w in ws]

@@ -40,12 +40,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, sublane_mult,
-                    supported_dtype, use_interpret)
+from jax.experimental import pallas as pl
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, sublane_mult,
+                    supported_dtype, use_interpret)
 
 #: max windows larger than this fall back (the first-match sweep is a
 #: statically unrolled per-cell loop)
@@ -95,10 +94,12 @@ def _pool_bwd_max_kernel(x_ref, y_ref, dy_ref, dx_ref, *, kh, kw,
     cell equal to the window max takes the whole cotangent (XLA
     select-and-scatter parity). ``pre_relu`` additionally masks cells
     that are not strictly positive (relu's zero-at-zero gradient)."""
-    x = x_ref[...]
-    xa = jnp.maximum(x, 0) if pre_relu else x
-    ymax = y_ref[...]                       # (rb, ox, C)
-    dyv = dy_ref[...]
+    # compared in f32 whatever the storage dtype: the v5e has no bf16
+    # vector compare (widening is exact, so the first match is the same)
+    x = x_ref[...].astype(jnp.float32)
+    xa = jnp.maximum(x, 0.0) if pre_relu else x
+    ymax = y_ref[...].astype(jnp.float32)   # (rb, ox, C)
+    dyv = dy_ref[...].astype(jnp.float32)
     taken = jnp.zeros(ymax.shape, jnp.bool_)
     for dy in range(kh):
         for dx in range(kw):
@@ -134,7 +135,7 @@ def _fwd_call(xr, reducer, pre_relu, scale, interpret, rb):
         in_specs=[pl.BlockSpec((rb, kh, ox, kw, c),
                                lambda i: (i, 0, 0, 0, 0))],
         out_specs=pl.BlockSpec((rb, ox, c), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, ox, c), xr.dtype),
+        out_shape=out_struct((n, ox, c), xr.dtype, xr),
         interpret=interpret,
     )(xr)
 
@@ -150,7 +151,7 @@ def _bwd_call(xr, y, dy, reducer, pre_relu, scale, interpret, rb):
             kern, grid=(n // rb,),
             in_specs=[row5, row3, row3],
             out_specs=row5,
-            out_shape=jax.ShapeDtypeStruct(xr.shape, xr.dtype),
+            out_shape=out_struct(xr.shape, xr.dtype, xr, dy),
             interpret=interpret,
         )(xr, y, dy)
     kern = functools.partial(_pool_bwd_lin_kernel, kh=kh, kw=kw,
@@ -159,7 +160,7 @@ def _bwd_call(xr, y, dy, reducer, pre_relu, scale, interpret, rb):
         kern, grid=(n // rb,),
         in_specs=[row3],
         out_specs=row5,
-        out_shape=jax.ShapeDtypeStruct(xr.shape, xr.dtype),
+        out_shape=out_struct(xr.shape, xr.dtype, dy),
         interpret=interpret,
     )(dy)
 
@@ -199,18 +200,19 @@ def fused_pool(x: jax.Array, kh: int, kw: int, stride: int,
     pad/extra must be 0 and windows must either tile exactly
     (stride == kh == kw, H % kh == 0, W % kw == 0) or be the single
     global window (kh == H and kw == W)."""
-    if not HAVE_PALLAS or not supported_dtype(x) or x.ndim != 4:
-        return None
-    if reducer not in ("max", "sum"):
-        return None
-    if pad != (0, 0) or extra != (0, 0):
+    if not supported_dtype(x) or x.ndim != 4 \
+            or reducer not in ("max", "sum"):
+        note_fallback("pool_unsupported")
         return None
     b, h, w, c = x.shape
-    if kh == h and kw == w:
-        pass                                     # global single window
-    elif not (stride == kh == kw and h % kh == 0 and w % kw == 0):
+    tiles = stride == kh == kw and h % kh == 0 and w % kw == 0
+    if pad != (0, 0) or extra != (0, 0) \
+            or not (tiles or (kh == h and kw == w)):
+        # overlapping, padded or ceil-mode windows: reduce_window
+        note_fallback("pool_geometry")
         return None
     if reducer == "max" and kh * kw > MAX_FIRST_MATCH_CELLS:
+        note_fallback("pool_max_window")
         return None
     oy, ox = h // kh if kh != h else 1, w // kw if kw != w else 1
     scale = 1.0 / (kh * kw) if scale_avg else 1.0
@@ -222,15 +224,22 @@ def fused_pool(x: jax.Array, kh: int, kw: int, stride: int,
         n_local = n // spmd.n_shards
     else:
         n_local = n
-    # VMEM budget: one (rb, kh, ox, kw, C) block + its output
-    per_row = kh * ox * kw * c * max(x.dtype.itemsize, 2)
-    target = max(8, min(block_rows, (1 << 20) // max(per_row, 1)
-                        // 8 * 8))
-    rb = row_block(n_local, target, mult=sublane_mult(x))
+    # VMEM budget: ~1 MiB per (rb, kh, ox, kw, C) block as it is LAID
+    # OUT — the two minor dims pad to the dtype's (sublane, 128) tile,
+    # so a kw = 2 window costs a whole tile of sublanes (the max
+    # backward holds two such blocks, double-buffered, plus their f32
+    # working copies, inside the chip's 16 MiB of scoped VMEM). rb is
+    # a leading block dim on both sides, so any divisor of the rows
+    # will do: no sublane multiple is needed.
+    sub = sublane_mult(x)
+    per_row = (kh * ox * -(-kw // sub) * sub * -(-c // 128) * 128
+               * x.dtype.itemsize)
+    target = max(1, min(block_rows, (1 << 20) // per_row))
+    rb = row_block(n_local, target, mult=1)
     if rb is None:
-        if spmd is not None:
-            note_fallback("pool_shape")
+        note_fallback("pool_shape")
         return None
+    note_fused("pool")
     itp = use_interpret(interpret)
     if spmd is not None:
         # pooling is row-local (windows never cross the batch dim):
@@ -240,7 +249,7 @@ def fused_pool(x: jax.Array, kh: int, kw: int, stride: int,
                 xl.reshape(-1, kh, ox, kw, c), reducer, pre_relu,
                 float(scale), itp, rb
             ).reshape(xl.shape[0], oy, ox, c),
-            in_batch=(True,), out_batch=True)(x)
+            in_batch=(True,), out_batch=True, interpret=itp)(x)
     xr = x.reshape(n, kh, ox, kw, c)
     y = _pool5(xr, reducer, pre_relu, float(scale), itp, rb)
     return y.reshape(b, oy, ox, c)

@@ -29,11 +29,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, use_interpret)
+from jax.experimental import pallas as pl
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, use_interpret)
 
 
 def quantize_act(x: jax.Array, act_scale) -> jax.Array:
@@ -99,7 +98,7 @@ def _q_mm_pallas(xq, wq, factor, bias, act, bm, bn, interpret):
         grid=(m // bm, n // bn),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        out_shape=out_struct((m, n), jnp.float32, xq),
         interpret=interpret,
     )(*args)
 
@@ -130,7 +129,7 @@ def int8_matmul(x: jax.Array, wq: jax.Array, w_scale: jax.Array,
     with weights/scales replicated, matching the PR-9 plumbing."""
     xq = quantize_act(x, act_scale)
     factor = dequant_factor(w_scale, act_scale)
-    if fused and HAVE_PALLAS and act in ("none", "relu"):
+    if fused and act in ("none", "relu"):
         m = xq.shape[0]
         m_local = m
         if spmd is not None:
@@ -142,6 +141,7 @@ def int8_matmul(x: jax.Array, wq: jax.Array, w_scale: jax.Array,
         blocks = _mm_blocks(m_local, xq.shape[1], wq.shape[1])
         if blocks is not None:
             bm, bn = blocks
+            note_fused("int8_matmul")
             itp = use_interpret(interpret)
             if spmd is not None:
                 return island(
@@ -149,7 +149,7 @@ def int8_matmul(x: jax.Array, wq: jax.Array, w_scale: jax.Array,
                     lambda xl, wl, fl, bl: _q_mm_pallas(
                         xl, wl, fl, bl, act, bm, bn, itp),
                     in_batch=(True, False, False, False),
-                    out_batch=True)(xq, wq, factor, bias)
+                    out_batch=True, interpret=itp)(xq, wq, factor, bias)
             return _q_mm_pallas(xq, wq, factor, bias, act, bm, bn, itp)
         note_fallback("quant_mm_shape")
     acc = lax.dot_general(xq, wq, (((1,), (0,)), ((), ())),

@@ -1,6 +1,6 @@
 """Fused uint8 stem decode-normalize: Pallas TPU kernel + jnp reference.
 
-The ``device_normalize`` input path (doc/e2e_input.md) ships uint8
+The ``device_normalize`` input path ships uint8
 batches (4x smaller H2D) and normalizes on-device — but as a SEPARATE
 jitted dispatch that reads the uint8 batch and writes a full fp32 copy
 the train step then re-reads. Per pixel that is 1 (u8 read) + 4 (f32
@@ -41,11 +41,10 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from .fused import (HAVE_PALLAS, FusedSpmd, batch_divisible, island,
-                    note_fallback, row_block, use_interpret)
+from jax.experimental import pallas as pl
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
+from .fused import (FusedSpmd, batch_divisible, island, note_fallback,
+                    note_fused, out_struct, row_block, use_interpret)
 
 
 def decode_normalize_reference(x: jax.Array, mean: Optional[jax.Array],
@@ -67,7 +66,8 @@ def _stem_kernel(*refs, has_mean):
     else:
         x_ref, f_ref, y_ref = refs
         mean_ref = None
-    y = x_ref[...].astype(jnp.float32)
+    # widened through int32: Mosaic has no direct uint8 -> float32 cast
+    y = x_ref[...].astype(jnp.int32).astype(jnp.float32)
     if mean_ref is not None:
         y = y - mean_ref[...]
     y = y * f_ref[...]
@@ -91,7 +91,7 @@ def _stem_call(x2, mean_row, factor, out_dtype, interpret, rb, cb):
         grid=(n // rb, cols // cb),
         in_specs=in_specs,
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((n, cols), out_dtype),
+        out_shape=out_struct((n, cols), out_dtype, x2),
         interpret=interpret,
     )(*ins)
 
@@ -120,7 +120,8 @@ def fused_decode_normalize(x: jax.Array, mean: Optional[jax.Array],
     None when the shape is unsupported (caller uses the jnp
     reference). With ``spmd`` the pass runs as a shard_map island over
     the batch dim (pure data path — no collectives, no vjp)."""
-    if not HAVE_PALLAS or x.dtype != jnp.uint8 or x.ndim != 4:
+    if x.dtype != jnp.uint8 or x.ndim != 4:
+        note_fallback("stem_unsupported")
         return None
     b, h, w, c = x.shape
     cols = h * w * c
@@ -137,8 +138,7 @@ def fused_decode_normalize(x: jax.Array, mean: Optional[jax.Array],
                                                        mult=8)
     cb = _col_block(cols)
     if rb is None or cb is None:
-        if spmd is not None:
-            note_fallback("stem_shape")
+        note_fallback("stem_shape")
         return None
     if mean is not None:
         mean = jnp.asarray(mean, jnp.float32)
@@ -150,9 +150,11 @@ def fused_decode_normalize(x: jax.Array, mean: Optional[jax.Array],
         elif mean.shape == (h, w, c):
             mean_row = mean.reshape(1, cols)
         else:
+            note_fallback("stem_mean_shape")
             return None
     else:
         mean_row = None
+    note_fused("stem")
     factor = jnp.asarray(factor, jnp.float32)
     itp = use_interpret(interpret)
     if spmd is not None:
@@ -164,14 +166,15 @@ def fused_decode_normalize(x: jax.Array, mean: Optional[jax.Array],
                                  f, jnp.dtype(out_dtype), itp, rb, cb)
                 return y2l.reshape(xl.shape)
             return island(spmd, local, in_batch=(True, False, False),
-                          out_batch=True)(x, mean_row, factor)
+                          out_batch=True,
+                          interpret=itp)(x, mean_row, factor)
 
         def local(xl, f):
             y2l = _stem_call(xl.reshape(-1, cols), None, f,
                              jnp.dtype(out_dtype), itp, rb, cb)
             return y2l.reshape(xl.shape)
         return island(spmd, local, in_batch=(True, False),
-                      out_batch=True)(x, factor)
+                      out_batch=True, interpret=itp)(x, factor)
     y2 = _stem_call(x.reshape(b, cols), mean_row, factor,
                     jnp.dtype(out_dtype), itp, rb, cb)
     return y2.reshape(b, h, w, c)

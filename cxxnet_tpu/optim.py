@@ -205,6 +205,9 @@ class Optimizer:
             global_param(cfg, "fused_kernels", "auto"))
         self.fused_ok = True
         self.fused_spmd = None
+        # where the fused apply records its selection (ops.fused.
+        # SelectionLog); the trainer points it at the network's log
+        self.fused_log: Dict[str, Any] = {}
         self.ls_init = float(global_param(cfg, "loss_scale_init",
                                           str(2.0 ** 15)))
         self.ls_window = int(global_param(cfg, "loss_scale_window", "200"))
@@ -382,10 +385,14 @@ class Optimizer:
         exact per-leaf parity with _apply below, asserted by
         tests/test_fused_ops.py. Returns None when the trees are not
         uniformly f32 (caller falls back)."""
+        from .ops.fused import note_fallback, note_fused, selection_site
         from .ops.fused_optim import fused_adam_apply, fused_sgd_apply
         got = self._leaf_groups(params)
-        if got is None:
-            return None
+        with selection_site(self.fused_log, "optimizer"):
+            if got is None:
+                note_fallback("optimizer_mixed_dtype")
+                return None
+            note_fused(f"{self.type}_apply")
         wl, treedef, groups = got
         gl = jax.tree_util.tree_leaves(grads)
         if self.type == "adam":

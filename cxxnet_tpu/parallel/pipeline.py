@@ -17,42 +17,12 @@ API: stage parameters are pytrees with a leading stage axis (S, ...);
 from __future__ import annotations
 
 import functools
-import os
-import re
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import shard_map
-
-# The pvary helpers below probe varying-manual-axes APIs (jax.typeof().vma,
-# lax.pcast(..., to="varying"), lax.pvary) behind broad except clauses, and
-# the deadlock-avoidance scheme in pipeline_apply_stages depends on those
-# casts actually happening. Fail loudly on JAX versions where the probed
-# semantics were never validated instead of silently skipping the casts.
-_VALIDATED_JAX = ((0, 9), (0, 10))       # inclusive (minor-version) range
-# tolerate suffixed components ('0.10rc1') — take the leading digits; a
-# completely non-numeric component counts as 0 so the gate still raises the
-# curated ImportError below rather than a bare ValueError at import time
-_jax_ver = tuple(
-    int(m.group()) if (m := re.match(r"\d+", v)) else 0
-    for v in jax.__version__.split(".")[:2])
-if not (_VALIDATED_JAX[0] <= _jax_ver <= _VALIDATED_JAX[1]) \
-        and os.environ.get("CXXNET_PP_VALIDATE") != "1":
-    # CXXNET_PP_VALIDATE=1 bypasses the gate so tools/validate_pp_jax.py
-    # can exercise the semantics on a candidate jax version — see
-    # doc/multichip.md "Re-validating pipeline parallelism"
-    raise ImportError(
-        f"cxxnet_tpu pipeline parallelism was validated on jax "
-        f"{_VALIDATED_JAX[0][0]}.{_VALIDATED_JAX[0][1]}–"
-        f"{_VALIDATED_JAX[1][0]}.{_VALIDATED_JAX[1][1]} only, found "
-        f"{jax.__version__}: the varying-axis casts it relies on "
-        f"(lax.pcast/pvary) are version-sensitive and load-bearing for "
-        f"collective ordering. Re-run tests/test_parallel_ext.py on this "
-        f"version, then widen _VALIDATED_JAX here.")
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -79,10 +49,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     perm = [(i, (i + 1) % S) for i in range(S)]
 
     def pvary(a):
-        try:
-            return lax.pcast(a, (axis_name,), to="varying")
-        except (AttributeError, TypeError):
-            return lax.pvary(a, (axis_name,))
+        return lax.pcast(a, (axis_name,), to="varying")
 
     # per-device "current activation" register and output accumulator
     state0 = pvary(jnp.zeros((mb,) + xs.shape[2:], x.dtype))
@@ -119,7 +86,6 @@ def pipeline_apply_stages(stage_fns, params: Any, x: jax.Array, aux: Any,
                           axis_name: str, n_microbatch: int,
                           boundary_sd, out_sd,
                           extra_vary_axes=(),
-                          grad_sum_axes=(),
                           stats_sd=None):
     """GPipe schedule over HETEROGENEOUS stages (the config-driven path).
 
@@ -168,9 +134,11 @@ def pipeline_apply_stages(stage_fns, params: Any, x: jax.Array, aux: Any,
     autodiff is not an option: transposing a device-index ``lax.switch``
     whose branches contain pvary boundaries inserts collectives into SOME
     branches only, so devices diverge in collective order and deadlock.
-    ``grad_sum_axes``: extra axes (e.g. the data axis) to sum the param
-    cotangent over so it leaves the vjp replicated, like the params came
-    in. Not twice-differentiable (the custom backward is primal-only).
+    ``extra_vary_axes``: the other manual axes of the enclosing
+    shard_map (data, manual-tp model, seq) — the schedule's carries vary
+    over them, and the param cotangent is summed over them so it leaves
+    the vjp replicated, like the params came in. Not
+    twice-differentiable (the custom backward is primal-only).
     """
     S = len(stage_fns)
     M = n_microbatch
@@ -189,17 +157,9 @@ def pipeline_apply_stages(stage_fns, params: Any, x: jax.Array, aux: Any,
         # vary only over the axes the value is not already varying on
         # (pcast rejects mixed-state axis lists)
         want = axes if want is None else want
-        try:
-            have = set(jax.typeof(a).vma)
-        except Exception:
-            have = set()
+        have = jax.typeof(a).vma
         need = tuple(ax for ax in want if ax not in have)
-        if not need:
-            return a
-        try:
-            return lax.pcast(a, need, to="varying")
-        except (AttributeError, TypeError):
-            return lax.pvary(a, need)
+        return lax.pcast(a, need, to="varying") if need else a
 
     if stats_sd is None:
         stats_sd = {}
@@ -382,14 +342,28 @@ def pipeline_apply_stages(stage_fns, params: Any, x: jax.Array, aux: Any,
         (_, dp_acc, dxs), _ = lax.scan(
             rtick, (dreg0, dp0, dxs0), jnp.arange(T - 1, -1, -1))
         # params entered replicated: sum the per-device stage contributions
-        # over the pipe axis (and the data axes) so the cotangent leaves
-        # replicated too. The pipe-axis psum covers dp AND dxs in one call,
-        # and the data-axis psum consumes its result — every collective in
-        # the backward chains, none can interleave with the ring.
+        # over the pipe axis so the cotangent leaves replicated too. The
+        # pipe-axis psum covers dp AND dxs in one call, and the psums
+        # over the remaining axes consume its result — every collective
+        # in the backward chains, none can interleave with the ring.
         dp_acc, dxs = lax.psum((dp_acc, dxs), axis_name)
-        if grad_sum_axes:
-            dp_acc = lax.psum(dp_acc, tuple(grad_sum_axes))
-        dx = dxs.reshape(x.shape).astype(x.dtype)
+        # a cotangent is summed over every axis its primal is REPLICATED
+        # over (each peer there holds a partial contribution: data
+        # shards, manual-tp slices), and must leave typed as the primal
+        # came in — custom_vjp checks the varying axes. Params vary over
+        # at most the pipe axis (an all-gathered FSDP leaf is typed
+        # varying there; its summed cotangent is cast to match).
+        if extra_vary_axes:
+            dp_acc = lax.psum(dp_acc, tuple(extra_vary_axes))
+        dp_acc = jax.tree_util.tree_map(
+            lambda ct, pr: pvary(ct, tuple(jax.typeof(pr).vma)),
+            dp_acc, params)
+        x_rep = tuple(ax for ax in extra_vary_axes
+                      if ax not in jax.typeof(x).vma)
+        if x_rep:
+            dxs = lax.psum(dxs, x_rep)
+        dx = pvary(dxs.reshape(x.shape).astype(x.dtype),
+                   tuple(jax.typeof(x).vma))
         daux = jax.tree_util.tree_map(jnp.zeros_like, aux_)
         return dp_acc, dx, daux
 
@@ -403,7 +377,7 @@ def pipeline_sharded(mesh: Mesh, stage_fn, stage_params, x: jax.Array,
     ``pipe_axis``; x is replicated; returns the full-batch output."""
     pparam_spec = jax.tree_util.tree_map(
         lambda _: P(pipe_axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(pipeline_apply, stage_fn, axis_name=pipe_axis,
                           n_microbatch=n_microbatch),
         mesh=mesh,

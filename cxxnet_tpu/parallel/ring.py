@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import _online_block_update
-from .compat import shard_map
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -75,7 +74,7 @@ def ring_attention_sharded(mesh: Mesh, q: jax.Array, k: jax.Array,
     """One-call ring attention: shards (B,S,H,D) over ``seq_axis`` of
     ``mesh``, runs the ring, returns the global result."""
     spec = P(None, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal,
                           scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)

@@ -19,8 +19,8 @@ Per-step components:
   pipeline (iterator + prefetch queue). Large => input-bound: buy
   decode threads / prefetch depth, not more chips.
 * **dispatch** — host time inside the update call (staging, tracing the
-  first call, enqueueing). Large on remote-attached chips => use
-  ``train_chain``.
+  first call, enqueueing). Large next to the device time (a small
+  step) => use ``train_chain``.
 * **device_block** — how far the device lags the host when the probe
   syncs, i.e. device compute the host did NOT hide behind its own work.
   Large => compute-bound: the chip is the bottleneck.

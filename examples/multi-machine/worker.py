@@ -22,7 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 n_cpu = int(os.environ.get("CXXNET_CPU_DEVICES", "0"))
 if n_cpu:
     import jax
-    from cxxnet_tpu.parallel.compat import force_cpu_devices
+    from cxxnet_tpu.parallel import force_cpu_devices
     force_cpu_devices(n_cpu)
 
 from cxxnet_tpu.main import main

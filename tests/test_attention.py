@@ -10,7 +10,6 @@ import pytest
 
 from cxxnet_tpu.ops import (attention_reference, chunked_attention,
                             flash_attention)
-from cxxnet_tpu.parallel import shard_map
 from cxxnet_tpu.parallel.ring import ring_attention_sharded
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -124,7 +123,7 @@ def test_gather_kv_attention_matches_reference(causal):
     q, k, v = _qkv(s=128)
 
     def sharded(q, k, v):
-        f = shard_map(
+        f = jax.shard_map(
             lambda a, b, c: gather_kv_attention(a, b, c, "seq",
                                                 causal=causal),
             mesh=mesh,

@@ -14,8 +14,8 @@
   expiry, ServingStats wiring, /healthz degradation and /statz run
   identity on a live ServeServer;
 * satellites — collect-callback gauges can't go stale (io prefetch
-  gauge included), the bench --budget-s watchdog always lands its
-  final JSON line (the r05 rc=124 regression).
+  gauge included), the bench budget watchdog always lands its final
+  JSON line, and bench.main refuses a platform that is not a TPU.
 """
 
 import json
@@ -787,8 +787,10 @@ def test_report_generates_from_ledger(tmp_path):
     led.event("future_event_type", mystery=1)       # open world
     led.event("run_end", status="ok")
     md = report.generate(path, None,
-                         [os.path.join(REPO, "BENCH_r04.json"),
-                          os.path.join(REPO, "BENCH_r05.json")])
+                         [os.path.join(REPO, "tests", "data",
+                                       "bench_fixture_parsed.json"),
+                          os.path.join(REPO, "tests", "data",
+                                       "bench_fixture_null.json")])
     assert "# Run report — `rep-1`" in md
     assert "status: **ok**" in md
     assert "Round trajectory" in md and "| 2 |" in md
@@ -796,8 +798,8 @@ def test_report_generates_from_ledger(tmp_path):
     assert "rollback" in md and "round 2 -> 1" in md
     assert "closed -> open" in md
     assert "future_event_type" in md                 # unknown: listed
-    assert "BENCH_r04.json | 4629" in md
-    assert "parsed=null" in md
+    assert "bench_fixture_parsed.json | 1234" in md
+    assert "bench_fixture_null.json | — | | | | rc=124, parsed=null" in md
 
 
 def test_report_critical_path_section_and_malformed_interior(tmp_path):
@@ -839,17 +841,42 @@ def test_report_cli(tmp_path):
 # -- bench budget watchdog regression (ROADMAP 5a) ----------------------------
 
 def test_bench_budget_watchdog_lands_final_json():
-    """BENCH r05 died rc=124 with parsed:null because the watchdog tied
-    the harness-timeout race. Contract under test: even a tiny
-    --budget-s run ALWAYS exits 0 with a parseable final JSON line
-    (the watchdog emit), well before an external kill."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_BUDGET_S="6")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--budget-s", "6"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    """A bench run killed by the harness timeout leaves no JSON line.
+    Contract under test: when the budget runs out mid-phase the
+    watchdog ALWAYS exits 0 with a parseable final JSON line carrying
+    what was recorded so far, well before an external kill. (Driven
+    through ``bench.Budget`` itself: ``bench.main`` measures the chip
+    and refuses this suite's CPU backend — next test.)"""
+    code = (
+        "import sys, time; sys.path.insert(0, %r); import bench\n"
+        "partial = {'metric': "
+        "'inception_bn_train_images_per_sec_per_chip', 'value': None}\n"
+        "b = bench.Budget(3.0, partial)\n"
+        "b.record({'value': 1.0})\n"
+        "assert b.low(60, 'some_phase')\n"
+        "time.sleep(60)   # a phase that outlives the budget\n" % REPO)
+    p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=120)
     assert p.returncode == 0, p.stderr[-2000:]
     lines = [l for l in p.stdout.strip().splitlines() if l.strip()]
     assert lines, f"no stdout at all; stderr: {p.stderr[-1000:]}"
-    parsed = json.loads(lines[-1])               # the r05 failure mode
+    parsed = json.loads(lines[-1])
     assert parsed["metric"] == "inception_bn_train_images_per_sec_per_chip"
-    assert "truncated_phases" in parsed          # tiny budget truncates
+    assert parsed["value"] == 1.0
+    assert "some_phase" in parsed["truncated_phases"]
+
+
+def test_bench_main_refuses_a_non_tpu_platform():
+    """bench.py measures the chip: on any other platform it stops with
+    a message naming the platform it found — no CPU pin, no child
+    process, and no JSON line that could be read as a device number."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--budget-s", "6"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0
+    assert "'cpu'" in p.stderr and "not a TPU" in p.stderr
+    assert p.stdout.strip() == ""
+    src = open(os.path.join(REPO, "bench.py")).read()
+    assert "subprocess" not in src and "jax_platforms" not in src

@@ -146,7 +146,7 @@ def test_rules_match_legacy_layer_pspecs():
 def test_config_rules_override_and_flow_into_manual_plan():
     """A ``partition_rules`` config entry overrides the generated table
     (first match wins) AND changes the derived manual-tp plan — the
-    0.4.x execution fallback follows the same declarative source."""
+    manual-tp execution plan follows the same declarative source."""
     cfg = parse_config_string(LM_CFG)
     net = Network(build_graph(cfg), cfg)
     assert tuple(net.param_pspecs()["lm_head"]["wmat"]) == (None, "model")

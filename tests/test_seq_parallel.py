@@ -154,16 +154,6 @@ def test_sp_with_moe_state():
     assert np.isfinite(tr.last_loss) and 0.0 < aux < 0.2
 
 
-# KNOWN-FAIL on jax 0.4.x: sp x tp needs GSPMD-auto param sharding INSIDE
-# the manual shard_map (auto=), which that version lowers to a PartitionId
-# op its SPMD partitioner rejects ("PartitionId instruction is not
-# supported"); passes on the validated jax 0.9-0.10 — hence the version
-# gate, not an unconditional skip.
-@pytest.mark.skipif(
-    tuple(int(v) for v in jax.__version__.split(".")[:2]) < (0, 9),
-    reason="GSPMD-auto sharding inside a manual shard_map fails on jax "
-           "0.4.x (PartitionId unsupported by its SPMD partitioner) and "
-           "is unvalidated below 0.9; validated passing on jax 0.9-0.10")
 def test_sp_composes_with_tp():
     """seq_parallel x model_parallel: the partial-manual shard_map leaves
     the 'model' axis to GSPMD, so TP param shardings (mha heads, MoE

@@ -312,20 +312,6 @@ def test_allreduce_pairs_single_process_identity():
     assert allreduce_metric_pairs(pairs) == pairs
 
 
-# KNOWN-FAIL on jax 0.4.x: cross-process collectives on the CPU backend
-# raise "Multiprocess computations aren't implemented on the CPU backend";
-# passes on newer jax where the CPU backend gained cross-host support —
-# hence the version gate, not an unconditional skip.
-_JAX_NO_CPU_MULTIPROCESS = pytest.mark.skipif(
-    tuple(int(v) for v in __import__("jax").__version__.split(".")[:2])
-    < (0, 9),
-    reason="CPU-backend multiprocess collectives fail on jax 0.4.x "
-           "('Multiprocess computations aren't implemented on the CPU "
-           "backend') and are unvalidated below 0.9; validated passing "
-           "on jax 0.9-0.10")
-
-
-@_JAX_NO_CPU_MULTIPROCESS
 def test_two_process_distributed_training(tmp_path):
     """Real multi-process jax.distributed run (the ps-lite local-mode
     analog): 2 workers x 2 virtual CPU devices form one 4-device
@@ -348,9 +334,6 @@ def test_two_process_distributed_training(tmp_path):
                   if f.endswith(".model")) == ["0000.model", "0001.model"]
 
 
-# KNOWN-FAIL on jax 0.4.x: same CPU-backend multiprocess limitation as
-# test_two_process_distributed_training above.
-@_JAX_NO_CPU_MULTIPROCESS
 def test_two_process_ring_attention(tmp_path):
     """Sequence parallelism across process boundaries: the 'seq' mesh axis
     spans 2 processes x 2 devices; ppermute carries k/v shards over the

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""e2e input-pipeline attribution probes (doc/e2e_input.md).
+"""e2e input-pipeline attribution probes.
 
 Measures, against the attached accelerator:
   1. isolated H2D bandwidth (u8 + f32 batch payloads)

@@ -74,9 +74,8 @@ def render(m) -> str:
         "# Inception-BN step: measured per-phase attribution",
         "",
         "Generated by `python tools/ibn_perf.py` (rerun on a "
-        "TPU-attached host to refresh the numbers of record; the same "
-        "attribution also lands in every `BENCH_r*.json` under "
-        "`attribution`/`calibration`). Method: one jax.profiler trace "
+        "TPU-attached host to refresh it; `bench.py` carries the same "
+        "attribution under `attribution`/`calibration`). Method: one jax.profiler trace "
         "of steady-state chained train steps, device op events parsed "
         "and classified by `cxxnet_tpu/telemetry/traceparse.py`; the "
         "spirit of the reference's doc/alexnet_perf.md, applied to the "
@@ -143,10 +142,10 @@ def render(m) -> str:
         lines.append(
             f"**Calibration ratio (measured / cost_analysis): "
             f"{calib['measured_vs_cost_ratio']:.3f}** — every "
-            "`roofline_pct` in the BENCH trajectory divides by this to "
+            "`roofline_pct` bench.py reports divides by this to "
             "become a chip number; <1 means XLA fused below its own "
-            "pre-fusion estimate, so the >100% roofline readings of "
-            "r02–r05 were real fusion wins, not measurement error.")
+            "pre-fusion estimate, so a >100% roofline reading is a "
+            "real fusion win, not measurement error.")
     else:
         lines.append(
             "This backend's trace carries no per-op memory counters, so "
@@ -172,7 +171,7 @@ def render(m) -> str:
             "",
             "Skipped on the CPU backend (interpret-mode kernels time "
             "the interpreter, not the optimization); the on-chip A/B "
-            "lands in `BENCH_r06+.json` under `fused_ab`.",
+            "is `bench.py`'s `fused_ab` entry.",
             "",
         ]
     fold = m["fold"]

@@ -665,7 +665,7 @@ def _critical_path_lines(cp: Dict, out: List[str]) -> None:
 
 def section_bench(paths: List[str], out: List[str]) -> None:
     """BENCH_r*.json trajectory. Two shapes are accepted: the driver's
-    wrapper (``{"n", "rc", "parsed": {...}|null}`` — r05's
+    wrapper (``{"n", "rc", "parsed": {...}|null}`` — a
     ``parsed: null`` renders as a failed round, which is itself signal)
     and a bare bench emit."""
     entries = []

@@ -18,7 +18,7 @@ Drives the PR-7 fleet layer end to end — the acceptance run:
   4. Assert the ledger carries both hosts' run_start/round_end/
      ckpt_save/run_end plus the dry-run hang_dump WITH stacks.
   5. Render a run report (tools/report.py) from the ledger + host 0's
-     telemetry_log + the checked-in BENCH_r0*.json trajectory and
+     telemetry_log + the bench-artifact fixtures under tests/data/ and
      assert its sections landed.
 
 Exits nonzero on any failure.  Run:  JAX_PLATFORMS=cpu python tools/smoke_fleet.py
@@ -188,13 +188,14 @@ def main() -> int:
     rc = subprocess.call(
         [sys.executable, os.path.join(_REPO, "tools", "report.py"),
          "--ledger", ledger_path, "--telemetry-log", tel_log,
-         "--bench", os.path.join(_REPO, "BENCH_r0*.json"),
+         "--bench", os.path.join(_REPO, "tests", "data",
+                                 "bench_fixture_*.json"),
          "-o", report_path], cwd=_REPO)
     assert rc == 0, "report.py failed"
     md = open(report_path, encoding="utf-8").read()
     for needle in ("# Run report", run_id, "Round trajectory",
                    "hang_dump", "straggler", "## Bench trajectory",
-                   "BENCH_r04.json", "parsed=null"):
+                   "bench_fixture_parsed.json", "parsed=null"):
         assert needle in md, f"{needle!r} missing from report:\n{md[:2000]}"
 
     print("smoke_fleet OK:", json.dumps({

@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 import jax  # noqa: E402
 
-from cxxnet_tpu.parallel.compat import force_cpu_devices  # noqa: E402
+from cxxnet_tpu.parallel import force_cpu_devices  # noqa: E402
 
 force_cpu_devices(8)
 
