@@ -216,16 +216,17 @@ def calibration_entry(cost_bytes: float, measured_bytes,
 
 
 def profile_attribution(tr, classes, batch, k=8):
-    """Capture a jax.profiler trace of ``k`` chained flagship steps and
-    attribute device op time (and measured HBM bytes, when the backend
-    records them) per phase — telemetry.traceparse. The chain is warmed
-    (compile retired) BEFORE the bracket so the trace holds steady-state
-    steps only. Returns the attribution dict (JSON-rounded); off the
-    TPU a failure becomes an {"error": ...} marker (see _on_tpu)."""
+    """Capture a jax.profiler trace of ``k`` flagship steps and
+    attribute device op time to forward / backward / optimizer and the
+    fused kinds inside each, through the step's own scopes —
+    telemetry.traceparse. The step is warmed (compile retired) BEFORE
+    the bracket so the trace holds steady-state steps only. Returns the
+    attribution dict (JSON-rounded); off the TPU there is no chip's
+    plane to read and the result is an {"error": ...} marker."""
     import numpy as np
     from cxxnet_tpu.io.data import DataBatch
-    from cxxnet_tpu.telemetry.traceparse import (attribute_profile,
-                                                 device_trace)
+    from cxxnet_tpu.telemetry.profiler import device_trace
+    from cxxnet_tpu.telemetry.traceparse import attribute_profile
     try:
         c_in, y_in, x_in = tr.graph.input_shape
         rng = np.random.RandomState(1)
@@ -235,32 +236,39 @@ def profile_attribution(tr, classes, batch, k=8):
                               size=(batch, 1)).astype(np.float32))
         b.data = tr.mesh.shard_batch(b.data)
         b.label = tr.mesh.shard_batch(b.label)
-        float(tr.update_chain(b, k)[-1])      # compile + warm, untraced
+        for _ in range(3):                    # compile + warm, untraced
+            tr.update(b)
+        float(tr.last_loss)
         dump = tempfile.mkdtemp(prefix="bench_profile_")
-        # device_trace: python tracer OFF — a python-traced flagship
-        # step floods the profiler's event cap and evicts the op events
-        # the attribution exists to read
-        with device_trace(dump):
-            losses = tr.update_chain(b, k)
-            float(losses[-1])                 # value sync inside bracket
-        att = attribute_profile(dump, steps=k)
+        # device_trace: host and python tracers OFF — either floods the
+        # profiler's event cap and evicts the op events the attribution
+        # exists to read. Plain update() calls, not a chain: one module
+        # run per step is what the reader counts steps by, and the step
+        # update() ran is the one whose scopes the program describes
+        with device_trace(dump) as clock:
+            for _ in range(k + 2):
+                tr.update(b)
+            float(tr.last_loss)               # value sync inside bracket
+        att = attribute_profile(dump, clock=clock)
+        if att is None:
+            raise ValueError("the dump holds no chip's plane, or fewer "
+                             "than three runs of the step")
     except Exception as e:
         if _on_tpu():
             raise
         return {"error": f"{type(e).__name__}: {e}"}
+    r4 = lambda v: round(v, 4)
     att["phases"] = {
-        ph: {"ms": round(d["ms"], 4), "pct": round(d["pct"], 2),
+        ph: {"ms": r4(d["ms"]), "pct": round(d["pct"], 2),
              "count": d["count"]}
         for ph, d in sorted(att["phases"].items(),
                             key=lambda kv: -kv[1]["ms"])}
-    att["total_op_ms"] = round(att["total_op_ms"], 4)
-    att["top_other"] = [(n, round(ms, 4)) for n, ms in att["top_other"]]
-    if att.get("measured_bytes_per_step"):
-        att["measured_bytes_per_step"] = round(
-            att["measured_bytes_per_step"], 1)
-    if att.get("measured_flops_per_step"):
-        att["measured_flops_per_step"] = round(
-            att["measured_flops_per_step"], 1)
+    att["kinds"] = {ph: {k_: r4(v) for k_, v in ks.items()}
+                    for ph, ks in att["kinds"].items()}
+    for key in ("layers", "top_unattributed", "idle_gaps"):
+        att[key] = [(n, r4(ms)) for n, ms in att[key]]
+    for key in ("step_ms", "busy_ms", "idle_pct", "unattributed_pct"):
+        att[key] = r4(att[key])
     att["dump_dir"] = dump
     return att
 
@@ -985,11 +993,11 @@ def main() -> None:
                 raise
             fused_ab = {"error": f"{type(e).__name__}: {e}"}
     budget.record({"fused_ab": fused_ab})
-    # -- measured attribution + calibrated roofline: trace k steady
-    # steps, classify device op time per phase, and (on backends whose
-    # trace carries memory counters) calibrate hbm_bytes_per_step
-    # against MEASURED bytes instead of the cost_analysis model
-    # (doc/ibn_perf.md; tools/ibn_perf.py regenerates the doc table)
+    # -- measured attribution: trace k steady steps and attribute device
+    # op time per phase and fused kind through the step's own scopes
+    # (doc/ibn_perf.md; tools/ibn_perf.py regenerates the doc table).
+    # No jax-0.9 dump carries memory counters, so the byte calibration
+    # below stands on the analytic model alone
     if budget.low(75, "attribution"):
         att = {"skipped": "budget"}
     else:

@@ -134,12 +134,49 @@ def mean_cache_path(p: AugmentParams) -> str:
     return path
 
 
+# -- minimal protobuf wire-format reader (the binaryproto mean import's;
+# only the standalone tools/import_caffe.py keeps a copy of its own, being
+# a no-package-import CLI)
+
+
+def read_varint(buf: bytes, pos: int):
+    out = shift = 0
+    while True:
+        b = buf[pos]
+        pos += 1
+        out |= (b & 0x7F) << shift
+        if not b & 0x80:
+            return out, pos
+        shift += 7
+
+
+def iter_fields(buf: bytes):
+    """Yield (field_number, wire_type, value) for one message's bytes."""
+    pos = 0
+    n = len(buf)
+    while pos < n:
+        key, pos = read_varint(buf, pos)
+        field, wt = key >> 3, key & 7
+        if wt == 0:
+            val, pos = read_varint(buf, pos)
+        elif wt == 1:
+            val, pos = buf[pos:pos + 8], pos + 8
+        elif wt == 2:
+            ln, pos = read_varint(buf, pos)
+            val, pos = buf[pos:pos + ln], pos + ln
+        elif wt == 5:
+            val, pos = buf[pos:pos + 4], pos + 4
+        else:
+            raise ValueError(f"unsupported protobuf wire type {wt}")
+        yield field, wt, val
+
+
 def load_binaryproto_mean(data: bytes, rgb_flip: bool = True) -> np.ndarray:
     """Parse a Caffe ``mean.binaryproto`` (a serialized BlobProto) into
     an (H, W, C) float32 RGB mean image — the classic ImageNet
     preprocessing artifact (reference tools/caffe_converter). Wire-level
-    protobuf parsing via the repo's shared minimal reader
-    (telemetry.traceparse.iter_fields) — no Caffe/protobuf dependency.
+    protobuf parsing via the minimal reader above
+    (:func:`iter_fields`) — no Caffe/protobuf dependency.
     Caffe blobs are NCHW with BGR channel order; ``rgb_flip`` (default)
     reverses the channel axis so the result matches this framework's
     RGB pipeline.
@@ -147,8 +184,6 @@ def load_binaryproto_mean(data: bytes, rgb_flip: bool = True) -> np.ndarray:
     BlobProto fields used: legacy dims num=1 channels=2 height=3
     width=4, payload ``data`` (repeated float, field 5, packed or not),
     new-style ``shape`` (field 7: BlobShape{repeated int64 dim=1})."""
-    from ..telemetry.traceparse import iter_fields, read_varint
-
     legacy = {1: 0, 2: 0, 3: 0, 4: 0}
     shape: list = []
     chunks: list = []

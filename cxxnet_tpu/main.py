@@ -941,6 +941,16 @@ class LearnTask:
             TRACER.add_complete("train.data_wait", t0, t1, cat="train")
             yield batch
 
+    @staticmethod
+    def _record_step(probe, tr, t_call: float, ready, steps: int = 1):
+        """Hand the probe one update call that began at ``t_call``,
+        split in two: the enqueue, and the train-metric drain the call
+        ended in (a wait for the device — ``Trainer.last_drain_s``)."""
+        spent = time.perf_counter() - t_call
+        drain = min(tr.last_drain_s, spent)
+        probe.record_step(spent - drain, ready=ready, steps=steps,
+                          drain_s=drain)
+
     def _train_rounds(self, tr, itr_train, evals) -> None:
         start = time.time()
         end_round = self.num_round
@@ -1048,8 +1058,7 @@ class LearnTask:
                     with step_span(r, steps=len(pending)):
                         losses = tr.update_chain_batches(pending)
                         if probe is not None:
-                            probe.record_step(time.perf_counter() - t_d,
-                                              ready=losses,
+                            self._record_step(probe, tr, t_d, losses,
                                               steps=len(pending))
                     if profiler is not None:
                         profiler.maybe_stop(tr._step_count, ready=losses)
@@ -1064,9 +1073,8 @@ class LearnTask:
                     with step_span(r):
                         tr.update(batch)
                         if probe is not None:
-                            probe.record_step(
-                                time.perf_counter() - t_d,
-                                ready=tr.last_loss_handle)
+                            self._record_step(probe, tr, t_d,
+                                              tr.last_loss_handle)
                     if profiler is not None:
                         profiler.maybe_stop(tr._step_count,
                                             ready=tr.last_loss_handle)
@@ -1087,8 +1095,8 @@ class LearnTask:
                 with step_span(r):
                     tr.update(b)
                     if probe is not None:
-                        probe.record_step(time.perf_counter() - t_d,
-                                          ready=tr.last_loss_handle)
+                        self._record_step(probe, tr, t_d,
+                                          tr.last_loss_handle)
                 n_images += b.batch_size - b.num_batch_padd
                 batch_count += 1
                 self._sentinel_step(tr, r)
@@ -1101,7 +1109,8 @@ class LearnTask:
             if (profiler is not None and profiler.done
                     and not self._profile_summarized):
                 # the telemetry_profile_steps bracket closed this round:
-                # print the measured per-phase attribution (traceparse)
+                # print the phase x kind attribution of its steps and
+                # the longest idle gaps by train.* span (traceparse),
                 # instead of leaving the dump for offline xprof. Root
                 # only — non-root ranks must not pay the dump parse for
                 # a line they never print.
@@ -1109,10 +1118,8 @@ class LearnTask:
                 att = profiler.summarize() if self._is_root else None
                 if att is not None:
                     from .telemetry.traceparse import attribution_fragment
-                    frag = attribution_fragment(att)
-                    if frag:
-                        print(f"round {r:8d}: {frag} "
-                              f"(dump: {profiler.dump_dir})", flush=True)
+                    print(f"round {r:8d}: {attribution_fragment(att)} "
+                          f"(dump: {profiler.dump_dir})", flush=True)
             line = f"round {r:8d}:[{int(time.time() - start)} sec]"
             if tr.eval_train:
                 line += tr.train_metric_report("train")

@@ -14,6 +14,7 @@ the primary layer's parameter subtree.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -27,6 +28,17 @@ from .ops.fused import selection_site
 
 Params = Dict[str, Dict[str, jax.Array]]
 NetState = Dict[str, Any]
+
+_SCOPE_UNSAFE = re.compile(r"[^A-Za-z0-9_.\-]")
+
+
+def layer_scope(name: str):
+    """``jax.named_scope`` of one graph layer: every op the layer
+    traces carries ``.../<name>/...`` in its ``op_name`` — forward under
+    ``jvp(..)``, backward under ``transpose(jvp(..))`` — which is how a
+    device trace is attributed to layers
+    (telemetry/traceparse.classify). Metadata only."""
+    return jax.named_scope(_SCOPE_UNSAFE.sub("_", name))
 
 
 @dataclasses.dataclass
@@ -242,7 +254,8 @@ class Network:
             # every fused-kernel choice this layer makes lands in
             # fused_log under its name (tracing is synchronous, so the
             # binding covers remat's inner trace too)
-            with selection_site(self.fused_log, spec.name):
+            with selection_site(self.fused_log, spec.name), \
+                    layer_scope(spec.name):
                 if self.remat and layer.has_params:
                     def _fn(lp, ls, rng_, *ins, _layer=layer, _ctx=ctx):
                         c = ApplyCtx(train=_ctx.train, rng=rng_,
@@ -273,10 +286,11 @@ class Network:
             if layer.is_loss and (label is not None
                                   or label_slices is not None):
                 a, b = g.label_slice(layer.target)
-                lab = (label_slices[(a, b)] if label_slices is not None
-                       else label[:, a:b])
-                total_loss = total_loss + layer.loss(
-                    outputs, lab.astype(jnp.float32), mask)
+                with layer_scope(spec.name):
+                    lab = (label_slices[(a, b)] if label_slices is not None
+                           else label[:, a:b])
+                    total_loss = total_loss + layer.loss(
+                        outputs, lab.astype(jnp.float32), mask)
         node_map = None
         if capture_nodes:
             node_map = {name: nodes[i] for i, name in enumerate(g.node_names)
@@ -660,7 +674,8 @@ class Network:
                     lstate = dict(lstate)
                     for key, orig in ent["state"].items():
                         lstate[key] = slice_leaf(lstate[key], 0, orig, me)
-            outputs, _ = layer.apply(lparams, lstate, inputs, ctx)
+            with layer_scope(spec.name):
+                outputs, _ = layer.apply(lparams, lstate, inputs, ctx)
             if ent and "sink_gather" in ent and layer.name in sink:
                 # batch-stat followers (BN) computed channel-local moments;
                 # gather them back to full width so the trainer's post-ring
@@ -715,9 +730,10 @@ class Network:
                            compute_dtype=self.compute_dtype,
                            seq_axis=seq_axis, data_axis=data_axis)
             inputs = [nodes[ni] for ni in spec.nindex_in]
-            outputs, lstate_out = layer.apply(
-                params.get(layer.name, {}), new_state.get(layer.name, {}),
-                inputs, ctx)
+            with layer_scope(spec.name):
+                outputs, lstate_out = layer.apply(
+                    params.get(layer.name, {}),
+                    new_state.get(layer.name, {}), inputs, ctx)
             if lstate_out:
                 new_state[layer.name] = lstate_out
             for ni, out in zip(spec.nindex_out, outputs):
@@ -725,10 +741,11 @@ class Network:
             if layer.is_loss and (label is not None
                                   or label_slices is not None):
                 a, b = g.label_slice(layer.target)
-                lab = (label_slices[(a, b)] if label_slices is not None
-                       else label[:, a:b])
-                total_loss = total_loss + layer.loss(
-                    outputs, lab.astype(jnp.float32), mask)
+                with layer_scope(spec.name):
+                    lab = (label_slices[(a, b)]
+                           if label_slices is not None else label[:, a:b])
+                    total_loss = total_loss + layer.loss(
+                        outputs, lab.astype(jnp.float32), mask)
         out = nodes[g.layers[-1].nindex_out[0]]
         return ForwardResult(loss=total_loss, state=new_state,
                              nodes={ni: nodes[ni] for ni in want}

@@ -351,7 +351,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running normalizer
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_fwd",
     )(qt, kt, vt)
     out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     return (out, lse) if with_lse else out
@@ -489,7 +489,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         out_specs=q_spec,
         out_shape=out_struct((B * H, Sq, D), q.dtype, qt, kt, vt, dot),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # swapped grid: (bh, k-block, q-block) — index maps swap i/j roles
@@ -506,7 +506,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                    out_struct((B * H, Sk, D), v.dtype, qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     unflat = lambda a, S: a.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -36,6 +36,12 @@ caller falls back to its reference implementation — counted by reason
 (:func:`note_fallback`), as a selected kernel is by op
 (:func:`note_fused`), into the model's selection log
 (:func:`selection_site`), which the trainer prints once.
+
+Names on the device: every wrapper traces under the scope
+``fused.<op>`` that :func:`note_fused` returns, and every
+``pl.pallas_call`` carries ``name="<op>_<fwd|bwd|...>"``, so a compiled
+step's custom-call instruction reads ``%bn_act_bwd.3`` with ``op_name``
+``.../<layer>/fused.bn_act/bn_act_bwd/pallas_call``.
 """
 
 from __future__ import annotations
@@ -166,9 +172,14 @@ def _record(kind: str, what: str) -> None:
         site[0][site[1]] = (kind, what)
 
 
-def note_fused(op: str) -> None:
-    """Record that the site being traced took fused kernel ``op``."""
+def note_fused(op: str):
+    """Record that the site being traced took fused kernel ``op``, and
+    return the scope ``fused.<op>`` for the wrapper to trace the kernel
+    call AND its own reshapes/transposes under (``with note_fused(..)``)
+    — the selection log and a device trace's ``op_name`` then use the
+    same word (telemetry/traceparse.classify reads it back)."""
     _record("fused", op)
+    return jax.named_scope(f"fused.{op}")
 
 
 def note_attention(impl: str) -> None:

@@ -90,7 +90,7 @@ def _epi_act_2d(x2, act, interpret, bn):
         in_specs=[pl.BlockSpec((bn, c), lambda j: (j, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
         out_shape=out_struct((n, c), x2.dtype, x2),
-        interpret=interpret,
+        interpret=interpret, name="bias_act_fwd_act",
     )(x2)
 
 
@@ -109,7 +109,7 @@ def _epi_act_bwd(act, interpret, bn, y, dy):
                   pl.BlockSpec((bn, c), lambda j: (j, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
         out_shape=out_struct((n, c), y.dtype, y, dy),
-        interpret=interpret,
+        interpret=interpret, name="bias_act_bwd_act",
     )(y, dy)
     return (dx,)
 
@@ -127,7 +127,7 @@ def _epi_bias_2d(x2, bias, act, interpret, bn):
                   pl.BlockSpec((1, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
         out_shape=out_struct((n, c), x2.dtype, x2),
-        interpret=interpret,
+        interpret=interpret, name="bias_act_fwd",
     )(x2, bias.reshape(1, c))
 
 
@@ -150,7 +150,7 @@ def _epi_bias_bwd(act, interpret, bn, res, dy):
         out_shape=[out_struct((n, c), y.dtype, y, dy),
                    out_struct((1, c), jnp.float32, y, dy)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="bias_act_bwd",
     )(y, dy)
     return dx, db.reshape(bias.shape).astype(bias.dtype)
 
@@ -202,7 +202,7 @@ def _epi_bias_mesh_bwd(act, interpret, bn, spmd, res, dy):
             out_shape=[out_struct((n, c), yl.dtype, yl, dyl),
                        out_struct((1, c), jnp.float32, yl, dyl)],
             scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, name="bias_act_bwd",
         )(yl.reshape(n, c), dyl.reshape(n, c))
         db = jax.lax.psum(db, spmd.batch_axis)
         return dx2.reshape(yl.shape), db
@@ -241,18 +241,18 @@ def fused_bias_act(x: jax.Array, bias: Optional[jax.Array],
     if bn is None or (bias is not None and bias.shape != (c,)):
         note_fallback("epilogue_shape")
         return None
-    note_fused("bias_act")
-    itp = use_interpret(interpret)
-    if spmd is not None:
+    with note_fused("bias_act"):
+        itp = use_interpret(interpret)
+        if spmd is not None:
+            if bias is None:
+                return island(
+                    spmd, lambda xl: _epi_act_2d(
+                        xl.reshape(-1, c), act, itp, bn).reshape(xl.shape),
+                    in_batch=(True,), out_batch=True, interpret=itp)(x)
+            return _epi_bias_mesh(x, bias, act, itp, bn, spmd)
+        x2 = x.reshape(n, c)
         if bias is None:
-            return island(
-                spmd, lambda xl: _epi_act_2d(
-                    xl.reshape(-1, c), act, itp, bn).reshape(xl.shape),
-                in_batch=(True,), out_batch=True, interpret=itp)(x)
-        return _epi_bias_mesh(x, bias, act, itp, bn, spmd)
-    x2 = x.reshape(n, c)
-    if bias is None:
-        y = _epi_act_2d(x2, act, itp, bn)
-    else:
-        y = _epi_bias_2d(x2, bias, act, itp, bn)
-    return y.reshape(x.shape)
+            y = _epi_act_2d(x2, act, itp, bn)
+        else:
+            y = _epi_bias_2d(x2, bias, act, itp, bn)
+        return y.reshape(x.shape)

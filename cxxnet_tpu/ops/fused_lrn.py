@@ -99,7 +99,7 @@ def _lrn_2d(x2, band, bandt, ab, beta, knorm, interpret, bn):
                   pl.BlockSpec((c, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
         out_shape=out_struct((n, c), x2.dtype, x2),
-        interpret=interpret,
+        interpret=interpret, name="lrn_fwd",
     )(x2, band)
 
 
@@ -121,7 +121,7 @@ def _lrn_bwd(ab, beta, knorm, interpret, bn, res, dy):
                   pl.BlockSpec((c, c), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((bn, c), lambda j: (j, 0)),
         out_shape=out_struct((n, c), x2.dtype, x2, dy),
-        interpret=interpret,
+        interpret=interpret, name="lrn_bwd",
     )(x2, dy, band, bandt)
     # band/bandt are trace-time constants; zero cotangents (DCE'd)
     return dx, jnp.zeros_like(band), jnp.zeros_like(bandt)
@@ -160,15 +160,15 @@ def fused_lrn(x: jax.Array, nsize: int, alpha: float, beta: float,
     if bn is None:
         note_fallback("lrn_shape")
         return None
-    note_fused("lrn")
-    band = jnp.asarray(band_matrix(c, nsize))
-    itp = use_interpret(interpret)
-    args = (band, band.T, float(alpha) / nsize, float(beta),
-            float(knorm), itp, bn)
-    if spmd is not None:
-        return island(
-            spmd, lambda xl: _lrn_2d(xl.reshape(-1, c),
-                                     *args).reshape(xl.shape),
-            in_batch=(True,), out_batch=True, interpret=itp)(x)
-    y = _lrn_2d(x.reshape(n, c), *args)
-    return y.reshape(x.shape)
+    with note_fused("lrn"):
+        band = jnp.asarray(band_matrix(c, nsize))
+        itp = use_interpret(interpret)
+        args = (band, band.T, float(alpha) / nsize, float(beta),
+                float(knorm), itp, bn)
+        if spmd is not None:
+            return island(
+                spmd, lambda xl: _lrn_2d(xl.reshape(-1, c),
+                                         *args).reshape(xl.shape),
+                in_batch=(True,), out_batch=True, interpret=itp)(x)
+        y = _lrn_2d(x.reshape(n, c), *args)
+        return y.reshape(x.shape)

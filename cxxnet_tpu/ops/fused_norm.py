@@ -156,7 +156,7 @@ def _bn_forward(x2, gamma, beta, eps, act, two_pass, interpret, bn):
                    out_struct((1, c), jnp.float32, x2)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32),
                         pltpu.VMEM((1, c), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="bn_act_fwd",
     )(x2, gamma.reshape(1, c), beta.reshape(1, c))
     return y, mean, var
 
@@ -229,7 +229,7 @@ def _bn_backward(x2, gamma, mean, rstd, y2, dy2, act, interpret, bn):
                    out_struct((1, c), jnp.float32, x2)],
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32),
                         pltpu.VMEM((1, c), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="bn_act_bwd",
     )(*ins)
     return dx, dgamma, dbeta
 
@@ -381,7 +381,7 @@ def _mesh_fwd_local(x, gamma, beta, *, c, eps, act, interpret, bn, axis,
         grid=(nb,), in_specs=[row], out_specs=[vec, vec],
         out_shape=[out_struct((1, c), jnp.float32, x2)] * 2,
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)] * 2,
-        interpret=interpret)(x2)
+        interpret=interpret, name="bn_act_fwd_sums")(x2)
     s1 = jax.lax.psum(s1, axis)
     s2 = jax.lax.psum(s2, axis)
     mean = s1 / n_total
@@ -392,8 +392,8 @@ def _mesh_fwd_local(x, gamma, beta, *, c, eps, act, interpret, bn, axis,
         functools.partial(_bn_norm_kernel, act=act),
         grid=(nb,), in_specs=[row, vec, vec, vec, vec], out_specs=row,
         out_shape=out_struct(x2.shape, x2.dtype, x2),
-        interpret=interpret)(x2, gamma.reshape(1, c),
-                             beta.reshape(1, c), mean, rstd)
+        interpret=interpret, name="bn_act_fwd_norm")(
+            x2, gamma.reshape(1, c), beta.reshape(1, c), mean, rstd)
     return (y2.reshape(x.shape), mean.reshape(c), var.reshape(c),
             rstd.reshape(c))
 
@@ -416,7 +416,7 @@ def _mesh_bwd_local(x, dy, y, gamma, mean, rstd, *, c, act, interpret,
         grid=(nb,), in_specs=in_specs, out_specs=[vec, vec],
         out_shape=[out_struct((1, c), jnp.float32, x2)] * 2,
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)] * 2,
-        interpret=interpret)(*ins)
+        interpret=interpret, name="bn_act_bwd_sums")(*ins)
     sb = jax.lax.psum(sb, axis)
     sxh = jax.lax.psum(sxh, axis)
     ins2 = [x2, dy2] + ([y.reshape(-1, c)] if act == "relu" else []) \
@@ -428,7 +428,7 @@ def _mesh_bwd_local(x, dy, y, gamma, mean, rstd, *, c, act, interpret,
         functools.partial(_bn_bwd_dx_kernel, act=act),
         grid=(nb,), in_specs=in_specs2, out_specs=row,
         out_shape=out_struct(x2.shape, x2.dtype, x2),
-        interpret=interpret)(*ins2)
+        interpret=interpret, name="bn_act_bwd_dx")(*ins2)
     return dx2.reshape(x.shape), sxh.reshape(c), sb.reshape(c)
 
 
@@ -515,13 +515,13 @@ def fused_bn_act(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     if bn is None or gamma.shape != (c,) or beta.shape != (c,):
         note_fallback("bn_shape")
         return None
-    note_fused("bn_act")
-    if spmd is not None:
-        y, mean, var = _bn_act_mesh(x, gamma, beta, float(eps), act,
-                                    use_interpret(interpret), bn, spmd,
-                                    float(n))
-        return y, mean, var
-    x2 = x.reshape(n, c)
-    y, mean, var = _bn_act_2d(x2, gamma, beta, float(eps), act,
-                              bool(two_pass), use_interpret(interpret), bn)
-    return y.reshape(x.shape), mean.reshape(c), var.reshape(c)
+    with note_fused("bn_act"):
+        if spmd is not None:
+            y, mean, var = _bn_act_mesh(x, gamma, beta, float(eps), act,
+                                        use_interpret(interpret), bn, spmd,
+                                        float(n))
+            return y, mean, var
+        x2 = x.reshape(n, c)
+        y, mean, var = _bn_act_2d(x2, gamma, beta, float(eps), act,
+                                  bool(two_pass), use_interpret(interpret), bn)
+        return y.reshape(x.shape), mean.reshape(c), var.reshape(c)

@@ -109,7 +109,7 @@ def _adam_kernel(s_ref, w_ref, g_ref, m1_ref, m2_ref,
     m2_out[...] = n_m2
 
 
-def _run(kern, scalars, mats, n_out, block_rows, interpret):
+def _run(kern, scalars, mats, n_out, block_rows, interpret, name):
     rows = mats[0].shape[0]
     grid = (rows // block_rows,)
     row_spec = pl.BlockSpec((block_rows, _LANES), lambda j: (j, 0))
@@ -121,7 +121,7 @@ def _run(kern, scalars, mats, n_out, block_rows, interpret):
         in_specs=[s_spec] + [row_spec] * len(mats),
         out_specs=[row_spec] * n_out,
         out_shape=[shape] * n_out,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(scalars, *mats)
 
 
@@ -159,7 +159,8 @@ def fused_sgd_apply(ws: List[jax.Array], gs: List[jax.Array],
     kern = functools.partial(_sgd_kernel, wd=float(wd), clip=float(clip),
                              nag=bool(nag))
     nw, nm = _run(kern, scalars, [wm, gm, mm], 2, block_rows,
-                  use_interpret(interpret))
+                  use_interpret(interpret),
+                  ("nag" if nag else "sgd") + "_apply_update")
     return (_unpack(nw, total, shapes, dtypes),
             _unpack(nm, total, shapes, dtypes))
 
@@ -193,7 +194,7 @@ def fused_adam_apply(ws: List[jax.Array], gs: List[jax.Array],
     kern = functools.partial(_adam_kernel, wd=float(wd), clip=float(clip),
                              d1=float(d1), d2=float(d2))
     nw, nm1, nm2 = _run(kern, scalars, [wm, gm, m1m, m2m], 3, block_rows,
-                        use_interpret(interpret))
+                        use_interpret(interpret), "adam_apply_update")
     return (_unpack(nw, total, shapes, dtypes),
             _unpack(nm1, total, shapes, dtypes),
             _unpack(nm2, total, shapes, dtypes))

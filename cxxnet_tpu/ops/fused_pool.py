@@ -136,7 +136,7 @@ def _fwd_call(xr, reducer, pre_relu, scale, interpret, rb):
                                lambda i: (i, 0, 0, 0, 0))],
         out_specs=pl.BlockSpec((rb, ox, c), lambda i: (i, 0, 0)),
         out_shape=out_struct((n, ox, c), xr.dtype, xr),
-        interpret=interpret,
+        interpret=interpret, name="pool_fwd",
     )(xr)
 
 
@@ -152,7 +152,7 @@ def _bwd_call(xr, y, dy, reducer, pre_relu, scale, interpret, rb):
             in_specs=[row5, row3, row3],
             out_specs=row5,
             out_shape=out_struct(xr.shape, xr.dtype, xr, dy),
-            interpret=interpret,
+            interpret=interpret, name="pool_bwd_max",
         )(xr, y, dy)
     kern = functools.partial(_pool_bwd_lin_kernel, kh=kh, kw=kw,
                              scale=scale)
@@ -161,7 +161,7 @@ def _bwd_call(xr, y, dy, reducer, pre_relu, scale, interpret, rb):
         in_specs=[row3],
         out_specs=row5,
         out_shape=out_struct(xr.shape, xr.dtype, dy),
-        interpret=interpret,
+        interpret=interpret, name="pool_bwd_lin",
     )(dy)
 
 
@@ -239,17 +239,17 @@ def fused_pool(x: jax.Array, kh: int, kw: int, stride: int,
     if rb is None:
         note_fallback("pool_shape")
         return None
-    note_fused("pool")
-    itp = use_interpret(interpret)
-    if spmd is not None:
-        # pooling is row-local (windows never cross the batch dim):
-        # collective-free island, exact shard_map transpose
-        return island(
-            spmd, lambda xl: _pool5(
-                xl.reshape(-1, kh, ox, kw, c), reducer, pre_relu,
-                float(scale), itp, rb
-            ).reshape(xl.shape[0], oy, ox, c),
-            in_batch=(True,), out_batch=True, interpret=itp)(x)
-    xr = x.reshape(n, kh, ox, kw, c)
-    y = _pool5(xr, reducer, pre_relu, float(scale), itp, rb)
-    return y.reshape(b, oy, ox, c)
+    with note_fused("pool"):
+        itp = use_interpret(interpret)
+        if spmd is not None:
+            # pooling is row-local (windows never cross the batch dim):
+            # collective-free island, exact shard_map transpose
+            return island(
+                spmd, lambda xl: _pool5(
+                    xl.reshape(-1, kh, ox, kw, c), reducer, pre_relu,
+                    float(scale), itp, rb
+                ).reshape(xl.shape[0], oy, ox, c),
+                in_batch=(True,), out_batch=True, interpret=itp)(x)
+        xr = x.reshape(n, kh, ox, kw, c)
+        y = _pool5(xr, reducer, pre_relu, float(scale), itp, rb)
+        return y.reshape(b, oy, ox, c)

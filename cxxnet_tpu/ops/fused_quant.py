@@ -99,7 +99,7 @@ def _q_mm_pallas(xq, wq, factor, bias, act, bm, bn, interpret):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=out_struct((m, n), jnp.float32, xq),
-        interpret=interpret,
+        interpret=interpret, name="int8_matmul_fwd",
     )(*args)
 
 

@@ -92,7 +92,7 @@ def _stem_call(x2, mean_row, factor, out_dtype, interpret, rb, cb):
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=out_struct((n, cols), out_dtype, x2),
-        interpret=interpret,
+        interpret=interpret, name="stem_fwd",
     )(*ins)
 
 
@@ -154,30 +154,30 @@ def fused_decode_normalize(x: jax.Array, mean: Optional[jax.Array],
             return None
     else:
         mean_row = None
-    note_fused("stem")
-    factor = jnp.asarray(factor, jnp.float32)
-    itp = use_interpret(interpret)
-    if spmd is not None:
-        # mean_row/factor may be traced step arguments — explicit
-        # island inputs (replicated), never closure captures
-        if mean_row is not None:
-            def local(xl, mr, f):
-                y2l = _stem_call(xl.reshape(-1, cols), mr,
-                                 f, jnp.dtype(out_dtype), itp, rb, cb)
-                return y2l.reshape(xl.shape)
-            return island(spmd, local, in_batch=(True, False, False),
-                          out_batch=True,
-                          interpret=itp)(x, mean_row, factor)
+    with note_fused("stem"):
+        factor = jnp.asarray(factor, jnp.float32)
+        itp = use_interpret(interpret)
+        if spmd is not None:
+            # mean_row/factor may be traced step arguments — explicit
+            # island inputs (replicated), never closure captures
+            if mean_row is not None:
+                def local(xl, mr, f):
+                    y2l = _stem_call(xl.reshape(-1, cols), mr,
+                                     f, jnp.dtype(out_dtype), itp, rb, cb)
+                    return y2l.reshape(xl.shape)
+                return island(spmd, local, in_batch=(True, False, False),
+                              out_batch=True,
+                              interpret=itp)(x, mean_row, factor)
 
-        def local(xl, f):
-            y2l = _stem_call(xl.reshape(-1, cols), None, f,
-                             jnp.dtype(out_dtype), itp, rb, cb)
-            return y2l.reshape(xl.shape)
-        return island(spmd, local, in_batch=(True, False),
-                      out_batch=True, interpret=itp)(x, factor)
-    y2 = _stem_call(x.reshape(b, cols), mean_row, factor,
-                    jnp.dtype(out_dtype), itp, rb, cb)
-    return y2.reshape(b, h, w, c)
+            def local(xl, f):
+                y2l = _stem_call(xl.reshape(-1, cols), None, f,
+                                 jnp.dtype(out_dtype), itp, rb, cb)
+                return y2l.reshape(xl.shape)
+            return island(spmd, local, in_batch=(True, False),
+                          out_batch=True, interpret=itp)(x, factor)
+        y2 = _stem_call(x.reshape(b, cols), mean_row, factor,
+                        jnp.dtype(out_dtype), itp, rb, cb)
+        return y2.reshape(b, h, w, c)
 
 
 def decode_normalize(x: jax.Array, mean: Optional[jax.Array], factor,

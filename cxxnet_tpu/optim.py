@@ -386,13 +386,17 @@ class Optimizer:
         tests/test_fused_ops.py. Returns None when the trees are not
         uniformly f32 (caller falls back)."""
         from .ops.fused import note_fallback, note_fused, selection_site
-        from .ops.fused_optim import fused_adam_apply, fused_sgd_apply
         got = self._leaf_groups(params)
         with selection_site(self.fused_log, "optimizer"):
             if got is None:
                 note_fallback("optimizer_mixed_dtype")
                 return None
-            note_fused(f"{self.type}_apply")
+            scope = note_fused(f"{self.type}_apply")
+        with scope:
+            return self._apply_fused_groups(got, grads, opt_state, sched)
+
+    def _apply_fused_groups(self, got, grads, opt_state, sched):
+        from .ops.fused_optim import fused_adam_apply, fused_sgd_apply
         wl, treedef, groups = got
         gl = jax.tree_util.tree_leaves(grads)
         if self.type == "adam":

@@ -9,14 +9,26 @@ One registry, one tracer, every subsystem a client:
   ``/metrics`` are views of it.
 * :mod:`.trace` — bounded-ring span tracing (:data:`TRACER`), exported
   as perfetto-loadable Chrome trace JSON via ``telemetry_trace=path``.
+  A ``task = train`` run keeps its own ``cat="train"`` spans
+  (``train.data_wait`` / ``h2d_stage`` / ``step_dispatch`` /
+  ``metric_drain`` / ``device_block``) in that ring WITHOUT the knob,
+  governed by ``telemetry_steptime`` (default 1; 0 keeps nothing):
+  about half a microsecond a span, and there after the session closed.
 * :mod:`.steptime` — :class:`StepTimeProbe`, the amortized-sync
-  data-wait / dispatch / device breakdown with the input-bound vs
-  compute-bound verdict in the round log.
+  data-wait / dispatch / drain / device breakdown with the input-bound
+  vs compute-bound verdict in the round log (``dispatch_ms`` is the
+  enqueue alone; ``drain_ms`` the wait in the train-metric drain).
 * :mod:`.exporter` — Prometheus text rendering, the standalone
   ``telemetry_port`` scrape endpoint, and the ``telemetry_log`` JSONL
   event log.
 * :mod:`.profiler` — ``telemetry_profile_steps=a-b`` jax.profiler
-  brackets.
+  brackets (device tracer only), after which the round log prints the
+  steps' phase x fused-kind x layer table and the longest idle gaps by
+  ``train.*`` span; and the step's own description
+  (``step_hlo_text`` / ``step_scope_table``).
+* :mod:`.traceparse` — the reader: ``scope_table`` / ``classify`` /
+  ``attribute_profile`` over the scopes the program puts on every
+  device op (a layer's name, ``optimizer``, ``fused.<kind>``).
 
 :class:`TelemetrySession` bundles the knob-driven pieces so the task
 driver (main.py) owns exactly one object with one ``close()``.
@@ -100,6 +112,10 @@ class TelemetrySession:
             self.storm = RecompileStormDetector(
                 window_s=cfg.storm_window_s,
                 threshold=cfg.storm_threshold)
+        # the train loop's own spans stay in the tracer's ring without
+        # telemetry_trace (telemetry/trace.py "Two levels"): the same
+        # knob that governs the step-time probe governs them
+        TRACER.keep(("train",) if cfg.steptime else ())
         if cfg.trace_path:
             TRACER.enable(capacity=cfg.trace_capacity)
             # the distributed layer rides the same knob: cross-process

@@ -1,21 +1,89 @@
-"""jax.profiler step brackets: ``telemetry_profile_steps=a-b``.
+"""jax.profiler brackets, and the train step's own description.
 
-The round-granular ``profile_dir`` knob (PR 0) traces the WHOLE loop —
-gigabytes on a long run and useless for isolating one steady-state step.
-This brackets exactly the global steps ``a..b`` (inclusive) with
-``jax.profiler.start_trace``/``stop_trace`` into a dump directory, and
-blocks on the last bracketed step's output before stopping so the
-device-side activity of step ``b`` actually lands in the dump.
+``telemetry_profile_steps=a-b`` brackets exactly the global steps
+``a..b`` (inclusive) into a dump directory — the round-granular
+``profile_dir`` knob traces the WHOLE loop, gigabytes on a long run —
+and blocks on the last bracketed step's output before stopping, so its
+device-side activity lands in the dump. The round log then prints the
+attribution of those steps (telemetry/traceparse.py).
+
+Device tracer only: with the host tracer on, the runtime writes one
+event per inner call of the host-side re-tiling of every batch it
+copies to the device (4.6 M events, 1.85 s a batch against
+milliseconds: PERF.md, PR 23), and Python frames evict the op events
+from the profiler's capped buffer.
+
+The step describes itself: on ``update()`` the trainer registers
+(weakly; nothing is lowered) how to lower the step it ran, and
+:func:`step_hlo_text` / :func:`step_scope_table` lower, compile (served
+from the compile caches) and memoise on demand — a reader of a dump
+needs no handle on the ``Trainer``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import re
-from typing import Any, Optional, Tuple
+import time
+import weakref
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from .trace import TRACER
 
 _RANGE_RE = re.compile(r"^\s*(\d+)\s*-\s*(\d+)\s*$")
+
+# -- the step's own description ----------------------------------------------
+
+_step: Dict[str, Any] = {"lower": None, "text": None, "scopes": None}
+
+
+def register_step(lower: Callable[[], Any]) -> None:
+    """``lower``: a bound method that returns the ``jax.stages.Lowered``
+    of the train step its trainer last ran. Held weakly; forgets what
+    was memoised for an earlier step."""
+    _step.update(lower=weakref.WeakMethod(lower), text=None, scopes=None)
+
+
+def step_hlo_text() -> Optional[str]:
+    """The compiled text of the registered train step (instruction
+    names as a device trace shows them, ``op_name`` metadata included),
+    or ``None`` when no trainer has run a step (or it is gone)."""
+    if _step["text"] is None:
+        lower = _step["lower"]() if _step["lower"] is not None else None
+        if lower is None:
+            return None
+        _step["text"] = lower().compile().as_text()
+    return _step["text"]
+
+
+def step_scope_table() -> Dict[str, str]:
+    """``traceparse.scope_table`` of :func:`step_hlo_text`, memoised;
+    empty when there is no step to describe."""
+    if _step["scopes"] is None:
+        from .traceparse import scope_table
+        text = step_hlo_text()
+        _step["scopes"] = scope_table(text) if text else {}
+    return _step["scopes"]
+
+
+# -- brackets ---------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: str):
+    """A ``jax.profiler`` bracket with the device tracer only (host and
+    Python tracers at level 0, see the module docstring). Yields the
+    ``(time.time_ns(), time.perf_counter())`` pair taken at start,
+    through which ``traceparse.attribute_profile`` puts the ``train.*``
+    spans on the dump's clock."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = opts.host_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        yield time.time_ns(), time.perf_counter()
+    finally:
+        jax.profiler.stop_trace()
 
 
 def parse_step_range(spec: str) -> Tuple[int, int]:
@@ -50,18 +118,13 @@ class StepProfiler:
         self.active = False
         self.done = False
         self._bracket = None
+        self._clock: Optional[Tuple[int, float]] = None
 
     def maybe_start(self, step: int) -> None:
         if self.done or self.active or step < self.start_step:
             return
-        # device_trace, not jax.profiler.start_trace: the bracket's
-        # primary consumer is now summarize()'s attribution, and a
-        # python-traced flagship step floods the profiler's event cap
-        # with interpreter frames, evicting the very op events the
-        # table reads (device/HLO activity still lands for xprof)
-        from .traceparse import device_trace
         self._bracket = device_trace(self.dump_dir)
-        self._bracket.__enter__()
+        self._clock = self._bracket.__enter__()
         self.active = True
         TRACER.instant("profiler.start_trace", cat="profile",
                        args={"step": step, "dir": self.dump_dir})
@@ -92,17 +155,15 @@ class StepProfiler:
             self._stop(ready)
 
     def summarize(self) -> Optional[dict]:
-        """Per-phase attribution of the bracketed steps (traceparse) —
-        None until the bracket has closed or when the dump is
-        unparseable. The driver prints ``attribution_fragment`` of this
-        after the bracket closes, turning the profile knob that used to
-        require offline xprof into an in-run phase table."""
+        """``traceparse.attribute_profile`` of the bracketed steps —
+        None until the bracket has closed, and where the dump has
+        nothing to read (the CPU backend has no device plane). The
+        driver prints ``attribution_fragment`` of this after the
+        bracket closes."""
         if not self.done:
             return None
         from .traceparse import attribute_profile
         try:
-            return attribute_profile(
-                self.dump_dir,
-                steps=self.stop_step - self.start_step + 1)
+            return attribute_profile(self.dump_dir, clock=self._clock)
         except Exception:
             return None

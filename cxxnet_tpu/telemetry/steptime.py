@@ -18,9 +18,14 @@ Per-step components:
 * **data_wait** — host blocked pulling the next batch from the input
   pipeline (iterator + prefetch queue). Large => input-bound: buy
   decode threads / prefetch depth, not more chips.
-* **dispatch** — host time inside the update call (staging, tracing the
-  first call, enqueueing). Large next to the device time (a small
-  step) => use ``train_chain``.
+* **dispatch** — host time enqueueing the step inside the update call
+  (staging, tracing the first call, the jitted call itself). Large
+  next to the device time (a small step) => use ``train_chain``.
+* **drain** — host time the update call spent fetching the PREVIOUS
+  step's train-metric outputs (``eval_train``, the default): a wait
+  for the device that lasts about a device step once the host runs
+  ahead, whatever the enqueue costs. Reported apart (``drain_ms``) so
+  that ``dispatch_ms`` tracks the trainer and not the device.
 * **device_block** — how far the device lags the host when the probe
   syncs, i.e. device compute the host did NOT hide behind its own work.
   Large => compute-bound: the chip is the bottleneck.
@@ -55,10 +60,12 @@ class StepTimeProbe:
         # per-step EMAs (seconds); None until the first sync window closes
         self.data_wait_ema: Optional[float] = None
         self.dispatch_ema: Optional[float] = None
+        self.drain_ema: Optional[float] = None
         self.device_block_ema: Optional[float] = None
         self.step_wall_ema: Optional[float] = None
         self._win_data_wait = 0.0
         self._win_dispatch = 0.0
+        self._win_drain = 0.0
         self._win_steps = 0
         self._win_t0: Optional[float] = None
         self._pending_data_wait = 0.0
@@ -94,18 +101,22 @@ class StepTimeProbe:
         self._pending_data_wait += max(0.0, seconds)
 
     def record_step(self, dispatch_s: float, ready: Any = None,
-                    steps: int = 1) -> None:
-        """One dispatched update (or a ``steps``-long fused chain).
-        ``ready`` is any device value produced by the step (the loss) —
-        blocked on only at sync points, never per step."""
+                    steps: int = 1, drain_s: float = 0.0) -> None:
+        """One dispatched update (or a ``steps``-long fused chain):
+        ``dispatch_s`` the enqueue, ``drain_s`` what the same call then
+        spent in the train-metric drain. ``ready`` is any device value
+        produced by the step (the loss) — blocked on only at sync
+        points, never per step."""
         now = time.perf_counter()
         if self._win_t0 is None:
-            self._win_t0 = now - dispatch_s - self._pending_data_wait
+            self._win_t0 = now - dispatch_s - drain_s \
+                - self._pending_data_wait
         self.steps += steps
         self._c_steps.inc(steps)
         self._win_steps += steps
         self._win_data_wait += self._pending_data_wait
         self._win_dispatch += max(0.0, dispatch_s)
+        self._win_drain += max(0.0, drain_s)
         self._pending_data_wait = 0.0
         if self._win_steps < self.sync_interval:
             return
@@ -147,6 +158,7 @@ class StepTimeProbe:
         self.data_wait_ema = mix(self.data_wait_ema,
                                  self._win_data_wait / n)
         self.dispatch_ema = mix(self.dispatch_ema, self._win_dispatch / n)
+        self.drain_ema = mix(self.drain_ema, self._win_drain / n)
         self.device_block_ema = mix(self.device_block_ema, block_s / n)
         self.step_wall_ema = mix(self.step_wall_ema, wall / n)
         self._g_data.set(self.data_wait_ema)
@@ -155,6 +167,7 @@ class StepTimeProbe:
         self._g_wall.set(self.step_wall_ema)
         self._win_data_wait = 0.0
         self._win_dispatch = 0.0
+        self._win_drain = 0.0
         self._win_steps = 0
         self._win_t0 = None
 
@@ -184,8 +197,8 @@ class StepTimeProbe:
         if self.data_wait_ema is None:
             return ""
         ms = lambda v: (v or 0.0) * 1e3
-        return ("\tdata_ms:%.2f\tdispatch_ms:%.2f\tdevice_ms:%.2f"
-                "\tbound:%s" % (ms(self.data_wait_ema),
-                                ms(self.dispatch_ema),
-                                ms(self.device_block_ema),
-                                self.verdict()))
+        return ("\tdata_ms:%.2f\tdispatch_ms:%.2f\tdrain_ms:%.2f"
+                "\tdevice_ms:%.2f\tbound:%s" % (
+                    ms(self.data_wait_ema), ms(self.dispatch_ema),
+                    ms(self.drain_ema), ms(self.device_block_ema),
+                    self.verdict()))

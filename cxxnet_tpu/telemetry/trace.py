@@ -7,12 +7,23 @@ block -> eval -> checkpoint) and the serve request lifecycle
 bounded ring buffer and exported as Chrome trace-event JSON
 (``{"traceEvents": [...]}``), loadable in Perfetto / chrome://tracing.
 
+Two levels of recording. ``enable()`` (the ``telemetry_trace=path``
+knob) records every span of every category, feeds the distributed-trace
+sink and is what ``dump`` exports. Without it the tracer records only
+the categories named by ``keep()``: a ``task = train`` run keeps its
+``cat="train"`` spans by default (``telemetry_steptime``; ``0`` keeps
+nothing), for whoever reads a profiler dump afterwards
+(telemetry/traceparse.attribute_profile, the benchmark's layer metrics).
+
 Design constraints, in order:
 
-* **disabled is free**: every instrumentation point costs one attribute
-  read and a truthiness check when tracing is off (``span`` returns a
-  shared no-op context manager); production code can therefore bracket
-  hot paths unconditionally;
+* **not recorded is free**: an instrumentation point whose category is
+  not recorded costs one attribute read and a set lookup (``span``
+  returns a shared no-op context manager); production code can
+  therefore bracket hot paths unconditionally. A span of a kept
+  category costs one tuple, no lock and no syscall — about half a
+  microsecond (PERF.md has the measurement); ``events()`` makes the
+  dicts;
 * **bounded**: the ring keeps the newest ``capacity`` events and counts
   what it dropped — a week-long run with tracing left on degrades to "the
   last N events", never to an OOM;
@@ -82,14 +93,19 @@ class _Span:
 
 class Tracer:
     """Bounded ring buffer of Chrome trace events; one process-global
-    instance at :data:`TRACER`. ``enable()`` turns recording on (the
-    ``telemetry_trace=path`` knob does this via main.py); every
-    ``span``/``add_complete``/``instant`` call before that is a no-op."""
+    instance at :data:`TRACER`. ``enable()`` turns full recording on
+    (the ``telemetry_trace=path`` knob does this via main.py);
+    ``keep(cats)`` names the categories whose spans are recorded even
+    without it (the train loop's, by default). A
+    ``span``/``add_complete`` call of any other category, and every
+    ``instant``, is a no-op until ``enable()``. The ring is the
+    process's: it outlives any ``TelemetrySession``."""
 
     def __init__(self, capacity: int = 65536):
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=capacity)
         self._enabled = False
+        self._kept: frozenset = frozenset()
         self._t0 = time.perf_counter()
         self.dropped = 0
         self._thread_names: Dict[int, str] = {}
@@ -125,6 +141,12 @@ class Tracer:
     def disable(self) -> None:
         self._enabled = False
 
+    def keep(self, cats=()) -> None:
+        """Record spans of these categories without ``enable()`` — into
+        the same ring, skipping the sink, the thread-name table and the
+        export. ``keep(())`` records nothing again."""
+        self._kept = frozenset(cats)
+
     def clear(self) -> None:
         with self._lock:
             self._buf.clear()
@@ -144,8 +166,8 @@ class Tracer:
     def span(self, name: str, cat: str = "",
              args: Optional[Dict[str, Any]] = None):
         """``with tracer.span("serve.infer", args={...}):`` — records one
-        complete ("X") event on exit. Free when disabled."""
-        if not self._enabled:
+        complete ("X") event on exit. Free when not recorded."""
+        if not self._enabled and cat not in self._kept:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
 
@@ -156,20 +178,31 @@ class Tracer:
         values — for durations measured across threads (queue wait) or
         already measured before the tracer is consulted."""
         if not self._enabled:
+            if cat in self._kept:
+                # a kept category alone: one tuple into the ring (an
+                # atomic append; the oldest falls out uncounted) and
+                # nothing else — events() makes the dict
+                self._buf.append((name, cat, t0, t1, tid if tid is not None
+                                  else threading.get_ident(), args))
             return
+        self._push(self._complete(name, cat, t0, t1,
+                                  tid if tid is not None
+                                  else threading.get_ident(), args))
+
+    def _complete(self, name, cat, t0, t1, tid, args) -> Dict[str, Any]:
         ev = {
             "name": name,
             "ph": "X",
             "ts": (t0 - self._t0) * 1e6,            # microseconds
             "dur": max(t1 - t0, 0.0) * 1e6,
             "pid": os.getpid(),
-            "tid": tid if tid is not None else threading.get_ident(),
+            "tid": tid,
         }
         if cat:
             ev["cat"] = cat
         if args:
             ev["args"] = args
-        self._push(ev)
+        return ev
 
     def instant(self, name: str, cat: str = "",
                 args: Optional[Dict[str, Any]] = None) -> None:
@@ -227,14 +260,25 @@ class Tracer:
     # -- reading / export ------------------------------------------------
     def events(self) -> List[Dict[str, Any]]:
         with self._lock:
-            return list(self._buf)
+            return self._events()
+
+    def _events(self) -> List[Dict[str, Any]]:
+        """The ring as dicts; the caller holds the lock (which a kept
+        span's append does not take: copy again if one lands)."""
+        while True:
+            try:
+                raw = list(self._buf)
+                break
+            except RuntimeError:
+                continue
+        return [self._complete(*e) if type(e) is tuple else e for e in raw]
 
     def dump(self, path: str) -> int:
         """Write the ring as Chrome trace-event JSON (perfetto-loadable);
         returns the event count. Thread-name metadata events are included
         so tracks carry readable names instead of bare tids."""
         with self._lock:
-            events = list(self._buf)
+            events = self._events()
             names = dict(self._thread_names)
             dropped = self.dropped
             # deep copy: a shallow dict() would share the nested

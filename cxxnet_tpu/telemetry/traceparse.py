@@ -1,503 +1,365 @@
-"""Profiler-trace parsing + per-phase attribution for the flagship step.
+"""A profiler dump of the train step, read under the program's own names.
 
-`jax.profiler.start_trace` dumps `plugins/profile/<ts>/` containing an
-``*.xplane.pb`` (the XSpace protobuf — the ground truth, carrying per-op
-stats like ``bytes accessed`` on TPU) and usually a ``*.trace.json.gz``
-(the Chrome-trace rendering of the same events). Both are parsed here
-without any protobuf/tensorflow dependency: the xplane reader walks the
-wire format directly (the tools/import_caffe.py technique) and the json
-reader is plain ``json``.
+The program names what it puts on the device: ``Network.apply`` traces
+every graph layer under ``jax.named_scope(<layer>)``, the step builders
+trace the parameter update under ``optimizer``, every fused op's wrapper
+under ``fused.<kind>`` (ops/fused.note_fused), and autodiff itself wraps
+the forward in ``jvp(..)`` and the backward in ``transpose(jvp(..))``.
+All of it lands in ``metadata={op_name="..."}`` of the compiled module's
+instructions. This file is the way back: :func:`scope_table` maps a
+compiled module's instruction names to those paths, :func:`classify`
+reads a path as ``(phase, layer, kind)`` — the one definition of a
+phase — and :func:`attribute_profile` joins them to a
+``jax.profiler`` dump.
 
-The output of :func:`attribute_profile` is the measured analog of the
-cost-analysis *model* the bench has carried since round 2: device-side
-op events classified into the phases of the Inception-BN step
-(conv / bn_act / pool / lrn / matmul / optimizer / h2d / other), with
-per-phase time shares and — when the backend records them — measured
-HBM bytes, so ``hbm_bytes_per_step`` can finally be calibrated against
-a chip number instead of XLA's pre-fusion estimate (ROADMAP item 1,
-doc/ibn_perf.md).
+What it knows about a dump was read off real traces of the flagship
+step on a TPU v5e (jax 0.9.0, libtpu 0.0.34; PERF.md section 3): a chip
+is the plane ``/device:TPU:<n>``; its line ``XLA Modules`` has one event
+per executable run (the one with most time is the train step, and the
+first of a dump is cut off where the profiler started); its line ``XLA
+Ops`` has one event per executed instruction, nested or sequential,
+named by the instruction's whole HLO text and carrying no category
+stat — an op's own time is its length less the events nested in it;
+the plane ``Task Environment`` carries ``profile_start_time``, the Unix
+nanosecond the dump's times count from, through which the loop's own
+``train.*`` spans (telemetry/trace.py, on ``perf_counter``) reach the
+dump's clock and name its idle gaps, the innermost span winning.
 
-Phase classification is heuristic by construction: XLA names fusions
-after their constituent ops (``tanh_reduce_fusion``) or anonymously
-(``fusion.123``); anonymous events fall into ``other`` (reported with
-their top names) rather than being guessed at. TPU xplanes additionally
-carry an ``hlo_category`` stat which, when present, is trusted over the
-name heuristic.
+Only a chip's dump reads: the CPU backend has no device plane, and
+:func:`attribute_profile` then returns ``None`` rather than guess.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import glob
 import gzip
-import json
 import os
-from typing import Dict, Iterator, List, Optional, Tuple
+import re
+from typing import Dict, List, Optional, Sequence, Tuple
 
-# ---- minimal protobuf wire-format reader (tools/import_caffe.py idiom) ----
+DEVICE_PLANE = "/device:TPU:"
+ENV_PLANE = "Task Environment"
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
+#: the phases of a train step, in the order tables print them
+PHASE_ORDER = ("forward", "backward", "optimizer", "other")
+#: how many of the heaviest layers / longest idle gaps a table names
+TOP_N, TOP_GAPS = 8, 5
 
-def _read_varint(buf: bytes, pos: int) -> Tuple[int, int]:
-    out = shift = 0
-    while True:
-        b = buf[pos]
-        pos += 1
-        out |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return out, pos
-        shift += 7
+# -- from the compiled text to scopes ----------------------------------------
 
-
-def _iter_fields(buf: bytes) -> Iterator[Tuple[int, int, object]]:
-    """Yield (field_number, wire_type, value) for one message's bytes."""
-    pos = 0
-    n = len(buf)
-    while pos < n:
-        key, pos = _read_varint(buf, pos)
-        field, wt = key >> 3, key & 7
-        if wt == 0:
-            val, pos = _read_varint(buf, pos)
-        elif wt == 1:
-            val, pos = buf[pos:pos + 8], pos + 8
-        elif wt == 2:
-            ln, pos = _read_varint(buf, pos)
-            val, pos = buf[pos:pos + ln], pos + ln
-        elif wt == 5:
-            val, pos = buf[pos:pos + 4], pos + 4
-        else:
-            raise ValueError(f"unsupported protobuf wire type {wt}")
-        yield field, wt, val
+_INSTR = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = ")
+_EVENT = re.compile(r"^%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
 
 
-def _signed(v: int) -> int:
-    """Two's-complement int64 view of a varint value."""
-    return v - (1 << 64) if v >= (1 << 63) else v
-
-
-#: public aliases — the ONE minimal wire reader shared across the repo
-#: (io/augment's binaryproto mean import reuses these; only the
-#: standalone tools/import_caffe.py keeps its own copy, being a
-#: no-package-import CLI)
-read_varint = _read_varint
-iter_fields = _iter_fields
-
-
-# ---- XSpace structure (tensorflow/tsl/profiler/protobuf/xplane.proto) ----
-#
-# XSpace  { repeated XPlane planes = 1 }
-# XPlane  { id=1 name=2 lines=3 event_metadata=4(map) stat_metadata=5(map) }
-# XLine   { id=1 name=2 timestamp_ns=3 events=4 display_name=11 }
-# XEvent  { metadata_id=1 offset_ps=2 duration_ps=3 stats=4 }
-# XStat   { metadata_id=1 double=2 uint64=3 int64=4 bytes=5 ref=6 }
-# X*Metadata { id=1 name=2 }  map entries: { key=1 value=2 }
-
-
-@dataclasses.dataclass
-class OpEvent:
-    """One aggregated device-side op: total duration + summed stats."""
-    name: str
-    dur_ps: int
-    count: int = 1
-    stats: Dict[str, float] = dataclasses.field(default_factory=dict)
-    category: str = ""
-
-
-def _parse_metadata_map(buf: bytes) -> Dict[int, str]:
-    """map<int64, X{Event,Stat}Metadata> entry -> {id: name}."""
-    out: Dict[int, str] = {}
-    key = None
-    meta_id, name = 0, ""
-    for field, wt, val in _iter_fields(buf):
-        if field == 1 and wt == 0:
-            key = val
-        elif field == 2 and wt == 2:
-            for f2, wt2, v2 in _iter_fields(val):
-                if f2 == 1 and wt2 == 0:
-                    meta_id = v2
-                elif f2 == 2 and wt2 == 2:
-                    name = v2.decode("utf-8", "replace")
-    out[key if key is not None else meta_id] = name
-    return out
-
-
-def _parse_stat(buf: bytes, stat_names: Dict[int, str]):
-    """XStat -> (name, value) with numeric values preferred."""
-    import struct
-    mid, value = 0, None
-    for field, wt, val in _iter_fields(buf):
-        if field == 1 and wt == 0:
-            mid = val
-        elif field == 2 and wt == 1:
-            value = struct.unpack("<d", val)[0]
-        elif field == 3 and wt == 0:
-            value = float(val)
-        elif field == 4 and wt == 0:
-            value = float(_signed(val))
-        elif field == 5 and wt == 2:
-            value = val.decode("utf-8", "replace")
-        elif field == 6 and wt == 0:
-            value = val          # ref into stat_metadata (string table)
-    name = stat_names.get(mid, str(mid))
-    if isinstance(value, int):   # ref_value: resolve through the table
-        value = stat_names.get(value, str(value))
-    return name, value
-
-
-def parse_xplane(path: str) -> List[dict]:
-    """Parse an ``*.xplane.pb`` into
-    ``[{"name", "lines": [{"name", "events": [OpEvent-per-occurrence]}]}]``.
-    Events are NOT aggregated here (the golden test wants raw structure);
-    :func:`_collect_op_events` aggregates."""
-    with open(path, "rb") as f:
-        buf = f.read()
-    planes = []
-    for field, wt, val in _iter_fields(buf):
-        if field != 1 or wt != 2:
+def scope_table(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` (names without the ``%``) for
+    every instruction of a compiled module's text that carries one. A
+    fusion left without metadata gets its root's (else the first its
+    computation holds); an instruction the compiler made of nothing the
+    program traced — a layout ``copy``, a ``bitcast`` — stays out."""
+    table: Dict[str, str] = {}
+    root_of: Dict[str, str] = {}
+    first_of: Dict[str, str] = {}
+    orphans: List[Tuple[str, str]] = []     # (instruction, computation)
+    cur = None
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c:
+                cur = c.group(1)
             continue
-        plane = {"name": "", "lines": []}
-        event_names: Dict[int, str] = {}
-        stat_names: Dict[int, str] = {}
-        raw_lines: List[bytes] = []
-        for f2, wt2, v2 in _iter_fields(val):
-            if f2 == 2 and wt2 == 2:
-                plane["name"] = v2.decode("utf-8", "replace")
-            elif f2 == 3 and wt2 == 2:
-                raw_lines.append(v2)
-            elif f2 == 4 and wt2 == 2:
-                event_names.update(_parse_metadata_map(v2))
-            elif f2 == 5 and wt2 == 2:
-                stat_names.update(_parse_metadata_map(v2))
-        for lv in raw_lines:
-            line = {"name": "", "events": []}
-            for f3, wt3, v3 in _iter_fields(lv):
-                if f3 == 2 and wt3 == 2:
-                    line["name"] = v3.decode("utf-8", "replace")
-                elif f3 == 4 and wt3 == 2:
-                    mid, dur = 0, 0
-                    stats: Dict[str, float] = {}
-                    for f4, wt4, v4 in _iter_fields(v3):
-                        if f4 == 1 and wt4 == 0:
-                            mid = v4
-                        elif f4 == 3 and wt4 == 0:
-                            dur = v4
-                        elif f4 == 4 and wt4 == 2:
-                            k, v = _parse_stat(v4, stat_names)
-                            if v is not None:
-                                stats[k] = v
-                    line["events"].append(OpEvent(
-                        name=event_names.get(mid, str(mid)), dur_ps=dur,
-                        stats=stats,
-                        category=str(stats.get("hlo_category", ""))))
-            plane["lines"].append(line)
-        planes.append(plane)
-    return planes
+        is_root, name = m.groups()
+        op = _OP_NAME.search(line)
+        if op:
+            table[name] = op.group(1)
+            first_of.setdefault(cur, op.group(1))
+            if is_root:
+                root_of[cur] = op.group(1)
+        else:
+            calls = _CALLS.search(line)
+            if calls:
+                orphans.append((name, calls.group(1)))
+    for name, comp in orphans:
+        scope = root_of.get(comp) or first_of.get(comp)
+        if scope:
+            table[name] = scope
+    return table
 
 
-def parse_trace_json(path: str) -> List[dict]:
-    """``*.trace.json(.gz)`` -> planes in the same shape as
-    :func:`parse_xplane` (pid = plane, tid = line; durations in ps)."""
-    op = gzip.open if path.endswith(".gz") else open
-    with op(path, "rb") as f:
-        doc = json.loads(f.read().decode("utf-8", "replace"))
-    pid_names: Dict[int, str] = {}
-    by_pid: Dict[int, List[OpEvent]] = {}
-    for e in doc.get("traceEvents", []):
-        ph = e.get("ph")
-        if ph == "M" and e.get("name") == "process_name":
-            pid_names[e.get("pid")] = e.get("args", {}).get("name", "")
-        elif ph == "X":
-            args = e.get("args", {}) or {}
-            stats = {k: v for k, v in args.items()}
-            by_pid.setdefault(e.get("pid"), []).append(OpEvent(
-                name=e.get("name", ""),
-                dur_ps=int(float(e.get("dur", 0.0)) * 1e6),  # us -> ps
-                stats=stats,
-                category=str(args.get("hlo_category", ""))))
-    return [{"name": pid_names.get(pid, str(pid)),
-             "lines": [{"name": "", "events": evs}]}
-            for pid, evs in by_pid.items()]
+_WRAPPER = re.compile(r"^([A-Za-z_][\w.\-]*)\((.*)\)$")
+#: wrappers that hold a function's name, not a scope's
+_FUNCTIONS = frozenset({"jit", "pjit"})
+#: names jax's own control flow and call primitives put on the stack
+_STRUCTURE = frozenset({
+    "while", "body", "cond", "scan", "checkpoint", "remat",
+    "rematted_computation", "closed_call", "core_call", "shard_map",
+    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_lin", "pallas_call"})
+#: forward work the step traces outside ``value_and_grad``
+_FORWARD_SCOPES = frozenset({"input_fold"})
 
 
-def find_profile_files(dump_dir: str) -> Dict[str, Optional[str]]:
-    """Newest ``plugins/profile/<ts>`` dump under ``dump_dir`` -> paths
-    of the xplane / trace.json artifacts (either may be None)."""
-    runs = sorted(glob.glob(os.path.join(
-        dump_dir, "plugins", "profile", "*")))
-    out: Dict[str, Optional[str]] = {"xplane": None, "trace_json": None}
-    if not runs:
-        return out
-    run = runs[-1]
-    xp = sorted(glob.glob(os.path.join(run, "*.xplane.pb")))
-    tj = sorted(glob.glob(os.path.join(run, "*.trace.json.gz"))) or \
-        sorted(glob.glob(os.path.join(run, "*.trace.json")))
-    out["xplane"] = xp[0] if xp else None
-    out["trace_json"] = tj[0] if tj else None
-    return out
+def scope_path(op_name: str) -> Tuple[frozenset, List[str]]:
+    """An ``op_name`` taken apart: the transforms that wrap any of its
+    components (``jvp``, ``transpose``, ``jit``..) and the named scopes
+    in order, outermost first. The last component is the primitive's
+    own name and a ``jit(..)`` holds a function's: neither is a scope."""
+    transforms, scopes = set(), []
+    for part in op_name.split("/")[:-1]:
+        wraps = []
+        m = _WRAPPER.match(part)
+        while m:
+            wraps.append(m.group(1))
+            part = m.group(2)
+            m = _WRAPPER.match(part)
+        transforms.update(wraps)
+        if part and not _FUNCTIONS.intersection(wraps) \
+                and not part.startswith("branch_") \
+                and part not in _STRUCTURE:
+            scopes.append(part)
+    return frozenset(transforms), scopes
 
 
-# ---- phase classification ---------------------------------------------------
-
-#: ordered (phase, name substrings) — first match wins. Backward conv ops
-#: are still "conv"; XLA-fused elementwise chains that kept an op kind in
-#: their name classify by it; anonymous fusions land in "other".
-PHASE_RULES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("h2d", ("copy", "transfer", "infeed", "outfeed", "h2d", "d2h",
-             "memcpy", "reshard", "device_put")),
-    ("optim", ("fused_optim", "multi_tensor", "optimizer", "sgd_",
-               "adam", "nag_", "apply_grad")),
-    ("lrn", ("lrn",)),
-    ("pool", ("reduce-window", "reduce_window", "select-and-scatter",
-              "select_and_scatter", "pool")),
-    ("conv", ("conv",)),
-    ("matmul", ("dot", "gemm", "matmul", "einsum")),
-    ("bn_act", ("bn_fwd", "bn_bwd", "_bn_", "batch-norm", "batchnorm",
-                "batch_norm", "rsqrt", "norm", "relu", "stem",
-                "decode_normalize", "epilogue", "bias_act")),
-)
-
-#: TPU ``hlo_category`` stat values -> phase (trusted over the name rules)
-CATEGORY_RULES: Tuple[Tuple[str, str], ...] = (
-    ("convolution", "conv"),
-    ("conv", "conv"),
-    ("reduce window", "pool"),
-    ("select and scatter", "pool"),
-    ("matmul", "matmul"),
-    ("dot", "matmul"),
-    ("data formatting", "h2d"),
-    ("copy", "h2d"),
-    ("infeed", "h2d"),
-    ("outfeed", "h2d"),
-)
-
-#: the table ordering for doc/ibn_perf.md (h2d last, other at the end)
-PHASE_ORDER = ("conv", "bn_act", "pool", "lrn", "matmul", "optim",
-               "h2d", "other")
-
-
-def classify_op(name: str, category: str = "") -> str:
-    """Classify one device op event into a step phase."""
-    cat = (category or "").lower()
-    if cat:
-        for key, phase in CATEGORY_RULES:
-            if key in cat:
-                return phase
-    low = (name or "").lower()
-    for phase, pats in PHASE_RULES:
-        for p in pats:
-            if p in low:
-                return phase
-    return "other"
-
-
-# runtime/bookkeeping events that are not device op work — excluded from
-# attribution (they time the host driving the device, not the step)
-_RUNTIME_MARKERS = (
-    "pjitfunction", "executehelper", "tfrtcpu", "threadpoollistener",
-    "thunkexecutor", "parsearguments", "start_trace", "stop_trace",
-    "__exit__", "profiler.py", "buffer::", "program_interpreter",
-    "xla launch", "stream::", "run graph",
-)
-
-
-#: control-flow CONTAINER ops (their duration includes their children,
-#: which appear as their own events — counting both double-attributes)
-_CONTAINER_PREFIXES = ("while", "conditional", "call")
-
-
-def _is_op_event(ev: OpEvent) -> bool:
-    low = ev.name.lower()
-    if low.startswith("$"):      # python-tracer frames, never op work
-        return False
-    if any(m in low for m in _RUNTIME_MARKERS):
-        return False
-    if any(low.startswith(p) for p in _CONTAINER_PREFIXES):
-        return False
-    # op events are either tagged by the profiler (hlo_op/hlo_module —
-    # the CPU backend's convention) or live on a device plane whose
-    # events the caller already filtered
-    return True
-
-
-def _collect_op_events(planes: List[dict]) -> Tuple[List[OpEvent], str]:
-    """Pick the planes/lines holding device-side op events and aggregate
-    by op name. Preference: planes named like an accelerator device;
-    fallback: any event carrying an ``hlo_op``/``hlo_module`` stat (the
-    CPU backend reports op events on host Eigen threads)."""
-    device = [p for p in planes
-              if "/device:" in p["name"].lower()
-              and "sparsecore" not in p["name"].lower()]
-    chosen: List[OpEvent] = []
-    where = ""
-    if device:
-        where = ",".join(p["name"] for p in device)
-        for p in device:
-            lines = [l for l in p["lines"]
-                     if "step" not in l["name"].lower()
-                     and "module" not in l["name"].lower()]
-            for l in lines:
-                chosen.extend(e for e in l["events"] if _is_op_event(e))
+def classify(scope: Optional[str]) -> Tuple[str, str, str]:
+    """``(phase, layer, kind)`` of one ``op_name``. ``phase``:
+    ``backward`` under ``transpose(..)``, else ``optimizer`` under the
+    scope of that name, else ``forward`` under ``jvp(..)`` (or a
+    forward scope outside autodiff: ``input_fold``), else ``other`` —
+    which is also where an instruction without a scope goes. ``layer``:
+    the outermost scope that is not a fused op's (a graph layer's name,
+    ``optimizer``, ``input_fold``), ``kind``: the ``<kind>`` of the
+    first ``fused.<kind>``; each ``""`` where there is none."""
+    if not scope:
+        return "other", "", ""
+    transforms, scopes = scope_path(scope)
+    kind = next((s[6:] for s in scopes if s.startswith("fused.")), "")
+    layer = next((s for s in scopes if not s.startswith("fused.")), "")
+    if "transpose" in transforms:
+        phase = "backward"
+    elif "optimizer" in scopes:
+        phase = "optimizer"
+    elif "jvp" in transforms or _FORWARD_SCOPES.intersection(scopes):
+        phase = "forward"
     else:
-        where = "host hlo events"
-        for p in planes:
-            for l in p["lines"]:
-                chosen.extend(
-                    e for e in l["events"]
-                    if ("hlo_op" in e.stats or "hlo_module" in e.stats)
-                    and _is_op_event(e))
-    agg: Dict[str, OpEvent] = {}
-    for e in chosen:
-        cur = agg.get(e.name)
-        if cur is None:
-            agg[e.name] = OpEvent(name=e.name, dur_ps=e.dur_ps, count=1,
-                                  stats=dict(e.stats),
-                                  category=e.category)
-        else:
-            cur.dur_ps += e.dur_ps
-            cur.count += 1
-            for k, v in e.stats.items():
-                if isinstance(v, (int, float)):
-                    prev = cur.stats.get(k, 0.0)
-                    if isinstance(prev, (int, float)):
-                        cur.stats[k] = prev + v
-    return list(agg.values()), where
+        phase = "other"
+    return phase, layer, kind
 
 
-_BYTES_STAT_NAMES = ("bytes accessed", "bytes_accessed")
-_FLOPS_STAT_NAMES = ("flops", "model_flops")
+# -- the dump -----------------------------------------------------------------
 
 
-class device_trace:
-    """Context manager: a profiler bracket tuned for ATTRIBUTION —
-    python tracer OFF so the (capped) event buffer holds device/HLO op
-    events instead of millions of interpreter frames (a python-traced
-    flagship step evicts every op event and the attribution reads
-    empty). Falls back to the plain ``jax.profiler`` bracket when the
-    backing ``ProfileOptions`` API is unavailable."""
-
-    def __init__(self, log_dir: str):
-        self.log_dir = log_dir
-        self._session = None
-        self._fallback = False
-
-    def __enter__(self):
-        try:
-            from jax._src.lib import xla_client
-            opts = xla_client.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            self._session = xla_client.profiler.ProfilerSession(opts)
-        except Exception:
-            import jax
-            self._fallback = True
-            jax.profiler.start_trace(self.log_dir)
-        return self
-
-    def __exit__(self, *exc):
-        if self._fallback:
-            import jax
-            jax.profiler.stop_trace()
-        elif self._session is not None:
-            self._session.stop_and_export(self.log_dir)
-        return False
+def find_xplane(dump: str) -> str:
+    """``dump`` itself if it is a file, else the newest ``.xplane.pb``
+    under a ``start_trace`` directory."""
+    if os.path.isfile(dump):
+        return dump
+    found = sorted(glob.glob(os.path.join(
+        dump, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {dump!r}")
+    return found[-1]
 
 
-def attribute_profile(dump_dir: str, steps: int = 1) -> dict:
-    """Parse the newest profile dump under ``dump_dir`` and attribute
-    op time (and, when recorded, HBM bytes) to step phases.
+def _load(path: str):
+    from jax.profiler import ProfileData
+    if path.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
 
-    Returns::
 
-        {"phases": {phase: {"ms": per-step, "pct": share-of-op-time,
-                            "count": events}},
-         "total_op_ms": per-step summed op time,
-         "measured_bytes_per_step": int | None,   # trace memory counters
-         "measured_flops_per_step": float | None,
-         "top_other": [(name, ms), ...],          # unclassified heavies
-         "steps": steps, "source": "xplane"|"trace_json",
-         "device": plane-name note}
+def _own_times(events):
+    """``[(name, start, end, own ns)]`` of ``(name, start, dur)`` events
+    sorted by ``(start, -dur)``: a ``while`` or a call spans the ops of
+    its body and must not count them twice."""
+    own = [e[2] for e in events]
+    stack: List[int] = []
+    for i, (_, start, dur) in enumerate(events):
+        while stack and events[stack[-1]][1] + events[stack[-1]][2] \
+                <= start:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= dur
+        stack.append(i)
+    return [(n, s, s + d, max(0, t))
+            for (n, s, d), t in zip(events, own)]
 
-    Summed op time can exceed wall time on parallel backends (CPU thread
-    pools overlap ops) — shares are of summed op time, which is the
-    honest attribution basis either way. Raises ``FileNotFoundError``
-    when no dump exists; a malformed dump degrades to the other format
-    before failing.
-    """
-    files = find_profile_files(dump_dir)
-    planes = None
-    source = None
-    errors = []
-    for key, parser in (("xplane", parse_xplane),
-                        ("trace_json", parse_trace_json)):
-        if files[key] is None:
+
+def _gaps(intervals, lo, hi):
+    """The parts of ``[lo, hi)`` that no ``(start, end)`` covers."""
+    out, cur = [], lo
+    for s, e in sorted(intervals):
+        if s > cur:
+            out.append((cur, min(s, hi)))
+        cur = max(cur, e)
+        if cur >= hi:
+            break
+    if cur < hi:
+        out.append((cur, hi))
+    return [(a, b) for a, b in out if b > a]
+
+
+def place_spans(spans: Sequence[dict], tracer, clock: Tuple[int, float],
+                profile_start_unix_ns: int):
+    """Ring events (telemetry/trace.py: ``ts``/``dur`` in microseconds
+    of the tracer's ``perf_counter`` epoch) as ``(name, start ns, end
+    ns)`` on a dump's clock; ``clock`` is the ``(time.time_ns(),
+    time.perf_counter())`` pair taken when the profiler started."""
+    unix_ns, perf_s = clock
+    # the ring's ``ts`` of the clock moment is to_ts_us(perf_s); on the
+    # dump's clock that moment is unix_ns - profile_start
+    shift = unix_ns - profile_start_unix_ns - tracer.to_ts_us(perf_s) * 1e3
+    return [(ev["name"], shift + ev["ts"] * 1e3,
+             shift + (ev["ts"] + ev["dur"]) * 1e3)
+            for ev in spans if ev.get("ph") == "X"]
+
+
+def gap_owner(gap, host) -> str:
+    """Which host span covers most of an idle gap (``elsewhere`` when
+    none covers any of it); of spans that cover as much, the shortest:
+    the innermost says most."""
+    best, most = "elsewhere", (0.0, 0.0)
+    for name, s, e in host:
+        cover = min(gap[1], e) - max(gap[0], s)
+        if cover > 0 and (cover, s - e) > most:
+            best, most = name, (cover, s - e)
+    return best
+
+
+def attribute_profile(dump: str, hlo_text: Optional[str] = None,
+                      clock: Optional[Tuple[int, float]] = None,
+                      spans: Optional[Sequence[dict]] = None
+                      ) -> Optional[dict]:
+    """Read the newest dump under ``dump`` (or the file ``dump``) and
+    attribute the first chip's op time to phase x kind x layer through
+    the step's scopes. ``hlo_text``: the compiled step's text (default:
+    ``profiler.step_hlo_text()``, what the trainer's last ``update()``
+    ran). ``clock``: see :func:`place_spans`; with it the longest idle
+    gaps are named by ``spans`` (default: the tracer's ``train``
+    spans). Per-step milliseconds over whole step periods::
+
+        {"steps", "step_ms", "busy_ms", "idle_pct", "device",
+         "phases": {phase: {"ms", "pct", "count"}},       # pct of busy
+         "kinds": {phase: {kind or "xla": ms}},
+         "layers": [(layer, ms)], "unattributed_pct",
+         "top_unattributed": [(instruction, ms)],
+         "idle_gaps": [(span name, ms)]}
+
+    ``None`` where the dump has no chip's plane or no whole step; raises
+    ``FileNotFoundError`` where there is no dump."""
+    from .trace import TRACER
+    profile = _load(find_xplane(dump))
+    ops = modules = None
+    device = ""
+    start_unix_ns = None
+    for plane in profile.planes:
+        if plane.name.startswith(DEVICE_PLANE) and ops is None:
+            device = plane.name
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    ops = sorted(((e.name, int(e.start_ns),
+                                   int(e.duration_ns))
+                                  for e in line.events),
+                                 key=lambda e: (e[1], -e[2]))
+                elif line.name == MODULES_LINE:
+                    modules = [(e.name, int(e.start_ns),
+                                int(e.duration_ns)) for e in line.events]
+        elif plane.name == ENV_PLANE:
+            start_unix_ns = next((v for k, v in plane.stats
+                                  if k == "profile_start_time"), None)
+    if not ops or not modules:
+        return None
+    by_module: Dict[str, int] = {}
+    for name, _, dur in modules:
+        by_module[name] = by_module.get(name, 0) + dur
+    step = max(by_module, key=by_module.get)
+    starts = sorted(s for n, s, _ in modules if n == step)
+    if len(starts) < 3:
+        return None
+    # whole periods, gaps included; the first run is cut off
+    lo, hi, steps = starts[1], starts[-1], len(starts) - 2
+    if hlo_text is None:
+        from .profiler import step_scope_table
+        table = step_scope_table()
+    else:
+        table = scope_table(hlo_text)
+    per = 1e-6 / steps                      # ns -> ms per step
+    phases = {p: {"ms": 0.0, "pct": 0.0, "count": 0} for p in PHASE_ORDER}
+    kinds: Dict[str, Dict[str, float]] = {p: {} for p in PHASE_ORDER}
+    layers: Dict[str, float] = {}
+    loose: Dict[str, float] = {}
+    busy = []
+    for text, s, e, own in _own_times(ops):
+        if not lo <= s < hi:
             continue
-        try:
-            planes = parser(files[key])
-            source = key
-            events, where = _collect_op_events(planes)
-            if events:
-                break
-        except Exception as e:           # fall through to the other format
-            errors.append(f"{key}: {type(e).__name__}: {e}")
-            planes = None
-    if planes is None:
-        raise FileNotFoundError(
-            f"no parseable profile dump under {dump_dir!r}"
-            + (f" ({'; '.join(errors)})" if errors else ""))
-    steps = max(1, int(steps))
-    phases: Dict[str, Dict[str, float]] = {}
-    other: List[Tuple[str, float]] = []
-    total_ps = 0
-    bytes_total = 0.0
-    flops_total = 0.0
-    have_bytes = have_flops = False
-    for ev in events:
-        phase = classify_op(ev.name, ev.category)
-        ms = ev.dur_ps / 1e9
-        total_ps += ev.dur_ps
-        d = phases.setdefault(phase, {"ms": 0.0, "pct": 0.0, "count": 0})
-        d["ms"] += ms
-        d["count"] += ev.count
+        busy.append((s, min(e, hi)))
+        m = _EVENT.match(text)
+        name = m.group(1) if m else text[:48]
+        phase, layer, kind = classify(table.get(name))
+        d = phases[phase]
+        d["ms"] += own * per
+        d["count"] += 1
+        k = kinds[phase]
+        k[kind or "xla"] = k.get(kind or "xla", 0.0) + own * per
         if phase == "other":
-            other.append((ev.name, ms))
-        for k in _BYTES_STAT_NAMES:
-            v = ev.stats.get(k)
-            if isinstance(v, (int, float)) and v > 0:
-                bytes_total += v
-                have_bytes = True
-                break
-        for k in _FLOPS_STAT_NAMES:
-            v = ev.stats.get(k)
-            if isinstance(v, (int, float)) and v > 0:
-                flops_total += v
-                have_flops = True
-                break
-    total_ms = total_ps / 1e9
+            loose[name] = loose.get(name, 0.0) + own * per
+        elif layer:
+            layers[layer] = layers.get(layer, 0.0) + own * per
+    gaps = _gaps(busy, lo, hi)
+    busy_ms = ((hi - lo) - sum(b - a for a, b in gaps)) * per
+    op_ms = sum(d["ms"] for d in phases.values())
     for d in phases.values():
-        d["pct"] = 100.0 * d["ms"] / total_ms if total_ms else 0.0
-        d["ms"] = d["ms"] / steps
-    other.sort(key=lambda kv: -kv[1])
+        d["pct"] = 100.0 * d["ms"] / op_ms if op_ms else 0.0
+        d["count"] = round(d["count"] / steps)
+    host = []
+    if clock is not None and start_unix_ns is not None:
+        if spans is None:
+            spans = [ev for ev in TRACER.events()
+                     if ev.get("cat") == "train"]
+        host = place_spans(spans, TRACER, clock, int(start_unix_ns))
+    top = lambda d, n: sorted(d.items(), key=lambda kv: -kv[1])[:n]
     return {
+        "steps": steps, "device": device,
+        "step_ms": (hi - lo) * per, "busy_ms": busy_ms,
+        "idle_pct": 100.0 * (1.0 - busy_ms / ((hi - lo) * per)),
         "phases": phases,
-        "total_op_ms": total_ms / steps,
-        "measured_bytes_per_step": (bytes_total / steps
-                                    if have_bytes else None),
-        "measured_flops_per_step": (flops_total / steps
-                                    if have_flops else None),
-        "top_other": [(n, ms / steps) for n, ms in other[:8]],
-        "steps": steps,
-        "source": source,
-        "device": where,
+        "kinds": {p: dict(top(k, len(k))) for p, k in kinds.items() if k},
+        "layers": top(layers, TOP_N),
+        "unattributed_pct": phases["other"]["pct"],
+        "top_unattributed": top(loose, TOP_N),
+        "idle_gaps": [(gap_owner(g, host), (g[1] - g[0]) * 1e-6)
+                      for g in sorted(gaps, key=lambda g: g[0] - g[1])
+                      [:TOP_GAPS]] if host else [],
     }
 
 
-def attribution_fragment(att: dict) -> str:
+def attribution_fragment(att: Optional[dict]) -> str:
     """One-line round-log rendering of an attribution (main.py prints it
-    after a telemetry_profile_steps bracket closes)."""
-    parts = []
-    for phase in PHASE_ORDER:
-        d = att["phases"].get(phase)
-        if d:
-            parts.append(f"{phase}:{d['ms']:.2f}ms({d['pct']:.0f}%)")
-    extra = ""
-    if att.get("measured_bytes_per_step"):
-        extra = f" hbm={att['measured_bytes_per_step'] / 1e9:.2f}GB/step"
-    return ("profile[" + " ".join(parts) + "]" + extra) if parts else ""
+    after a ``telemetry_profile_steps`` bracket closes): the phases, the
+    fused kinds inside each, the heaviest layers and the longest idle
+    gaps by the ``train.*`` span that covers them."""
+    if not att:
+        return ""
+    ms = lambda v: f"{v:.2f}ms"
+    parts = [f"{p}:{ms(d['ms'])}({d['pct']:.0f}%)"
+             for p, d in att["phases"].items() if d["count"] or d["ms"]]
+    out = (f"profile[step:{ms(att['step_ms'])} "
+           f"idle:{att['idle_pct']:.1f}% " + " ".join(parts) + "]")
+    fused = [f"{p}/{k}:{ms(v)}" for p, ks in att["kinds"].items()
+             for k, v in ks.items() if k != "xla"]
+    if fused:
+        out += " kinds[" + " ".join(fused) + "]"
+    if att["layers"]:
+        out += " layers[" + " ".join(
+            f"{n}:{ms(v)}" for n, v in att["layers"]) + "]"
+    if att["idle_gaps"]:
+        out += " gaps[" + " ".join(
+            f"{n}:{ms(v)}" for n, v in att["idle_gaps"]) + "]"
+    return out

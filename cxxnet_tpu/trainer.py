@@ -67,7 +67,8 @@ def _fold_input(data, net):
     x, mean, factor = data
     from .ops.fused import selection_site
     from .ops.fused_stem import decode_normalize
-    with selection_site(net.fused_log, "input_fold"):
+    with selection_site(net.fused_log, "input_fold"), \
+            jax.named_scope("input_fold"):
         return decode_normalize(x, mean, factor, net.compute_dtype,
                                 fused=net._fused_now(),
                                 spmd=net.fused_spmd)
@@ -115,6 +116,14 @@ def _scaled_value_and_grad(loss_fn, params, opt_state):
     return (loss / scale, aux), grads
 
 
+def _optimizer_scope():
+    """The ``optimizer`` scope every step builder (std, sp, pp) traces
+    its accumulation and parameter update under — the third phase of
+    telemetry/traceparse.classify, beside the ``jvp``/``transpose``
+    wrappers autodiff puts on the forward and the backward itself."""
+    return jax.named_scope("optimizer")
+
+
 def _apply_accum(opt, period, params, opt_state, accum, sched,
                  finite_axes=()):
     """The period-boundary apply: scale the accumulated grads, step the
@@ -124,11 +133,12 @@ def _apply_accum(opt, period, params, opt_state, accum, sched,
     starts as zeros_like the fp32 masters and jnp.add promotes), so
     update_period composes with every compute-dtype policy; under fp16
     it holds loss-SCALED sums that Optimizer.update unscales at apply."""
-    scaled = jax.tree_util.tree_map(lambda g: g / period, accum)
-    params, opt_state = opt.update(params, scaled, opt_state, sched,
-                                   finite_axes=finite_axes)
-    return params, opt_state, jax.tree_util.tree_map(
-        jnp.zeros_like, accum)
+    with _optimizer_scope():
+        scaled = jax.tree_util.tree_map(lambda g: g / period, accum)
+        params, opt_state = opt.update(params, scaled, opt_state, sched,
+                                       finite_axes=finite_axes)
+        return params, opt_state, jax.tree_util.tree_map(
+            jnp.zeros_like, accum)
 
 
 def _apply_grads(opt, period, do_update, params, opt_state, accum, grads,
@@ -139,14 +149,16 @@ def _apply_grads(opt, period, do_update, params, opt_state, accum, grads,
     axis) — threaded to the fp16 overflow check so every shard agrees on
     skip-vs-apply (see Optimizer.update)."""
     if period > 1:
-        accum = jax.tree_util.tree_map(jnp.add, accum, grads)
+        with _optimizer_scope():
+            accum = jax.tree_util.tree_map(jnp.add, accum, grads)
         if do_update:
             params, opt_state, accum = _apply_accum(
                 opt, period, params, opt_state, accum, sched,
                 finite_axes=finite_axes)
     else:
-        params, opt_state = opt.update(params, grads, opt_state, sched,
-                                       finite_axes=finite_axes)
+        with _optimizer_scope():
+            params, opt_state = opt.update(params, grads, opt_state,
+                                           sched, finite_axes=finite_axes)
     return params, opt_state, accum
 
 
@@ -308,6 +320,11 @@ class Trainer:
         self._base_key = jax.random.PRNGKey(self.seed)
         self._step_count = 0
         self._train_step_fns: Dict[bool, Any] = {}
+        # the step update() last ran, with its arguments' shapes: what
+        # telemetry.profiler.step_hlo_text() lowers on demand
+        self._described_step = None
+        self._described_args = None
+        self.last_drain_s = 0.0     # see _drain_pending_metric
         self._eval_step_fn = None
         self._last_loss = None
         self._sched_cache = None
@@ -1647,7 +1664,8 @@ class Trainer:
                     (loss, (new_state, nodes)), grads = fwd_bwd(
                         p, o, s, d, l, m, e, r)
                     act = None
-                a = jax.tree_util.tree_map(jnp.add, a, grads)
+                with _optimizer_scope():
+                    a = jax.tree_util.tree_map(jnp.add, a, grads)
 
                 p_old, o_old = p, o
                 p, o, a = jax.lax.cond(
@@ -1799,6 +1817,7 @@ class Trainer:
         and sp modes; no accumulation under sp, and no pp (pp models
         are dispatch-floor-irrelevant — their steps are tens of ms)."""
         assert self.params is not None, "call init_model() first"
+        self.last_drain_s = 0.0
         k = len(batches)
         if k == 0:
             raise ValueError("update_chain_batches: empty batch list")
@@ -2016,9 +2035,10 @@ class Trainer:
                     ) -> DataBatch:
         """Traced wrapper over :meth:`_stage_batch` — the host->device
         transfer span ("train.h2d_stage"; dispatch-side duration, the
-        copies themselves are async). Free when tracing is off."""
-        if not TRACER.enabled:
-            return self._stage_batch(batch, for_eval)
+        copies themselves are async). Entered twice a step: from
+        ``prefetch_device`` with the host batch, and from ``update()``
+        with the staged one (a pass-through). The shared no-op span
+        where the tracer does not record ``train`` spans."""
         with TRACER.span("train.h2d_stage", cat="train"):
             return self._stage_batch(batch, for_eval)
 
@@ -2106,6 +2126,7 @@ class Trainer:
         staged by ``stage_batch``/``prefetch_device``."""
         assert self.params is not None, "call init_model() first"
         t_dispatch0 = time.perf_counter()
+        self.last_drain_s = 0.0
         do_update = (self.sample_counter + 1) % self.update_period == 0 \
             if self.update_period > 1 else True
         step = self._get_train_step(do_update, batch)
@@ -2119,42 +2140,27 @@ class Trainer:
         # which normalized eagerly)
         data, label = self._fold_args(staged), staged.label
         rng_in = self._rng_key
-        if self._pp > 1:
+        # std steps take the extra-data tuple; sp/pp staging has none
+        extra = () if self._pp > 1 or self._sp > 1 \
+            else (tuple(staged.extra_data),)
+        args = (self.params, self.opt_state, self.net_state, accum_in,
+                data, label, mask) + extra + (self._rng_key,
+                                              self._sched_scalars())
+        if step is not self._described_step:
+            self._describe_step(step, args)
+        out = step(*args)
+        if self.health_on and self._pp <= 1:
             (self.params, self.opt_state, self.net_state, accum, loss,
-             nodes, self._rng_key) = step(
-                 self.params, self.opt_state, self.net_state,
-                 accum_in, data, label, mask, self._rng_key,
-                 self._sched_scalars())
-        elif self._sp > 1:
-            if self.health_on:
-                (self.params, self.opt_state, self.net_state, accum,
-                 loss, nodes, self._last_health, self._rng_key) = step(
-                     self.params, self.opt_state, self.net_state,
-                     accum_in, data, label, mask, self._rng_key,
-                     self._sched_scalars())
-            else:
-                (self.params, self.opt_state, self.net_state, accum,
-                 loss, nodes, self._rng_key) = step(
-                     self.params, self.opt_state, self.net_state,
-                     accum_in, data, label, mask, self._rng_key,
-                     self._sched_scalars())
-        elif self.health_on:
-            (self.params, self.opt_state, self.net_state, accum, loss,
-             nodes, self._last_health, self._rng_key) = step(
-                 self.params, self.opt_state, self.net_state,
-                 accum_in, data, label, mask, tuple(staged.extra_data),
-                 self._rng_key, self._sched_scalars())
-            # stash the step's inputs (device references, one batch) so
-            # the one-shot NaN-provenance walk can re-run this exact
-            # forward/backward (modelhealth.diagnose_nonfinite)
-            self._health_batch = (data, label, mask,
-                                  tuple(staged.extra_data), rng_in)
+             nodes, self._last_health, self._rng_key) = out
+            if self._sp <= 1:
+                # stash the step's inputs (device references, one batch)
+                # so the one-shot NaN-provenance walk can re-run this
+                # exact forward/backward
+                # (modelhealth.diagnose_nonfinite)
+                self._health_batch = (data, label, mask, extra[0], rng_in)
         else:
             (self.params, self.opt_state, self.net_state, accum, loss,
-             nodes, self._rng_key) = step(
-                 self.params, self.opt_state, self.net_state,
-                 accum_in, data, label, mask, tuple(staged.extra_data),
-                 self._rng_key, self._sched_scalars())
+             nodes, self._rng_key) = out
         if self.update_period > 1:
             self.accum = accum
         self._last_loss = loss
@@ -2415,17 +2421,28 @@ class Trainer:
         return out
 
     def _drain_pending_metric(self) -> None:
-        if self._pending_metric is not None:
-            nodes, batch = self._pending_metric
-            self._pending_metric = None
-            if isinstance(batch, list):
-                # chain-banked nodes: (k, rows, ...) stacked per step
-                for i, b in enumerate(batch):
-                    self._add_metric(self.train_metric,
-                                     {key: v[i]
-                                      for key, v in nodes.items()}, b)
-            else:
-                self._add_metric(self.train_metric, nodes, batch)
+        """Fold the previous step's banked metric nodes into the train
+        metric: a host fetch of device values, so it waits for that
+        step — ``train.metric_drain``, whichever caller (``update``,
+        the chained update, ``train_metric_report``) gets here.
+        ``last_drain_s`` keeps the time, for the loop to hand the
+        step-time probe apart from the enqueue; every update call
+        starts it at zero."""
+        if self._pending_metric is None:
+            return
+        t0 = time.perf_counter()
+        nodes, batch = self._pending_metric
+        self._pending_metric = None
+        if isinstance(batch, list):
+            # chain-banked nodes: (k, rows, ...) stacked per step
+            for i, b in enumerate(batch):
+                self._add_metric(self.train_metric,
+                                 {key: v[i] for key, v in nodes.items()}, b)
+        else:
+            self._add_metric(self.train_metric, nodes, batch)
+        t1 = time.perf_counter()
+        self.last_drain_s = t1 - t0
+        TRACER.add_complete("train.metric_drain", t0, t1, cat="train")
 
     def train_metric_report(self, name: str = "train") -> str:
         self._drain_pending_metric()
@@ -2514,6 +2531,29 @@ class Trainer:
         return bool(self._params_finite_fn(self.params))
 
     # -- introspection -----------------------------------------------------
+    def _describe_step(self, step, args) -> None:
+        """Remember the step about to run by its arguments' shapes,
+        dtypes and shardings, and register (weakly) how to lower it
+        with telemetry.profiler — so that a reader of a profiler dump
+        can ask for the compiled text (``step_hlo_text``) without
+        holding the trainer. Nothing is lowered or compiled here."""
+        from .telemetry import profiler
+
+        def abstract(x):
+            if isinstance(x, jax.Array):
+                # an uncommitted array (a schedule scalar) follows the
+                # others' devices, as in the call itself
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=x.sharding if x.committed else None)
+            return x
+        self._described_step = step
+        self._described_args = jax.tree_util.tree_map(abstract, args)
+        profiler.register_step(self._lower_described_step)
+
+    def _lower_described_step(self):
+        return self._described_step.lower(*self._described_args)
+
     def lower_train_step(self, batch: DataBatch):
         """The jitted train step lowered for ``batch`` (a
         ``jax.stages.Lowered``): its text shows which kernels the trace

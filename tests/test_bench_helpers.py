@@ -59,13 +59,14 @@ def test_analytic_bytes_scales_with_batch(tiny_trainer):
 
 
 def test_profile_attribution_and_calibration(tiny_trainer):
-    """End-to-end: trace a short chain of real flagship steps, parse,
-    and build the calibration entry — the exact path bench.main runs.
-    On CPU the trace has no memory counters, so the ratio must be the
-    analytic cross-check, not a fabricated measurement."""
+    """End-to-end: bracket a few real flagship steps, read the dump, and
+    build the calibration entry — the exact path bench.main runs. The
+    attribution is a chip's: on the CPU backend the dump has no device
+    plane, and the entry says so instead of reading host events; the
+    byte ratio is then the analytic cross-check, not a fabricated
+    measurement."""
     att = bench.profile_attribution(tiny_trainer, 8, 8, k=2)
-    assert "error" not in att, att
-    assert att["total_op_ms"] > 0 and att["phases"]
+    assert "no chip's plane" in att["error"], att
     cost = tiny_trainer.step_cost_analysis(_batch(tiny_trainer, 8, 8))
     analytic = bench.analytic_step_bytes(tiny_trainer, 8)
     e = bench.calibration_entry(cost["bytes_accessed"],
@@ -73,8 +74,12 @@ def test_profile_attribution_and_calibration(tiny_trainer):
                                 analytic["total"])
     assert e["cost_analysis_bytes_per_step"] > 0
     assert e["analytic_vs_cost_ratio"] > 0
-    if att.get("measured_bytes_per_step") is None:
-        assert e["measured_vs_cost_ratio"] is None
+    assert e["measured_vs_cost_ratio"] is None
+    # the step the bracket ran described itself on the way
+    from cxxnet_tpu.telemetry import profiler, traceparse
+    phases = {traceparse.classify(s)[0]
+              for s in profiler.step_scope_table().values()}
+    assert {"forward", "backward", "optimizer"} <= phases
 
 
 def _batch(tr, batch, classes):

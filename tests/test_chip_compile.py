@@ -297,3 +297,66 @@ def test_mesh_epilogue_and_pool_compile_on_four_chips(four_chips, dtype):
                     grad_argnums=(0, 1), specs=[P("data"), P()])
     assert _kernels(text) >= 4
     assert "all-reduce" in text         # the dbias psum
+
+
+# -- the kernels name themselves (PR 24) ---------------------------------------
+
+def _kernel_scopes(text: str):
+    """``[(instruction name, op_name)]`` of the compiled text's Pallas
+    custom calls."""
+    import re
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+        op = re.search(r'op_name="([^"]*)"', line)
+        out.append((name, op.group(1) if op else ""))
+    return out
+
+
+_KINDS = {
+    "bn_act": (lambda x, g, b: fused_bn_act(x, g, b, eps=1e-10, act="relu",
+                                            interpret=False),
+               [((64, 14, 14, 128), BF16), ((128,), F32), ((128,), F32)],
+               (0, 1, 2), {"bn_act_fwd", "bn_act_bwd"}),
+    "pool": (lambda x: fused_pool(x, 2, 2, 2, (0, 0), (0, 0), "max", False,
+                                  False, interpret=False),
+             [((64, 28, 28, 128), BF16)], (0,),
+             {"pool_fwd", "pool_bwd_max"}),
+    "bias_act": (lambda x, b: fused_bias_act(x, b, "relu", interpret=False),
+                 [((64, 13, 13, 384), BF16), ((384,), F32)], (0, 1),
+                 {"bias_act_fwd", "bias_act_bwd"}),
+    "lrn": (lambda x: fused_lrn(x, 5, 1e-4, 0.75, 1.0, interpret=False),
+            [((64, 13, 13, 256), BF16)], (0,), {"lrn_fwd", "lrn_bwd"}),
+    "stem": (lambda x, mean, f: fused_decode_normalize(
+        x, mean, f, BF16, interpret=False),
+        [((32, 64, 64, 3), jnp.uint8), ((64, 64, 3), F32), ((), F32)], (),
+        {"stem_fwd"}),
+    "sgd_apply": (lambda ws, gs, ms, lr, mom: fused_sgd_apply(
+        ws, gs, ms, lr, mom, wd=1e-4, clip=0.0, nag=False, interpret=False),
+        [[((3, 3, 64, 64), F32)]] * 3 + [((), F32), ((), F32)], (),
+        {"sgd_apply_update"}),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+def test_kernel_events_carry_their_kind(one_chip, kind):
+    """What a device trace calls a Pallas kernel is its custom call's
+    instruction name: ``pl.pallas_call(name="<kind>_<fwd|bwd|..>")``
+    puts the kind there (it read ``jvp__`` / ``transpose_jvp__``
+    before), forward and backward, and into the ``op_name``."""
+    from cxxnet_tpu.telemetry.traceparse import classify as tp_classify
+    fn, args, grad_argnums, want = _KINDS[kind]
+    text = _compile(fn, one_chip, args, grad_argnums=grad_argnums)
+    got = _kernel_scopes(text)
+    assert got
+    stems = set()
+    for name, scope in got:
+        stem = name.rsplit(".", 1)[0] if name[-1].isdigit() else name
+        assert stem.startswith(kind + "_"), (name, scope)
+        assert f"/{stem}/pallas_call" in scope, (name, scope)
+        if kind != "sgd_apply":     # the optimizer binds that scope
+            assert tp_classify(scope)[2] == kind, (name, scope)
+        stems.add(stem)
+    assert stems == want

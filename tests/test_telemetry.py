@@ -190,6 +190,136 @@ def test_trace_disabled_is_noop():
     assert tr.events() == []
 
 
+def test_trace_kept_category_records_without_enable():
+    """keep(): spans of the named categories reach the ring without
+    enable() — no sink, no export path — and nothing else does."""
+    tr = Tracer(capacity=8)
+    tr.keep(("train",))
+    with tr.span("train.step_dispatch", cat="train", args={"step": 1}):
+        pass
+    tr.add_complete("train.data_wait", 1.0, 1.5, cat="train")
+    tr.add_complete("serve.infer", 1.0, 1.5, cat="serve")
+    with tr.span("ckpt.save", cat="ckpt"):
+        pass
+    tr.instant("profiler.start_trace", cat="train")
+    sunk = []
+    tr.set_sink(lambda ev: sunk.append(ev) or True)   # would eat all
+    tr.add_complete("train.metric_drain", 2.0, 2.25, cat="train")
+    evs = tr.events()
+    assert [e["name"] for e in evs] == [
+        "train.step_dispatch", "train.data_wait", "train.metric_drain"]
+    assert sunk == []
+    assert evs[1]["dur"] == pytest.approx(0.5e6)
+    assert evs[1]["ts"] == pytest.approx(tr.to_ts_us(1.0))
+    assert evs[0]["pid"] == os.getpid() and evs[0]["args"] == {"step": 1}
+    for i in range(20):                 # bounded: the newest survive
+        tr.add_complete(f"train.e{i}", 3.0, 3.0, cat="train")
+    assert len(tr.events()) == 8 and tr.events()[-1]["name"] == "train.e19"
+    tr.keep(())
+    tr.add_complete("train.data_wait", 1.0, 1.5, cat="train")
+    assert tr.events()[-1]["name"] == "train.e19"
+
+
+_TRAIN_CFG = """
+data = train
+iter = synthetic
+  num_inst = 256
+  num_class = 5
+  input_shape = 1,1,16
+iter = end
+netconfig=start
+layer[+1:h1] = fullc:fc1
+  nhidden = 16
+layer[+1:a1] = relu
+layer[a1->out] = fullc:fc2
+  nhidden = 5
+layer[+0] = softmax
+netconfig=end
+input_shape = 1,1,16
+batch_size = 16
+eta = 0.1
+metric = error
+num_round = 2
+save_model = 0
+dev = cpu
+silent = 1
+print_step = 0
+telemetry_sync_interval = 4
+"""
+
+_LOOP_SPANS = {"train.data_wait", "train.h2d_stage", "train.step_dispatch",
+               "train.metric_drain", "train.device_block"}
+
+
+def _train(extra=""):
+    from cxxnet_tpu.config import parse_config_string
+    from cxxnet_tpu.main import LearnTask
+    from cxxnet_tpu.telemetry import TRACER
+    TRACER.disable()
+    TRACER.clear()
+    try:
+        LearnTask(parse_config_string(_TRAIN_CFG + extra)).run()
+        return TRACER.events()
+    finally:
+        TRACER.disable()
+        TRACER.keep(())
+        TRACER.clear()
+
+
+def test_default_train_run_keeps_the_loops_spans():
+    """No telemetry_trace: the ring still holds the loop's own spans,
+    and only those — after the session has closed."""
+    evs = _train()
+    assert {e["name"] for e in evs} >= _LOOP_SPANS
+    assert {e.get("cat") for e in evs} == {"train"}
+    steps = [e for e in evs if e["name"] == "train.step_dispatch"]
+    assert len(steps) == 2 * (256 // 16)
+    # the drain is its own span, after the enqueue and outside it
+    drains = [e for e in evs if e["name"] == "train.metric_drain"]
+    assert len(drains) >= len(steps) - 1
+    first = steps[1]
+    after = [d for d in drains if d["ts"] >= first["ts"]][0]
+    assert after["ts"] >= first["ts"] + first["dur"] - 1e-3
+    # h2d_stage nests in data_wait (prefetch_device stages in next())
+    waits = [e for e in evs if e["name"] == "train.data_wait"]
+    stage = [e for e in evs if e["name"] == "train.h2d_stage"]
+    assert any(w["ts"] <= s["ts"] and s["ts"] + s["dur"]
+               <= w["ts"] + w["dur"] + 1e-3 for w in waits for s in stage)
+
+
+def test_steptime_zero_keeps_no_spans():
+    assert _train("telemetry_steptime = 0\n") == []
+
+
+def test_telemetry_trace_still_dumps_every_category(tmp_path):
+    path = str(tmp_path / "trace.json")
+    _train(f"telemetry_trace = {path}\neval = test\niter = synthetic\n"
+           "  num_inst = 32\n  num_class = 5\n  input_shape = 1,1,16\n"
+           "iter = end\ntelemetry_profile_steps = 2-3\n"
+           f"telemetry_profile_dir = {tmp_path / 'prof'}\n")
+    doc = json.loads(open(path, "rb").read().decode("utf-8"))
+    names = {e["name"] for e in doc["traceEvents"]}
+    assert names >= _LOOP_SPANS | {"train.eval", "profiler.start_trace",
+                                   "profiler.stop_trace", "thread_name"}
+    cats = {e.get("cat") for e in doc["traceEvents"] if e.get("ph") != "M"}
+    assert {"train", "profile"} <= cats
+
+
+def test_round_log_splits_enqueue_from_drain():
+    """record_step(drain_s=..): dispatch_ms is the enqueue, drain_ms the
+    wait in the metric drain; the verdict rule reads neither."""
+    probe = StepTimeProbe(sync_interval=4, registry=MetricRegistry())
+    for _ in range(8):
+        probe.note_data_wait(0.0001)
+        probe.record_step(dispatch_s=0.002, ready=np.float32(0.0),
+                          drain_s=0.050)
+    assert probe.dispatch_ema == pytest.approx(0.002)
+    assert probe.drain_ema == pytest.approx(0.050)
+    frag = probe.report_fragment()
+    assert "\tdispatch_ms:2.00\tdrain_ms:50.00\tdevice_ms:" in frag
+    assert probe.verdict() == "balanced"    # as without the drain
+
+
 # -- step-time probe --------------------------------------------------------
 
 class _SyncCountingLoss:

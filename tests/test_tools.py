@@ -387,6 +387,41 @@ def _blobproto(chw: "np.ndarray") -> bytes:
             + _pb_field(5, 2, _varint(len(data)) + data))
 
 
+def test_wire_reader_golden_fields():
+    """io/augment's minimal protobuf reader (moved there from
+    telemetry/traceparse.py in PR 24, its one other user gone): every
+    wire type it knows, byte for byte."""
+    import struct
+    from cxxnet_tpu.io.augment import iter_fields, read_varint
+    assert read_varint(b"\x01", 0) == (1, 1)
+    assert read_varint(b"\xac\x02", 0) == (300, 2)         # two bytes
+    assert read_varint(b"\x00\xff\xff\xff\xff\x0f", 1) == (2 ** 32 - 1, 6)
+    msg = (_pb_field(1, 0, _varint(150))
+           + _pb_field(2, 1, struct.pack("<d", 2.5))
+           + _pb_field(3, 2, _varint(3) + b"abc")
+           + _pb_field(4, 5, struct.pack("<f", 1.5))
+           + _pb_field(16, 0, _varint(2 ** 40)))            # two-byte key
+    got = list(iter_fields(msg))
+    assert [(f, wt) for f, wt, _ in got] == [(1, 0), (2, 1), (3, 2),
+                                             (4, 5), (16, 0)]
+    assert got[0][2] == 150 and got[4][2] == 2 ** 40
+    assert struct.unpack("<d", got[1][2])[0] == 2.5
+    assert got[2][2] == b"abc"
+    assert struct.unpack("<f", got[3][2])[0] == 1.5
+    assert list(iter_fields(b"")) == []
+
+
+def test_wire_reader_nested_and_unsupported():
+    from cxxnet_tpu.io.augment import iter_fields
+    inner = _pb_field(1, 0, _varint(7)) + _pb_field(1, 0, _varint(9))
+    outer = _pb_field(7, 2, _varint(len(inner)) + inner)
+    (field, wt, val), = iter_fields(outer)
+    assert (field, wt) == (7, 2)
+    assert [v for _, _, v in iter_fields(val)] == [7, 9]
+    with pytest.raises(ValueError, match="wire type 3"):
+        list(iter_fields(_pb_field(1, 3, b"")))             # group start
+
+
 def test_binaryproto_mean_parse_and_flip():
     from cxxnet_tpu.io.augment import load_binaryproto_mean
     chw = np.arange(3 * 4 * 4, dtype=np.float32).reshape(3, 4, 4)

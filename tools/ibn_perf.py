@@ -76,8 +76,9 @@ def render(m) -> str:
         "Generated by `python tools/ibn_perf.py` (rerun on a "
         "TPU-attached host to refresh it; `bench.py` carries the same "
         "attribution under `attribution`/`calibration`). Method: one jax.profiler trace "
-        "of steady-state chained train steps, device op events parsed "
-        "and classified by `cxxnet_tpu/telemetry/traceparse.py`; the "
+        "of steady-state train steps, the chip's op events attributed "
+        "to forward / backward / optimizer through the step's own "
+        "`jax.named_scope`s by `cxxnet_tpu/telemetry/traceparse.py`; the "
         "spirit of the reference's doc/alexnet_perf.md, applied to the "
         "flagship.",
         "",
@@ -91,11 +92,12 @@ def render(m) -> str:
     if m["platform"] == "cpu":
         lines += [
             "> **CPU-scale capture.** No accelerator was attached when "
-            "this revision was generated; the table below is a real "
-            "measured attribution of the scaled-down flagship on the "
-            "CPU backend — methodology identical, magnitudes NOT "
-            "transferable to TPU. Regenerate on a TPU host for the "
-            "numbers of record.",
+            "this revision was generated. The attribution reads a "
+            "chip's trace (`/device:TPU:<n>`) and says so where there "
+            "is none; the byte figures below are of the scaled-down "
+            "flagship on the CPU backend, magnitudes NOT transferable "
+            "to TPU. Regenerate on a TPU host for the numbers of "
+            "record.",
             "",
         ]
     if "error" in att:
@@ -104,23 +106,29 @@ def render(m) -> str:
         lines += [
             "## Per-phase attribution (share of summed device-op time)",
             "",
-            "| phase | ms/step | % of op time | events |",
-            "|---|---|---|---|",
+            "| phase | ms/step | % of op time | events/step | fused "
+            "kinds inside it (ms/step) |",
+            "|---|---|---|---|---|",
         ]
         phases = att.get("phases", {})
         for ph in PHASE_ORDER:
             d = phases.get(ph)
             if d:
+                kinds = ", ".join(
+                    f"{k} {v:.2f}" for k, v in
+                    att.get("kinds", {}).get(ph, {}).items() if k != "xla")
                 lines.append(f"| {ph} | {d['ms']:.3f} | {d['pct']:.1f} "
-                             f"| {d['count']} |")
+                             f"| {d['count']} | {kinds} |")
         lines += [
             "",
-            f"Total op time {att['total_op_ms']:.2f} ms/step over "
-            f"{att['steps']} traced steps (summed op time can exceed "
-            "wall step time on parallel backends; shares are of op "
-            "time). Largest unclassified events: "
+            f"Total op time {att['busy_ms']:.2f} ms/step over "
+            f"{att['steps']} traced steps of {att['step_ms']:.2f} ms, "
+            f"device idle {att['idle_pct']:.2f} %. Heaviest layers: "
             + ", ".join(f"`{n}` ({ms:.2f} ms)"
-                        for n, ms in att.get("top_other", [])[:4])
+                        for n, ms in att.get("layers", [])[:4])
+            + "; heaviest instructions no scope reaches: "
+            + ", ".join(f"`{n}` ({ms:.2f} ms)"
+                        for n, ms in att.get("top_unattributed", [])[:4])
             + ".",
             "",
         ]
