@@ -4,8 +4,10 @@
 Drives the program's main path once, through the entry points a user
 calls (``cxxnet_tpu.main.LearnTask`` -> ``Trainer`` -> ``save_model`` ->
 ``task = serve``'s ``ServeServer``), at the full width of the flagship:
-Inception-BN, batch 256, 224x224x3, 1000 classes, bfloat16, fused kernels
-left at ``auto``, ``dev = tpu``. Weights and data are random, from
+Inception-BN, batch 256, 224x224x3, 1000 classes, bfloat16, ``dev = tpu``,
+``fused_kernels`` left at ``auto`` — which selects XLA's own code for
+every kind, so the proof is a step with no fused site, no Pallas kernel
+and no host callback in it. Weights and data are random, from
 ``--seed``. ONE process: a chip belongs to one process at a time, so
 nothing here starts a child.
 
@@ -14,7 +16,8 @@ nothing here starts a child.
                                       flagship against one device
     python chip_smoke.py --rehearse-cpu [--chips 4]
                                       the same control flow at a tiny
-                                      size on the CPU backend (kernels
+                                      size on the CPU backend, with
+                                      ``fused_kernels = 1`` (kernels
                                       interpreted) — its last line says
                                       "cpu", so it can never be read as
                                       a chip pass
@@ -96,8 +99,9 @@ def flagship_config(size: dict, dev: str, seed: int, model_dir: str,
     pairs = parse_config_string(data + net) + [
         ("dev", dev), ("seed", str(seed)), ("model_dir", model_dir)]
     if rehearse:
-        # auto means off on the CPU backend: force the kernels on so the
-        # rehearsal walks the same selection code, interpreted
+        # auto selects no kernel: force them on so the rehearsal (and
+        # tests/test_chip_smoke.py) still walks the selection code,
+        # interpreted
         pairs.append(("fused_kernels", "1"))
     return pairs + list(extra)
 
@@ -180,14 +184,19 @@ def train_phase(size, dev, seed, out_dir, rehearse):
     require(sum(comps[1:]) == 0, phase,
             f"compiles after the warm-up step: {comps}")
     sel = _selection(tr)
-    require(sel["fused"] > 0, phase, f"no site took a fused kernel: {sel}")
-    kernels = tr.lower_train_step(staged).as_text().count("tpu_custom_call")
-    if rehearse:
+    text = tr.lower_train_step(staged).as_text()
+    kernels = text.count("tpu_custom_call")
+    if rehearse:                           # fused_kernels = 1, interpreted
+        require(sel["fused"] > 0, phase,
+                f"no site took a fused kernel: {sel}")
         require(kernels == 0, phase, "rehearsal compiled a TPU kernel?")
-    else:
-        require(kernels > 0, phase,
-                "no compiled Pallas kernel (tpu_custom_call) in the "
-                "lowered train step: kernels interpreted or absent")
+    else:                                  # the default: XLA's own code
+        require(sel["fused"] == 0 and kernels == 0, phase,
+                f"fused_kernels = auto selected a kernel: {kernels} "
+                f"tpu_custom_call in the lowered train step, {sel}")
+        require("callback" not in text, phase,
+                "a host callback in the lowered train step (jit would "
+                "not write it to the compile cache)")
     ckpt = tr.checkpoint_path(model_dir, 0)
     tr.save_model(ckpt)
     tr.wait_saves()
@@ -314,7 +323,8 @@ def serve_phase(size, cfg, ckpt, tr, seed):
 
 def four_chip_phase(size, kind, seed, out_dir, rehearse):
     """The data-parallel flagship: the same global batch on ``kind:0-3``
-    (fused islands, sync-BN psum) and on ``kind:0``, one process."""
+    (sync-BN over the mesh; the rehearsal's forced kernels as shard_map
+    islands) and on ``kind:0``, one process."""
     phase = "dp4"
     model_dir = os.path.join(out_dir, "models")
     t0 = time.perf_counter()
@@ -322,8 +332,9 @@ def four_chip_phase(size, kind, seed, out_dir, rehearse):
         size, f"{kind}:0-3", seed, model_dir, rehearse))
     require(tr4.mesh.data_parallel == 4, phase,
             f"mesh is not dp=4: {dict(tr4.mesh.mesh.shape)}")
-    require(tr4.net._fused_now() and tr4.net.fused_spmd is not None, phase,
-            "fused islands are off on the dp mesh")
+    require(tr4.net._fused_now() == rehearse, phase,
+            "fused islands are off on the dp mesh under fused_kernels = 1"
+            if rehearse else "fused_kernels = auto selected the kernels")
 
     def spread(arr):
         return len({s.device for s in arr.addressable_shards})
@@ -342,9 +353,10 @@ def four_chip_phase(size, kind, seed, out_dir, rehearse):
     require("all-reduce" in text, phase,
             "no all-reduce in the compiled dp=4 step")
     kernels = text.count("tpu_custom_call")
-    require(rehearse or kernels > 0, phase,
-            "no compiled Pallas kernel in the dp=4 step")
     sel4 = _selection(tr4)
+    require(kernels == 0 and (sel4["fused"] > 0) == rehearse, phase,
+            f"{kernels} tpu_custom_call in the compiled dp=4 step, "
+            f"selection {sel4}")
     dp4_s = time.perf_counter() - t0
     peak4 = _peak_bytes()
     task4.telemetry.close()

@@ -93,10 +93,12 @@ _FUSED_MODES = {
 
 def parse_fused_mode(val: str) -> str:
     """Canonicalize the ``fused_kernels`` knob (doc/tasks.md "Fused
-    kernels") to auto|on|off. ``auto`` selects the Pallas kernels on
-    TPU backends only; ``on`` forces them everywhere (interpret mode
-    off-TPU — the CPU test path); ``off`` is the escape hatch back to
-    the jnp references. The same values are honored by the
+    kernels") to auto|on|off. ``auto`` selects a Pallas kernel only
+    for a kind that has won a benchmark cell on the chip — none has, so
+    it runs the jnp references on every backend; ``on`` forces the
+    kernels everywhere (compiled on a TPU, interpret mode off-TPU — the
+    CPU test path); ``off`` is the jnp references without the relu
+    folding. The same values are honored by the
     ``CXXNET_FUSED_KERNELS`` env override (ops/fused.py)."""
     canon = _FUSED_MODES.get(str(val).strip().lower())
     if canon is None:

@@ -30,33 +30,6 @@ import jax.numpy as jnp
 
 from .base import Layer, is_flat, register_layer
 
-def _clamp_check_enabled() -> bool:
-    """Trace-time gate for the variance-clamp telemetry: set
-    CXXNET_BN_CLAMP_WARN=0 to keep the min + cond + host-callback ops
-    out of the compiled step entirely — timed paths (bench) opt out so
-    outfeed-sensitive backends don't pay for diagnostics."""
-    import os
-    return os.environ.get("CXXNET_BN_CLAMP_WARN", "1") != "0"
-
-
-def _warn_variance_clamp(layer, worst):
-    """Host callback: the one-pass E[x^2]-E[x]^2 moment went negative by
-    more than eps on some channel — f32 cancellation is eating variance
-    (|mean| >> std), and the clamp is silently degrading that channel
-    toward inv = rsqrt(eps). Strictly more likely under a reduced compute
-    policy, hence the loud warning (ADVICE r5). Once per layer INSTANCE:
-    two models sharing a layer name must each get their own warning."""
-    if getattr(layer, "_clamp_warned", False):
-        return
-    layer._clamp_warned = True
-    print(f"WARNING batch_norm {layer.name!r}: one-pass variance went "
-          f"negative (min E[x^2]-E[x]^2 = {float(worst):.3e}, beyond eps "
-          f"{layer.eps:.1e}) and was clamped to 0 — f32 cancellation on a "
-          f"large-mean/low-variance channel; normalization degrades "
-          f"toward rsqrt(eps) there. Consider rescaling inputs or "
-          f"raising eps.", flush=True)
-
-
 class _BatchNormBase(Layer):
     moving_avg = True
     has_params = True
@@ -155,33 +128,18 @@ class _BatchNormBase(Layer):
                     # variance reduction DEPEND on the mean, forcing XLA to
                     # read the conv output twice; sibling independent
                     # reductions fuse into one multi-output kernel (one
-                    # read). The step is HBM-bound (doc/bytes_audit.md), so
-                    # the saved read is real throughput. Tradeoff: f32
-                    # cancellation loses variance precision when
+                    # read). What the saved read is worth on the chip:
+                    # not measured (no cell sets bn_two_pass). Tradeoff:
+                    # f32 cancellation loses variance precision when
                     # |mean| >> std (error ~1e-7 x mean^2 absolute);
                     # acceptable for post-conv activations, and the clamp
                     # guards the tiny-negative case, but a pathological
                     # large-mean/low-var channel degrades toward
-                    # inv = rsqrt(eps).
+                    # inv = rsqrt(eps). health = 1 watches for it: the
+                    # probe's bn_var_min reads 0 there and the
+                    # bn_collapse advice fires (telemetry/modelhealth.py).
                     raw_var = ex2 - jnp.square(mean)
                 var = jnp.maximum(raw_var, 0.0)
-                if not self.two_pass and ctx.stat_sink is None \
-                        and _clamp_check_enabled():
-                    # clamp telemetry (ADVICE r5): a tiny negative is
-                    # expected f32 noise, but a clamp beyond eps means real
-                    # variance was cancelled away — warn once per layer,
-                    # host-side. Skipped inside the pipeline stat-sink path
-                    # (the stage bodies run under a custom-vjp lax.switch
-                    # schedule where host callbacks are not worth the
-                    # risk); the moments merge in the trainer there anyway.
-                    worst = jnp.min(raw_var)
-                    jax.lax.cond(
-                        worst < -self.eps,
-                        lambda w: jax.debug.callback(
-                            lambda v, _l=self: _warn_variance_clamp(_l, v),
-                            w),
-                        lambda w: None,
-                        worst)
                 inv = jax.lax.rsqrt(var + self.eps)
                 out = (x - mean) * inv * slope + bias
                 if act == "relu":

@@ -70,9 +70,10 @@ class Network:
         # is temp_col_max's memory/compute staging, SURVEY §5)
         self.remat = bool(int(global_param(cfg, "remat", "0")))
         # fused Pallas kernel suite (ops/fused.py; doc/tasks.md "Fused
-        # kernels"): fused_kernels = auto|1|0 — auto selects on TPU,
-        # 1 forces (interpret off-TPU, the test path), 0 restores the
-        # jnp references. The trainer clears fused_single_device on
+        # kernels"): fused_kernels = auto|1|0 — auto selects the kinds
+        # that won a cell on the chip (none: the jnp references), 1
+        # forces the kernels (interpret off-TPU, the test path), 0 is
+        # the jnp references. The trainer clears fused_single_device on
         # multi-device meshes: a pallas_call is opaque to the GSPMD
         # partitioner and fused BN moments would be shard-local where
         # the jnp path is sync-BN.

@@ -3,19 +3,25 @@
 
 Selection contract — the one rule every fused op follows:
 
-* ``fused_kernels = auto`` (default): kernels are selected on TPU
-  backends only; every other backend runs the jnp reference the layer
-  already shipped. This is the production setting — the fused kernels
-  exist to move fewer HBM bytes per step, which only a real TPU pays
-  for.
+* ``fused_kernels = auto`` (default): a kernel is selected only for a
+  kind that has won a benchmark cell on the chip against its jnp/XLA
+  form — and none has (PERF.md section 6, PR 25 and PR 26: ``bn_act``,
+  ``bias_act``, ``lrn``, ``pool`` and ``sgd_apply`` each lost every
+  cell that reaches them, by the kernels' own time plus the
+  ``copy``/``reshape`` XLA puts around their ``[rows*H*W, C]``
+  operands; the kinds no cell reaches share that operand contract and
+  have no chip pair). So today ``auto`` runs the jnp reference of
+  every kind on every backend, TPU included.
 * ``fused_kernels = 1``: kernels are selected everywhere; on the CPU
   backend they run under ``interpret=True`` (the SAME kernel code is
   exercised by CPU tests and smokes). On a TPU backend they are always
   compiled: a kernel the chip's compiler refuses raises, it never
-  gives way to its reference.
-* ``fused_kernels = 0``: jnp references everywhere — the escape hatch.
-* env ``CXXNET_FUSED_KERNELS`` overrides the config knob with the same
-  values (ops-level kill switch that needs no config edit).
+  gives way to its reference. The explicit way back for a
+  configuration no cell covers.
+* ``fused_kernels = 0``: jnp references everywhere, and no relu
+  folding into producers (model.py reads the knob, not the env).
+* env ``CXXNET_FUSED_KERNELS`` overrides the knob's kernel selection
+  with the same values (ops-level switch that needs no config edit).
 
 Gating beyond the knob (callers, not this module): a ``pallas_call``
 is an opaque custom call the GSPMD partitioner cannot shard, so on a
@@ -68,15 +74,13 @@ resolve_mode = parse_fused_mode
 
 def kernels_active(mode: str) -> bool:
     """Trace-time selection decision for a resolved mode string. The
-    ``CXXNET_FUSED_KERNELS`` env var wins over the config knob."""
+    ``CXXNET_FUSED_KERNELS`` env var wins over the config knob. Only
+    ``on`` selects kernels: ``auto`` would add the kinds that won a
+    cell on the chip, and there is none (module docstring)."""
     env = os.environ.get("CXXNET_FUSED_KERNELS", "")
     if env:
         mode = resolve_mode(env)
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    return jax.default_backend() == "tpu"
+    return mode == "on"
 
 
 @dataclasses.dataclass(frozen=True)

@@ -234,9 +234,8 @@ class Trainer:
         # cxxnet_fused_fallback_total{reason} counter, so a mesh run
         # that still falls back is visible in /metrics and the ledger.
         from .ops.fused import FusedSpmd, kernels_active, note_fallback
-        # warn/count only when the kernels WOULD have run (knob x env x
-        # backend) — an auto-on-CPU run loses nothing and should not
-        # spam the fallback counter
+        # warn/count only when the kernels WOULD have run (knob x env):
+        # an auto run selected none, loses nothing and says nothing
         would_fuse = kernels_active(self.net.fused_mode)
         # one selection log for layers, input fold and optimizer
         self.optimizer.fused_log = self.net.fused_log
@@ -2554,26 +2553,23 @@ class Trainer:
     def _lower_described_step(self):
         return self._described_step.lower(*self._described_args)
 
-    def lower_train_step(self, batch: DataBatch):
-        """The jitted train step lowered for ``batch`` (a
-        ``jax.stages.Lowered``): its text shows which kernels the trace
-        selected, and compiling it gives the executable's cost and
-        memory analysis — nothing runs."""
+    def _train_step_call(self, batch: DataBatch):
+        """(jitted train step, the arguments ``update()`` would hand it
+        for ``batch``): what :meth:`lower_train_step` lowers and the
+        selection tests trace."""
         assert self.params is not None, "call init_model() first"
         step = self._get_train_step(True, batch)
         mask = self._mask(batch)
         rng = self._rng_key_at(0)       # update()'s: the SAME program
         accum_in = self.accum if self.update_period > 1 else {}
+        head = (self.params, self.opt_state, self.net_state, accum_in)
+        tail = (rng, self._sched_scalars())
         if self._pp > 1:
             data, label = self.mesh.shard_batch(batch.data, batch.label)
-            return step.lower(self.params, self.opt_state, self.net_state,
-                              accum_in, data, label, mask, rng,
-                              self._sched_scalars())
+            return step, head + (data, label, mask) + tail
         if self._sp > 1:
             data, label = self._shard_seq_batch(batch.data, batch.label)
-            return step.lower(self.params, self.opt_state, self.net_state,
-                              accum_in, data, label, mask, rng,
-                              self._sched_scalars())
+            return step, head + (data, label, mask) + tail
         data, label = self.mesh.shard_batch(batch.data, batch.label)
         if self._fold_capable(batch):
             # lower the FOLDED step (uint8 in, normalize in-trace) so
@@ -2582,9 +2578,15 @@ class Trainer:
             mean, factor = self._fold_consts(batch.norm)
             data = (data, mean, factor)
         extra = tuple(self.mesh.shard_batch(e) for e in batch.extra_data)
-        return step.lower(self.params, self.opt_state, self.net_state,
-                          accum_in, data, label, mask, extra, rng,
-                          self._sched_scalars())
+        return step, head + (data, label, mask, extra) + tail
+
+    def lower_train_step(self, batch: DataBatch):
+        """The jitted train step lowered for ``batch`` (a
+        ``jax.stages.Lowered``): its text shows which kernels the trace
+        selected, and compiling it gives the executable's cost and
+        memory analysis — nothing runs."""
+        step, args = self._train_step_call(batch)
+        return step.lower(*args)
 
     def step_cost_analysis(self, batch: DataBatch) -> Dict[str, float]:
         """XLA cost analysis of the jitted train step: FLOPs and bytes

@@ -179,10 +179,17 @@ def test_trainer_dp_mesh_keeps_fused_and_matches_single_device():
         assert abs(a - b) < 5e-3, (lm, l1)
 
 
-def test_trainer_pp_mesh_still_clears_with_counter():
+@pytest.mark.parametrize("knob,counted", [("fused_kernels = 1\n", 1),
+                                          ("", 0)], ids=["on", "auto"])
+def test_trainer_pp_mesh_still_clears_with_counter(monkeypatch, knob,
+                                                   counted):
     """Topologies the islands do not cover (pp) still clear the gate —
-    now with the cxxnet_fused_fallback_total{reason} counter bumped."""
+    with the cxxnet_fused_fallback_total{reason} counter bumped under
+    ``fused_kernels = 1``; under ``auto`` no kernel was selected, so
+    nothing is lost and nothing is counted, on a TPU backend either."""
     from cxxnet_tpu.telemetry.registry import get_registry
+    monkeypatch.delenv("CXXNET_FUSED_KERNELS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fam = get_registry().counter(
         "cxxnet_fused_fallback_total",
         "fused kernel suite fallbacks to the reference path, by reason",
@@ -191,12 +198,12 @@ def test_trainer_pp_mesh_still_clears_with_counter():
     cfg = parse_config_string(
         CONV_CFG.replace("layer[5->6] = fullc:fc",
                          "layer[5->6] = fullc:fc\n  stage = 1")
-        + "fused_kernels = 1\npipeline_parallel = 2\n")
+        + knob + "pipeline_parallel = 2\n")
     tr = Trainer(cfg, mesh_ctx=make_mesh_context(
         devices=jax.devices()[:2], pipeline_parallel=2))
     assert not tr.net._fused_now()
     assert not tr.optimizer._fused_active()
-    assert fam.labels("pipeline_parallel").value == before + 1
+    assert fam.labels("pipeline_parallel").value == before + counted
 
 
 def test_sp_mesh_keeps_fused_optimizer():

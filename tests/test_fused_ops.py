@@ -44,17 +44,23 @@ def test_resolve_mode():
         resolve_mode("sometimes")
 
 
-def test_kernels_active_modes(monkeypatch):
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_kernels_active_modes(monkeypatch, backend):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.delenv("CXXNET_FUSED_KERNELS", raising=False)
     assert kernels_active("off") is False
     assert kernels_active("on") is True
-    # auto keys on the backend — CPU test runs resolve to False
-    assert kernels_active("auto") == (jax.default_backend() == "tpu")
-    # env kill switch beats an explicit config 'on'
+    # auto selects a kernel only for a kind that has won a cell on the
+    # chip: none has, so it is the references on every backend
+    assert kernels_active("auto") is False
+    # env switch beats an explicit config 'on' / 'off' / 'auto'
     monkeypatch.setenv("CXXNET_FUSED_KERNELS", "0")
     assert kernels_active("on") is False
     monkeypatch.setenv("CXXNET_FUSED_KERNELS", "1")
     assert kernels_active("off") is True
+    assert kernels_active("auto") is True
+    monkeypatch.setenv("CXXNET_FUSED_KERNELS", "auto")
+    assert kernels_active("on") is False
 
 
 def test_row_block():
@@ -283,14 +289,94 @@ def _train_jaxpr(tr):
 
 
 def test_fused_selected_in_jaxpr():
-    """The selection probe the TPU path relies on: with the knob forced
-    on, the traced train forward contains the fused custom calls; with
-    the escape hatch, the jaxpr is reference-only."""
+    """The selection probe: with the knob forced on, the traced train
+    forward contains the fused custom calls; with the escape hatch, and
+    at the default, the jaxpr is reference-only."""
     assert "pallas_call" in _train_jaxpr(_trainer("fused_kernels = 1\n"))
     assert "pallas_call" not in _train_jaxpr(_trainer("fused_kernels = 0\n"))
-    # default auto resolves by backend — off on the CPU test runner
-    assert ("pallas_call" in _train_jaxpr(_trainer(""))) \
-        == (jax.default_backend() == "tpu")
+    assert "pallas_call" not in _train_jaxpr(_trainer(""))
+
+
+#: conv + batch_norm + relu (the flagship's site) behind a pool
+BN_TOY = """
+input_shape = 3,8,8
+batch_size = 16
+netconfig = start
+layer[0->1] = conv:c1
+  kernel_size = 3
+  nchannel = 24
+  pad = 1
+  no_bias = 1
+layer[1->2] = batch_norm:bn1
+layer[2->3] = relu:r1
+layer[3->4] = max_pooling:p1
+  kernel_size = 2
+  stride = 2
+layer[4->5] = flatten:f
+layer[5->6] = fullc:fc
+  nhidden = 4
+layer[+0] = softmax
+netconfig = end
+"""
+
+#: AlexNet's kinds: conv + bias + relu, lrn, pool, fullc + bias, sgd
+ALEX_TOY = """
+input_shape = 3,8,8
+batch_size = 16
+netconfig = start
+layer[0->1] = conv:c1
+  kernel_size = 3
+  nchannel = 24
+  pad = 1
+layer[1->2] = relu:r1
+layer[2->3] = lrn:l1
+  local_size = 5
+layer[3->4] = max_pooling:p1
+  kernel_size = 2
+  stride = 2
+layer[4->5] = flatten:f
+layer[5->6] = fullc:fc1
+  nhidden = 32
+layer[6->7] = relu:r2
+layer[7->8] = fullc:fc2
+  nhidden = 4
+layer[+0] = softmax
+netconfig = end
+"""
+
+#: CONV_CFG's globals (eta, momentum, wd, dev, eval_train)
+_TOY_TAIL = CONV_CFG.split("netconfig = end\n")[1]
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("net,kinds", [
+    (BN_TOY, {"bn_act", "pool", "bias_act", "sgd_apply"}),
+    (ALEX_TOY, {"bias_act", "lrn", "pool", "sgd_apply"})],
+    ids=["conv_bn_relu", "alexnet_like"])
+def test_default_train_step_is_xla_only(monkeypatch, backend, net, kinds):
+    """The whole jitted train step (forward, backward, optimizer) at the
+    default, ``fused_kernels = auto``: no Pallas kernel and no host
+    callback of any kind in its jaxpr — whatever the backend says it is
+    — and no site in the selection log under ``fused``. With
+    ``fused_kernels = 1`` the same step holds the kernels of every kind
+    the net reaches."""
+    from cxxnet_tpu.ops.fused import selection_counts
+    monkeypatch.delenv("CXXNET_FUSED_KERNELS", raising=False)
+
+    def traced(extra):
+        tr = Trainer(parse_config_string(net + _TOY_TAIL + extra))
+        tr.init_model()
+        step, args = tr._train_step_call(_batch())
+        return str(jax.make_jaxpr(step)(*args)), \
+            selection_counts(tr.net.fused_log)
+
+    forced, by = traced("fused_kernels = 1\n")   # interpreted: before the
+    assert "pallas_call" in forced                # backend is patched
+    assert set(by["fused"]) == kinds
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    default, by = traced("")
+    assert "pallas_call" not in default and "callback" not in default
+    assert not by["fused"] and not by["reference"]
 
 
 def test_env_escape_hatch(monkeypatch):

@@ -52,16 +52,18 @@ def measure():
     fold = bench.input_fold_entry(tr, c, image, classes, batch)
     ab = None
     if on_accel:
-        tr_ref = bench.make_trainer(scale, image, classes, batch,
+        # the headline trainer (fused_kernels = auto) is the reference
+        # path; the rerun forces the kernels
+        tr_fus = bench.make_trainer(scale, image, classes, batch,
                                     platform,
-                                    overrides=(("fused_kernels", "0"),))
-        c_ref = bench.compute_bench(tr_ref, image, classes, batch,
+                                    overrides=(("fused_kernels", "1"),))
+        c_fus = bench.compute_bench(tr_fus, image, classes, batch,
                                     max(3, steps // 2))
-        ab = {"fused_ms": c["per_step_ms"],
-              "ref_ms": c_ref["per_step_ms"],
-              "speedup": c_ref["per_step_ms"] / c["per_step_ms"],
-              "fused_bytes": c["hbm_bytes_per_step"],
-              "ref_bytes": c_ref["hbm_bytes_per_step"]}
+        ab = {"fused_ms": c_fus["per_step_ms"],
+              "ref_ms": c["per_step_ms"],
+              "speedup": c["per_step_ms"] / c_fus["per_step_ms"],
+              "fused_bytes": c_fus["hbm_bytes_per_step"],
+              "ref_bytes": c["hbm_bytes_per_step"]}
     chip = jax.devices()[0].device_kind
     return {"chip": chip, "platform": platform, "scale": scale,
             "image": image, "batch": batch, "c": c, "att": att,
