@@ -3,33 +3,81 @@
 
     python benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-ONE process (a chip belongs to one process). It builds the program's own
+ONE process at a time touches JAX (a chip belongs to one process): the
+command itself only supervises, and the run is its child. Where the
+child's set-up BUILT executables (the persistent compile cache was
+cold), it stops before the window, the cache now holding them, and the
+run is made once more in a fresh child that loads them: a process that
+has compiled its step trains more slowly on a mesh for the rest of its
+life (0.6-1.7 % in ``ibn_dp4``; PERF.md, PR 27), so a checkout's first
+run would differ from every later one in more than ``setup_s``.
+``setup_s`` counts from the command's start, through both children. The
+child builds the program's own
 ``LearnTask`` from the cell's configuration file, opens the cell's feed,
-checks the program against the plain float32 reference, runs a short
-warm-up round and then ONE measured round **through the program's own
+runs a short warm-up round, has the configuration's own reference check
+the program, and then runs ONE measured round **through the program's own
 round loop** (``LearnTask._train_rounds`` -> ``prefetch_device`` ->
 ``_timed_batches`` -> ``Trainer.update``; probe, sentinel, ``print_step``
 at their defaults), and prints one JSON object as its last line:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, when
-traced, ``breakdown``. With ``--trace 0`` the metrics are the cell's
-end-to-end metrics and no profiler runs; with ``--trace 1`` the last
-seconds of the window are profiled (the device tracer only), the dump
-stays under ``benchmarks/.cache/trace/<cell>``, and the metrics are the
-cell's per-layer metrics: those on the host's clock from the window
-before the profiler, those of the device from the trace.
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, when
+traced ``breakdown``, and last ``compared``: every number that decided
+``correct`` beside its limit (on standard error too, as its last line).
+With ``--trace 0`` the metrics are the cell's end-to-end metrics and no
+profiler runs; with ``--trace 1`` the last seconds of the window are
+profiled (the device tracer only), the dump stays under
+``benchmarks/.cache/trace/<cell>``, and the metrics are the cell's
+per-layer metrics: those on the host's clock from the window before the
+profiler, those of the device from the trace.
 
 Driven by data: this file holds no list of cells, configurations,
-traffic mixes or metrics. It finds
+traffic mixes or metrics, and knows no layer kind, no loss shape, no
+tolerance and no item size. It finds
 
 * the cell, its configuration and the metrics it reports in the
   manifest (``BENCHMARK.json`` at the root, or ``--manifest``);
 * the traffic mix in ``traffic/<traffic>.json``, whose ``feed`` names
   ``feeds/<feed>.py``;
+* the configuration's plain reference in ``references/<reference>.py``,
+  named by the configuration file's ``reference``;
 * each per-layer metric's reader in ``layer_metrics/<metric>.py``
 
 under the manifest's own ``paths`` first and this directory second, so a
-later PR adds a cell, a configuration, a mix, a feed or a metric as
-files plus manifest entries, editing nothing that is here.
+later PR adds a cell, a configuration, a mix, a feed, a reference or a
+metric as files plus manifest entries, editing nothing that is here.
+
+What each kind of file is held to:
+
+* a configuration file (JSON): ``net.conf`` (the program's conf beside
+  it), ``overrides``, ``input_shape``, ``item`` and whatever its feed and
+  its reference read; its optional keys and what each is when left out
+  are in ``config_defaults.json`` (``reference``, ``check``,
+  ``items_per_row``: the items a row of a batch trains, checked against
+  the loop's first batch before the window — for ``"item": "token"`` it
+  is the label's width, otherwise 1 — so that ``train_items_per_s_chip``
+  counts images for a convnet and positions for a sequence model);
+* a feed: ``section(traffic, ctx) -> str`` (the conf's data section) and
+  ``open(task, tr, traffic, ctx)`` -> an object with ``batches()`` (an
+  endless stream), ``close()`` and, where one batch is repeated,
+  ``one_batch = True``. ``ctx`` holds ``seed``, ``chips``, ``rows``,
+  ``root``, ``cache_dir``, ``say``, ``input_shape``, ``num_class`` (when
+  the file has it) and ``config``: the whole configuration file;
+* a reference module — the plain reference, which imports nothing of the
+  program — with
+  ``check(kind, view) -> (ok, said)``: the reference's part of
+  ``correct``. ``kind`` is the configuration's ``check``; ``view`` holds
+  ``config``, ``layers`` and ``defaults`` (the parsed layers and global
+  pairs), ``trainer``, ``params0`` (a copy of the initial weights, only
+  when the module's optional ``needs_initial_params(kind)`` says so: the
+  step donates its arguments), ``batch0`` (the loop's first batch),
+  ``warm_losses`` (the warm-up round's), ``dtype`` (the compute dtype),
+  ``rows``, ``chips`` and ``say``. ``said`` is printed: every number
+  compared beside its limit;
+  ``train_step_flops(view) -> float``: the model operations of one step
+  at ``rows`` rows, from shapes, nothing run. It becomes
+  ``view["step_flops"]`` of the readers.
+  A module that lacks either is a non-zero exit that names it;
+* a reader: ``read(view) -> float | None`` (``None``: nothing to read,
+  the metric is left out of the line).
 
 A platform other than ``tpu``, or fewer devices than the cell's
 ``chips``, is a non-zero exit with no result line. ``--rehearse-cpu``
@@ -42,7 +90,7 @@ from __future__ import annotations
 
 import time
 
-_T_PROCESS = time.perf_counter()        # set-up is counted from here
+_T_PROCESS = time.perf_counter()        # the supervisor's starts set-up
 
 import argparse
 import importlib.util
@@ -50,6 +98,8 @@ import json
 import math
 import os
 import shutil
+import signal
+import subprocess
 import sys
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -63,6 +113,13 @@ WARMUP_STEPS = 6
 #: half, if that is shorter); the host-clock metrics are read from the
 #: part before them, where no profiler runs
 TRACE_SECONDS = 3.0
+#: a child's exit code for "my set-up built executables, which the
+#: persistent compile cache now holds: run me again in a fresh process"
+RUN_AGAIN = 75
+
+
+class BuiltInSetup(Exception):
+    """The first child's way out before the window, through ``finally``."""
 
 
 def say(**fields) -> None:
@@ -270,50 +327,34 @@ def run_round(task, tr, rnd: Round, round_no: int) -> float:
     return time.perf_counter()
 
 
-# -- correctness -----------------------------------------------------------
-
-#: |program loss - reference loss| at the first step. The program
-#: computes in bfloat16 (8 bits of mantissa) against the reference's
-#: float32: two summation orders of the same bf16 step differ by 2.8e-4
-#: on the flagship (PERF.md, PR 21) and program and reference by 6.3e-4
-#: (my chip run, PR 23); the bound is PR 21's 5e-3, a fifth of one
-#: bfloat16 epsilon (2**-8) of a loss of 6.9. float32 cells (the CPU
-#: rehearsal) are held to 1e-3.
-LOSS_TOL = {"bfloat16": 5e-3, "float32": 1e-3}
-#: eval-mode logits (centred log-softmax), worst element over the
-#: largest reference element: each bf16 rounding is 2**-8 = 0.4 % and
-#: AlexNet stacks eight weighted layers.
-LOGIT_TOL = {"bfloat16": 5e-2, "float32": 1e-3}
+# -- the command: a supervisor that never touches JAX ---------------------------
+def _die_with_parent():
+    import ctypes
+    ctypes.CDLL(None).prctl(1, signal.SIGKILL)      # PR_SET_PDEATHSIG
 
 
-def check_train_loss(ref, layers, defaults, params0, batch0, loss0, dtype):
-    import jax
-    import numpy as np
-    data = ref.normalise(batch0.data, batch0.norm)
-    fn = jax.jit(ref.make_loss_fn(layers, defaults))
-    want = float(fn(params0, data, np.asarray(_host_label(batch0))))
-    tol = LOSS_TOL[dtype]
-    ok = math.isfinite(want) and abs(loss0 - want) <= tol
-    return ok, {"check": "train_loss", "program": loss0, "reference": want,
-                "abs_diff": abs(loss0 - want), "tolerance": tol}
-
-
-def check_eval_logits(ref, layers, defaults, tr, batch0, dtype):
-    import jax
-    import numpy as np
-    got = ref.centered_log(tr.predict_raw(batch0))
-    data = ref.normalise(batch0.data, batch0.norm)
-    fn = jax.jit(ref.make_eval_fn(layers, defaults))
-    want = ref.centered_log(np.asarray(fn(tr.params, tr.net_state, data)))
-    err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
-    tol = LOGIT_TOL[dtype]
-    return (math.isfinite(err) and err <= tol), {
-        "check": "eval_logits", "rel_err": err, "tolerance": tol,
-        "max_abs_logit": float(np.max(np.abs(want)))}
-
-
-def _host_label(batch):
-    return batch.host_label if batch.host_label is not None else batch.label
+def supervise(argv) -> int:
+    """Runs the run as a child, and once more where the first child says
+    that its set-up built what the compile cache now holds. The child
+    writes to this process's own standard output and error; it is ended
+    with this process, and waited for, on every path out."""
+    code = 1
+    for attempt in (1, 2):
+        proc = subprocess.Popen(
+            [sys.executable, os.path.abspath(__file__), *argv,
+             "--attempt", str(attempt), "--started-at", repr(_T_PROCESS)],
+            preexec_fn=_die_with_parent)
+        for sig in (signal.SIGTERM, signal.SIGINT):
+            signal.signal(sig, lambda *_: proc.terminate())
+        try:
+            code = proc.wait()
+        finally:
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait()
+        if code != RUN_AGAIN:
+            break
+    return code if code >= 0 else 128 - code
 
 
 # -- the run ------------------------------------------------------------------
@@ -345,7 +386,15 @@ def main(argv=None) -> int:
                     default=os.path.join(_ROOT, "BENCHMARK.json"))
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="CPU backend, toy manifests only")
+    # the supervisor's to its children: which of the two, and its start
+    ap.add_argument("--attempt", type=int, default=0, help=argparse.SUPPRESS)
+    ap.add_argument("--started-at", type=float, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    if not args.attempt:
+        return supervise(sys.argv[1:] if argv is None else list(argv))
+    # set-up is counted from the supervisor's start: perf_counter is the
+    # machine's monotonic clock, one for every process
+    t_command = args.started_at
 
     sys.path.insert(0, _ROOT)
     with open(args.manifest) as f:
@@ -360,8 +409,10 @@ def main(argv=None) -> int:
     cfg_entry = named(manifest["configs"], cell["config"], "config")
     cfg_path = os.path.join(os.path.dirname(os.path.abspath(args.manifest)),
                             cfg_entry["file"])
+    with open(os.path.join(_HERE, "config_defaults.json")) as f:
+        cfg_file = json.load(f)["optional_keys"]
     with open(cfg_path) as f:
-        cfg_file = json.load(f)
+        cfg_file.update(json.load(f))
     with open(find_file(dirs, "traffic", cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
     feed_mod = load_module(find_file(dirs, "feeds",
@@ -385,26 +436,33 @@ def main(argv=None) -> int:
         die(f"cell {cell['name']} needs {chips} chip(s), JAX found "
             f"{len(devs)}")
     import numpy as np
-    from benchmarks import flops, reference, trace_reduce
+    from benchmarks import flops, trace_reduce
     from cxxnet_tpu.main import LearnTask
     from cxxnet_tpu.ops.fused import selection_counts
     from cxxnet_tpu.telemetry.anomaly import install_compile_counter
     from cxxnet_tpu.telemetry.registry import REGISTRY
+    ref_path = find_file(dirs, "references", cfg_file["reference"] + ".py")
+    ref_mod = load_module(ref_path)
+    for fn in ("check", "train_step_flops"):
+        if not callable(getattr(ref_mod, fn, None)):
+            die(f"the reference module {ref_path} has no {fn}()")
 
     seed = args.seed % _SEED_MOD
     rows = int(traffic["rows_per_chip"]) * chips
     cache_dir = os.path.join(_HERE, ".cache")
     ctx = {"seed": seed, "chips": chips, "rows": rows, "root": _ROOT,
            "input_shape": tuple(cfg_file["input_shape"]),
-           "num_class": int(cfg_file["num_class"]),
-           "cache_dir": cache_dir, "say": say}
+           "config": cfg_file, "cache_dir": cache_dir, "say": say}
+    if "num_class" in cfg_file:
+        ctx["num_class"] = int(cfg_file["num_class"])
     dev = f"{platform}:0" if chips == 1 else f"{platform}:0-{chips - 1}"
     pairs = build_pairs(cfg_file, cfg_path, dev, seed, rows,
                         feed_mod.section(traffic, ctx),
                         os.path.join(cache_dir, "models"))
     install_compile_counter()
     compiles = REGISTRY.get("cxxnet_compiles_total")
-    marks = [("imports_and_data", time.perf_counter())]
+    marks = [("before_this_process", _T_PROCESS),
+             ("imports_and_data", time.perf_counter())]
     task = LearnTask(pairs)      # enables the compile cache by its rule
     tr = task.trainer
     task._init_model()
@@ -424,38 +482,53 @@ def main(argv=None) -> int:
     wrap_probe(task, spans)
     feed = feed_mod.open(task, tr, traffic, ctx)
     marks.append(("feed_open", time.perf_counter()))
-    check = cfg_file.get("check", "train_loss")
-    params0 = None
-    if check == "train_loss":
+    ref_view = {"config": cfg_file, "layers": tr.graph.layers,
+                "defaults": dict(tr.graph.defcfg), "trainer": tr,
+                "params0": None, "dtype": tr.policy.compute_name,
+                "rows": rows, "chips": chips, "say": say}
+    wants_params0 = getattr(ref_mod, "needs_initial_params", None)
+    if wants_params0 is not None and wants_params0(cfg_file["check"]):
         # the step donates its arguments: keep the initial weights for
         # the reference, which sees the loop's first batch afterwards
         import jax.numpy as jnp
-        params0 = jax.tree_util.tree_map(jnp.copy, tr.params)
+        ref_view["params0"] = jax.tree_util.tree_map(jnp.copy, tr.params)
     try:
         # -- warm-up: a short first round through the same loop and feed
         warm = Round(feed.batches(), spans, steps=WARMUP_STEPS)
         marks.append(("warmup_round", run_round(task, tr, warm, 0)))
         warm_losses = [float(v) for v in jax.device_get(handles)]
         del handles[:]
-        layers, defaults = tr.graph.layers, dict(tr.graph.defcfg)
-        dtype = tr.policy.compute_name
-        if check == "train_loss":
-            ok_ref, ref_said = check_train_loss(
-                reference, layers, defaults, params0, warm.first_batch,
-                warm_losses[0], dtype)
-            params0 = None
-        else:
-            ok_ref, ref_said = check_eval_logits(
-                reference, layers, defaults, tr, warm.first_batch, dtype)
+        ref_view.update(batch0=warm.first_batch, warm_losses=warm_losses)
+        ok_ref, ref_said = ref_mod.check(cfg_file["check"], ref_view)
+        ok_ref = bool(ok_ref)
+        ref_view["params0"] = None
         say(**ref_said, ok=ok_ref)
         marks.append(("reference_check", time.perf_counter()))
+        # what one row of a batch counts for, against the batch itself
+        first = warm.first_batch
+        seen = first.label if first.host_label is None else first.host_label
+        per_row = int(cfg_file["items_per_row"])
+        in_row = int(np.shape(seen)[-1]) if cfg_file["item"] == "token" else 1
+        if per_row != in_row:
+            die(f"{cfg_path} says items_per_row {per_row}, but a row of "
+                f"the first batch holds {in_row} {cfg_file['item']}(s)", 1)
         ok_warm = all(math.isfinite(v) for v in warm_losses)
-        if getattr(feed, "one_batch", False):
-            # one batch, repeated: the loss has to fall
+        # one batch, repeated: the loss has to fall
+        has_to_fall = bool(getattr(feed, "one_batch", False))
+        if has_to_fall:
             ok_warm = ok_warm and warm_losses[-1] < warm_losses[0]
         by = selection_counts(tr.net.fused_log)
         say(warmup_losses=warm_losses, ok=ok_warm,
             fused_kernels={k: dict(c) for k, c in by.items()})
+        # what this set-up built, and did not load: the program's count of
+        # backend compiles, which wraps the cached path too, less its count
+        # of persistent-cache hits. Where the cache is on, a fresh process
+        # finds them there, and the window is its to run
+        hits = REGISTRY.get("cxxnet_compile_cache_hits_total")
+        built = int(compiles.value - (hits.value if hits else 0))
+        say(built_in_setup=built, attempt=args.attempt)
+        if built and args.attempt == 1 and compile_cache_dir():
+            raise BuiltInSetup
         # -- the window: one round of the program's own loop
         profiler = Profiler(os.path.join(cache_dir, "trace",
                                          cell["name"])) \
@@ -464,21 +537,23 @@ def main(argv=None) -> int:
         c0 = compiles.value
         rnd = Round(feed.batches(), spans, seconds=args.seconds,
                     profiler=profiler)
-        setup_s = time.perf_counter() - _T_PROCESS
+        setup_s = time.perf_counter() - t_command
         say(setup_split_s={name: t - t0 for (name, t), t0 in zip(
-            marks, [_T_PROCESS] + [t for _, t in marks])})
+            marks, [t_command] + [t for _, t in marks])})
         t_end = run_round(task, tr, rnd, 1)
         if profiler is not None and profiler.t_start is not None:
             profiler.stop()
         window_s = t_end - rnd.t_first
         n_compiles = int(compiles.value - c0)
         losses = np.asarray(jax.device_get(handles), np.float64)
+    except BuiltInSetup:
+        return RUN_AGAIN
     finally:
         feed.close()
         task.telemetry.close()
     steps = len(losses)
     failed = int(np.sum(~np.isfinite(losses)))
-    items = rows * (steps - failed)
+    items = rows * per_row * (steps - failed)
     correct = bool(ok_ref and ok_warm and failed == 0 and steps > 0)
     # where the host was in the window's slowest steps: a stall shows
     # here as one long period, named by the span that filled it
@@ -556,17 +631,12 @@ def main(argv=None) -> int:
         # the runs whose rate they explain
         lo = rnd.t_first
         hi = profiler.t_start if profiler.t_start else t_end
-        records = []        # shapes only: nothing runs
-        jax.eval_shape(lambda p, d: reference.forward(
-            layers, defaults, p, {}, d, True, record=records),
-            tr.params, jax.ShapeDtypeStruct(
-                (rows,) + tuple(np.shape(warm.first_batch.data)[1:]),
-                np.float32))
-        step_flops = flops.train_step_flops(records)
         view = {"spans": [e for e in spans.events if lo <= e[1] < hi],
                 "span_window_s": hi - lo,
                 "compiles_in_window": n_compiles,
-                "trace": reduced, "step_flops": step_flops,
+                "trace": reduced,
+                # the configuration's own count, from shapes: nothing runs
+                "step_flops": float(ref_mod.train_step_flops(ref_view)),
                 "rows": rows, "chips": chips,
                 "peaks": flops.chip_peaks(devs[0].device_kind)
                 if platform != "cpu" else None}
@@ -582,7 +652,15 @@ def main(argv=None) -> int:
                                       "unit": m["unit"]}
         result["metrics"] = metrics
     result["device"] = device
+    # every number compared, beside its limit: last in the line, and the
+    # last thing on standard error
+    result["compared"] = compared = dict(
+        ref_said, warmup_loss_first=warm_losses[0],
+        warmup_loss_last=warm_losses[-1],
+        warmup_loss_has_to_fall=has_to_fall,
+        nonfinite_steps=failed, nonfinite_steps_limit=0)
     print(json.dumps(result), flush=True)
+    print("compared: " + json.dumps(compared), file=sys.stderr, flush=True)
     return 0
 
 

@@ -17,19 +17,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 RUN = os.path.join(ROOT, "benchmarks", "run.py")
 TOY = os.path.join(ROOT, "tests", "benchmarks", "data", "toy")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+#: the contract's five and ``compared``, which comes last in the line
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 #: what only a chip's trace can give: absent from every CPU line
 DEVICE_DERIVED = {"pallas_ms_per_step", "conv_mxu_pct",
                   "collective_exposed_ms", "device_idle_pct"}
 
 
-def run(*args, manifest=None, rehearse=True, timeout=300):
+def run(*args, manifest=None, rehearse=True, timeout=300, **more_env):
     cmd = [sys.executable, RUN]
     if manifest is not None:
         cmd += ["--manifest", manifest]
     if rehearse:
         cmd += ["--rehearse-cpu"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **more_env)
     env.pop("XLA_FLAGS", None)
     return subprocess.run(cmd + list(args), cwd=ROOT, env=env,
                           capture_output=True, text=True, timeout=timeout)
@@ -45,13 +47,16 @@ def last_line(proc):
     ("toy_records", 1, 1),
     ("toy_alexnet_resident", 1, 0),
     ("toy_dp4", 4, 1),
+    ("toy_lm_resident", 1, 0),
+    ("toy_lm_resident", 1, 1),
 ])
 def test_cpu_rehearsal_ends_in_the_contracts_last_line(cell, chips, trace):
     out = last_line(run(
         "--workload", cell, "--seed", "3000000019", "--seconds", "1",
         "--trace", str(trace),
         manifest=os.path.join(TOY, "BENCHMARK.json")))
-    assert set(out) == RESULT_KEYS
+    assert set(out) == RESULT_KEYS and list(out)[-1] == "compared"
+    assert "tolerance" in out["compared"]
     assert out["correct"] is True
     assert out["attempted"] > 0 and out["failed"] == 0
     assert out["device"]["platform"] == "cpu"
@@ -81,6 +86,33 @@ def test_a_manifest_cell_off_the_chip_exits_nonzero_with_no_result():
     proc = run("--workload", "ibn_resident", "--seed", "1", "--seconds",
                "1", "--trace", "0")
     assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+def test_a_set_up_that_compiled_hands_the_window_to_a_fresh_process(tmp_path):
+    """On a cold persistent compile cache the first child builds the
+    executables and stops before the window; a second child loads them
+    and makes the run, with the first's time in ``setup_s``. On a warm
+    cache there is one child. One result line either way."""
+    def walk():
+        proc = run("--workload", "toy_resident", "--seed", "3000000021",
+                   "--seconds", "1", "--trace", "0",
+                   manifest=os.path.join(TOY, "BENCHMARK.json"),
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax"))
+        said = [json.loads(line) for line in proc.stdout.splitlines()
+                if line.startswith("{")]
+        children = [(s["attempt"], s["built_in_setup"] > 0)
+                    for s in said if "built_in_setup" in s]
+        results = [s for s in said if "correct" in s]
+        assert len(results) == 1 and results[0] == last_line(proc)
+        assert results[0]["correct"] is True
+        split = next(s["setup_split_s"] for s in said if "setup_split_s" in s)
+        assert results[0]["metrics"]["setup_s"]["value"] \
+            == pytest.approx(sum(split.values()), abs=0.5)
+        return children, split["before_this_process"]
+    children, before = walk()
+    assert children == [(1, True), (2, False)] and before > 2.0
+    children, before = walk()
+    assert children == [(1, False)] and before < 2.0
 
 
 NEW_FEED = '''

@@ -84,6 +84,17 @@ def test_configs(manifest):
         assert held["name"] == c["name"] and held["source"] == c["source"]
         assert held["reduced"] == c["reduced"]
         assert held["item"] in ("image", "token")
+        if "reference" in held:
+            # a file under paths, found as a feed is
+            assert NAME.match(held["reference"])
+            assert any(os.path.isfile(os.path.join(
+                ROOT, p, "references", held["reference"] + ".py"))
+                for p in manifest["paths"])
+        if held["item"] == "token":
+            # a row counts for its positions
+            assert held["items_per_row"] == held["input_shape"][-1]
+        else:
+            assert held.get("items_per_row", 1) == 1
         conf = os.path.join(os.path.dirname(os.path.join(ROOT, c["file"])),
                             held["net"]["conf"])
         assert os.path.isfile(conf)

@@ -90,17 +90,20 @@ def scoped(request, monkeypatch):
 
 
 def test_the_manifest_carries_the_eight_entries_appended():
+    """PR 24's eight and the eight they were appended to are there, each
+    once; where in ``per_layer`` is nobody's business: a later PR puts
+    its own wherever it likes."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         names = [m["name"] for m in json.load(f)["per_layer"]]
-    assert names[-8:] == [
-        "forward_ms_per_step", "backward_ms_per_step",
-        "optimizer_ms_per_step", "fused_site_ms_per_step",
-        "fused_bn_ms_per_step", "scope_unattributed_pct",
-        "enqueue_ms_per_step", "h2d_stage_ms_per_step"]
-    assert names[:8] == [
-        "compiles_in_window", "data_wait_pct", "dispatch_ms_per_step",
-        "pallas_ms_per_step", "relayout_ms_per_step", "conv_mxu_pct",
-        "collective_exposed_ms", "device_idle_pct"]
+    for name in (
+            "forward_ms_per_step", "backward_ms_per_step",
+            "optimizer_ms_per_step", "fused_site_ms_per_step",
+            "fused_bn_ms_per_step", "scope_unattributed_pct",
+            "enqueue_ms_per_step", "h2d_stage_ms_per_step",
+            "compiles_in_window", "data_wait_pct", "dispatch_ms_per_step",
+            "pallas_ms_per_step", "relayout_ms_per_step", "conv_mxu_pct",
+            "collective_exposed_ms", "device_idle_pct"):
+        assert names.count(name) == 1, name
 
 
 @pytest.mark.parametrize("name", DEVICE_READERS)

@@ -22,12 +22,25 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import importlib.util  # noqa: E402
+import types  # noqa: E402
+
 from benchmarks import flops, reference  # noqa: E402
 from cxxnet_tpu.config import parse_config_string  # noqa: E402
 from cxxnet_tpu.io.data import DataBatch  # noqa: E402
 from cxxnet_tpu.trainer import Trainer  # noqa: E402
 
 TOL = 1e-5
+
+
+def convnet_module():
+    """``benchmarks/references/convnet.py``, loaded as ``run.py`` loads it."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_convnet", os.path.join(ROOT, "benchmarks", "references",
+                                      "convnet.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 #: the flagship's kinds: conv, batch_norm, relu, max and avg pooling
 #: (overlapping, padded, ceil-mode, global), split, ch_concat, flatten,
@@ -183,6 +196,52 @@ def test_eval_logits_match_the_program_alexnet_kinds(fused):
     assert abs(tr2.last_loss - want_loss) < TOL
 
 
+@pytest.mark.parametrize("kind", ["train_loss", "eval_logits"])
+def test_the_convnet_module_says_what_run_py_said(kind):
+    """The two checks moved from ``run.py`` into ``references/convnet.py``
+    (PR 27): through the module's ``check`` each gives, key for key and
+    number for number, the dictionary ``run.py``'s own function gave —
+    written out here as it stood there."""
+    import jax
+    convnet = convnet_module()
+    assert convnet.LOSS_TOL == {"bfloat16": 5e-3, "float32": 1e-3}
+    assert convnet.LOGIT_TOL == {"bfloat16": 5e-2, "float32": 1e-3}
+    assert convnet.needs_initial_params("train_loss")
+    assert not convnet.needs_initial_params("eval_logits")
+    tr, batch = build(INCEPTION_LIKE if kind == "train_loss"
+                      else ALEXNET_LIKE, 0)
+    layers, defaults = tr.graph.layers, dict(tr.graph.defcfg)
+    params0 = jax.tree_util.tree_map(np.array, tr.params)
+    tr.update(batch)
+    view = {"layers": layers, "defaults": defaults, "trainer": tr,
+            "params0": params0, "batch0": batch,
+            "warm_losses": [tr.last_loss, 0.0], "dtype": "float32",
+            "rows": 6, "chips": 1, "config": {}, "say": print}
+    ok, said = convnet.check(kind, view)
+    if kind == "train_loss":
+        want = float(jax.jit(reference.make_loss_fn(layers, defaults))(
+            params0, reference.normalise(batch.data, batch.norm),
+            np.asarray(batch.label)))
+        before = {"check": "train_loss", "program": tr.last_loss,
+                  "reference": want, "abs_diff": abs(tr.last_loss - want),
+                  "tolerance": 1e-3}
+        assert said["abs_diff"] < TOL
+    else:
+        got = reference.centered_log(tr.predict_raw(batch))
+        ref = reference.centered_log(np.asarray(jax.jit(
+            reference.make_eval_fn(layers, defaults))(
+                tr.params, tr.net_state,
+                reference.normalise(batch.data, batch.norm))))
+        err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+        before = {"check": "eval_logits", "rel_err": err, "tolerance": 1e-3,
+                  "max_abs_logit": float(np.max(np.abs(ref)))}
+        assert said["rel_err"] < 1e-4
+    assert ok is True
+    assert list(said) == list(before) and said == before
+    with pytest.raises(ValueError, match="no check 'perplexity'"):
+        convnet.check("perplexity", view)
+
+
 def test_unknown_layer_kind_is_an_error():
     tr, batch = build(INCEPTION_LIKE.replace("relu:ac2", "sigmoid:ac2"), 0)
     with pytest.raises(ValueError, match="no layer kind 'sigmoid'"):
@@ -229,26 +288,66 @@ def test_flops_hand_counts():
     assert flops.train_step_flops(records) == 2 * conv + 3 * fc
 
 
-def test_flops_of_the_two_nets_from_their_shapes():
-    """The forward counts the literature gives: AlexNet ~0.72 G
-    multiply-adds per 227 image, BN-Inception ~2.0 G per 224 image."""
-    import jax
+def graph_and_shapes(name):
+    """A benchmark conf's graph and the shapes of its weights."""
     from cxxnet_tpu.graph import build_graph
-    for name, lo, hi in (("alexnet", 0.65e9, 0.80e9),
-                         ("inception_bn", 1.8e9, 2.2e9)):
-        with open(os.path.join(ROOT, "benchmarks", "configs",
-                               name + ".conf")) as f:
-            pairs = parse_config_string(f.read())
-        graph = build_graph(pairs)
-        c, y, x = graph.input_shape
-        records = []
-        shapes = _param_shapes(graph, (c, y, x))
-        jax.eval_shape(lambda p, d: reference.forward(
-            graph.layers, dict(graph.defcfg), p, {}, d, True,
-            record=records), shapes,
-            jax.ShapeDtypeStruct((1, y, x, c), np.float32))
-        macs = flops.forward_flops(records) / 2
-        assert lo < macs < hi, (name, macs)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".conf")) as f:
+        graph = build_graph(parse_config_string(f.read()))
+    return graph, _param_shapes(graph, graph.input_shape)
+
+
+def step_count_through_the_module(name, rows):
+    """``references/convnet.py:train_step_flops`` on the view ``run.py``
+    hands it, for a benchmark conf at ``rows`` rows: shapes only."""
+    graph, shapes = graph_and_shapes(name)
+    c, y, x = graph.input_shape
+    view = {"layers": graph.layers, "defaults": dict(graph.defcfg),
+            "trainer": types.SimpleNamespace(params=shapes),
+            "batch0": types.SimpleNamespace(
+                data=np.zeros((1, y, x, c), np.uint8)),
+            "rows": rows, "chips": 1, "config": {}}
+    return convnet_module().train_step_flops(view)
+
+
+@pytest.mark.parametrize("name,lo,hi,gflop_an_image", [
+    ("alexnet", 0.65e9, 0.80e9, 4.1356),
+    ("inception_bn", 1.8e9, 2.2e9, 11.960)])
+def test_flops_of_the_two_nets_from_their_shapes(name, lo, hi,
+                                                 gflop_an_image):
+    """The forward counts the literature gives: AlexNet ~0.72 G
+    multiply-adds per 227 image, BN-Inception ~2.0 G per 224 image. And
+    the step's count the way ``run.py`` asks for it since PR 27, through
+    the reference module: what every ``step_mfu_pct`` on record divides
+    by, to its five digits."""
+    import jax
+    graph, shapes = graph_and_shapes(name)
+    c, y, x = graph.input_shape
+    records = []
+    jax.eval_shape(lambda p, d: reference.forward(
+        graph.layers, dict(graph.defcfg), p, {}, d, True,
+        record=records), shapes,
+        jax.ShapeDtypeStruct((1, y, x, c), np.float32))
+    macs = flops.forward_flops(records) / 2
+    assert lo < macs < hi, (name, macs)
+    step = step_count_through_the_module(name, 8)
+    assert step == 8 * flops.train_step_flops(records)
+    assert float(f"{step / 8 / 1e9:.5g}") == gflop_an_image
+
+
+def test_the_counts_on_record_through_the_reference_module():
+    """What ``run.py`` counted on the chip for 256 rows of the flagship
+    (PR 25: the traced runs' value x period x peak gives it back to the
+    last digit, and did again on both sides in PR 27) is what
+    ``references/convnet.py`` counts: the same code in another file. And
+    the constants PERF.md divides by: % of a v5e per item/s/chip."""
+    flagship = step_count_through_the_module("inception_bn", 256)
+    assert flagship == 3061650554880.0
+    alexnet = step_count_through_the_module("alexnet", 1024)
+    assert alexnet == 4234865147904.0
+    peak = flops.chip_peaks("TPU v5 lite")["bf16_tflops"] * 1e12
+    assert 100 * flagship / 256 / peak == pytest.approx(6.0709e-3, rel=1e-4)
+    assert 100 * alexnet / 1024 / peak == pytest.approx(2.0993e-3, rel=1e-4)
 
 
 def _param_shapes(graph, in_shape):
