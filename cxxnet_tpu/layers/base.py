@@ -177,6 +177,11 @@ class ApplyCtx:
     # scalar stats under its layer name. None = health off (the default
     # path pays one attribute check, nothing more).
     health_sink: Optional[Dict[str, Any]] = None
+    # the batch's label slices by name (``label_vec``), (batch, w) each:
+    # bound by Network.apply where it was handed the whole label, for the
+    # one kind that reads labels as an INPUT (``label_ids``). None
+    # otherwise (inference; the sequence-parallel and pipeline steps).
+    labels: Optional[Dict[str, Any]] = None
 
 
 class Layer:

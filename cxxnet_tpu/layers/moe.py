@@ -19,6 +19,34 @@ Config (sequence node (E,S,1) -> (E,S,1)):
 The load-balancing auxiliary loss (mean fraction-routed * mean gate prob
 per expert, scaled by num_expert) rides the layer state under
 ``_aux_loss`` and is added to the training objective by Network.apply.
+
+``router = sigmoid`` is the other formulation (the DeepSeek-V3 family's
+``noaux_tc``), with no capacity and no dropped token:
+
+  s = sigmoid(x W_r) over ALL ``num_expert`` experts, in float32
+  chosen = top-``topk`` of (s + b)      b: per-expert selection bias,
+                                         layer state, never a gradient
+  g_i = s_i / sum_{j in chosen} s_j * ``routed_scaling_factor``
+  y = shared(x) + sum_{i in chosen and held} g_i E_i(x)
+
+with ``E_i`` (and the ``shared_expert`` shared ones, as one expert of
+that many times the width) SwiGLU without bias, ``nhidden`` wide.
+``expert_first`` / ``expert_held`` name the contiguous range of experts
+THIS chip holds (all of them when left out): the layer routes over all,
+computes the pairs (position, expert) whose expert it holds, and hands
+the partial sum on — one chip's share of an expert-parallel group; no
+code stands in for the other chips or their exchange. Every held pair
+is computed whatever the imbalance: pairs are sorted by expert and the
+experts' products run as grouped matrix products over the groups' true
+sizes (``jax.lax.ragged_dot``), on a buffer with room for
+every pair the held experts can get (positions x min(topk, held)), so
+the step is one executable whatever the routing. After a training
+step's routing ``b_i <- b_i - bias_update_rate * sign(load_i - mean
+load)`` over all experts; there is no auxiliary loss. The layer state
+also carries ``stats``: pairs held, pairs routed elsewhere, pairs
+dropped (0 by construction), the largest held expert's load over the
+mean load of all experts, and max |b| — the trainer adds them to the telemetry
+registry when it drains the train metric.
 """
 
 from __future__ import annotations
@@ -28,7 +56,75 @@ import jax.numpy as jnp
 from jax import lax
 
 from .base import Layer, register_layer
-from .seq import _seq, _unseq
+from .seq import _seq, _unseq, swiglu
+
+#: the order of a sigmoid-routed layer's ``stats`` vector
+MOE_STATS = ("pairs_held", "pairs_elsewhere", "pairs_dropped",
+             "load_max_over_mean", "sel_bias_absmax")
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """``x[perm]`` for a permutation ``perm`` whose inverse is ``inv``:
+    the backward is the gather ``g[inv]``, not a scatter-add."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _rows_of_pairs(xf, order, inv):
+    """Row ``order[m] // K`` of ``xf`` (N, E) for the first ``M`` sorted
+    pairs ``order`` (M,) of the permutation of the N*K (position, slot)
+    pairs whose inverse is ``inv`` (N*K,). The backward brings the rows' gradients back to
+    pair order with the inverse permutation and adds each position's K
+    slots: two dense passes where a gather's own transpose is a
+    scatter-add over rows that repeat."""
+    return xf[order // (inv.shape[0] // xf.shape[0])]
+
+
+def _rows_fwd(xf, order, inv):
+    return _rows_of_pairs(xf, order, inv), (order, inv, xf.shape[0])
+
+
+def _rows_bwd(res, g):
+    order, inv, n = res
+    pairs, m = inv.shape[0], order.shape[0]
+    if m < pairs:
+        g = jnp.concatenate(
+            [g, jnp.zeros((pairs - m, g.shape[1]), g.dtype)], axis=0)
+    back = g[inv].reshape(n, pairs // n, g.shape[1])
+    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_rows_of_pairs.defvjp(_rows_fwd, _rows_bwd)
+
+
+def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, live):
+    """SwiGLU of rows ``xs`` (M, E), sorted by group, through the groups'
+    own weights (G, E, F) / (G, F, E): three grouped matrix products over
+    the groups' true sizes (``jax.lax.ragged_dot``: on a TPU XLA's own
+    grouped kernel, which beat JAX's Pallas one 2.6x at the cell's
+    shapes; PERF.md section 6, PR 28). ``live`` (M,) marks the rows that
+    belong to a group. A row past the last group belongs to none, and on
+    a TPU the kernel leaves it UNWRITTEN, forward and backward — whatever
+    was in that memory, NaN included — so every product's input and
+    output is zeroed there: the mask's own transpose then zeroes the
+    rows' cotangents on the way in and their gradients on the way out."""
+    keep = lambda a: jnp.where(live[:, None], a, jnp.zeros((), a.dtype))
+    dot = lambda a, w: keep(lax.ragged_dot(keep(a), w, group_sizes))
+    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    return dot(h, w_down)
 
 
 @register_layer("moe")
@@ -58,13 +154,21 @@ class MoELayer(Layer):
         elif name == "capacity_factor":
             self.capacity_factor = float(val)
         elif name == "act":
-            if val not in ("gelu", "relu"):
+            if val not in ("gelu", "relu", "swiglu"):
                 raise ValueError(f"unknown moe act {val!r}")
             self.act = val
         elif name == "moe_loss_coef":
             self.moe_loss_coef = float(val)
         elif name == "no_drop":
             self.no_drop = int(val)
+        elif name == "router":
+            if val not in ("softmax", "sigmoid"):
+                raise ValueError(f"unknown moe router {val!r}")
+            self.router = val
+        elif name in ("shared_expert", "expert_first", "expert_held"):
+            setattr(self, name, int(val))
+        elif name in ("routed_scaling_factor", "bias_update_rate"):
+            setattr(self, name, float(val))
 
     def __init__(self, spec, global_cfg):
         self.num_expert = 8
@@ -73,9 +177,29 @@ class MoELayer(Layer):
         self.act = "gelu"
         self.moe_loss_coef = 0.01
         self.no_drop = 0
+        self.router = "softmax"
+        self.shared_expert = 0
+        self.expert_first, self.expert_held = 0, 0
+        self.routed_scaling_factor = 1.0
+        self.bias_update_rate = 0.001
         super().__init__(spec, global_cfg)
-        if self.topk not in (1, 2):
-            raise ValueError("moe: topk must be 1 or 2")
+        if self.router == "sigmoid":
+            self.act = "swiglu"
+            self.expert_held = self.expert_held or self.num_expert
+            if not 1 <= self.topk <= self.num_expert:
+                raise ValueError("moe: topk must be in 1..num_expert")
+            if not (0 <= self.expert_first and self.expert_first
+                    + self.expert_held <= self.num_expert):
+                raise ValueError(
+                    f"moe {spec.name!r}: experts [{self.expert_first}, "
+                    f"{self.expert_first + self.expert_held}) are not "
+                    f"among {self.num_expert}")
+        elif self.topk not in (1, 2):
+            raise ValueError("moe: topk must be 1 or 2 under the softmax "
+                             "(capacity) router; router = sigmoid takes "
+                             "any")
+        elif self.act == "swiglu":
+            raise ValueError("moe: act = swiglu needs router = sigmoid")
 
     def infer_shapes(self, in_shapes):
         self.check_n(in_shapes, 1, 1)
@@ -86,6 +210,21 @@ class MoELayer(Layer):
         f = self.hp.num_hidden or 4 * e
         x = self.num_expert
         kr, k1, k2 = jax.random.split(key, 3)
+        if self.router == "sigmoid":
+            held = self.expert_held
+            k1, k3, ks = jax.random.split(k1, 3)
+            w = self.hp.init_weight
+            p = {"router": {"wmat": w(kr, (e, x), e, x)},
+                 "g": {"wmat": w(k3, (held, e, f), e, f)},
+                 "h": {"wmat": w(k1, (held, e, f), e, f)},
+                 "o": {"wmat": w(k2, (held, f, e), f, e)}}
+            if self.shared_expert:
+                fs = self.shared_expert * f
+                s1, s2, s3 = jax.random.split(ks, 3)
+                p["shared"] = {"g": {"wmat": w(s1, (e, fs), e, fs)},
+                               "h": {"wmat": w(s2, (e, fs), e, fs)},
+                               "o": {"wmat": w(s3, (fs, e), fs, e)}}
+            return p
         return {
             "router": {"wmat": self.hp.init_weight(kr, (e, x), e, x)},
             "h": {"wmat": self.hp.init_weight(k1, (x, e, f), e, f),
@@ -95,14 +234,99 @@ class MoELayer(Layer):
         }
 
     def param_pspecs(self):
+        if self.router == "sigmoid":
+            # one chip's share: the held experts are whole on this chip
+            return {}
         # experts sharded over 'model' (expert parallelism); router replicated
         return {"h": {"wmat": ("model", None, None), "bias": ("model", None)},
                 "o": {"wmat": ("model", None, None), "bias": ("model", None)}}
 
     def init_state(self, in_shapes):
+        if self.router == "sigmoid":
+            return {"sel_bias": jnp.zeros((self.num_expert,), jnp.float32),
+                    "stats": jnp.zeros((len(MOE_STATS),), jnp.float32)}
         return {"_aux_loss": jnp.zeros((), jnp.float32)}
 
+    def _apply_sigmoid(self, params, state, inputs, ctx):
+        """``router = sigmoid``: see the module's header."""
+        from ..ops.fused import note_grouped
+        if ctx.seq_axis is not None:
+            raise ValueError("moe: router = sigmoid has no "
+                             "sequence-parallel path")
+        cd = ctx.compute_dtype
+        x = _seq(inputs[0]).astype(cd)
+        B, T, E = x.shape
+        N, X, K = B * T, self.num_expert, self.topk
+        first, held = self.expert_first, self.expert_held
+        xf = x.reshape(N, E)
+        sel_bias = state["sel_bias"]
+        with jax.named_scope("moe.route"):
+            logits = jnp.einsum(
+                "ne,ex->nx", xf.astype(jnp.float32),
+                params["router"]["wmat"].astype(jnp.float32),
+                precision=lax.Precision.HIGHEST)
+            score = jax.nn.sigmoid(logits)                     # (N, X)
+            _, idx = lax.top_k(score + lax.stop_gradient(sel_bias), K)
+            gate = jnp.take_along_axis(score, idx, axis=1)     # (N, K)
+            gate = gate / (jnp.sum(gate, axis=1, keepdims=True) + 1e-20)
+            gate = gate * self.routed_scaling_factor
+            # group the pairs by expert: held ones first, in expert
+            # order; the others, which other chips compute, last
+            flat = idx.reshape(N * K)
+            is_held = (flat >= first) & (flat < first + held)
+            key = jnp.where(is_held, flat - first, held)
+            order = jnp.argsort(key, stable=True)              # (N*K,)
+            load = jnp.zeros((X,), jnp.float32).at[flat].add(1.0)
+            sizes = lax.dynamic_slice_in_dim(load, first, held) \
+                .astype(jnp.int32)
+            n_held = jnp.sum(sizes)
+            # room for every pair the held experts can get
+            M = N * min(K, held)
+            rows = order[:M]
+            live = (jnp.arange(M) < n_held)
+            # the inverse permutation: a pair's row among the sorted
+            inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+                jnp.arange(N * K, dtype=jnp.int32))
+        with jax.named_scope("moe.experts"):
+            note_grouped("ragged_dot")
+            w = lambda nm: params[nm]["wmat"].astype(cd)
+            ys = grouped_swiglu(_rows_of_pairs(xf, rows, inv), w("g"),
+                                w("h"), w("o"), sizes, live)   # (M, E)
+        with jax.named_scope("moe.combine"):
+            g_rows = jnp.where(live, gate.reshape(N * K)[rows], 0.0)
+            ys = ys.astype(jnp.float32) * g_rows[:, None]
+            if M < N * K:
+                ys = jnp.concatenate(
+                    [ys, jnp.zeros((N * K - M, E), ys.dtype)], axis=0)
+            # back to (position, slot) order
+            out = jnp.sum(_permute_rows(ys.astype(cd), inv, order)
+                          .reshape(N, K, E).astype(jnp.float32), axis=1)
+        if self.shared_expert:
+            with jax.named_scope("moe.shared"):
+                sp = params["shared"]
+                out = out + swiglu(
+                    xf, *(sp[k]["wmat"].astype(cd)
+                          for k in ("g", "h", "o"))).astype(jnp.float32)
+        out = out.astype(cd).reshape(B, T, E)
+        if ctx.stat_sink is not None:      # a pipeline stage keeps no state
+            return [_unseq(out)], {}
+        with jax.named_scope("moe.route"):
+            new_bias = sel_bias
+            if ctx.train and self.bias_update_rate:
+                new_bias = sel_bias - self.bias_update_rate * jnp.sign(
+                    load - jnp.mean(load))
+            held_f = n_held.astype(jnp.float32)
+            computed = jnp.minimum(held_f, float(M))
+            stats = jnp.stack([
+                computed, float(N * K) - held_f, held_f - computed,
+                jnp.max(sizes).astype(jnp.float32) / (N * K / X),
+                jnp.max(jnp.abs(new_bias))])
+        return [_unseq(out)], {"sel_bias": lax.stop_gradient(new_bias),
+                               "stats": lax.stop_gradient(stats)}
+
     def apply(self, params, state, inputs, ctx):
+        if self.router == "sigmoid":
+            return self._apply_sigmoid(params, state, inputs, ctx)
         x = _seq(inputs[0]).astype(ctx.compute_dtype)   # (B, T, E)
         B, T, E = x.shape
         X = self.num_expert

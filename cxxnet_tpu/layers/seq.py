@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (attention_reference, chunked_attention,
-                             flash_attention, rope)
+                             flash_attention, rope, rope_interleaved)
 from .base import Layer, Shape3, register_layer
 from .loss import LossLayerBase
 
@@ -104,6 +104,41 @@ class LayerNormLayer(Layer):
         var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
         y = (x - mu) * jax.lax.rsqrt(var + self.eps)
         y = y * params["gamma"] + params["beta"]
+        return [y.astype(ctx.compute_dtype)], state
+
+
+def rms_normalize(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, reduced
+    in float32 whatever ``x`` is; float32 out."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + eps) * gamma.astype(jnp.float32)
+
+
+@register_layer("rmsnorm")
+class RMSNormLayer(Layer):
+    """RMSNorm over the feature axis of a sequence node: no mean is
+    taken off and there is no beta. ``gamma`` follows the bias hyper
+    group like layernorm's; ``eps`` defaults to 1e-6."""
+    has_params = True
+
+    def set_param(self, name, val):
+        if name == "eps":
+            self.eps = float(val)
+
+    def __init__(self, spec, global_cfg):
+        self.eps = 1e-6
+        super().__init__(spec, global_cfg)
+
+    def infer_shapes(self, in_shapes):
+        self.check_n(in_shapes, 1, 1)
+        return [in_shapes[0]]
+
+    def init_params(self, key, in_shapes):
+        return {"gamma": jnp.ones((in_shapes[0][0],), jnp.float32)}
+
+    def apply(self, params, state, inputs, ctx):
+        y = rms_normalize(inputs[0], params["gamma"], self.eps)
         return [y.astype(ctx.compute_dtype)], state
 
 
@@ -272,15 +307,151 @@ class MultiHeadAttentionLayer(Layer, _SeqLinearMixin):
         return [_unseq(y)], state
 
 
+@register_layer("mla")
+class LatentAttentionLayer(Layer):
+    """Multi-head latent attention (the DeepSeek-V2/V3 family's), causal,
+    in its training ("prefill") form: keys and values are materialised
+    from the compressed latent; the absorbed decode form and the latent
+    cache belong to serving and are not here. With x a position's vector:
+
+      c_q = RMS(x W_qa);  per head [q_nope ; q_rope] = c_q W_qb
+      [c_kv ; k_rope] = x W_kva;  c_kv <- RMS(c_kv)
+      per head [k_nope ; v] = c_kv W_kvb
+      q_rope, k_rope rotated on INTERLEAVED pairs (``rope_interleaved``),
+      k_rope ONE vector shared by all heads
+      scores = (q_nope.k_nope + q_rope.k_rope) / sqrt(d_nope + d_rope)
+      y = concat_h(softmax(scores) v) W_o            no bias anywhere
+
+    Config: ``nhead``, ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_theta``, ``eps``, ``attn_impl`` in {auto, ref, flash}. q.k is
+    ``d_nope + d_rope`` wide and v ``v_head_dim``: ``ref`` runs on XLA's
+    dots at those widths (the tests' oracle), ``flash`` is the Pallas
+    kernel, which takes v's width apart from q's and k's, at blocks of
+    the largest of 1024, 512, 256, 128 that divides the positions (one
+    block where there are fewer than 128). ``auto`` is the kernel on a
+    TPU where such a block exists — it won the layer's A/B on the chip,
+    33 ms against 528 for XLA's dots at 8192 positions, and blocks of
+    1024 won among blocks (PERF.md section 6, PR 28) — else ``ref``."""
+    has_params = True
+
+    _INT = ("nhead", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim")
+
+    def set_param(self, name, val):
+        if name in self._INT:
+            setattr(self, name, int(val))
+        elif name in ("rope_theta", "eps"):
+            setattr(self, name, float(val))
+        elif name == "attn_impl":
+            if val not in ("auto", "ref", "flash"):
+                raise ValueError(f"unknown mla attn_impl {val!r}")
+            self.attn_impl = val
+
+    def __init__(self, spec, global_cfg):
+        self.nhead = 8
+        self.q_lora_rank = self.kv_lora_rank = 0
+        self.qk_nope_head_dim = self.qk_rope_head_dim = 0
+        self.v_head_dim = 0
+        self.rope_theta = 10000.0
+        self.eps = 1e-6
+        self.attn_impl = "auto"
+        super().__init__(spec, global_cfg)
+        for k in self._INT:
+            if getattr(self, k) <= 0:
+                raise ValueError(f"mla layer {spec.name!r} needs {k}")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("mla: qk_rope_head_dim must be even")
+
+    def infer_shapes(self, in_shapes):
+        self.check_n(in_shapes, 1, 1)
+        return [in_shapes[0]]
+
+    def init_params(self, key, in_shapes):
+        e, h = in_shapes[0][0], self.nhead
+        rq, rkv = self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        ks = jax.random.split(key, 5)
+        w = self.hp.init_weight
+        return {
+            "qa": {"wmat": w(ks[0], (e, rq), e, rq)},
+            "qnorm": {"gamma": jnp.ones((rq,), jnp.float32)},
+            "qb": {"wmat": w(ks[1], (rq, h, dn + dr), rq, h * (dn + dr))},
+            "kva": {"wmat": w(ks[2], (e, rkv + dr), e, rkv + dr)},
+            "kvnorm": {"gamma": jnp.ones((rkv,), jnp.float32)},
+            "kvb": {"wmat": w(ks[3], (rkv, h, dn + dv), rkv,
+                               h * (dn + dv))},
+            "o": {"wmat": w(ks[4], (h, dv, e), h * dv, e)},
+        }
+
+    def param_pspecs(self):
+        return {"qb": {"wmat": (None, "model", None)},
+                "kvb": {"wmat": (None, "model", None)},
+                "o": {"wmat": ("model", None, None)}}
+
+    def _attend(self, q, k, v):
+        from ..ops.fused import note_attention
+        impl, S = self.attn_impl, q.shape[1]
+        blk = next((b for b in (1024, 512, 256, 128) if S % b == 0),
+                   S if S < 128 else 0)
+        if impl == "auto":
+            impl = "flash" if jax.default_backend() == "tpu" and blk \
+                else "ref"
+        note_attention("mla." + impl)
+        if impl == "ref":
+            return attention_reference(q, k, v, causal=True)
+        return flash_attention(q, k, v, True, None, blk, blk)
+
+    def apply(self, params, state, inputs, ctx):
+        if ctx.seq_axis is not None:
+            raise ValueError("mla has no sequence-parallel path")
+        cd = ctx.compute_dtype
+        x = _seq(inputs[0]).astype(cd)
+        dn, dr = self.qk_nope_head_dim, self.qk_rope_head_dim
+        rkv = self.kv_lora_rank
+        w = lambda nm: params[nm]["wmat"].astype(cd)
+        with jax.named_scope("mla.proj"):
+            c_q = rms_normalize(jnp.einsum("bse,er->bsr", x, w("qa")),
+                                params["qnorm"]["gamma"], self.eps)
+            q = jnp.einsum("bsr,rhd->bshd", c_q.astype(cd), w("qb"))
+            kv = jnp.einsum("bse,er->bsr", x, w("kva"))
+            c_kv = rms_normalize(kv[..., :rkv], params["kvnorm"]["gamma"],
+                                 self.eps)
+            k_rope = kv[..., rkv:][:, :, None, :]         # (B, S, 1, dr)
+            kvb = jnp.einsum("bsr,rhd->bshd", c_kv.astype(cd), w("kvb"))
+            k_nope, v = kvb[..., :dn], kvb[..., dn:]
+            q_rope = rope_interleaved(q[..., dn:], self.rope_theta)
+            k_rope = rope_interleaved(k_rope, self.rope_theta)
+            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(
+                    k_rope, k_nope.shape[:3] + (dr,))], axis=-1)
+        with jax.named_scope("mla.attend"):
+            o = self._attend(q, k, v)
+        with jax.named_scope("mla.proj"):
+            y = jnp.einsum("bshd,hde->bse", o, w("o"))
+        return [_unseq(y)], state
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``(silu(x W_g) * (x W_u)) W_d`` on (..., E); no bias."""
+    h = jax.nn.silu(jnp.einsum("...e,ef->...f", x, w_gate)) \
+        * jnp.einsum("...e,ef->...f", x, w_up)
+    return jnp.einsum("...f,fe->...e", h, w_down)
+
+
 @register_layer("ffn")
 class FFNLayer(Layer, _SeqLinearMixin):
     """Position-wise feed-forward (E,S,1) -> (E,S,1); ``nhidden`` = inner
-    dim, ``act`` in {gelu, relu}. TP: inner dim sharded over 'model'."""
+    dim, ``act`` in {gelu, relu, swiglu}. ``swiglu`` is the gated form
+    ``(silu(x W_g) * (x W_h)) W_o`` with a third matrix ``g`` and no
+    bias. TP: inner dim sharded over 'model'."""
     has_params = True
 
     def set_param(self, name, val):
         if name == "act":
-            if val not in ("gelu", "relu"):
+            if val not in ("gelu", "relu", "swiglu"):
                 raise ValueError(f"unknown ffn act {val!r}")
             self.act = val
 
@@ -296,15 +467,26 @@ class FFNLayer(Layer, _SeqLinearMixin):
         e = in_shapes[0][0]
         f = self.hp.num_hidden or 4 * e
         k1, k2 = jax.random.split(key)
+        if self.act == "swiglu":
+            k1, k3 = jax.random.split(k1)
+            return {"g": self._linear_params(k3, e, f, True),
+                    "h": self._linear_params(k1, e, f, True),
+                    "o": self._linear_params(k2, f, e, True)}
         return {"h": self._linear_params(k1, e, f, self.hp.no_bias),
                 "o": self._linear_params(k2, f, e, self.hp.no_bias)}
 
     def param_pspecs(self):
+        gate = {"g": {"wmat": (None, "model")}} \
+            if self.act == "swiglu" else {}
         return {"h": {"wmat": (None, "model"), "bias": ("model",)},
-                "o": {"wmat": ("model", None), "bias": None}}
+                "o": {"wmat": ("model", None), "bias": None}, **gate}
 
     def apply(self, params, state, inputs, ctx):
         x = _seq(inputs[0]).astype(ctx.compute_dtype)
+        if self.act == "swiglu":
+            return [_unseq(swiglu(
+                x, *(params[k]["wmat"].astype(ctx.compute_dtype)
+                     for k in ("g", "h", "o"))))], state
         h = jnp.einsum("bse,ef->bsf", x,
                        params["h"]["wmat"].astype(ctx.compute_dtype))
         if "bias" in params["h"]:
@@ -379,24 +561,92 @@ class AddLayer(Layer):
         return [out], state
 
 
+@register_layer("label_ids")
+class LabelIdsLayer(Layer):
+    """The label slice named by ``target`` as a flat id node (1,1,S), for
+    a head that reads the row's labels as an input: the
+    multi-token-prediction module embeds the next token, which is the
+    label at the same position. Its graph input (any node; the data node
+    by convention) only orders it. Where a pass has no label (inference)
+    it emits zeros: such a head is a training-time head."""
+
+    def set_param(self, name, val):
+        if name == "target":
+            self.target = val
+
+    def __init__(self, spec, global_cfg):
+        self.target = "label"
+        super().__init__(spec, global_cfg)
+
+    def infer_shapes(self, in_shapes):
+        self.check_n(in_shapes, 1, 1)
+        return [in_shapes[0]]
+
+    def apply(self, params, state, inputs, ctx):
+        lab = (ctx.labels or {}).get(self.target)
+        x = inputs[0]
+        if lab is None:
+            return [jnp.zeros_like(x)], state
+        return [lab.reshape(x.shape).astype(x.dtype)], state
+
+
 @register_layer("lmloss")
 class LMLossLayer(LossLayerBase):
     """Per-token softmax cross-entropy for language modeling: logits node
     (V,S,1) vs a label slice of width S (token ids). Forward emits per-token
     **log**-probabilities (log_softmax: numerically exact where probs would
     underflow f32, so confidently-wrong tokens keep their gradient; argmax
-    metrics are unaffected); loss = masked mean NLL over all tokens."""
+    metrics are unaffected); loss = masked mean NLL over all tokens.
+
+    ``shift = k`` (default 0) scores position i against the label at
+    i + k and leaves the row's last k positions out of the mean: a
+    multi-token-prediction head of depth k over the same label row.
+    ``grad_scale`` weighs the head in the objective.
+
+    ``metric_stats``: what a train metric needs of this node, reduced on
+    the device — per row (summed log-probability of the labels, argmax
+    hits, positions counted) — so that the loop fetches three numbers a
+    row instead of S x V log-probabilities."""
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "shift":
+            self.shift = int(val)
+
+    def __init__(self, spec, global_cfg):
+        self.shift = 0
+        super().__init__(spec, global_cfg)
+        if self.shift < 0:
+            raise ValueError("lmloss: shift must be >= 0")
 
     def apply(self, params, state, inputs, ctx):
         x = inputs[0]                              # (b, S, 1, V)
         logits = x.astype(jnp.float32)
-        return [jax.nn.log_softmax(logits, axis=-1)], state
+        with jax.named_scope("head_loss"):
+            return [jax.nn.log_softmax(logits, axis=-1)], state
 
-    def loss(self, outputs, label, mask):
+    def _aligned(self, outputs, label):
         logp_all = outputs[0]                      # (b, S, 1, V) log-probs
         b, S = logp_all.shape[0], logp_all.shape[1]
         lp2 = logp_all.reshape(b, S, -1)
         idx = label.astype(jnp.int32)              # (b, S)
-        logp = jnp.take_along_axis(lp2, idx[:, :, None], axis=2)[:, :, 0]
-        per_example = -jnp.mean(logp, axis=1)      # mean over tokens
-        return self._mean(per_example, mask)
+        if self.shift:
+            lp2, idx = lp2[:, :S - self.shift], idx[:, self.shift:]
+        return lp2, idx
+
+    def loss(self, outputs, label, mask):
+        with jax.named_scope("head_loss"):
+            lp2, idx = self._aligned(outputs, label)
+            logp = jnp.take_along_axis(lp2, idx[:, :, None], axis=2)[:, :, 0]
+            per_example = -jnp.mean(logp, axis=1)      # mean over tokens
+            return self._mean(per_example, mask)
+
+    def metric_stats(self, outputs, label):
+        with jax.named_scope("head_loss"):
+            lp2, idx = self._aligned(outputs, label)
+            logp = jnp.take_along_axis(lp2, idx[:, :, None], axis=2)[:, :, 0]
+            hits = (jnp.argmax(lp2, axis=2) == idx).astype(jnp.float32)
+            count = jnp.full((lp2.shape[0],), float(lp2.shape[1]),
+                             jnp.float32)
+            return jnp.stack([jnp.sum(logp, axis=1), jnp.sum(hits, axis=1),
+                              count], axis=1)

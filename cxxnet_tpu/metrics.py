@@ -34,6 +34,20 @@ class Metric:
     def get(self) -> float:
         return self.sum / max(self.cnt, 1)
 
+    #: whether ``add_reduced`` can stand in for ``add``: the trainer then
+    #: fetches a loss head's (n, 3) reduction instead of its node
+    takes_reduced = False
+
+    def add_reduced(self, stats: np.ndarray) -> None:
+        """stats: (n, 3) per row — summed log-probability of the labels,
+        argmax hits, positions counted — reduced on the device by the
+        loss head the node belongs to, against that head's own labels."""
+        raise NotImplementedError
+
+    @staticmethod
+    def _positions(stats: np.ndarray) -> int:
+        return int(round(float(np.sum(stats[:, 2]))))
+
 
 class MetricRMSE(Metric):
     def add(self, pred, label):
@@ -116,10 +130,38 @@ class MetricSeqError(Metric):
         self.sum += float(np.sum(guess != label.astype(np.int64)))
         self.cnt += n * S
 
+    takes_reduced = True
+
+    def add_reduced(self, stats):
+        self.sum += float(np.sum(stats[:, 2] - stats[:, 1]))
+        self.cnt += self._positions(stats)
+
+
+class MetricSeqLogloss(Metric):
+    """Mean negative log-probability of the label per position: pred is
+    the flattened (n, S*V) per-token LOG-probabilities of an ``lmloss``
+    node, label (n, S) token ids."""
+
+    def add(self, pred, label):
+        n, S = label.shape
+        lp = pred.reshape(n, S, pred.shape[1] // S)
+        idx = label.astype(np.int64)
+        self.sum += float(-np.sum(np.take_along_axis(
+            lp, idx[:, :, None], axis=2)))
+        self.cnt += n * S
+
+    takes_reduced = True
+
+    def add_reduced(self, stats):
+        self.sum += float(-np.sum(stats[:, 0]))
+        self.cnt += self._positions(stats)
+
 
 def create_metric(name: str, label_field: str) -> Metric:
     if name == "seq_error":
         return MetricSeqError(name, label_field)
+    if name == "seq_logloss":
+        return MetricSeqLogloss(name, label_field)
     if name == "rmse":
         return MetricRMSE(name, label_field)
     if name == "error":
@@ -154,13 +196,19 @@ class MetricSet:
 
     def add_eval(self, node_values: Dict[Optional[str], np.ndarray],
                  node_labels: Dict[Optional[str], np.ndarray],
-                 label_slices: Dict[str, Tuple[int, int]]) -> None:
+                 label_slices: Dict[str, Tuple[int, int]],
+                 reduced=()) -> None:
         """node_values maps node-name (or None for top) to (n, k) scores for
         the real (unpadded) rows this process holds; node_labels carries the
         row-aligned (n, w) label block per node (rows can differ per node in
-        multi-host runs when some nodes are replicated)."""
+        multi-host runs when some nodes are replicated). For a node in
+        ``reduced`` the value is its loss head's (n, 3) reduction
+        (``Metric.add_reduced``)."""
         for m, node in zip(self.metrics, self.nodes):
             pred = node_values[node]
+            if node in reduced:
+                m.add_reduced(np.asarray(pred))
+                continue
             label = node_labels[node]
             a, b = label_slices[m.label_field]
             m.add(np.asarray(pred), np.asarray(label[:, a:b]))

@@ -50,6 +50,10 @@ class ForwardResult:
     # per-layer activation health stats (model-health probe; None unless
     # apply(health=True)): {layer: {"absmax", "zero_frac"?, "bn_var_min"?}}
     health: Optional[Dict[str, Any]] = None
+    # node name -> a loss head's device-side reduction of that node for
+    # the train metric (``LMLossLayer.metric_stats``); only where the
+    # whole label was handed in
+    metric_stats: Optional[Dict[str, jax.Array]] = None
 
 
 class Network:
@@ -229,6 +233,11 @@ class Network:
         fused_now = self._fused_now()
         health_sink: Optional[Dict[str, Any]] = {} if health else None
         total_loss = jnp.zeros((), jnp.float32)
+        metric_stats: Dict[str, jax.Array] = {}
+        labels = None
+        if label is not None:
+            labels = {n: label[:, slice(*g.label_slice(n))]
+                      for n in g.label_name_map}
         for li, (spec, layer) in enumerate(zip(g.layers, self.layers)):
             if li in self._act_folded:
                 # relu folded into its producer's epilogue
@@ -248,7 +257,7 @@ class Network:
                            else None,
                            fuse_act=self._fuse_act.get(li),
                            cin_pad=self._cin_pad.get(li),
-                           health_sink=health_sink)
+                           health_sink=health_sink, labels=labels)
             inputs = [nodes[ni] for ni in spec.nindex_in]
             lparams = params.get(layer.name, {})
             lstate = new_state.get(layer.name, {})
@@ -292,6 +301,9 @@ class Network:
                            else label[:, a:b])
                     total_loss = total_loss + layer.loss(
                         outputs, lab.astype(jnp.float32), mask)
+                    if label is not None and hasattr(layer, "metric_stats"):
+                        metric_stats[g.node_names[spec.nindex_out[0]]] = \
+                            layer.metric_stats(outputs, lab)
         node_map = None
         if capture_nodes:
             node_map = {name: nodes[i] for i, name in enumerate(g.node_names)
@@ -300,7 +312,8 @@ class Network:
         # req = top node, nnet_impl-inl.hpp:203-216)
         out = nodes[g.layers[-1].nindex_out[0]] if g.layers else data
         return ForwardResult(loss=total_loss, state=new_state,
-                             nodes=node_map, out=out, health=health_sink)
+                             nodes=node_map, out=out, health=health_sink,
+                             metric_stats=metric_stats)
 
     #: layer types whose exact-zero output fraction IS the dead-unit
     #: signal (a relu that emits 0 for every batch row is a dead unit;

@@ -42,6 +42,10 @@ from jax.experimental.pallas import tpu as pltpu
 from .fused import out_struct, use_interpret
 
 _NEG = -1e30
+#: dot_general dimension numbers of ``a @ b.T`` and ``a.T @ b`` on 2-D
+#: tiles: the transposed operand is contracted in place, not copied
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _scale(q: jax.Array, scale: Optional[float]) -> float:
@@ -91,6 +95,26 @@ def rope_at(x: jax.Array, theta: float, pos: jax.Array) -> jax.Array:
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_interleaved(x: jax.Array, theta: float, offset=0) -> jax.Array:
+    """Rotary embedding on INTERLEAVED pairs of (B, S, H, D): features
+    (2i, 2i+1) are one pair, rotated by ``pos * theta**(-2i/D)`` (the
+    ``rope_interleave`` layout of the DeepSeek-V3 family's checkpoints;
+    :func:`rope` pairs feature i with i + D/2). The output keeps the
+    interleaved order, so q.k is what the published model computes."""
+    B, S, H, D = x.shape
+    if D % 2:
+        raise ValueError(f"rope needs an even head_dim, got {D}")
+    pos = jnp.arange(S, dtype=jnp.float32) + jnp.asarray(offset, jnp.float32)
+    inv = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
+    ang = pos[:, None] * inv[None, :]                 # (S, D/2)
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xp = x.astype(jnp.float32).reshape(B, S, H, D // 2, 2)
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(B, S, H, D).astype(x.dtype)
 
 
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -278,10 +302,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)          # (block_q, D)
-        kb = k_ref[0].astype(jnp.float32)         # (block_k, D)
-        vb = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
+        # operands go to the MXU in their own dtype (a bf16 product
+        # upcast to float32 first costs several passes), sums in float32
+        q = q_ref[0]                              # (block_q, D)
+        kb = k_ref[0]                             # (block_k, D)
+        vb = v_ref[0]                             # (block_k, Dv)
+        s = lax.dot_general(q, kb, _NT,
+                            preferred_element_type=jnp.float32) * scale
         if causal:
             mask = _block_causal_mask(qi, kj, block_q, block_k)
             s = jnp.where(mask, s, _NEG)
@@ -293,7 +320,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
         acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32)
+            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
         m_ref[:, 0] = m_new
 
     if causal:
@@ -314,7 +341,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    with_lse: bool = False):
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     if Sq % block_q or Sk % block_k:
@@ -324,7 +351,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     # (B,S,H,D) -> (B*H, S, D): one grid row per (batch, head)
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, Dv)
     kern = functools.partial(
         _flash_fwd_kernel, scale=_scale(q, scale), causal=causal,
         block_q=block_q, block_k=block_k)
@@ -334,26 +361,26 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             # (BH, Sq, 1): trailing dims (block_q, 1) satisfy the TPU
             # (8, 128)-divisible-or-full block constraint
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            out_struct((B * H, Sq, D), q.dtype, qt, kt, vt),
+            out_struct((B * H, Sq, Dv), q.dtype, qt, kt, vt),
             out_struct((B * H, Sq, 1), jnp.float32, qt, kt, vt),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),   # acc
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # acc
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running normalizer
         ],
         interpret=interpret, name="flash_fwd",
     )(qt, kt, vt)
-    out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    out = out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
     return (out, lse) if with_lse else out
 
 
@@ -386,22 +413,25 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)          # (block_q, D)
-        kb = k_ref[0].astype(jnp.float32)         # (block_k, D)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)        # (block_q, D)
+        q = q_ref[0]                              # (block_q, D)
+        kb = k_ref[0]                             # (block_k, D)
+        vb = v_ref[0]                             # (block_k, Dv)
+        do = do_ref[0]                            # (block_q, Dv)
         lse = lse_ref[0, :, 0]                    # (block_q,)
         delta = delta_ref[0, :, 0]                # (block_q,)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
+        s = lax.dot_general(q, kb, _NT,
+                            preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse[:, None])
         if causal:
             # explicit zeroing: fully-masked rows carry a sentinel lse,
             # where exp(s - lse) would NOT vanish on its own
             p = jnp.where(_block_causal_mask(qi, kj, block_q, block_k),
                           p, 0.0)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, vb, _NT,
+                             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * scale
-        dq_acc[...] += jnp.dot(ds, kb, preferred_element_type=jnp.float32)
+        dq_acc[...] += jnp.dot(ds.astype(kb.dtype), kb,
+                               preferred_element_type=jnp.float32)
 
     if causal:
         @pl.when(kj * block_k <= qi * block_q + block_q - 1)
@@ -430,21 +460,25 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)          # (block_q, D)
-        kb = k_ref[0].astype(jnp.float32)         # (block_k, D)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q = q_ref[0]                              # (block_q, D)
+        kb = k_ref[0]                             # (block_k, D)
+        vb = v_ref[0]                             # (block_k, Dv)
+        do = do_ref[0]                            # (block_q, Dv)
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
+        s = lax.dot_general(q, kb, _NT,
+                            preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse[:, None])
         if causal:
             p = jnp.where(_block_causal_mask(qi, kj, block_q, block_k),
                           p, 0.0)
-        dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
+        dv_acc[...] += lax.dot_general(
+            p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, vb, _NT,
+                             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_acc[...] += lax.dot_general(
+            ds.astype(q.dtype), q, _TN, preferred_element_type=jnp.float32)
 
     if causal:
         # only q blocks at or below the diagonal contribute to this k tile
@@ -465,27 +499,29 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     """Fused Pallas backward: dq from one kernel, dk/dv from another,
     both rebuilding the softmax from the forward's logsumexp."""
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     sc = _scale(q, scale)
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    dot = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, Dv)
+    dot = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise precompute
     delta = jnp.sum(dot.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
+                    * out.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
                     .astype(jnp.float32), axis=-1)[..., None]
 
     q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
     k_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
+    v_spec = pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0))
+    o_spec = pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0))
     r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=sc, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(B * H, Sq // block_q, Sk // block_k),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, r_spec, r_spec],
         out_specs=q_spec,
         out_shape=out_struct((B * H, Sq, D), q.dtype, qt, kt, vt, dot),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -495,21 +531,23 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     # swapped grid: (bh, k-block, q-block) — index maps swap i/j roles
     q_spec2 = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
     k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    v_spec2 = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
+    o_spec2 = pl.BlockSpec((1, block_q, Dv), lambda b, j, i: (b, i, 0))
     r_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=sc, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(B * H, Sk // block_k, Sq // block_q),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
-        out_specs=[k_spec2, k_spec2],
+        in_specs=[q_spec2, k_spec2, v_spec2, o_spec2, r_spec2, r_spec2],
+        out_specs=[k_spec2, v_spec2],
         out_shape=[out_struct((B * H, Sk, D), k.dtype, qt, kt, vt, dot),
-                   out_struct((B * H, Sk, D), v.dtype, qt, kt, vt, dot)],
+                   out_struct((B * H, Sk, Dv), v.dtype, qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         interpret=interpret, name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
-    unflat = lambda a, S: a.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    unflat = lambda a, S: a.reshape(B, H, S, -1).transpose(0, 2, 1, 3)
     return unflat(dq, Sq), unflat(dk, Sk), unflat(dv, Sk)
 
 

@@ -193,6 +193,12 @@ def note_attention(impl: str) -> None:
     _record("attention", impl)
 
 
+def note_grouped(impl: str) -> None:
+    """Record which grouped matrix product the moe layer being traced
+    runs its held experts on (``ragged_dot``: XLA's own)."""
+    _record("grouped", impl)
+
+
 def note_fallback(reason: str, warn: Optional[str] = None) -> None:
     """Record a fused-path fallback: the site being traced took its
     reference for ``reason``. Always bumps
@@ -229,8 +235,9 @@ def selection_summary(log: SelectionLog) -> str:
     line = (f"fused_kernels: {sum(by['fused'].values())} sites fused "
             f"({part(by['fused'])}); {sum(by['reference'].values())} "
             f"took the reference ({part(by['reference'])})")
-    if by["attention"]:
-        line += f"; attention: {part(by['attention'])}"
+    for kind in ("attention", "grouped"):
+        if by[kind]:
+            line += f"; {kind}: {part(by[kind])}"
     return line
 
 

@@ -43,15 +43,36 @@ from . import checkpoint as ckpt
 
 _METRIC_RE = re.compile(r"^metric(?:\[([^,\]]+)(?:,([^\]]+))?\])?$")
 _TOP = "!top"
+#: prefix of a key of the step's node outputs that holds, for the node
+#: key after it, the loss head's own device-side reduction for the train
+#: metric (``LMLossLayer.metric_stats``: three numbers a row) instead of
+#: the node
+_REDUCED = "!reduced:"
+#: key of the step's node outputs under which the sigmoid-routed moe
+#: layers' ``stats`` vectors ride, by layer name: fetched with the train
+#: metric, one step late, at no sync of their own
+_MOE = "!moe"
 
 
-def _collect_nodes(res, needed):
+def _collect_nodes(res, needed, reduce=None, drop_top=False):
     """Assemble the step's node outputs: the top node plus any captured
-    metric/extract-bound nodes — shared by every train/eval step builder."""
-    nodes = {_TOP: res.out}
+    metric/extract-bound nodes — shared by every train/eval step builder.
+    ``reduce`` ({node key: node name}, the std train step's): those keys
+    carry the loss head's reduction of the node under ``_REDUCED + key``
+    in the node's place; ``drop_top``: no metric reads the top node."""
+    nodes = {} if drop_top else {_TOP: res.out}
     if needed:
         nodes.update({n: res.nodes[n] for n in needed})
+    for key, name in (reduce or {}).items():
+        if key in nodes and name in (res.metric_stats or {}):
+            del nodes[key]
+            nodes[_REDUCED + key] = res.metric_stats[name]
     return nodes
+
+
+def _moe_stats(net_state):
+    return {name: st["stats"] for name, st in net_state.items()
+            if isinstance(st, dict) and "stats" in st}
 
 
 def _fold_input(data, net):
@@ -308,6 +329,28 @@ class Trainer:
                 self.metric.add(val, label_field, node)
                 self.train_metric.add(val, label_field, node)
                 self._metric_nodes.append(node)
+        # a sequence loss head reduces its node on the device to what
+        # the train metric needs (three numbers a row) where every
+        # metric bound to that node can take them; a top node that no
+        # metric reads, while others are read, is not fetched at all
+        top_name = (self.graph.node_names[
+            self.graph.layers[-1].nindex_out[0]]
+            if self.graph.layers else None)
+        heads = {self.graph.node_names[spec.nindex_out[0]]
+                 for spec, layer in zip(self.graph.layers,
+                                        self.net.layers)
+                 if hasattr(layer, "metric_stats")}
+        self._reduce_keys: Dict[str, str] = {}
+        for key in {n or _TOP for n in self._metric_nodes}:
+            name = top_name if key == _TOP else key
+            if name in heads and all(
+                    m.takes_reduced for m, n in zip(
+                        self.train_metric.metrics, self._metric_nodes)
+                    if (n or _TOP) == key):
+                self._reduce_keys[key] = name
+        self._drop_top = bool(self._metric_nodes
+                              and None not in self._metric_nodes
+                              and top_name in heads)
         # counters (reference epoch_counter = #updates; round = epoch)
         self.epoch_counter = 0
         self.sample_counter = 0
@@ -548,9 +591,12 @@ class Trainer:
                 self._param_pspecs(params))
 
     def init_model(self) -> None:
-        params, net_state = self.net.init(self._base_key)
+        # one executable each, not one per leaf and op: a sequence model
+        # of 60 leaves spent a minute of a cold start compiling them
+        params, net_state = jax.jit(self.net.init)(self._base_key)
         self.params, self.net_state, self.opt_state = self._place(
-            params, net_state, self.optimizer.init_state(params))
+            params, net_state,
+            jax.jit(self.optimizer.init_state)(params))
         self._init_accum(params)
 
     def _checkpoint_sharded(self, path: str) -> bool:
@@ -1607,6 +1653,7 @@ class Trainer:
         # (bench) chains never carry it. health_on False leaves every
         # closure below on the exact pre-health path.
         health_on = self.health_on and (not chain or multi)
+        reduce_keys, drop_top = self._reduce_keys, self._drop_top
 
         def fwd_bwd(params, opt_state, net_state, data, label, mask,
                     extra, rng):
@@ -1617,7 +1664,11 @@ class Trainer:
                 res = net.apply(p, net_state, data, label, mask,
                                 extra_data=extra, rng=rng, train=True,
                                 capture_nodes=capture, health=health_on)
-                aux = (res.state, _collect_nodes(res, needed))
+                nodes = _collect_nodes(res, needed, reduce_keys, drop_top)
+                moe = _moe_stats(res.state)
+                if moe and not chain:
+                    nodes[_MOE] = moe
+                aux = (res.state, nodes)
                 return res.loss, aux + ((res.health,) if health_on
                                         else ())
             return _scaled_value_and_grad(loss_fn, params, opt_state)
@@ -2319,15 +2370,21 @@ class Trainer:
                            else batch.host_label)
         node_vals = {}
         node_labels = {}
+        reduced = set()
         for key, arr in nodes.items():
+            if key == _MOE:
+                continue
             rows, idx = self._local_rows(arr)
             keep = idx < n_real          # drop tail padding rows
+            if key.startswith(_REDUCED):
+                key = key[len(_REDUCED):]
+                reduced.add(None if key == _TOP else key)
             name = None if key == _TOP else key
             node_vals[name] = rows[keep]
             node_labels[name] = label[idx[keep]]
         slices = {name: self.graph.label_slice(name)
                   for name in self.graph.label_name_map}
-        mset.add_eval(node_vals, node_labels, slices)
+        mset.add_eval(node_vals, node_labels, slices, reduced)
 
     # -- evaluation / inference -------------------------------------------
     def _make_eval_step(self, extract: Tuple[str, ...] = ()):
@@ -2439,9 +2496,41 @@ class Trainer:
                                  {key: v[i] for key, v in nodes.items()}, b)
         else:
             self._add_metric(self.train_metric, nodes, batch)
+            if _MOE in nodes:
+                self._count_moe(nodes[_MOE])
         t1 = time.perf_counter()
         self.last_drain_s = t1 - t0
         TRACER.add_complete("train.metric_drain", t0, t1, cat="train")
+
+    def _count_moe(self, stats) -> None:
+        """One drained step's sigmoid-routed moe ``stats`` (layers/
+        moe.MOE_STATS, by layer) into the telemetry registry: the three
+        pair counters summed over the layers and the steps counted (a
+        mean's two halves), the two gauges by layer, and the pairs held
+        at this step alone (what a reader of a trace's last steps
+        wants while the routing drifts)."""
+        from .layers.moe import MOE_STATS
+        from .telemetry.registry import get_registry
+        reg = get_registry()
+        total = np.zeros(3)
+        for layer, vec in jax.device_get(stats).items():
+            v = dict(zip(MOE_STATS, np.asarray(vec, np.float64)))
+            total += [v["pairs_held"], v["pairs_elsewhere"],
+                      v["pairs_dropped"]]
+            for g in ("load_max_over_mean", "sel_bias_absmax"):
+                reg.gauge("cxxnet_moe_" + g, "sigmoid-routed moe: " + g
+                          + " at the last drained step",
+                          labels=("layer",)).labels(layer).set(v[g])
+        for kind, n in zip(("held", "elsewhere", "dropped"), total):
+            reg.counter(f"cxxnet_moe_pairs_{kind}_total",
+                        "sigmoid-routed moe: (position, expert) pairs "
+                        f"{kind}, summed over layers and drained "
+                        "steps").inc(float(n))
+        reg.counter("cxxnet_moe_steps_total",
+                    "train steps whose moe stats were drained").inc()
+        reg.gauge("cxxnet_moe_pairs_held_last_step",
+                  "sigmoid-routed moe: pairs held at the last drained "
+                  "step, summed over layers").set(float(total[0]))
 
     def train_metric_report(self, name: str = "train") -> str:
         self._drain_pending_metric()

@@ -1,0 +1,283 @@
+"""The layer kinds JoyAI-LLM-Flash needs (PR 28) — ``rmsnorm``, ``ffn``
+with ``act = swiglu``, ``mla``, ``moe`` with ``router = sigmoid`` —
+against the configuration's own plain reference
+(``benchmarks/references/joyai_llm_flash.py``, which imports nothing of
+the program) on seeded weights, at a small size on the CPU: hidden 64, 4
+heads, ranks 48 / 32, head parts 16 / 8 / 16, 16 experts of width 32
+with top-3."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cxxnet_tpu.graph import LayerSpec
+from cxxnet_tpu.layers import ApplyCtx, create_layer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+E, S, B = 64, 32, 2
+
+
+def load_reference(name="joyai_llm_flash_for_layers"):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        ROOT, "benchmarks", "references", "joyai_llm_flash.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = load_reference()
+
+#: the keys of the configuration file the reference reads, at the small
+#: size (tests/benchmarks/data/joyai_toy has the same)
+C = {"hidden_size": E, "num_attention_heads": 4, "q_lora_rank": 48,
+     "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+     "v_head_dim": 16, "rope_theta": 32000000.0, "rms_norm_eps": 1e-6,
+     "n_routed_experts_published": 16, "n_routed_experts": 16,
+     "expert_first": 0, "num_experts_per_tok": 3, "norm_topk_prob": True,
+     "routed_scaling_factor": 2.5, "n_shared_experts": 1,
+     "moe_intermediate_size": 32, "bias_update_rate": 0.001}
+
+MLA_CFG = [("nhead", "4"), ("q_lora_rank", "48"), ("kv_lora_rank", "32"),
+           ("qk_nope_head_dim", "16"), ("qk_rope_head_dim", "8"),
+           ("v_head_dim", "16"), ("rope_theta", "32000000"),
+           ("init_sigma", "0.2")]
+
+
+def make(kind, cfg):
+    return create_layer(LayerSpec(kind, "L", [0], [1], list(cfg)), [])
+
+
+def moe_layer(first=0, held=16, **extra):
+    cfg = [("router", "sigmoid"), ("num_expert", "16"), ("topk", "3"),
+           ("nhidden", "32"), ("shared_expert", "1"),
+           ("routed_scaling_factor", "2.5"), ("expert_first", str(first)),
+           ("expert_held", str(held)), ("init_sigma", "0.3")]
+    return make("moe", cfg + [(k, str(v)) for k, v in extra.items()])
+
+
+def ctx(train=True):
+    return ApplyCtx(train=train, compute_dtype=jnp.float32)
+
+
+def x_node(seed=0, rows=B):
+    return jax.random.normal(jax.random.PRNGKey(seed), (rows, S, 1, E))
+
+
+def seq(x):
+    return x.reshape(x.shape[0], x.shape[1], x.shape[3])
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.max(np.abs(a - b)) <= tol * max(1.0, np.max(np.abs(b))), \
+        np.max(np.abs(a - b))
+
+
+def tree_close(a, b, tol=2e-5):
+    jax.tree_util.tree_map(lambda u, v: close(u, v, tol), a, b)
+
+
+def test_rmsnorm_is_the_references():
+    layer = make("rmsnorm", [])
+    p = {"gamma": 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (E,))}
+    x = x_node()
+    (y,), _ = layer.apply(p, {}, [x], ctx())
+    with jax.default_matmul_precision("highest"):
+        close(seq(y), ref.rms(seq(x), p["gamma"], 1e-6))
+    assert layer.init_params(jax.random.PRNGKey(0), [(E, S, 1)]).keys() \
+        == {"gamma"}
+    from cxxnet_tpu.optim import tag_for_param
+    assert tag_for_param("gamma") == "bias"
+
+
+def test_swiglu_ffn_is_the_references():
+    layer = make("ffn", [("act", "swiglu"), ("nhidden", "96"),
+                         ("init_sigma", "0.2")])
+    p = layer.init_params(jax.random.PRNGKey(2), [(E, S, 1)])
+    assert set(p) == {"g", "h", "o"} and "bias" not in p["g"]
+    x = x_node()
+
+    def prog(p, x):
+        return seq(layer.apply(p, {}, [x], ctx())[0][0])
+    with jax.default_matmul_precision("highest"):
+        close(prog(p, x), ref.swiglu(seq(x), p))
+        g1 = jax.grad(lambda p, x: jnp.sum(prog(p, x) ** 2), (0, 1))(p, x)
+        g2 = jax.grad(lambda p, x: jnp.sum(ref.swiglu(seq(x), p) ** 2),
+                      (0, 1))(p, x)
+    tree_close(g1, g2)
+
+
+@pytest.mark.parametrize("impl", ["auto", "ref", "flash"])
+def test_mla_forward_and_gradients_are_the_references(impl):
+    layer = make("mla", MLA_CFG + [("attn_impl", impl)])
+    p = layer.init_params(jax.random.PRNGKey(3), [(E, S, 1)])
+    x = x_node()
+
+    def prog(p, x):
+        return seq(layer.apply(p, {}, [x], ctx())[0][0])
+    with jax.default_matmul_precision("highest"):
+        close(prog(p, x), ref.attention(p, seq(x), C))
+        g1 = jax.grad(lambda p, x: jnp.sum(prog(p, x) ** 2), (0, 1))(p, x)
+        g2 = jax.grad(lambda p, x: jnp.sum(
+            ref.attention(p, seq(x), C) ** 2), (0, 1))(p, x)
+    tree_close(g1, g2, 5e-5)
+
+
+def test_mla_rotary_is_on_interleaved_pairs():
+    """Against the reference's own two forms: the program agrees with
+    the interleaved one, and the halves form is another function."""
+    from cxxnet_tpu.ops.attention import rope, rope_interleaved
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, S, 2, 8))
+    close(rope_interleaved(x, 32e6), ref.rotary(x, 32e6, True))
+    close(rope(x, 32e6), ref.rotary(x, 32e6, False))
+    assert np.max(np.abs(np.asarray(rope_interleaved(x, 32e6))
+                         - np.asarray(rope(x, 32e6)))) > 0.1
+
+
+def moe_apply(layer, p, bias, x, train=True):
+    st = dict(layer.init_state([(E, S, 1)]), sel_bias=bias)
+    (y,), new = layer.apply(p, st, [x], ctx(train))
+    return seq(y), new
+
+
+@pytest.mark.parametrize("bias_scale", [0.0, 0.2])
+def test_moe_sigmoid_router_is_the_references(bias_scale):
+    """Sigmoid scores, the bias in the choice only (a planted one moves
+    the choice and never the gates), weights normalised over the chosen
+    and scaled, the shared expert, the bias's update rule."""
+    layer = moe_layer()
+    p = layer.init_params(jax.random.PRNGKey(5), [(E, S, 1)])
+    bias = bias_scale * jax.random.normal(jax.random.PRNGKey(6), (16,))
+    x = x_node(7)
+    with jax.default_matmul_precision("highest"):
+        y, new = moe_apply(layer, p, bias, x)
+        want, want_bias = ref.experts(p, bias, seq(x), C)
+        close(y, want)
+        close(new["sel_bias"], want_bias, 1e-7)
+        g1 = jax.grad(lambda p, x: jnp.sum(
+            moe_apply(layer, p, bias, x)[0] ** 2), (0, 1))(p, x)
+        g2 = jax.grad(lambda p, x: jnp.sum(
+            ref.experts(p, bias, seq(x), C)[0] ** 2), (0, 1))(p, x)
+    tree_close(g1, g2, 5e-5)
+    stats = dict(zip(__import__("cxxnet_tpu.layers.moe", fromlist=["x"])
+                     .MOE_STATS, np.asarray(new["stats"])))
+    assert stats["pairs_held"] == B * S * 3 and stats["pairs_dropped"] == 0
+    assert stats["pairs_elsewhere"] == 0
+    # eval leaves the bias alone
+    _, kept = moe_apply(layer, p, bias, x, train=False)
+    close(kept["sel_bias"], bias, 0)
+
+
+def test_moe_bias_never_enters_the_gates_or_the_gradient():
+    layer = moe_layer()
+    p = layer.init_params(jax.random.PRNGKey(5), [(E, S, 1)])
+    x = x_node(7)
+    # a bias that keeps every choice (one constant) changes nothing
+    y0, _ = moe_apply(layer, p, jnp.zeros((16,)), x)
+    y1, _ = moe_apply(layer, p, jnp.full((16,), 0.3), x)
+    close(y0, y1, 1e-7)
+    g = jax.grad(lambda b: jnp.sum(moe_apply(layer, p, b, x)[0] ** 2))(
+        0.1 * jnp.arange(16.0))
+    assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
+@pytest.mark.parametrize("skew", ["one_expert", "half_empty"])
+def test_no_pair_is_dropped_under_planted_skew(skew):
+    """Every position to one expert (plus its two runners-up), or half
+    the experts never chosen: the routers are planted through the bias,
+    one compiled executable serves both and the balanced case, every held
+    pair is computed and the output is the reference's."""
+    layer = moe_layer(first=0, held=8)
+    c = dict(C, n_routed_experts=8)
+    p = layer.init_params(jax.random.PRNGKey(8), [(E, S, 1)])
+    x = x_node(9)
+    fn = jax.jit(lambda p, b, x: moe_apply(layer, p, b, x))
+    planted = {"one_expert": jnp.zeros((16,)).at[jnp.array([2, 3, 5])]
+               .set(5.0),
+               "half_empty": jnp.asarray(np.where(np.arange(16) % 2 == 0,
+                                                  5.0, 0.0), jnp.float32)}
+    with jax.default_matmul_precision("highest"):
+        for bias in (jnp.zeros((16,)), planted[skew]):
+            y, new = fn(p, bias, x)
+            close(y, ref.experts(p, bias, seq(x), c)[0])
+            held, elsewhere, dropped = np.asarray(new["stats"])[:3]
+            assert dropped == 0 and held + elsewhere == B * S * 3
+        assert fn._cache_size() == 1
+    if skew == "one_expert":
+        assert held == B * S * 3        # all three chosen are held
+        assert np.asarray(new["stats"])[3] > 5.0    # load max over mean
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """Four chips of four experts each: the routed parts all the shares
+    give, with the shared expert — which every chip computes alike —
+    counted once, are what the uncut reference gives for the whole layer,
+    forward and input gradient."""
+    whole = moe_layer()
+    p = whole.init_params(jax.random.PRNGKey(10), [(E, S, 1)])
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(11), (16,))
+    x = x_node(12)
+    p_shared_off = dict(p, shared=jax.tree_util.tree_map(
+        jnp.zeros_like, p["shared"]))
+
+    def share(first, x, params):
+        layer = moe_layer(first=first, held=4)
+        cut = dict(params, **{k: {"wmat": params[k]["wmat"][first:first + 4]}
+                              for k in ("g", "h", "o")})
+        return moe_apply(layer, cut, bias, x)[0]
+
+    def summed(x):
+        routed = sum(share(f, x, p_shared_off) for f in (0, 4, 8, 12))
+        shared_once = share(0, x, p) - share(0, x, p_shared_off)
+        return routed + shared_once
+    with jax.default_matmul_precision("highest"):
+        want = lambda x: ref.experts(p, bias, seq(x), C)[0]
+        close(summed(x), want(x))
+        close(jax.grad(lambda x: jnp.sum(summed(x) ** 2))(x),
+              jax.grad(lambda x: jnp.sum(want(x) ** 2))(x), 5e-5)
+
+
+def test_capacity_router_still_refuses_topk_beyond_two():
+    with pytest.raises(ValueError, match="topk must be 1 or 2"):
+        make("moe", [("num_expert", "8"), ("topk", "3")])
+    # and the capacity path keeps its state and parameter layout
+    layer = make("moe", [("num_expert", "4"), ("topk", "2"),
+                         ("nhidden", "16")])
+    assert set(layer.init_state([(E, S, 1)])) == {"_aux_loss"}
+    assert "bias" in layer.init_params(jax.random.PRNGKey(0),
+                                       [(E, S, 1)])["h"]
+
+
+def test_lmloss_shift_and_the_metric_reduced_on_the_device():
+    from cxxnet_tpu.metrics import MetricSeqError, MetricSeqLogloss
+    V = 11
+    logits = jax.random.normal(jax.random.PRNGKey(13), (B, S, 1, V))
+    label = jax.random.randint(jax.random.PRNGKey(14), (B, S), 0, V) \
+        .astype(jnp.float32)
+    mask = jnp.ones((B,))
+    for shift in (0, 1):
+        layer = create_layer(LayerSpec("lmloss", "L", [1], [1],
+                                       [("shift", str(shift))]), [])
+        out, _ = layer.apply({}, {}, [logits], ctx())
+        lp = np.asarray(out[0]).reshape(B, S, V)
+        lab = np.asarray(label, np.int64)
+        n = S - shift
+        picked = np.take_along_axis(lp[:, :n], lab[:, shift:, None], 2)[..., 0]
+        close(layer.loss(out, label, mask), -picked.mean(), 1e-6)
+        stats = np.asarray(layer.metric_stats(out, label))
+        close(stats[:, 0], picked.sum(1), 1e-6)
+        close(stats[:, 1], (lp[:, :n].argmax(2) == lab[:, shift:]).sum(1), 0)
+        close(stats[:, 2], np.full(B, n), 0)
+        if shift == 0:
+            # the reduced form reads what the whole node reads
+            for cls in (MetricSeqError, MetricSeqLogloss):
+                whole, reduced = cls("m", "label"), cls("m", "label")
+                whole.add(lp.reshape(B, -1), np.asarray(label))
+                reduced.add_reduced(stats)
+                assert whole.cnt == reduced.cnt
+                close(whole.get(), reduced.get(), 1e-6)

@@ -71,6 +71,15 @@ CASES = {
     "lmloss": (f"1,1,12", f"layer[+1] = embed\n  nhidden = 8\n"
                f"  vocab_size = {SEQ_V}\nlayer[+1] = seqfc\n"
                f"  nhidden = {SEQ_V}\nlayer[+0] = lmloss\n"),
+    "rmsnorm": (f"1,1,12", f"layer[+1] = embed\n  nhidden = 8\n"
+                f"  vocab_size = {SEQ_V}\nlayer[+1] = rmsnorm\n"),
+    "mla": (f"1,1,12", f"layer[+1] = embed\n  nhidden = 8\n"
+            f"  vocab_size = {SEQ_V}\nlayer[+1] = mla\n  nhead = 2\n"
+            f"  q_lora_rank = 6\n  kv_lora_rank = 4\n"
+            f"  qk_nope_head_dim = 4\n  qk_rope_head_dim = 2\n"
+            f"  v_head_dim = 4\n"),
+    "label_ids": (f"1,1,12", f"layer[+1] = label_ids\nlayer[+1] = embed\n"
+                  f"  nhidden = 8\n  vocab_size = {SEQ_V}\n"),
 }
 
 # covered separately: share/pairtest/fixconn in test_layers.py and below,
@@ -94,7 +103,7 @@ def test_layer_forward_and_grad(ltype):
     rng = np.random.RandomState(0)
     c, y, x = (int(v) for v in shape.split(","))
     if ltype in ("embed", "posembed", "layernorm", "mha", "ffn", "moe",
-                 "seqfc", "add", "lmloss"):
+                 "seqfc", "add", "lmloss", "rmsnorm", "mla", "label_ids"):
         data = jnp.asarray(rng.randint(0, SEQ_V, (4, 1, 1, x))
                            .astype(np.float32))
     elif c == 1 and y == 1:
