@@ -332,7 +332,12 @@ class LatentAttentionLayer(Layer):
     block where there are fewer than 128). ``auto`` is the kernel on a
     TPU where such a block exists — it won the layer's A/B on the chip,
     33 ms against 528 for XLA's dots at 8192 positions, and blocks of
-    1024 won among blocks (PERF.md section 6, PR 28) — else ``ref``."""
+    1024 won among blocks (PERF.md section 6, PR 28) — else ``ref``.
+    The kernel's backward is one kernel that builds each score tile
+    once for dq, dk and dv, at the same blocks (1024 x 1024 won its A/B
+    too: PERF.md section 6, PR 29); under ``remat = 1`` the model keeps
+    the kernel's output and logsumexp and rebuilds only ``mla.proj``
+    (q, k, v from the layer's input) in the backward pass."""
     has_params = True
 
     _INT = ("nhead", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",

@@ -24,7 +24,16 @@ from .config import ConfigPairs, Policy
 from .graph import NetGraph, global_param, policy_from_config
 from .layers import ApplyCtx, Layer, create_layer
 from .layers.base import Shape3, is_flat, to_nhwc
+from .ops.attention import FLASH_RESIDUALS
 from .ops.fused import selection_site
+
+#: ``remat = 1`` rebuilds a layer's activations in the backward pass but
+#: for the values named here: the flash kernel's output and logsumexp,
+#: which its backward needs and only the kernel's forward makes. A layer
+#: that never reaches the kernel has no such name and keeps nothing, as
+#: under a bare ``jax.checkpoint``
+_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *FLASH_RESIDUALS)
 
 Params = Dict[str, Dict[str, jax.Array]]
 NetState = Dict[str, Any]
@@ -277,7 +286,8 @@ class Network:
                                      fuse_act=_ctx.fuse_act,
                                      cin_pad=_ctx.cin_pad)
                         return _layer.apply(lp, ls, list(ins), c)
-                    outputs, lstate_out = jax.checkpoint(_fn)(
+                    outputs, lstate_out = jax.checkpoint(
+                        _fn, policy=_REMAT_POLICY)(
                         lparams, lstate, ctx.rng, *inputs)
                 else:
                     outputs, lstate_out = layer.apply(lparams, lstate,

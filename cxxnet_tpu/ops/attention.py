@@ -15,13 +15,17 @@ taking (batch, seq, heads, head_dim) arrays:
   also the backward path for the flash kernel.
 * ``flash_attention`` — Pallas kernels tiling q into MXU-friendly blocks
   and streaming k/v blocks through VMEM. The forward also emits the
-  per-row logsumexp; the backward is FUSED (dq and dk/dv kernels that
-  rebuild the softmax from that statistic — no second online pass, no
-  chunked recompute). On the CPU backend the same kernels run under
-  the Pallas interpreter (``fused.use_interpret``); on a TPU backend
-  they are compiled. Not twice-differentiable (the fused backward is a kernel,
-  not traced jnp); differentiate ``chunked_attention`` for higher-order
-  uses.
+  per-row logsumexp; the backward is ONE fused kernel (``flash_bwd``)
+  that rebuilds each tile's softmax once from that statistic — no
+  second online pass, no chunked recompute — and feeds dq, dk and dv
+  from it. The forward's output and logsumexp carry ``checkpoint_name``s
+  (``FLASH_RESIDUALS``), so a ``jax.checkpoint`` that saves those names
+  (``model.py`` under ``remat = 1``) does not run the forward kernel a
+  second time in the backward pass. On the CPU backend the same kernels
+  run under the Pallas interpreter (``fused.use_interpret``); on a TPU
+  backend they are compiled. Not twice-differentiable (the fused
+  backward is a kernel, not traced jnp); differentiate
+  ``chunked_attention`` for higher-order uses.
 
 Masking convention: ``causal=True`` masks strictly-future positions.
 Fully-masked rows produce zeros (guarded divide), so ragged/padded
@@ -36,6 +40,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -270,14 +275,16 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 # -- Pallas flash attention ---------------------------------------------------
 
-def _block_causal_mask(qi, kj, block_q, block_k):
+def _block_causal_mask(qi, kj, block_q, block_k, transposed=False):
     """Causal keep-mask for one (q-block, k-block) tile — shared by the
-    forward and both backward kernels so the masking convention cannot
-    drift between them."""
+    forward and the backward kernel so the masking convention cannot
+    drift between them. ``transposed``: the (block_k, block_q) tile the
+    backward holds."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
     qpos = qi * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, 1 if transposed else 0)
     kpos = kj * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, 0 if transposed else 1)
     return qpos >= kpos
 
 
@@ -390,6 +397,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Flash attention (B,S,H,D): Pallas forward, fused Pallas backward.
+    v may be narrower or wider than q and k. The backward is one kernel
+    (``flash_bwd``, :func:`_flash_backward`) at the forward's blocks: it
+    needs q, k, v, the cotangent, and the forward's output and
+    logsumexp, which are saved under the names ``FLASH_RESIDUALS`` — a
+    rematerialising caller keeps those two and rebuilds the rest.
 
     ``interpret=None``: compiled on a TPU backend, interpreted on the
     CPU backend — the same kernel is exercised in CPU tests (the
@@ -399,60 +411,32 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           use_interpret(interpret))
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_acc, *, scale, causal, block_q, block_k):
-    """dQ_i = scale * sum_j dS_ij K_j, dS = P o (dP - delta); P rebuilt
-    from the saved logsumexp (no second online pass). Grid
-    (batch*head, q-block, k-block sequential)."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                      scale, causal, block_q, block_k):
+    """The whole backward of one (batch*head, k-block, q-block) grid cell:
+    the score tile, its softmax P (rebuilt from the saved logsumexp, no
+    second online pass), dP and dS = P o (dP - delta) are made ONCE and
+    feed all three gradients,
 
-    @pl.when(kj == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+      dV_j += P_ij^T dO_i    dK_j += dS_ij^T Q_i    dQ_i += dS_ij K_j
 
-    def compute():
-        q = q_ref[0]                              # (block_q, D)
-        kb = k_ref[0]                             # (block_k, D)
-        vb = v_ref[0]                             # (block_k, Dv)
-        do = do_ref[0]                            # (block_q, Dv)
-        lse = lse_ref[0, :, 0]                    # (block_q,)
-        delta = delta_ref[0, :, 0]                # (block_q,)
-        s = lax.dot_general(q, kb, _NT,
-                            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse[:, None])
-        if causal:
-            # explicit zeroing: fully-masked rows carry a sentinel lse,
-            # where exp(s - lse) would NOT vanish on its own
-            p = jnp.where(_block_causal_mask(qi, kj, block_q, block_k),
-                          p, 0.0)
-        dp = lax.dot_general(do, vb, _NT,
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_acc[...] += jnp.dot(ds.astype(kb.dtype), kb,
-                               preferred_element_type=jnp.float32)
-
-    if causal:
-        @pl.when(kj * block_k <= qi * block_q + block_q - 1)
-        def _guarded():
-            compute()
-    else:
-        compute()
-
-    @pl.when(kj == nk - 1)
-    def _finish():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *,
-                          scale, causal, block_q, block_k):
-    """dK_j = scale * sum_i dS_ij^T Q_i; dV_j = sum_i P_ij^T dO_i. Grid
-    (batch*head, k-block, q-block sequential)."""
+    (dS carries ``scale``). The tile is held TRANSPOSED, (block_k,
+    block_q): dV's and dK's products then contract its lanes as they
+    stand and only dQ's contracts its rows, and the per-row statistics
+    arrive lane-dense as (1, block_q) rows. The q-blocks are the
+    innermost, sequential grid dimension: dK and dV of the k-block
+    accumulate in VMEM scratch across it; dQ is the float32 block of the
+    head's WHOLE row, resident in VMEM while the head's tiles run (its
+    index map is constant within a head), added to in place at the
+    q-block's rows and written back once a head."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+
+    @pl.when(jnp.logical_and(kj == 0, qi == 0))
+    def _init_dq():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
 
     @pl.when(qi == 0)
     def _init():
@@ -464,21 +448,23 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         kb = k_ref[0]                             # (block_k, D)
         vb = v_ref[0]                             # (block_k, Dv)
         do = do_ref[0]                            # (block_q, Dv)
-        lse = lse_ref[0, :, 0]
-        delta = delta_ref[0, :, 0]
-        s = lax.dot_general(q, kb, _NT,
-                            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse[:, None])
+        st = lax.dot_general(kb, q, _NT,
+                             preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0])             # lse: (1, block_q)
         if causal:
-            p = jnp.where(_block_causal_mask(qi, kj, block_q, block_k),
-                          p, 0.0)
-        dv_acc[...] += lax.dot_general(
-            p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, vb, _NT,
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += lax.dot_general(
-            ds.astype(q.dtype), q, _TN, preferred_element_type=jnp.float32)
+            # explicit zeroing: fully-masked rows carry a sentinel lse,
+            # where exp(s - lse) would NOT vanish on its own
+            pt = jnp.where(_block_causal_mask(qi, kj, block_q, block_k,
+                                              transposed=True), pt, 0.0)
+        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(vb, do, _NT,
+                              preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0]) * scale).astype(q.dtype)
+        dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_ref[0, rows, :] += lax.dot_general(
+            dst, kb, _TN, preferred_element_type=jnp.float32)
 
     if causal:
         # only q blocks at or below the diagonal contribute to this k tile
@@ -494,66 +480,85 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+#: what the backward kernel may use of a v5e's 128 MiB of VMEM (the
+#: compiler's default scoped limit is 16 MiB, under one head's dq row at
+#: 8192 positions): the rest stays the compiler's own
+_BWD_VMEM_LIMIT = 96 * 2 ** 20
+
+
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     interpret):
-    """Fused Pallas backward: dq from one kernel, dk/dv from another,
-    both rebuilding the softmax from the forward's logsumexp."""
+    """Fused Pallas backward, ONE kernel (``flash_bwd``): every executed
+    tile rebuilds its softmax once from the forward's logsumexp and
+    feeds dq, dk and dv from it. ``out`` is (B, Sq, H, Dv) as the
+    forward returned it, ``lse`` the lane-dense (B*H, Sq) the forward
+    rule holds; dq leaves the kernel in float32 (it is summed in place
+    across the k-blocks) and is rounded on the way back to (B, Sq, H, D).
+    """
     B, Sq, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
+    # a head's float32 dq row (lanes padded to 128s, the output's two
+    # pipeline buffers) beside the tile's float32 intermediates
+    lanes = -(-D // 128) * 128
+    need = 2 * Sq * lanes * 4 + 8 * block_q * block_k * 4
+    if need > _BWD_VMEM_LIMIT:
+        raise ValueError(
+            f"flash_attention backward: a head's float32 dq row "
+            f"({Sq} x {lanes} lanes, twice) and its ({block_q},{block_k}) "
+            f"tiles need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} "
+            f"the kernel may use: shorten the sequence or shard it")
     sc = _scale(q, scale)
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
     vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, Dv)
     dot = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
-    # delta_i = rowsum(dO_i * O_i) — cheap elementwise precompute
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * out.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
-                    .astype(jnp.float32), axis=-1)[..., None]
+    # delta_i = rowsum(dO_i * O_i): elementwise where both already lie,
+    # then one small transpose to the kernel's lane-dense rows
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Sq)
 
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    v_spec = pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0))
-    o_spec = pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0))
-    r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=sc, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(B * H, Sq // block_q, Sk // block_k),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, r_spec, r_spec],
-        out_specs=q_spec,
-        out_shape=out_struct((B * H, Sq, D), q.dtype, qt, kt, vt, dot),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret, name="flash_bwd_dq",
-    )(qt, kt, vt, dot, lse, delta)
-
-    # swapped grid: (bh, k-block, q-block) — index maps swap i/j roles
-    q_spec2 = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    k_spec2 = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    v_spec2 = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
-    o_spec2 = pl.BlockSpec((1, block_q, Dv), lambda b, j, i: (b, i, 0))
-    r_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=sc, causal=causal,
+    # grid (bh, k-block j, q-block i), the q-blocks innermost
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    v_spec = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
+    o_spec = pl.BlockSpec((1, block_q, Dv), lambda b, j, i: (b, i, 0))
+    r_spec = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
+    dq_spec = pl.BlockSpec((1, Sq, D), lambda b, j, i: (b, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=sc, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(B * H, Sk // block_k, Sq // block_q),
-        in_specs=[q_spec2, k_spec2, v_spec2, o_spec2, r_spec2, r_spec2],
-        out_specs=[k_spec2, v_spec2],
-        out_shape=[out_struct((B * H, Sk, D), k.dtype, qt, kt, vt, dot),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, r_spec, r_spec],
+        out_specs=[dq_spec, k_spec, v_spec],
+        out_shape=[out_struct((B * H, Sq, D), jnp.float32, qt, kt, vt, dot),
+                   out_struct((B * H, Sk, D), k.dtype, qt, kt, vt, dot),
                    out_struct((B * H, Sk, Dv), v.dtype, qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, Dv), jnp.float32)],
-        interpret=interpret, name="flash_bwd_dkv",
-    )(qt, kt, vt, dot, lse, delta)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="flash_bwd",
+    )(qt, kt, vt, dot, lse.reshape(B * H, 1, Sq), delta)
 
     unflat = lambda a, S: a.reshape(B, H, S, -1).transpose(0, 2, 1, 3)
-    return unflat(dq, Sq), unflat(dk, Sk), unflat(dv, Sk)
+    return unflat(dq.astype(q.dtype), Sq), unflat(dk, Sk), unflat(dv, Sk)
+
+
+#: the two residuals of the kernel's forward that its backward cannot
+#: rebuild cheaply, by the names ``jax.checkpoint`` policies know them
+#: under (``model.py`` saves exactly these across ``remat = 1``)
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                               use_interpret(interpret), with_lse=True)
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    # held lane-dense, (B*H, Sq): a trailing 1 may be padded to 128 lanes
+    lse = checkpoint_name(lse.reshape(lse.shape[:2]), FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 

@@ -243,20 +243,23 @@ def test_flash_attention_compiles(one_chip, shape, dtype):
                                          False)
     text = _compile(fn, one_chip, [(shape, dtype)] * 3,
                     grad_argnums=(0, 1, 2))
-    assert _kernels(text) >= 3          # forward, dq, dk/dv
+    assert _kernels(text) == 2          # forward, backward
 
 
 # -- benchmarks/configs/joyai_llm_flash.conf (latent attention at 8k) ----------
 
 def test_latent_attention_flash_compiles_at_the_cell_s_widths(one_chip):
     """One row of 8192 positions, 32 heads, q.k 192 wide (no multiple
-    of the 128 lanes) and v 128 wide in its own right, blocks of 1024:
-    the shape ``mla`` hands the kernel in ``joyai_ep16_train_8k``."""
+    of the 128 lanes) and v 128 wide in its own right, blocks of 1024
+    forward and backward (they won both A/Bs on the chip): the shape
+    ``mla`` hands the kernel in ``joyai_ep16_train_8k``. The backward
+    holds a head's whole float32 dq row in VMEM (8192 x 256 lanes,
+    twice) under a limit raised for it: an overflow fails here."""
     fn = lambda q, k, v: flash_attention(q, k, v, True, None, 1024, 1024,
                                          False)
     text = _compile(fn, one_chip, [((1, 8192, 32, 192), BF16)] * 2
                     + [((1, 8192, 32, 128), BF16)], grad_argnums=(0, 1, 2))
-    assert _kernels(text) >= 3          # forward, dq, dk/dv
+    assert _kernels(text) == 2          # forward, backward
 
 
 def test_adam_apply_compiles_at_lm_leaves(one_chip):

@@ -281,3 +281,67 @@ def test_lmloss_shift_and_the_metric_reduced_on_the_device():
                 reduced.add_reduced(stats)
                 assert whole.cnt == reduced.cnt
                 close(whole.get(), reduced.get(), 1e-6)
+
+
+# -- remat = 1 and the flash kernel's residuals (PR 29) ------------------------
+
+def _toy_net(remat, attn_impl="flash"):
+    """The toy ``joyai_llm_flash`` net (tests/benchmarks/data/joyai_toy)
+    with its latent attention on the Pallas kernel."""
+    from cxxnet_tpu.config import parse_config_string
+    from cxxnet_tpu.graph import build_graph
+    from cxxnet_tpu.model import Network
+    with open(os.path.join(ROOT, "tests", "benchmarks", "data", "joyai_toy",
+                           "configs", "joyai_toy.conf")) as f:
+        text = f.read()
+    assert "remat = 1\n" in text and text.count("= mla:") == 3
+    text = text.replace("remat = 1\n", f"remat = {remat}\n").replace(
+        "  v_head_dim = 16\n",
+        f"  v_head_dim = 16\n  attn_impl = {attn_impl}\n")
+    cfg = parse_config_string(text + "batch_size = 2\n")
+    return Network(build_graph(cfg), cfg)
+
+
+def _toy_loss(net, state, data, label):
+    return lambda p: net.apply(p, state, data, label=label,
+                               mask=jnp.ones((2,)), train=True).loss
+
+
+def test_toy_net_under_remat_is_the_plain_net_and_runs_each_kernel_once():
+    """Loss and every leaf's gradient under ``remat = 1`` are those
+    under ``remat = 0`` with ``attn_impl = flash``, and the rebuilt
+    layers hold no second run of the kernel's forward: three attention
+    layers, three forward kernels, three backward kernels."""
+    plain, remat = _toy_net(0), _toy_net(1)
+    params, state = plain.init(jax.random.PRNGKey(3))
+    toks = np.random.RandomState(3).randint(0, 64, (2, 32))
+    data = jnp.asarray(toks.reshape(2, 1, 1, 32), jnp.float32)
+    label = jnp.asarray((toks + toks[:, :1]) % 64, jnp.float32)
+    l0, g0 = jax.value_and_grad(_toy_loss(plain, state, data, label))(params)
+    l1, g1 = jax.value_and_grad(_toy_loss(remat, state, data, label))(params)
+    close(l1, l0, 1e-6)
+    assert jax.tree_util.tree_structure(g0) == \
+        jax.tree_util.tree_structure(g1)
+    tree_close(g1, g0, 1e-5)
+    text = str(jax.make_jaxpr(jax.grad(
+        _toy_loss(remat, state, data, label)))(params))
+    assert (text.count("name=flash_fwd"), text.count("name=flash_bwd")) \
+        == (3, 3)
+
+
+def test_a_net_without_the_kernel_keeps_nothing_under_remat(monkeypatch):
+    """``remat = 1`` saves what the flash kernel's forward names and
+    nothing else: a net that never reaches the kernel lowers to the
+    program it lowered to under the bare ``jax.checkpoint``."""
+    remat = _toy_net(1, attn_impl="ref")
+    params, state = remat.init(jax.random.PRNGKey(3))
+    data = jnp.zeros((2, 1, 1, 32), jnp.float32)
+    label = jnp.ones((2, 32), jnp.float32)
+    lower = lambda: jax.jit(jax.grad(_toy_loss(
+        remat, state, data, label))).lower(params).as_text()
+    kept = lower()
+    bare = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint",
+                        lambda f, policy=None: bare(f))
+    assert lower() == kept
+    assert "flash" not in kept
