@@ -216,12 +216,12 @@ def calibration_entry(cost_bytes: float, measured_bytes,
 
 def profile_attribution(tr, classes, batch, k=8):
     """Capture a jax.profiler trace of ``k`` flagship steps and
-    attribute device op time to forward / backward / optimizer and the
-    fused kinds inside each, through the step's own scopes —
-    telemetry.traceparse. The step is warmed (compile retired) BEFORE
-    the bracket so the trace holds steady-state steps only. Returns the
-    attribution dict (JSON-rounded); off the TPU there is no chip's
-    plane to read and the result is an {"error": ...} marker."""
+    attribute device op time to forward / backward / optimizer
+    through the step's own scopes — telemetry.traceparse. The step is
+    warmed (compile retired) BEFORE the bracket so the trace holds
+    steady-state steps only. Returns the attribution dict
+    (JSON-rounded); off the TPU there is no chip's plane to read and
+    the result is an {"error": ...} marker."""
     import numpy as np
     from cxxnet_tpu.io.data import DataBatch
     from cxxnet_tpu.telemetry.profiler import device_trace
@@ -534,20 +534,10 @@ def compute_bench(tr, image, classes, batch, steps, ref_cost_fn=None):
         # bytes-implied cap is conservative, not a law of physics
         "roofline_pct": roofline_pct,
         "arith_intensity": ai,
-        # compiled-step HBM traffic (cost_analysis bytes-accessed): THE
-        # number the fused kernel suite exists to shrink — the flagship
-        # is bandwidth-bound, so fusion wins must show here (and as a
-        # higher arith_intensity), not be asserted
+        # compiled-step HBM traffic (cost_analysis bytes-accessed): the
+        # flagship is bandwidth-bound, so a byte saving must show here
+        # (and as a higher arith_intensity), not be asserted
         "hbm_bytes_per_step": cost["bytes_accessed"],
-        # whether the fused Pallas kernels were selected for this trainer
-        # — the ACTUAL post-gate selection (knob x backend x mesh gate),
-        # not the requested knob (pinned by test_bench_helpers)
-        "fused_kernels": bool(tr.net._fused_now()),
-        # islands active: fused kernels running under shard_map on a
-        # multi-device mesh (ISSUE 9) — the fused_ab entry on a mesh
-        # then measures the fusion win on the topology that matters
-        "fused_on_mesh": bool(tr.net._fused_now()
-                              and tr.net.fused_spmd is not None),
         "peak_bf16_tflops": peak,
         "hbm_gbs": hbm_gbs,
         "loss_start": loss_start,
@@ -886,7 +876,7 @@ def main() -> None:
         "--full", action="store_true",
         help="run the float-e2e / h2d / decode-pool sub-benches too. "
              "The default run time-boxes to the phases that feed the "
-             "metric of record: flagship compute, fused A/B, profile "
+             "metric of record: flagship compute, profile "
              "attribution, fp32 compare, ONE uint8 e2e window, and the "
              "secondary models (a run that sprawls over every phase "
              "dies to the harness timeout)")
@@ -946,51 +936,13 @@ def main() -> None:
         "per_step_ms": round(c["per_step_ms"], 3),
         "arith_intensity": round(c["arith_intensity"], 1),
         "hbm_bytes_per_step": round(c["hbm_bytes_per_step"], 1),
-        "fused_kernels": c["fused_kernels"],
-        "fused_on_mesh": c["fused_on_mesh"],
         "loss_start": round(c["loss_start"], 4),
         "loss_end": round(c["loss_end"], 4),
         "n_chips": c["n_chips"],
         "chip": jax.devices()[0].device_kind,
     })
-    # -- fused-kernel A/B: the PR-5 suite priced ON-CHIP in the same
-    # artifact (ROADMAP item 1). The headline trainer runs
-    # fused_kernels=auto, which selects the jnp references (no kind has
-    # won a cell on the chip); one rerun with fused_kernels=1 prices
-    # the suite directly.
-    if budget.low(150, "fused_ab"):
-        fused_ab = {"skipped": "budget"}
-    else:
-        try:
-            tr_fus = make_trainer(scale, image, classes, batch, platform,
-                                  overrides=(("fused_kernels", "1"),))
-            c_fus = compute_bench(tr_fus, image, classes, batch,
-                                  max(3, steps // 2))
-            pick = ("ips", "per_step_ms", "hbm_bytes_per_step",
-                    "arith_intensity", "mfu_est", "roofline_pct",
-                    "fused_kernels", "fused_on_mesh")
-            fused_ab = {
-                "fused": {k: round(c_fus[k], 3)
-                          if isinstance(c_fus[k], float)
-                          else c_fus[k] for k in pick},
-                "reference": {k: round(c[k], 3) if isinstance(c[k], float)
-                              else c[k] for k in pick},
-                # >1: the fused suite's step is faster on this chip
-                "speedup_fused_vs_ref": round(
-                    c["per_step_ms"] / c_fus["per_step_ms"], 4)
-                if c_fus["per_step_ms"] else None,
-                "bytes_ratio_fused_vs_ref": round(
-                    c_fus["hbm_bytes_per_step"] / c["hbm_bytes_per_step"],
-                    4) if c["hbm_bytes_per_step"] else None,
-            }
-            del tr_fus, c_fus
-        except Exception as e:
-            if _on_tpu():
-                raise
-            fused_ab = {"error": f"{type(e).__name__}: {e}"}
-    budget.record({"fused_ab": fused_ab})
     # -- measured attribution: trace k steady steps and attribute device
-    # op time per phase and fused kind through the step's own scopes
+    # op time per phase through the step's own scopes
     # (doc/ibn_perf.md; tools/ibn_perf.py regenerates the doc table).
     # No jax-0.9 dump carries memory counters, so the byte calibration
     # below stands on the analytic model alone
@@ -1171,7 +1123,6 @@ def main() -> None:
             "roofline_pct": round(mc["roofline_pct"], 2),
             "arith_intensity": round(mc["arith_intensity"], 1),
             "hbm_bytes_per_step": round(mc["hbm_bytes_per_step"], 1),
-            "fused_kernels": mc["fused_kernels"],
             "step_tflop": round(mc["step_tflop"], 4),
             # device step time from the chained-dispatch slope — NOT wall
             # per-dispatch time, which bottoms out at the host's dispatch
@@ -1217,7 +1168,6 @@ def main() -> None:
         "roofline_pct": round(c["roofline_pct"], 2),
         "arith_intensity": round(c["arith_intensity"], 1),
         "hbm_bytes_per_step": round(c["hbm_bytes_per_step"], 1),
-        "fused_kernels": c["fused_kernels"],
         "step_tflop": round(c["step_tflop"], 4),
         "per_step_ms": round(c["per_step_ms"], 3),
         "timing": ("k-step chained dispatch, slope of two chain lengths "
@@ -1242,10 +1192,8 @@ def main() -> None:
         "loss_start": round(c["loss_start"], 4),
         "loss_end": round(c["loss_end"], 4),
         "fp32_compare": fp32_cmp,
-        # fused_kernels=1 vs 0 flagship A/B, measured per-phase
-        # attribution, and the measured-vs-cost_analysis byte
-        # calibration — the ROADMAP item-1 trio, all in one artifact
-        "fused_ab": fused_ab,
+        # measured per-phase attribution and the
+        # measured-vs-cost_analysis byte calibration
         "attribution": att,
         "calibration": calib,
         "input_fold": fold_entry,

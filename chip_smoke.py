@@ -4,23 +4,20 @@
 Drives the program's main path once, through the entry points a user
 calls (``cxxnet_tpu.main.LearnTask`` -> ``Trainer`` -> ``save_model`` ->
 ``task = serve``'s ``ServeServer``), at the full width of the flagship:
-Inception-BN, batch 256, 224x224x3, 1000 classes, bfloat16, ``dev = tpu``,
-``fused_kernels`` left at ``auto`` — which selects XLA's own code for
-every kind, so the proof is a step with no fused site, no Pallas kernel
-and no host callback in it. Weights and data are random, from
-``--seed``. ONE process: a chip belongs to one process at a time, so
-nothing here starts a child.
+Inception-BN, batch 256, 224x224x3, 1000 classes, bfloat16, ``dev = tpu``.
+Every convnet op has one implementation, XLA's own code, so the proof is
+a step with no Pallas kernel and no host callback in it. Weights and
+data are random, from ``--seed``. ONE process: a chip belongs to one
+process at a time, so nothing here starts a child.
 
     python chip_smoke.py              one chip: train, then serve
     python chip_smoke.py --chips 4    four chips: ONLY the data-parallel
                                       flagship against one device
     python chip_smoke.py --rehearse-cpu [--chips 4]
                                       the same control flow at a tiny
-                                      size on the CPU backend, with
-                                      ``fused_kernels = 1`` (kernels
-                                      interpreted) — its last line says
-                                      "cpu", so it can never be read as
-                                      a chip pass
+                                      size on the CPU backend — its
+                                      last line says "cpu", so it can
+                                      never be read as a chip pass
 
 Every phase prints one JSON line (seconds with compile apart from steady
 state, peak device bytes, kernel and compile counts); any failed check
@@ -77,7 +74,7 @@ def require(ok: bool, phase: str, why: str) -> None:
 
 
 def flagship_config(size: dict, dev: str, seed: int, model_dir: str,
-                    rehearse: bool, extra=()):
+                    extra=()):
     """The flagship's config pairs: gen_inception_bn's net and globals
     behind one batch of the seeded ``synthetic`` iterator."""
     sys.path.insert(0, os.path.join(_REPO, "examples", "ImageNet"))
@@ -96,14 +93,9 @@ def flagship_config(size: dict, dev: str, seed: int, model_dir: str,
     net = generate(scale=size["scale"], image_size=size["image"],
                    num_class=size["classes"], batch_size=size["batch"],
                    with_data=False)
-    pairs = parse_config_string(data + net) + [
-        ("dev", dev), ("seed", str(seed)), ("model_dir", model_dir)]
-    if rehearse:
-        # auto selects no kernel: force them on so the rehearsal (and
-        # tests/test_chip_smoke.py) still walks the selection code,
-        # interpreted
-        pairs.append(("fused_kernels", "1"))
-    return pairs + list(extra)
+    return parse_config_string(data + net) + [
+        ("dev", dev), ("seed", str(seed)),
+        ("model_dir", model_dir)] + list(extra)
 
 
 def _compiles():
@@ -126,14 +118,12 @@ def _peak_bytes():
 
 
 def _selection(tr) -> dict:
-    """{"fused": n, "reference": n, "by": {...}} from the trainer's log
-    (the trainer prints its one-line form itself, after its first step)."""
+    """{"<kind>:<what>": n} from the trainer's selection log: empty for
+    a convnet, whose ops have one implementation each."""
     from cxxnet_tpu.ops.fused import selection_counts
-    by = selection_counts(tr.net.fused_log)
-    return {"fused": sum(by["fused"].values()),
-            "reference": sum(by["reference"].values()),
-            "by": {f"{kind}:{what}": n for kind, c in by.items()
-                   for what, n in c.items()}}
+    return {f"{kind}:{what}": n
+            for kind, c in selection_counts(tr.net.fused_log).items()
+            for what, n in c.items()}
 
 
 def build_trainer(phase: str, cfg):
@@ -170,11 +160,11 @@ def take_steps(phase: str, tr, staged, n: int):
     return losses, secs, comps
 
 
-def train_phase(size, dev, seed, out_dir, rehearse):
+def train_phase(size, dev, seed, out_dir):
     phase = "train"
     model_dir = os.path.join(out_dir, "models")
     os.makedirs(model_dir, exist_ok=True)
-    cfg = flagship_config(size, dev, seed, model_dir, rehearse)
+    cfg = flagship_config(size, dev, seed, model_dir)
     t0 = time.perf_counter()
     task, tr, staged = build_trainer(phase, cfg)
     build_s = time.perf_counter() - t0
@@ -186,17 +176,12 @@ def train_phase(size, dev, seed, out_dir, rehearse):
     sel = _selection(tr)
     text = tr.lower_train_step(staged).as_text()
     kernels = text.count("tpu_custom_call")
-    if rehearse:                           # fused_kernels = 1, interpreted
-        require(sel["fused"] > 0, phase,
-                f"no site took a fused kernel: {sel}")
-        require(kernels == 0, phase, "rehearsal compiled a TPU kernel?")
-    else:                                  # the default: XLA's own code
-        require(sel["fused"] == 0 and kernels == 0, phase,
-                f"fused_kernels = auto selected a kernel: {kernels} "
-                f"tpu_custom_call in the lowered train step, {sel}")
-        require("callback" not in text, phase,
-                "a host callback in the lowered train step (jit would "
-                "not write it to the compile cache)")
+    require(not sel and kernels == 0, phase,
+            f"a kernel in the convnet's step: {kernels} tpu_custom_call "
+            f"in the lowered train step, selection {sel}")
+    require("callback" not in text, phase,
+            "a host callback in the lowered train step (jit would "
+            "not write it to the compile cache)")
     ckpt = tr.checkpoint_path(model_dir, 0)
     tr.save_model(ckpt)
     tr.wait_saves()
@@ -321,20 +306,16 @@ def serve_phase(size, cfg, ckpt, tr, seed):
         peak_bytes_in_use=_peak_bytes())
 
 
-def four_chip_phase(size, kind, seed, out_dir, rehearse):
+def four_chip_phase(size, kind, seed, out_dir):
     """The data-parallel flagship: the same global batch on ``kind:0-3``
-    (sync-BN over the mesh; the rehearsal's forced kernels as shard_map
-    islands) and on ``kind:0``, one process."""
+    (sync-BN over the mesh) and on ``kind:0``, one process."""
     phase = "dp4"
     model_dir = os.path.join(out_dir, "models")
     t0 = time.perf_counter()
     task4, tr4, staged4 = build_trainer(phase, flagship_config(
-        size, f"{kind}:0-3", seed, model_dir, rehearse))
+        size, f"{kind}:0-3", seed, model_dir))
     require(tr4.mesh.data_parallel == 4, phase,
             f"mesh is not dp=4: {dict(tr4.mesh.mesh.shape)}")
-    require(tr4.net._fused_now() == rehearse, phase,
-            "fused islands are off on the dp mesh under fused_kernels = 1"
-            if rehearse else "fused_kernels = auto selected the kernels")
 
     def spread(arr):
         return len({s.device for s in arr.addressable_shards})
@@ -354,7 +335,7 @@ def four_chip_phase(size, kind, seed, out_dir, rehearse):
             "no all-reduce in the compiled dp=4 step")
     kernels = text.count("tpu_custom_call")
     sel4 = _selection(tr4)
-    require(kernels == 0 and (sel4["fused"] > 0) == rehearse, phase,
+    require(kernels == 0 and not sel4, phase,
             f"{kernels} tpu_custom_call in the compiled dp=4 step, "
             f"selection {sel4}")
     dp4_s = time.perf_counter() - t0
@@ -365,7 +346,7 @@ def four_chip_phase(size, kind, seed, out_dir, rehearse):
 
     t0 = time.perf_counter()
     task1, tr1, staged1 = build_trainer(phase, flagship_config(
-        size, f"{kind}:0", seed, model_dir, rehearse))
+        size, f"{kind}:0", seed, model_dir))
     losses1, secs1, _ = take_steps(phase, tr1, staged1, 1)
     task1.telemetry.close()
     d0 = abs(losses4[0] - losses1[0])
@@ -444,11 +425,10 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            four_chip_phase(size, kind, args.seed, args.out,
-                            args.rehearse_cpu)
+            four_chip_phase(size, kind, args.seed, args.out)
         else:
             task, tr, cfg, ckpt = train_phase(
-                size, f"{kind}:0", args.seed, args.out, args.rehearse_cpu)
+                size, f"{kind}:0", args.seed, args.out)
             task.telemetry.close()
             serve_phase(size, cfg, ckpt, tr, args.seed)
     except PhaseFailed as e:

@@ -10,7 +10,6 @@ from .core import (Finding, LintPass, LintResult, ModuleInfo, Project,
                    load_baseline, run_analysis, write_baseline)
 from .deadcode import DeadSymbolPass
 from .durability import AtomicIoPass
-from .islands import ShardmapVjpPass
 from .namespaces import ConfigNamespacePass
 from .purity import TracePurityPass
 from .signals import SignalSafetyPass
@@ -19,7 +18,6 @@ from .threads import ThreadShutdownPass
 #: registration order = report order for same-location findings
 PASS_CLASSES = (
     TracePurityPass,
-    ShardmapVjpPass,
     AtomicIoPass,
     SignalSafetyPass,
     ThreadShutdownPass,

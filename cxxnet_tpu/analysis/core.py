@@ -4,8 +4,8 @@ The reference C++ framework got its load-bearing invariants enforced by
 the compiler — ``template<typename xpu>`` device polymorphism simply
 failed to build when an op touched the wrong device path
 (/root/reference/src/global.h). The JAX port's equivalent invariants
-(custom_vjp outside shard_map islands, durable writes only through
-``write_bytes_atomic``, signal handlers that only set events, …) are
+(durable writes only through ``write_bytes_atomic``, signal handlers
+that only set events, …) are
 Python conventions, and PRs 5-10 each shipped a 10+-item review list
 fixing fresh violations of exactly these classes. This package turns
 that recurring review tax into a mechanized tier-1 gate: stdlib-``ast``

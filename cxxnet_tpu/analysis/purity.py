@@ -17,7 +17,7 @@ which keeps the pass precise instead of drowning callers in maybes):
 * functions passed to ``jax.jit`` / ``pmap`` / ``vmap`` / ``grad`` /
   ``value_and_grad`` / ``lax.scan`` / ``lax.fori_loop`` /
   ``lax.while_loop`` / ``lax.cond`` / ``lax.switch`` /
-  ``shard_map`` / ``ops.fused.island`` / ``pl.pallas_call`` /
+  ``shard_map`` / ``pl.pallas_call`` /
   ``*.defvjp``,
 * any same-module function called by name from a traced body
   (transitive closure), including lambdas.
@@ -48,7 +48,7 @@ _TRACING_DECOS = {"jit", "pmap", "custom_vjp"}
 _ENTRY_ARGS: Dict[str, Tuple[int, ...]] = {
     "jit": (0,), "pmap": (0,), "vmap": (0,), "grad": (0,),
     "value_and_grad": (0,), "scan": (0,), "shard_map": (0,),
-    "pallas_call": (0,), "island": (1,), "fori_loop": (2,),
+    "pallas_call": (0,), "fori_loop": (2,),
     "while_loop": (0, 1), "cond": (1, 2), "custom_vjp": (0,),
     "checkpoint": (0,), "remat": (0,),
 }

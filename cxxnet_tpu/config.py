@@ -81,29 +81,23 @@ class Policy:
         return jnp.dtype(self.compute_dtype).name
 
 
-# -- fused kernel selection ---------------------------------------------------
+# -- auto|1|0 options ---------------------------------------------------------
 
-# accepted spellings of the ``fused_kernels`` config value -> canonical mode
-_FUSED_MODES = {
+# accepted spellings of an auto|1|0 config value -> canonical mode
+_AUTO_ON_OFF = {
     "auto": "auto", "": "auto",
     "1": "on", "on": "on", "true": "on", "yes": "on",
     "0": "off", "off": "off", "false": "off", "no": "off",
 }
 
 
-def parse_fused_mode(val: str) -> str:
-    """Canonicalize the ``fused_kernels`` knob (doc/tasks.md "Fused
-    kernels") to auto|on|off. ``auto`` selects a Pallas kernel only
-    for a kind that has won a benchmark cell on the chip — none has, so
-    it runs the jnp references on every backend; ``on`` forces the
-    kernels everywhere (compiled on a TPU, interpret mode off-TPU — the
-    CPU test path); ``off`` is the jnp references without the relu
-    folding. The same values are honored by the
-    ``CXXNET_FUSED_KERNELS`` env override (ops/fused.py)."""
-    canon = _FUSED_MODES.get(str(val).strip().lower())
+def parse_auto_on_off(key: str, val: str) -> str:
+    """Canonicalize the value of an auto|1|0 option (``input_fold``) to
+    auto|on|off."""
+    canon = _AUTO_ON_OFF.get(str(val).strip().lower())
     if canon is None:
         raise ConfigError(
-            f"fused_kernels must be one of auto|1|0 (got {val!r})")
+            f"{key} must be one of auto|1|0 (got {val!r})")
     return canon
 
 

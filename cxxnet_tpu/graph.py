@@ -286,16 +286,14 @@ def build_graph(cfg: ConfigPairs) -> NetGraph:
     return graph
 
 
-#: producers whose epilogue can absorb a following relu (fused-kernel
-#: suite, doc/tasks.md "Fused kernels"): batch_norm fuses it into the
-#: normalize pass, conv/fullc into the bias epilogue
+#: producers that apply a following relu themselves: batch_norm after
+#: the normalize, conv/fullc after the bias
 ACT_FUSABLE_PRODUCERS = ("batch_norm", "batch_norm_no_ma", "conv", "fullc")
 
 
 def act_fusion_plan(graph: NetGraph):
-    """Static activation-fold plan for the fused kernel suite: find
-    producer -> relu edges where the relu can be absorbed into the
-    producer's fused epilogue.
+    """Static activation-fold plan: find producer -> relu edges where
+    the relu can be applied by the producer.
 
     Returns ``(fuse_act, folded)``: ``fuse_act`` maps a producer layer
     index to the activation name it must apply ("relu"), ``folded`` is
@@ -310,10 +308,8 @@ def act_fusion_plan(graph: NetGraph):
       consumer of the producer's output (otherwise some layer reads the
       pre-activation value, which the fold would destroy).
 
-    Numerics are identical whether or not a fused kernel is actually
-    selected at trace time: folded producers apply the activation in
-    their reference path too (see the layers), so the plan can be
-    computed once per Network regardless of backend.
+    Folded producers apply the activation themselves (see the
+    layers), so the plan is computed once per Network.
     """
     consumers: Dict[int, List[int]] = {}
     for li, spec in enumerate(graph.layers):

@@ -146,23 +146,9 @@ class ApplyCtx:
     # across microbatches and the trainer merges one exact full-batch EMA
     # update after the ring (see Network.apply_stage)
     stat_sink: Optional[Dict[str, Any]] = None
-    # fused Pallas kernel selection (ops/fused.py): True when this trace
-    # may use the fused BN/LRN/epilogue kernels — resolved by the
-    # Network per call (knob x backend x single-device). Layers must
-    # treat it as a hint: unsupported shapes fall back to their jnp
-    # reference inside the same apply.
-    fused: bool = False
-    # mesh context for the fused kernels (ops.fused.FusedSpmd): set on
-    # multi-device meshes so each fused op runs as a fully-manual
-    # shard_map island (batch dim over the data axis, per-op
-    # collectives) instead of a bare pallas_call GSPMD cannot shard.
-    # None on a single device AND inside already-manual step bodies
-    # (sp/pp), where a bare pallas_call is fine.
-    fused_spmd: Optional[Any] = None
-    # activation folded into this layer's epilogue by the graph-level
-    # plan (graph.act_fusion_plan): "relu" or None. Layers honoring it
-    # MUST apply the activation on their reference path too — the fold
-    # is decided statically, kernel selection per trace.
+    # activation folded into this layer by the graph-level plan
+    # (graph.act_fusion_plan): "relu" or None. The folded relu layer is
+    # a pass-through, so a layer handed one MUST apply it.
     fuse_act: Optional[str] = None
     # stem channel padding (graph.stem_pad_plan): pad this conv's input
     # channels (and the matching weight dim) with zeros up to this count

@@ -73,7 +73,7 @@ class ConvolutionLayer(Layer):
             # bypasses the s2d fold (cin packing buys nothing once the
             # contraction is int8) but keeps the stem cin_pad — int8
             # zero-pad of the I dim is exact, same as the fp path
-            from ..ops.fused_quant import int8_conv
+            from ..ops.quant import int8_conv
             x, w = inputs[0], params["wmat"]
             if (ctx.cin_pad and hp.num_group == 1
                     and x.shape[-1] < ctx.cin_pad):
@@ -113,14 +113,6 @@ class ConvolutionLayer(Layer):
                 feature_group_count=hp.num_group)
         bias = params.get("bias")
         act = ctx.fuse_act or "none"   # graph-folded relu (act_fusion_plan)
-        if ctx.fused and (bias is not None or act != "none"):
-            # fused bias+activation epilogue (ops/fused_epilogue.py):
-            # the conv stays on XLA's MXU lowering, the epilogue runs
-            # as one Pallas pass (None -> unsupported shape, fall back)
-            from ..ops.fused_epilogue import fused_bias_act
-            fy = fused_bias_act(y, bias, act, spmd=ctx.fused_spmd)
-            if fy is not None:
-                return [fy], state
         if bias is not None:
             y = y + bias.astype(y.dtype)
         if act == "relu":
@@ -221,21 +213,6 @@ class _PoolingLayer(Layer):
     def apply(self, params, state, inputs, ctx):
         hp = self.hp
         x = inputs[0]
-        if ctx.fused:
-            # fused pooling kernel (ops/fused_pool.py): non-overlapping
-            # and global-window geometries in one VMEM pass with a
-            # fused backward (no select-and-scatter); pre_relu folds
-            # in. None -> unsupported geometry, reduce_window below.
-            from ..ops.fused_pool import fused_pool
-            fy = fused_pool(
-                x, kh=hp.kernel_height, kw=hp.kernel_width,
-                stride=hp.stride, pad=(hp.pad_y, hp.pad_x),
-                extra=(self._extra_y, self._extra_x),
-                reducer="max" if self.reducer == "max" else "sum",
-                scale_avg=self.scale_avg, pre_relu=self.pre_relu,
-                spmd=ctx.fused_spmd)
-            if fy is not None:
-                return [fy], state
         if self.pre_relu:
             x = jax.nn.relu(x)
         if self.reducer == "max":
@@ -351,18 +328,6 @@ class LRNLayer(Layer):
 
     def apply(self, params, state, inputs, ctx):
         x = inputs[0]
-        if ctx.fused:
-            # fused cross-channel window kernel (ops/fused_lrn.py — the
-            # classic cxxnet hand-fused LRN, TPU-native): square,
-            # window-sum, powf, product in ONE VMEM pass, fused backward,
-            # and no fusion barrier needed (a pallas_call is opaque to
-            # the consumer-conv refusion this layer's barrier guards
-            # against). None -> unsupported shape, jnp path below.
-            from ..ops.fused_lrn import fused_lrn
-            fy = fused_lrn(x, self.nsize, self.alpha, self.beta,
-                           self.knorm, spmd=ctx.fused_spmd)
-            if fy is not None:
-                return [fy], state
         sq = jnp.square(x)
         half = self.nsize // 2
         # window sum over channels via pad + strided slice sum; unrolled

@@ -63,25 +63,16 @@ class FullConnectLayer(Layer):
         if "wmat_scale" in params:
             # PTQ-derived int8 weights (quant/ptq.py): static-scale
             # activation quantization + int8 x int8 -> int32 matmul +
-            # fused dequant/bias/act epilogue (ops/fused_quant.py)
-            from ..ops.fused_quant import int8_matmul
+            # dequant/bias/act epilogue (ops/quant.py)
+            from ..ops.quant import int8_matmul
             y = int8_matmul(x, params["wmat"], params["wmat_scale"],
                             params["act_scale"], params.get("bias"),
-                            ctx.fuse_act or "none",
-                            fused=ctx.fused, spmd=ctx.fused_spmd)
+                            ctx.fuse_act or "none")
             return [_as_node(y)], state
         w = params["wmat"].astype(ctx.compute_dtype)
         y = jnp.dot(x.astype(ctx.compute_dtype), w)
         bias = params.get("bias")
         act = ctx.fuse_act or "none"   # graph-folded relu (act_fusion_plan)
-        if ctx.fused and (bias is not None or act != "none"):
-            # fused bias+activation epilogue (ops/fused_epilogue.py) on
-            # the matmul output; None -> unsupported shape, jnp path
-            from ..ops.fused_epilogue import fused_bias_act
-            fy = fused_bias_act(_as_node(y), bias, act,
-                                spmd=ctx.fused_spmd)
-            if fy is not None:
-                return [fy], state
         if bias is not None:
             y = y + bias.astype(y.dtype)
         if act == "relu":

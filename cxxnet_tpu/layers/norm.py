@@ -57,8 +57,7 @@ class _BatchNormBase(Layer):
         elif name == "bn_two_pass":
             # ADVICE r5: numerically-robust two-pass E[(x-mean)^2]
             # variance (an extra read of x) instead of the default
-            # one-pass E[x^2]-E[x]^2 — honored by BOTH the jnp path
-            # and the fused kernel
+            # one-pass E[x^2]-E[x]^2
             self.two_pass = bool(int(val))
 
     def __init__(self, spec, global_cfg):
@@ -92,17 +91,6 @@ class _BatchNormBase(Layer):
             "running_var": jnp.zeros((self._channel,), jnp.float32),
         }
 
-    def _batch_stats_fused(self, x, slope, bias, ctx):
-        """Fused Pallas BN (+ folded relu) — ops/fused_norm.py: moments,
-        normalize, scale/shift, and the activation in one HBM round
-        trip. Returns (out, mean, var) or None (unsupported shape ->
-        jnp path)."""
-        from ..ops.fused_norm import fused_bn_act
-        return fused_bn_act(x, slope, bias, eps=self.eps,
-                            act=ctx.fuse_act or "none",
-                            two_pass=self.two_pass,
-                            spmd=ctx.fused_spmd)
-
     def apply(self, params, state, inputs, ctx):
         x = inputs[0]
         axes = (0, 1, 2)   # NHWC: stats over batch+spatial, per channel;
@@ -110,50 +98,43 @@ class _BatchNormBase(Layer):
         slope, bias = params["wmat"], params["bias"]
         act = ctx.fuse_act or "none"   # graph-folded relu (act_fusion_plan)
         if ctx.train:
-            fused = (self._batch_stats_fused(x, slope, bias, ctx)
-                     if ctx.fused and ctx.stat_sink is None else None)
-            if fused is not None:
-                out, mean, var = fused
+            xf = x.astype(jnp.float32)
+            mean = jnp.mean(xf, axis=axes)
+            ex2 = jnp.mean(jnp.square(xf), axis=axes)
+            if self.two_pass:
+                # ADVICE r5 option: mean-dependent second read, no
+                # cancellation risk (bn_two_pass = 1)
+                raw_var = jnp.mean(jnp.square(xf - mean), axis=axes)
             else:
-                xf = x.astype(jnp.float32)
-                mean = jnp.mean(xf, axis=axes)
-                ex2 = jnp.mean(jnp.square(xf), axis=axes)
-                if self.two_pass:
-                    # ADVICE r5 option: mean-dependent second read, no
-                    # cancellation risk (bn_two_pass = 1)
-                    raw_var = jnp.mean(jnp.square(xf - mean), axis=axes)
-                else:
-                    # ONE-PASS moments: E[x^2]-E[x]^2 instead of the
-                    # two-pass E[(x-mean)^2]. The two-pass form makes the
-                    # variance reduction DEPEND on the mean, forcing XLA to
-                    # read the conv output twice; sibling independent
-                    # reductions fuse into one multi-output kernel (one
-                    # read). What the saved read is worth on the chip:
-                    # not measured (no cell sets bn_two_pass). Tradeoff:
-                    # f32 cancellation loses variance precision when
-                    # |mean| >> std (error ~1e-7 x mean^2 absolute);
-                    # acceptable for post-conv activations, and the clamp
-                    # guards the tiny-negative case, but a pathological
-                    # large-mean/low-var channel degrades toward
-                    # inv = rsqrt(eps). health = 1 watches for it: the
-                    # probe's bn_var_min reads 0 there and the
-                    # bn_collapse advice fires (telemetry/modelhealth.py).
-                    raw_var = ex2 - jnp.square(mean)
-                var = jnp.maximum(raw_var, 0.0)
-                inv = jax.lax.rsqrt(var + self.eps)
-                out = (x - mean) * inv * slope + bias
-                if act == "relu":
-                    out = jax.nn.relu(out)
-                out = out.astype(x.dtype)
+                # ONE-PASS moments: E[x^2]-E[x]^2 instead of the
+                # two-pass E[(x-mean)^2]. The two-pass form makes the
+                # variance reduction DEPEND on the mean, forcing XLA to
+                # read the conv output twice; sibling independent
+                # reductions fuse into one multi-output kernel (one
+                # read). What the saved read is worth on the chip:
+                # not measured (no cell sets bn_two_pass). Tradeoff:
+                # f32 cancellation loses variance precision when
+                # |mean| >> std (error ~1e-7 x mean^2 absolute);
+                # acceptable for post-conv activations, and the clamp
+                # guards the tiny-negative case, but a pathological
+                # large-mean/low-var channel degrades toward
+                # inv = rsqrt(eps). health = 1 watches for it: the
+                # probe's bn_var_min reads 0 there and the
+                # bn_collapse advice fires (telemetry/modelhealth.py).
+                raw_var = ex2 - jnp.square(mean)
+            var = jnp.maximum(raw_var, 0.0)
+            inv = jax.lax.rsqrt(var + self.eps)
+            out = (x - mean) * inv * slope + bias
+            if act == "relu":
+                out = jax.nn.relu(out)
+            out = out.astype(x.dtype)
             if self.moving_avg:
                 if ctx.stat_sink is not None:
                     # pipeline body: hand raw moments to the schedule (the
                     # trainer merges an exact full-batch EMA update after
                     # the ring); state is untouched here. Sink the TRUE
                     # second moment (not var+mean^2, which the clamp
-                    # would have distorted) — only the jnp path reaches
-                    # here (the fused kernel is gated on stat_sink being
-                    # None), so ex2 is always the undistorted E[x^2]
+                    # would have distorted)
                     ctx.stat_sink[self.name] = {"mean": mean, "sq": ex2}
                 else:
                     m = self.bn_momentum
@@ -167,10 +148,6 @@ class _BatchNormBase(Layer):
         if self.moving_avg:
             mean, var = state["running_exp"], state["running_var"]
         else:
-            fused = (self._batch_stats_fused(x, slope, bias, ctx)
-                     if ctx.fused else None)
-            if fused is not None:
-                return [fused[0]], state
             xf = x.astype(jnp.float32)
             mean = jnp.mean(xf, axis=axes)
             if self.two_pass:

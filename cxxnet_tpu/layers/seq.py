@@ -527,13 +527,12 @@ class SeqFCLayer(Layer, _SeqLinearMixin):
         if "wmat_scale" in params:
             # PTQ-derived int8 weights (quant/ptq.py): positions fold
             # into rows so the projection runs as one int8 matmul with
-            # the fused dequant/bias epilogue (ops/fused_quant.py)
-            from ..ops.fused_quant import int8_matmul
+            # the dequant/bias epilogue (ops/quant.py)
+            from ..ops.quant import int8_matmul
             b, s, e = x.shape
             y2 = int8_matmul(x.reshape(b * s, e), params["wmat"],
                              params["wmat_scale"], params["act_scale"],
-                             params.get("bias"), "none",
-                             fused=ctx.fused, spmd=None)
+                             params.get("bias"), "none")
             return [_unseq(y2.reshape(b, s, -1))], state
         x = x.astype(ctx.compute_dtype)
         y = jnp.einsum("bse,ek->bsk", x,

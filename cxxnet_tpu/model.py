@@ -82,22 +82,6 @@ class Network:
         # recipe for memory-bound models (no reference analog; the closest
         # is temp_col_max's memory/compute staging, SURVEY §5)
         self.remat = bool(int(global_param(cfg, "remat", "0")))
-        # fused Pallas kernel suite (ops/fused.py; doc/tasks.md "Fused
-        # kernels"): fused_kernels = auto|1|0 — auto selects the kinds
-        # that won a cell on the chip (none: the jnp references), 1
-        # forces the kernels (interpret off-TPU, the test path), 0 is
-        # the jnp references. The trainer clears fused_single_device on
-        # multi-device meshes: a pallas_call is opaque to the GSPMD
-        # partitioner and fused BN moments would be shard-local where
-        # the jnp path is sync-BN.
-        from .ops.fused import resolve_mode
-        self.fused_mode = resolve_mode(
-            global_param(cfg, "fused_kernels", "auto"))
-        self.fused_single_device = True
-        # mesh context (ops.fused.FusedSpmd) the trainer binds on
-        # multi-device meshes: fused ops then run as shard_map islands
-        # with per-op collectives instead of being cleared wholesale
-        self.fused_spmd = None
         # site -> which implementation it took (ops.fused.SelectionLog):
         # written while apply() is traced, printed once by the trainer
         self.fused_log: Dict[str, Tuple[str, str]] = {}
@@ -140,22 +124,16 @@ class Network:
             [self.node_shapes[ni] for ni in spec.nindex_in]
             for spec in graph.layers]
         # static activation-fold plan (graph.act_fusion_plan): producer
-        # layers absorb a following relu into their (possibly fused)
-        # epilogue; the folded relus pass through in apply(). Numerics
-        # are backend-independent — producers apply the act on their
-        # reference path too — so the plan is computed unconditionally
-        # unless the knob is a hard off.
-        if self.fused_mode != "off":
-            from .graph import act_fusion_plan
-            self._fuse_act, self._act_folded = act_fusion_plan(graph)
-        else:
-            self._fuse_act, self._act_folded = {}, set()
+        # layers apply a following relu themselves and the folded relus
+        # pass through in apply()
+        from .graph import act_fusion_plan
+        self._fuse_act, self._act_folded = act_fusion_plan(graph)
         # stem channel padding (graph.stem_pad_plan): value-exact, so on
         # by default; stem_pad = 0 disables, stem_pad = N (>= 2)
         # overrides the pad-to width (default 4 — lane/sublane-friendly
         # for the RGB stem and its space-to-depth fold). "1"/"on" mean
-        # ON at the default width, matching the sibling knobs'
-        # (fused_kernels, input_fold) auto|1|0 grammar — a width of 1
+        # ON at the default width, matching input_fold's auto|1|0
+        # grammar — a width of 1
         # could never pad anything and silently-off would invert the
         # user's intent.
         sp = global_param(cfg, "stem_pad", "auto").strip().lower()
@@ -165,16 +143,6 @@ class Network:
             from .graph import stem_pad_plan
             pad_to = int(sp) if sp.isdigit() and int(sp) >= 2 else 4
             self._cin_pad = stem_pad_plan(graph, pad_to=pad_to)
-
-    def _fused_now(self) -> bool:
-        """Per-trace fused-kernel decision: knob/env x backend (ops.
-        fused.kernels_active) x the trainer's mesh gate — which now
-        either binds a ``fused_spmd`` island context (dp meshes) or
-        clears ``fused_single_device`` (topologies the islands do not
-        cover), never both."""
-        from .ops.fused import kernels_active
-        return ((self.fused_single_device or self.fused_spmd is not None)
-                and kernels_active(self.fused_mode))
 
     # -- init --------------------------------------------------------------
     def init(self, key: jax.Array) -> Tuple[Params, NetState]:
@@ -239,7 +207,6 @@ class Network:
             rng = jax.random.PRNGKey(0)
         new_state: NetState = dict(state)
         cdt = self.compute_dtype if compute_dtype is None else compute_dtype
-        fused_now = self._fused_now()
         health_sink: Optional[Dict[str, Any]] = {} if health else None
         total_loss = jnp.zeros((), jnp.float32)
         metric_stats: Dict[str, jax.Array] = {}
@@ -261,16 +228,13 @@ class Network:
             ctx = ApplyCtx(train=train, rng=jax.random.fold_in(rng, li),
                            compute_dtype=cdt,
                            seq_axis=seq_axis, data_axis=data_axis,
-                           fused=fused_now,
-                           fused_spmd=self.fused_spmd if fused_now
-                           else None,
                            fuse_act=self._fuse_act.get(li),
                            cin_pad=self._cin_pad.get(li),
                            health_sink=health_sink, labels=labels)
             inputs = [nodes[ni] for ni in spec.nindex_in]
             lparams = params.get(layer.name, {})
             lstate = new_state.get(layer.name, {})
-            # every fused-kernel choice this layer makes lands in
+            # every implementation choice this layer makes lands in
             # fused_log under its name (tracing is synchronous, so the
             # binding covers remat's inner trace too)
             with selection_site(self.fused_log, spec.name), \
@@ -281,8 +245,6 @@ class Network:
                                      compute_dtype=_ctx.compute_dtype,
                                      seq_axis=_ctx.seq_axis,
                                      data_axis=_ctx.data_axis,
-                                     fused=_ctx.fused,
-                                     fused_spmd=_ctx.fused_spmd,
                                      fuse_act=_ctx.fuse_act,
                                      cin_pad=_ctx.cin_pad)
                         return _layer.apply(lp, ls, list(ins), c)

@@ -1,10 +1,11 @@
-"""TPU-native operator library (Pallas kernels + jnp references).
+"""TPU-native operator library.
 
-The reference framework's op-extension mechanism is the hand-written
-mshadow expression (e.g. InsanityPoolingExp with a custom Plan,
-/root/reference/src/layer/insanity_pooling_layer-inl.hpp:13-100); the
-TPU-native analog is a Pallas kernel paired with a jnp reference
-implementation, validated by golden tests (the pairtest idea, SURVEY §4).
+One Pallas kernel family lives here, flash attention
+(``attention.py``), paired with its jnp references and validated by
+golden tests (the pairtest idea, SURVEY §4); it is selected by what the
+code observes (``attn_impl = auto``). ``stem.py`` and ``quant.py`` are
+plain jnp ops shared by more than one caller, and ``fused.py`` is the
+model's selection log.
 """
 
 from .attention import (
@@ -13,25 +14,10 @@ from .attention import (
     flash_attention,
     rope,
 )
-from .fused import kernels_active, resolve_mode
-from .fused_epilogue import bias_act_reference, fused_bias_act
-from .fused_lrn import fused_lrn, lrn_reference
-from .fused_norm import bn_act_reference, fused_bn_act
-from .fused_optim import fused_adam_apply, fused_sgd_apply
 
 __all__ = [
     "attention_reference",
     "chunked_attention",
     "flash_attention",
     "rope",
-    "kernels_active",
-    "resolve_mode",
-    "fused_bn_act",
-    "bn_act_reference",
-    "fused_lrn",
-    "lrn_reference",
-    "fused_bias_act",
-    "bias_act_reference",
-    "fused_sgd_apply",
-    "fused_adam_apply",
 ]

@@ -22,7 +22,7 @@ taking (batch, seq, heads, head_dim) arrays:
   (``FLASH_RESIDUALS``), so a ``jax.checkpoint`` that saves those names
   (``model.py`` under ``remat = 1``) does not run the forward kernel a
   second time in the backward pass. On the CPU backend the same kernels
-  run under the Pallas interpreter (``fused.use_interpret``); on a TPU
+  run under the Pallas interpreter (:func:`use_interpret`); on a TPU
   backend they are compiled. Not twice-differentiable (the fused
   backward is a kernel, not traced jnp); differentiate
   ``chunked_attention`` for higher-order uses.
@@ -44,7 +44,26 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused import out_struct, use_interpret
+
+def use_interpret(interpret: Optional[bool]) -> bool:
+    """The one place a kernel's ``interpret`` flag is decided: ``None``
+    means compiled on a TPU backend and the Pallas interpreter on the
+    CPU backend (``dev = cpu``, the tests) — the same kernel code runs
+    either way."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """One ``out_shape`` entry of a ``pallas_call`` that may sit inside
+    a ``shard_map``: under its ``check_vma`` the struct must say over
+    which mesh axes the output varies, and a kernel's output varies
+    wherever any of its ``operands`` does (the empty set outside a
+    shard_map)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
 
 _NEG = -1e30
 #: dot_general dimension numbers of ``a @ b.T`` and ``a.T @ b`` on 2-D

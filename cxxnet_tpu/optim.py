@@ -192,22 +192,6 @@ class Optimizer:
         self.hypers = build_hypers(cfg)
         from .graph import global_param, policy_from_config
         self.fp16 = policy_from_config(cfg).needs_loss_scale
-        # fused multi-tensor apply (ops/fused_optim.py): one streaming
-        # Pallas pass per tag group instead of N per-leaf elementwise
-        # chains. Same knob as the layer kernels (fused_kernels =
-        # auto|1|0, env CXXNET_FUSED_KERNELS). On a replicated-master
-        # dp mesh the trainer binds ``fused_spmd`` and the apply runs
-        # as a fully-replicated shard_map island; with SHARDED masters
-        # (tp / fsdp) it clears fused_ok instead (counted in
-        # cxxnet_fused_fallback_total).
-        from .ops.fused import resolve_mode
-        self.fused_mode = resolve_mode(
-            global_param(cfg, "fused_kernels", "auto"))
-        self.fused_ok = True
-        self.fused_spmd = None
-        # where the fused apply records its selection (ops.fused.
-        # SelectionLog); the trainer points it at the network's log
-        self.fused_log: Dict[str, Any] = {}
         self.ls_init = float(global_param(cfg, "loss_scale_init",
                                           str(2.0 ** 15)))
         self.ls_window = int(global_param(cfg, "loss_scale_window", "200"))
@@ -358,93 +342,8 @@ class Optimizer:
         new_rest["_mp"] = {"scale": new_scale, "good": good}
         return new_params, new_rest
 
-    # -- fused multi-tensor apply ------------------------------------------
-    def _fused_active(self) -> bool:
-        from .ops.fused import kernels_active
-        return self.fused_ok and kernels_active(self.fused_mode)
-
-    @staticmethod
-    def _leaf_groups(tree):
-        """Flatten a (possibly nested) param-like dict and group leaf
-        indices by tag; returns (leaves, treedef, {tag: [idx]}) or
-        ``None`` when any leaf is not f32 (the fused kernels hold the
-        master-dtype contract — mixed dtypes take the per-leaf path)."""
-        pairs, treedef = jax.tree_util.tree_flatten_with_path(tree)
-        groups: Dict[str, list] = {}
-        leaves = []
-        for i, (path, leaf) in enumerate(pairs):
-            if jnp.asarray(leaf).dtype != jnp.float32:
-                return None
-            key = getattr(path[-1], "key", None)
-            groups.setdefault(tag_for_param(key), []).append(i)
-            leaves.append(leaf)
-        return leaves, treedef, groups
-
-    def _apply_fused(self, params, grads, opt_state, sched):
-        """One fused Pallas pass per tag group (ops/fused_optim.py) —
-        exact per-leaf parity with _apply below, asserted by
-        tests/test_fused_ops.py. Returns None when the trees are not
-        uniformly f32 (caller falls back)."""
-        from .ops.fused import note_fallback, note_fused, selection_site
-        got = self._leaf_groups(params)
-        with selection_site(self.fused_log, "optimizer"):
-            if got is None:
-                note_fallback("optimizer_mixed_dtype")
-                return None
-            scope = note_fused(f"{self.type}_apply")
-        with scope:
-            return self._apply_fused_groups(got, grads, opt_state, sched)
-
-    def _apply_fused_groups(self, got, grads, opt_state, sched):
-        from .ops.fused_optim import fused_adam_apply, fused_sgd_apply
-        wl, treedef, groups = got
-        gl = jax.tree_util.tree_leaves(grads)
-        if self.type == "adam":
-            t = opt_state["t"] + 1
-            m1l = jax.tree_util.tree_leaves(opt_state["m1"])
-            m2l = jax.tree_util.tree_leaves(opt_state["m2"])
-            nw: list = [None] * len(wl)
-            nm1: list = [None] * len(wl)
-            nm2: list = [None] * len(wl)
-            for tag, idxs in groups.items():
-                h = self.hypers[tag]
-                d1, d2 = h.beta1_decay, h.beta2_decay
-                tf = t.astype(jnp.float32)
-                lr, _ = sched[tag]
-                lr_t = lr * jnp.sqrt(1.0 - (1.0 - d2) ** tf) \
-                    / (1.0 - (1.0 - d1) ** tf)
-                ws, nm1s, nm2s = fused_adam_apply(
-                    [wl[i] for i in idxs], [gl[i] for i in idxs],
-                    [m1l[i] for i in idxs], [m2l[i] for i in idxs],
-                    lr_t, wd=h.wd, clip=h.clip_gradient, d1=d1, d2=d2,
-                    spmd=self.fused_spmd)
-                for i, w_, a_, b_ in zip(idxs, ws, nm1s, nm2s):
-                    nw[i], nm1[i], nm2[i] = w_, a_, b_
-            unflat = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
-            return unflat(nw), {"m1": unflat(nm1), "m2": unflat(nm2),
-                                "t": t}
-        ml = jax.tree_util.tree_leaves(opt_state["mom"])
-        nw = [None] * len(wl)
-        nm = [None] * len(wl)
-        for tag, idxs in groups.items():
-            h = self.hypers[tag]
-            lr, momentum = sched[tag]
-            ws, ms = fused_sgd_apply(
-                [wl[i] for i in idxs], [gl[i] for i in idxs],
-                [ml[i] for i in idxs], lr, momentum,
-                wd=h.wd, clip=h.clip_gradient, nag=self.type == "nag",
-                spmd=self.fused_spmd)
-            for i, w_, m_ in zip(idxs, ws, ms):
-                nw[i], nm[i] = w_, m_
-        unflat = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
-        return unflat(nw), {"mom": unflat(nm)}
-
     def _apply(self, params, grads, opt_state, sched: Dict[str, Any]):
         """The raw (unscaled, always-applied) optimizer step."""
-        if self._fused_active():
-            fused = self._apply_fused(params, grads, opt_state, sched)
-            if fused is not None:
-                return fused
         if self.type == "adam":
             t = opt_state["t"] + 1
 

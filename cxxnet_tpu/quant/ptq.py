@@ -6,7 +6,7 @@ output channels on the last axis), with activation scales calibrated
 from a small batch stream (abs-max, optionally percentile-clipped).
 
 The quantized layer's params carry everything the int8 execution path
-(ops/fused_quant.py) needs, INSIDE the ordinary params tree:
+(ops/quant.py) needs, INSIDE the ordinary params tree:
 
     {"wmat":       int8, same shape as the source weight,
      "wmat_scale": f32 per-out-channel vector,

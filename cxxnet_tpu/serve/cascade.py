@@ -1,6 +1,6 @@
 """Cascade inference: a confidence router in front of a two-tier fleet.
 
-The cheap tier (int8-quantized replicas, ops/fused_quant.py) answers
+The cheap tier (int8-quantized replicas, ops/quant.py) answers
 every request first; rows whose prediction confidence clears
 ``cascade_threshold`` are final, the rest escalate to the flagship
 (full-precision) tier. The cost model is the classic cascade win:

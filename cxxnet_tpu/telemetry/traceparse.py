@@ -2,8 +2,7 @@
 
 The program names what it puts on the device: ``Network.apply`` traces
 every graph layer under ``jax.named_scope(<layer>)``, the step builders
-trace the parameter update under ``optimizer``, every fused op's wrapper
-under ``fused.<kind>`` (ops/fused.note_fused), and autodiff itself wraps
+trace the parameter update under ``optimizer``, and autodiff itself wraps
 the forward in ``jvp(..)`` and the backward in ``transpose(jvp(..))``.
 All of it lands in ``metadata={op_name="..."}`` of the compiled module's
 instructions. This file is the way back: :func:`scope_table` maps a
@@ -134,7 +133,10 @@ def classify(scope: Optional[str]) -> Tuple[str, str, str]:
     which is also where an instruction without a scope goes. ``layer``:
     the outermost scope that is not a fused op's (a graph layer's name,
     ``optimizer``, ``input_fold``), ``kind``: the ``<kind>`` of the
-    first ``fused.<kind>``; each ``""`` where there is none."""
+    first ``fused.<kind>``; each ``""`` where there is none. No step
+    of this tree traces a ``fused.<kind>`` scope any more (the Pallas
+    suite that did left in PR 30); the benchmark's recorded traces were
+    cut with it in, and its tests pin the numbers they give."""
     if not scope:
         return "other", "", ""
     transforms, scopes = scope_path(scope)

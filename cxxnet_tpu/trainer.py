@@ -78,21 +78,16 @@ def _moe_stats(net_state):
 def _fold_input(data, net):
     """input_fold entry point inside the compiled step: a
     ``(uint8-batch, mean, factor)`` tuple is normalized in-trace
-    (ops/fused_stem.decode_normalize — Pallas when the fused suite is
-    active, jnp otherwise) into the compute dtype; a plain array passes
-    through untouched. The tuple's mean/factor are traced ARGUMENTS,
+    (ops/stem.decode_normalize) into the compute dtype; a plain array
+    passes through untouched. The tuple's mean/factor are traced ARGUMENTS,
     not baked constants, so two iterators with different normalization
     metadata share one compiled step."""
     if not isinstance(data, tuple):
         return data
     x, mean, factor = data
-    from .ops.fused import selection_site
-    from .ops.fused_stem import decode_normalize
-    with selection_site(net.fused_log, "input_fold"), \
-            jax.named_scope("input_fold"):
-        return decode_normalize(x, mean, factor, net.compute_dtype,
-                                fused=net._fused_now(),
-                                spmd=net.fused_spmd)
+    from .ops.stem import decode_normalize
+    with jax.named_scope("input_fold"):
+        return decode_normalize(x, mean, factor, net.compute_dtype)
 
 
 def _chain_scan(one, length):
@@ -245,64 +240,7 @@ class Trainer:
                 "fsdp_axis composes with the std (GSPMD dp/tp) step "
                 "only; the pp step has its own at-rest FSDP over "
                 "'pipe' and sp keeps params replicated")
-        # fused Pallas kernels x meshes: a bare pallas_call is an
-        # opaque custom call the GSPMD partitioner cannot shard, so on
-        # a dp (or dp x tp) mesh the fused ops run as fully-manual
-        # shard_map islands (ops.fused.FusedSpmd; sync-BN as a psum
-        # over the data axis inside the fused moment pass) and the
-        # gate stays OPEN. Topologies the islands do not cover clear
-        # the gate as before — but loudly: one-time warning plus the
-        # cxxnet_fused_fallback_total{reason} counter, so a mesh run
-        # that still falls back is visible in /metrics and the ledger.
-        from .ops.fused import FusedSpmd, kernels_active, note_fallback
-        # warn/count only when the kernels WOULD have run (knob x env):
-        # an auto run selected none, loses nothing and says nothing
-        would_fuse = kernels_active(self.net.fused_mode)
-        # one selection log for layers, input fold and optimizer
-        self.optimizer.fused_log = self.net.fused_log
         self._selection_reported = False
-        if self._pp > 1:
-            self.net.fused_single_device = False
-            self.optimizer.fused_ok = False
-            if would_fuse:
-                note_fallback(
-                    "pipeline_parallel",
-                    warn="reference path on this pp mesh (fused kernels "
-                         "do not run inside the pipeline's lax.switch "
-                         "stage schedule)")
-        elif self._sp > 1:
-            # the sp step body is already a manual shard_map: bare
-            # pallas_calls are legal there (no island needed), and no
-            # sp-safe layer uses the BN/LRN/epilogue kernels anyway —
-            # only the fused optimizer fires. sp x tp keeps 'model'
-            # AUTOMATIC inside the body, where a pallas_call would
-            # again be GSPMD-opaque: clear the gate there.
-            if self.mesh.model_parallel > 1:
-                self.net.fused_single_device = False
-                self.optimizer.fused_ok = False
-                if would_fuse:
-                    note_fallback(
-                        "seq_x_model",
-                        warn="reference path on this sp x tp mesh (the "
-                             "'model' axis stays automatic inside the "
-                             "sp shard_map)")
-        elif self.mesh.num_devices > 1:
-            self.net.fused_spmd = FusedSpmd(
-                mesh=self.mesh.mesh, batch_axis=self.mesh.data_axis)
-            if self.mesh.model_parallel > 1 or self._fsdp_axis:
-                # model-sharded / FSDP masters cannot flow through the
-                # fully-replicated optimizer island; the layer kernels
-                # keep their islands, only the optimizer falls back
-                self.optimizer.fused_ok = False
-                if would_fuse:
-                    note_fallback(
-                        "sharded_optimizer_state",
-                        warn="per-leaf optimizer on this mesh (masters/"
-                             "optimizer state are sharded; the fused "
-                             "multi-tensor island needs them "
-                             "replicated) — layer kernels stay fused")
-            else:
-                self.optimizer.fused_spmd = self.net.fused_spmd
         if self.health_on and self._pp > 1:
             # the pp step's stat plumbing is the microbatch ring's stat
             # sink — per-step health trees do not ride it; std (GSPMD
@@ -379,16 +317,17 @@ class Trainer:
         self._fold_cache = None
         # input_fold (doc/tasks.md "Input fold"): device_normalize
         # batches enter the compiled train step as uint8 and the
-        # cast/mean/scale happens IN-TRACE (ops/fused_stem), killing the
+        # cast/mean/scale happens IN-TRACE (ops/stem.py), killing the
         # separate normalize dispatch's fp32 HBM round-trip of the whole
         # batch (~310 MB/step at flagship shape). Exact math (f32
         # compute, one cast to the compute dtype — where the layers'
         # own astype puts the input anyway), so auto means ON; off is
         # the escape hatch. std (GSPMD dp/tp) train path only: the
         # sp/pp shard_map steps keep the eager normalize.
-        from .config import parse_fused_mode
+        from .config import parse_auto_on_off
         self.input_fold = (
-            parse_fused_mode(gp("input_fold", "auto")) != "off"
+            parse_auto_on_off("input_fold",
+                              gp("input_fold", "auto")) != "off"
             and self._sp == 1 and self._pp == 1)
         # one-step deferred train-metric fetch: device->host reads of step
         # N's outputs happen after step N+1 is dispatched, so the transfer
@@ -2013,9 +1952,8 @@ class Trainer:
 
     def _report_selection(self) -> None:
         """Say once, after the first train step has been traced, which
-        sites took a fused kernel and which their reference, by reason
-        (and which attention implementation) — a shape that quietly
-        misses its kernel is visible on one device too."""
+        attention implementation and which grouped product the sites
+        that choose one took."""
         if self._selection_reported or not self.net.fused_log:
             return
         self._selection_reported = True

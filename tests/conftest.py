@@ -61,7 +61,7 @@ QUICK_MODULES = {
     # measured (one process, CPU mesh) ~80-110 s total here, which is
     # comfortably <5 min on the ~3x-slower driver tier. Excluded on
     # measured cost: attention (17 s), examples (27 s), flagship_e2e
-    # (74 s), fused_ops (25 s), seq_parallel (32 s), layer_sweep,
+    # (74 s), convnet_ops (107 s), seq_parallel (32 s), layer_sweep,
     # trainer, parallel_ext, seq_layers/ext, kaggle_workflow,
     # bench_helpers (builds+traces a scaled flagship).
     "test_accuracy.py",
@@ -73,7 +73,7 @@ QUICK_MODULES = {
     # explicit @pytest.mark.quick marks, while the multi-run LearnTask
     # / subprocess (compile-cache warm restart, steptime-verdict
     # train) tests stay out of the tier
-    "test_fused_stem_pool.py",
+    "test_input_fold.py",
     "test_graph.py",
     "test_import_cxxnet.py",
     "test_io_pipeline.py",

@@ -1,13 +1,16 @@
-"""The main path's Pallas kernels, compiled for a TPU v5e that is
-described and not attached (on-chip-measurement guide, section 2.3).
+"""The main path's programs, compiled for a TPU v5e that is described
+and not attached (on-chip-measurement guide, section 2.3).
 
-Interpret mode — what every other kernel test here runs — accepts
-programs the chip's compiler refuses (the v5e has no bf16 vector
-compare; tiles must align; VMEM is finite). These tests hand the
-installed TPU compiler each kernel, forward and backward, at the widths
-of the checked-in example configs, so a refusal costs a test failure
-here and not chip time. Nothing runs: a pass says "compiles", never
-"correct" or "fast".
+The CPU backend — what every other test here runs — accepts programs
+the chip's compiler refuses (the v5e has no bf16 vector compare; tiles
+must align; VMEM is finite). These tests hand the installed TPU
+compiler each convnet layer's own ``apply``, forward and gradient, and
+the attention kernels, at the widths of the checked-in example configs,
+so a refusal costs a test failure here and not chip time. For the
+convnet layers they also pin what PR 30 left: one implementation per
+op, XLA's own code — no ``tpu_custom_call`` and no host callback in the
+compiled text, on one chip and on the four-chip mesh under GSPMD.
+Nothing runs: a pass says "compiles", never "correct" or "fast".
 
 Everything that touches the topology lives in the module-scoped,
 non-autouse fixtures below, so only the xdist worker that is handed
@@ -15,7 +18,6 @@ this file loads the TPU library, every worker collects the same tests,
 and no child process is started.
 """
 
-import functools
 import os
 import sys
 
@@ -29,14 +31,9 @@ from cxxnet_tpu.config import parse_config_string
 from cxxnet_tpu.graph import build_graph
 from cxxnet_tpu.model import Network
 from cxxnet_tpu.ops.attention import flash_attention, paged_attention
-from cxxnet_tpu.ops.fused import FusedSpmd
-from cxxnet_tpu.ops.fused_epilogue import fused_bias_act
-from cxxnet_tpu.ops.fused_lrn import fused_lrn
-from cxxnet_tpu.ops.fused_norm import fused_bn_act
-from cxxnet_tpu.ops.fused_optim import fused_adam_apply, fused_sgd_apply
-from cxxnet_tpu.ops.fused_pool import fused_pool
-from cxxnet_tpu.ops.fused_quant import int8_matmul
-from cxxnet_tpu.ops.fused_stem import fused_decode_normalize
+from cxxnet_tpu.ops.quant import int8_matmul
+from cxxnet_tpu.ops.stem import decode_normalize
+from cxxnet_tpu.optim import create_optimizer
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -80,18 +77,21 @@ def four_chips(topo):
                 ("data", "pipe", "seq", "model"))
 
 
+def _is_sd(a) -> bool:
+    """A (shape, dtype) pair: the leaf of the arg trees below."""
+    return isinstance(a, tuple) and len(a) == 2 and isinstance(a[0], tuple)
+
+
 def _compile(fn, mesh, args, grad_argnums=(), specs=None):
     """Compile ``fn`` — and, with ``grad_argnums``, the gradient of its
     (first) output's sum — for ``mesh``'s described devices; returns
     the compiled text. ``args``: (shape, dtype) pairs or pytrees of
     them; ``specs``: one PartitionSpec per arg (default replicated)."""
     specs = specs or [P()] * len(args)
-    is_sd = lambda a: isinstance(a, tuple) and len(a) == 2 \
-        and isinstance(a[0], tuple)
     structs = [jax.tree_util.tree_map(
         lambda sd, _s=spec: jax.ShapeDtypeStruct(
             sd[0], sd[1], sharding=NamedSharding(mesh, _s)),
-        a, is_leaf=is_sd) for a, spec in zip(args, specs)]
+        a, is_leaf=_is_sd) for a, spec in zip(args, specs)]
 
     def first(out):
         return out[0] if isinstance(out, (tuple, list)) else out
@@ -108,7 +108,43 @@ def _kernels(text: str) -> int:
     return text.count("tpu_custom_call")
 
 
-# -- Inception-BN b256 (examples/ImageNet/inception_bn.conf) -------------------
+# -- the convnet layers: XLA's own code, and the chip's compiler takes it -------
+
+def _net(body: str, input_shape, dtype):
+    c, h, w = input_shape
+    cdt = "bfloat16" if dtype == BF16 else "float32"
+    cfg = parse_config_string(
+        f"netconfig=start\n{body}\nnetconfig=end\n"
+        f"input_shape = {c},{h},{w}\ncompute_dtype = {cdt}\n")
+    g = build_graph(cfg)
+    return Network(g, g.defcfg)
+
+
+def _xla_only(text: str) -> None:
+    assert _kernels(text) == 0
+    assert "callback" not in text.lower()
+
+
+def _compile_layers(body, batch, input_shape, dtype, mesh, sharded=False):
+    """Compile forward and gradient (w.r.t. parameters and input) of a
+    training-mode pass through the layers of ``body`` at ``batch`` rows
+    of ``input_shape`` (c, h, w); returns the compiled text."""
+    net = _net(body, input_shape, dtype)
+    params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    sd = lambda t: jax.tree_util.tree_map(
+        lambda a: (tuple(a.shape), a.dtype), t)
+    c, h, w = input_shape
+    fn = lambda p, st, x: net.apply(p, st, x, train=True).out
+    return _compile(fn, mesh, [sd(params), sd(state),
+                               ((batch, h, w, c), dtype)],
+                    grad_argnums=(0, 2),
+                    specs=[P(), P(), P("data") if sharded else P()])
+
+
+_BN_RELU = "layer[0->1] = batch_norm:bn\nlayer[1->2] = relu:ac"
+
+# Inception-BN b256 (examples/ImageNet/inception_bn.conf)
+
 
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("shape", [
@@ -118,122 +154,138 @@ def _kernels(text: str) -> int:
     (256, 14, 14, 160),       # 4c
     (256, 7, 7, 352),         # 5a/5b 1x1 (C not a lane multiple)
 ], ids=lambda s: "x".join(map(str, s)))
-def test_bn_act_relu_compiles(one_chip, shape, dtype):
-    c = shape[-1]
-    fn = lambda x, g, b: fused_bn_act(x, g, b, eps=1e-10, act="relu",
-                                      interpret=False)
-    text = _compile(fn, one_chip, [(shape, dtype), ((c,), F32), ((c,), F32)],
-                    grad_argnums=(0, 1, 2))
-    assert _kernels(text) >= 2          # forward + backward
+def test_batch_norm_relu_compiles(one_chip, shape, dtype):
+    b, h, w, c = shape
+    _xla_only(_compile_layers(_BN_RELU, b, (c, h, w), dtype, one_chip))
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_pool_global_avg_compiles(one_chip, dtype):
-    fn = lambda x: fused_pool(x, 7, 7, 1, (0, 0), (0, 0), "sum", True,
-                              False, interpret=False)
-    text = _compile(fn, one_chip, [((256, 7, 7, 1024), dtype)],
-                    grad_argnums=(0,))
-    assert _kernels(text) >= 2
+def test_global_avg_pooling_compiles(one_chip, dtype):
+    body = "layer[0->1] = avg_pooling:gap\n  kernel_size = 7\n  stride = 1"
+    _xla_only(_compile_layers(body, 256, (1024, 7, 7), dtype, one_chip))
 
 
-def _inception_leaves():
-    """The flagship's real parameter leaves, grouped as the optimizer
-    groups them (one fused apply per tag)."""
-    sys.path.insert(0, os.path.join(_REPO, "examples", "ImageNet"))
-    try:
-        from gen_inception_bn import generate
-    finally:
-        sys.path.pop(0)
-    cfg = parse_config_string(generate(with_data=False))
+def _leaves_of(conf_text: str):
+    cfg = parse_config_string(conf_text)
     shapes = Network(build_graph(cfg), cfg).param_shapes()
-    by_tag = {}
-    for layer in shapes.values():
-        for tag, leaf in layer.items():
-            by_tag.setdefault(tag, []).append((tuple(leaf.shape), F32))
-    return by_tag
+    return jax.tree_util.tree_map(lambda a: (tuple(a.shape), F32), shapes)
 
 
-def test_sgd_apply_compiles_at_flagship_leaves(one_chip):
-    by_tag = _inception_leaves()
-    assert sum(map(len, by_tag.values())) > 200
-    for tag, leaves in by_tag.items():
-        fn = lambda ws, gs, ms, lr, mom: fused_sgd_apply(
-            ws, gs, ms, lr, mom, wd=1e-4, clip=0.0, nag=False,
-            interpret=False)
-        text = _compile(fn, one_chip,
-                        [leaves, leaves, leaves, ((), F32), ((), F32)])
-        assert _kernels(text) == 1, tag
+_LM_CONF = os.path.join(_REPO, "examples", "LM", "long_context_lm.conf")
 
 
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_stem_decode_normalize_compiles(one_chip, dtype):
-    fn = lambda x, mean, f: fused_decode_normalize(
-        x, mean, f, dtype, interpret=False)
-    text = _compile(fn, one_chip, [((256, 224, 224, 3), jnp.uint8),
-                                   ((224, 224, 3), F32), ((), F32)])
-    assert _kernels(text) == 1
-
-
-# -- AlexNet b256 / kaggle_bowl b64 / digits (bias+relu, LRN, tiled pool) ------
-
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("shape", [
-    (256, 55, 55, 96),        # alexnet cv1
-    (256, 13, 13, 384),       # alexnet cv3
-    (256, 1, 1, 4096),        # alexnet fc6/fc7 as a flat node
-    (256, 28, 28, 96),        # the shape the v5e's compiler first refused
-    (64, 41, 41, 48),         # kaggle_bowl cv1
-], ids=lambda s: "x".join(map(str, s)))
-def test_bias_act_relu_compiles(one_chip, shape, dtype):
-    c = shape[-1]
-    fn = lambda x, b: fused_bias_act(x, b, "relu", interpret=False)
-    text = _compile(fn, one_chip, [(shape, dtype), ((c,), F32)],
-                    grad_argnums=(0, 1))
-    assert _kernels(text) >= 2
+@pytest.mark.parametrize("updater", ["sgd", "adam"])
+def test_optimizer_update_compiles_at_real_leaves(one_chip, updater):
+    """The per-leaf update over the flagship's parameter tree (sgd) and
+    the long-context LM's (adam): one program, no kernel."""
+    if updater == "sgd":
+        sys.path.insert(0, os.path.join(_REPO, "examples", "ImageNet"))
+        try:
+            from gen_inception_bn import generate
+        finally:
+            sys.path.pop(0)
+        leaves = _leaves_of(generate(with_data=False))
+        assert len(jax.tree_util.tree_leaves(leaves)) > 400   # 2 a leaf
+    else:
+        with open(_LM_CONF) as f:
+            leaves = _leaves_of(f.read())
+    opt = create_optimizer(updater, [("eta", "0.01"), ("wd", "0.0001")])
+    as_struct = lambda t: jax.tree_util.tree_map(
+        lambda sd: jax.ShapeDtypeStruct(*sd), t, is_leaf=_is_sd)
+    state = jax.eval_shape(opt.init_state, as_struct(leaves))
+    state = jax.tree_util.tree_map(lambda a: (tuple(a.shape), a.dtype),
+                                   state)
+    sched = {tag: [((), F32), ((), F32)] for tag in ("wmat", "bias")}
+    fn = lambda p, g, st, sc: opt.update(p, g, st, sc)
+    _xla_only(_compile(fn, one_chip, [leaves, leaves, state, sched]))
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_act_only_epilogue_compiles(one_chip, dtype):
-    fn = lambda x: fused_bias_act(x, None, "relu", interpret=False)
-    text = _compile(fn, one_chip, [((256, 27, 27, 256), dtype)],
-                    grad_argnums=(0,))
-    assert _kernels(text) >= 2
+def test_decode_normalize_compiles(one_chip, dtype):
+    fn = lambda x, mean, f: decode_normalize(x, mean, f, dtype)
+    _xla_only(_compile(fn, one_chip, [((256, 224, 224, 3), jnp.uint8),
+                                      ((224, 224, 3), F32), ((), F32)]))
+
+
+# AlexNet b256 / kaggle_bowl b64 / digits (bias+relu, LRN, tiled pool)
+
+def _conv(k, n, stride=1, pad=0, extra=""):
+    return (f"layer[0->1] = conv:cv\n  kernel_size = {k}\n"
+            f"  nchannel = {n}\n  stride = {stride}\n  pad = {pad}\n{extra}"
+            "layer[1->2] = relu:ac")
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("shape,pre_relu", [
-    ((256, 56, 56, 192), False),   # ImageNet-class width, 2x2/2
-    ((256, 56, 56, 192), True),    # relu_max_pooling fold
-    ((128, 8, 8, 32), False),      # examples/digits/digits_lenet.conf mp1
+@pytest.mark.parametrize("body,batch,input_shape", [
+    (_conv(11, 96, stride=4), 256, (3, 227, 227)),     # alexnet cv1
+    (_conv(3, 384, pad=1), 256, (256, 13, 13)),        # alexnet cv3
+    ("layer[0->1] = fullc:fc\n  nhidden = 4096\n"      # alexnet fc6
+     "layer[1->2] = relu:ac", 256, (256, 6, 6)),
+    (_conv(1, 96), 256, (192, 28, 28)),                # inception 3a 1x1
+    (_conv(4, 48, pad=2), 64, (1, 40, 40)),            # kaggle_bowl cv1
+], ids=["alexnet_cv1", "alexnet_cv3", "alexnet_fc6", "ibn_3a_1x1",
+        "bowl_cv1"])
+def test_bias_relu_producers_compile(one_chip, body, batch, input_shape,
+                                     dtype):
+    _xla_only(_compile_layers(body, batch, input_shape, dtype, one_chip))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_conv_without_bias_then_relu_compiles(one_chip, dtype):
+    body = _conv(5, 256, pad=2, extra="  no_bias = 1\n  ngroup = 2\n")
+    _xla_only(_compile_layers(body, 256, (96, 27, 27), dtype, one_chip))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("kind,batch,input_shape", [
+    ("max_pooling", 256, (192, 56, 56)),      # ImageNet-class width, 2x2/2
+    ("relu_max_pooling", 256, (192, 56, 56)),
+    ("max_pooling", 128, (32, 8, 8)),         # digits_lenet.conf mp1
 ], ids=["56x56x192", "56x56x192-prerelu", "digits"])
-def test_pool_max_tile_compiles(one_chip, shape, pre_relu, dtype):
-    fn = lambda x: fused_pool(x, 2, 2, 2, (0, 0), (0, 0), "max", False,
-                              pre_relu, interpret=False)
-    text = _compile(fn, one_chip, [(shape, dtype)], grad_argnums=(0,))
-    assert _kernels(text) >= 2
+def test_max_pooling_compiles(one_chip, kind, batch, input_shape, dtype):
+    body = f"layer[0->1] = {kind}:mp\n  kernel_size = 2\n  stride = 2"
+    _xla_only(_compile_layers(body, batch, input_shape, dtype, one_chip))
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("shape", [(256, 27, 27, 96), (256, 13, 13, 256)],
+@pytest.mark.parametrize("input_shape", [(96, 27, 27), (256, 13, 13)],
                          ids=["lrn1", "lrn2"])
-def test_lrn_compiles(one_chip, shape, dtype):
-    fn = lambda x: fused_lrn(x, 5, 1e-4, 0.75, 1.0, interpret=False)
-    text = _compile(fn, one_chip, [(shape, dtype)], grad_argnums=(0,))
-    assert _kernels(text) >= 2
+def test_lrn_compiles(one_chip, input_shape, dtype):
+    body = ("layer[0->1] = lrn:lrn\n  local_size = 5\n  alpha = 0.0001\n"
+            "  beta = 0.75\n  knorm = 1")
+    _xla_only(_compile_layers(body, 256, input_shape, dtype, one_chip))
 
 
 @pytest.mark.parametrize("k,n", [(9216, 4096), (4096, 4096)],
                          ids=["fc6", "fc7"])
 def test_int8_matmul_compiles(one_chip, k, n):
     """serve_dtype = int8 on AlexNet's big FCs at a /predict bucket."""
-    fn = lambda x, wq, ws, s, b: int8_matmul(
-        x, wq, ws, s, b, "relu", fused=True, interpret=False)
-    text = _compile(fn, one_chip, [((32, k), F32), ((k, n), jnp.int8),
-                                   ((n,), F32), ((), F32), ((n,), F32)])
-    assert _kernels(text) == 1
+    fn = lambda x, wq, ws, s, b: int8_matmul(x, wq, ws, s, b, "relu")
+    _xla_only(_compile(fn, one_chip, [
+        ((32, k), F32), ((k, n), jnp.int8), ((n,), F32), ((), F32),
+        ((n,), F32)]))
 
 
-# -- long_context_lm.conf (flash attention, adam, paged decode) ----------------
+# four chips, the batch sharded over 'data': GSPMD's own collectives
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_sync_bn_compiles_on_four_chips(four_chips, dtype):
+    text = _compile_layers(_BN_RELU, 256, (192, 56, 56), dtype, four_chips,
+                           sharded=True)
+    _xla_only(text)
+    assert "all-reduce" in text         # the batch moments, over the mesh
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_conv_bias_relu_pool_compile_on_four_chips(four_chips, dtype):
+    body = (_conv(3, 96, pad=1) + "\nlayer[2->3] = max_pooling:mp\n"
+            "  kernel_size = 2\n  stride = 2")
+    text = _compile_layers(body, 256, (96, 56, 56), dtype, four_chips,
+                           sharded=True)
+    _xla_only(text)
+    assert "all-reduce" in text         # dbias and dw, over the mesh
+
+# -- long_context_lm.conf (flash attention, paged decode) -------------------
 
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("shape", [(8, 2048, 8, 16), (4, 2048, 8, 64)],
@@ -262,17 +314,6 @@ def test_latent_attention_flash_compiles_at_the_cell_s_widths(one_chip):
     assert _kernels(text) == 2          # forward, backward
 
 
-def test_adam_apply_compiles_at_lm_leaves(one_chip):
-    leaves = [(s, F32) for s in [
-        (64, 128), (128,), (128,), (128, 8, 16), (8, 16), (8, 16, 128),
-        (128, 512), (512,), (512, 128), (128, 64), (64,)]]
-    fn = lambda ws, gs, a, b, lr: fused_adam_apply(
-        ws, gs, a, b, lr, wd=0.0, clip=0.0, d1=0.1, d2=0.001,
-        interpret=False)
-    text = _compile(fn, one_chip, [leaves] * 4 + [((), F32)])
-    assert _kernels(text) == 1
-
-
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_paged_decode_attention_compiles(one_chip, dtype):
     """serve/lm's decode step: plain XLA (no kernel), still the chip's
@@ -285,94 +326,3 @@ def test_paged_decode_attention_compiles(one_chip, dtype):
                             ((4,), jnp.int32)])
 
 
-# -- four chips: the dp islands (sync-BN psum, dbias psum) ---------------------
-
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_mesh_bn_act_compiles_on_four_chips(four_chips, dtype):
-    spmd = FusedSpmd(mesh=four_chips)
-    fn = lambda x, g, b: fused_bn_act(x, g, b, eps=1e-10, act="relu",
-                                      interpret=False, spmd=spmd)
-    text = _compile(fn, four_chips,
-                    [((256, 56, 56, 192), dtype), ((192,), F32),
-                     ((192,), F32)],
-                    grad_argnums=(0, 1, 2),
-                    specs=[P("data"), P(), P()])
-    assert _kernels(text) >= 4          # sums+normalize, bwd sums+dx
-    assert "all-reduce" in text         # the moment psum
-
-
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_mesh_epilogue_and_pool_compile_on_four_chips(four_chips, dtype):
-    spmd = FusedSpmd(mesh=four_chips)
-
-    def fn(x, b):
-        y = fused_bias_act(x, b, "relu", interpret=False, spmd=spmd)
-        return fused_pool(y, 2, 2, 2, (0, 0), (0, 0), "max", False, False,
-                          interpret=False, spmd=spmd)
-    text = _compile(fn, four_chips, [((256, 56, 56, 96), dtype), ((96,), F32)],
-                    grad_argnums=(0, 1), specs=[P("data"), P()])
-    assert _kernels(text) >= 4
-    assert "all-reduce" in text         # the dbias psum
-
-
-# -- the kernels name themselves (PR 24) ---------------------------------------
-
-def _kernel_scopes(text: str):
-    """``[(instruction name, op_name)]`` of the compiled text's Pallas
-    custom calls."""
-    import re
-    out = []
-    for line in text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
-            continue
-        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
-        op = re.search(r'op_name="([^"]*)"', line)
-        out.append((name, op.group(1) if op else ""))
-    return out
-
-
-_KINDS = {
-    "bn_act": (lambda x, g, b: fused_bn_act(x, g, b, eps=1e-10, act="relu",
-                                            interpret=False),
-               [((64, 14, 14, 128), BF16), ((128,), F32), ((128,), F32)],
-               (0, 1, 2), {"bn_act_fwd", "bn_act_bwd"}),
-    "pool": (lambda x: fused_pool(x, 2, 2, 2, (0, 0), (0, 0), "max", False,
-                                  False, interpret=False),
-             [((64, 28, 28, 128), BF16)], (0,),
-             {"pool_fwd", "pool_bwd_max"}),
-    "bias_act": (lambda x, b: fused_bias_act(x, b, "relu", interpret=False),
-                 [((64, 13, 13, 384), BF16), ((384,), F32)], (0, 1),
-                 {"bias_act_fwd", "bias_act_bwd"}),
-    "lrn": (lambda x: fused_lrn(x, 5, 1e-4, 0.75, 1.0, interpret=False),
-            [((64, 13, 13, 256), BF16)], (0,), {"lrn_fwd", "lrn_bwd"}),
-    "stem": (lambda x, mean, f: fused_decode_normalize(
-        x, mean, f, BF16, interpret=False),
-        [((32, 64, 64, 3), jnp.uint8), ((64, 64, 3), F32), ((), F32)], (),
-        {"stem_fwd"}),
-    "sgd_apply": (lambda ws, gs, ms, lr, mom: fused_sgd_apply(
-        ws, gs, ms, lr, mom, wd=1e-4, clip=0.0, nag=False, interpret=False),
-        [[((3, 3, 64, 64), F32)]] * 3 + [((), F32), ((), F32)], (),
-        {"sgd_apply_update"}),
-}
-
-
-@pytest.mark.parametrize("kind", sorted(_KINDS))
-def test_kernel_events_carry_their_kind(one_chip, kind):
-    """What a device trace calls a Pallas kernel is its custom call's
-    instruction name: ``pl.pallas_call(name="<kind>_<fwd|bwd|..>")``
-    puts the kind there (it read ``jvp__`` / ``transpose_jvp__``
-    before), forward and backward, and into the ``op_name``."""
-    from cxxnet_tpu.telemetry.traceparse import classify as tp_classify
-    fn, args, grad_argnums, want = _KINDS[kind]
-    text = _compile(fn, one_chip, args, grad_argnums=grad_argnums)
-    got = _kernel_scopes(text)
-    assert got
-    stems = set()
-    for name, scope in got:
-        stem = name.rsplit(".", 1)[0] if name[-1].isdigit() else name
-        assert stem.startswith(kind + "_"), (name, scope)
-        assert f"/{stem}/pallas_call" in scope, (name, scope)
-        if kind != "sgd_apply":     # the optimizer binds that scope
-            assert tp_classify(scope)[2] == kind, (name, scope)
-        stems.add(stem)
-    assert stems == want

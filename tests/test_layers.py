@@ -111,7 +111,7 @@ def test_avg_pooling_counts_padding():
     np.testing.assert_allclose(np.asarray(res.out), expect, rtol=1e-6)
 
 
-def test_relu_max_pooling_fused():
+def test_relu_max_pooling_clamps_before_the_max():
     net = make_net("layer[0->1] = relu_max_pooling\n  kernel_size = 2\n  stride = 2",
                    input_shape="1,4,4")
     x = -np.ones((1, 4, 4, 1), np.float32)

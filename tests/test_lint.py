@@ -106,63 +106,6 @@ def test_trace_purity_suppression(tmp_path):
     assert r.suppressed[0].message.startswith("print()")
 
 
-# -- shardmap-vjp -------------------------------------------------------------
-
-_ISLAND_BAD = """\
-    import jax
-    from jax.experimental.shard_map import shard_map
-
-    @jax.custom_vjp
-    def op(x):
-        return x
-
-    def body(x):
-        return op(x)
-
-    w = shard_map(body, mesh=None, in_specs=(), out_specs=())
-    """
-
-
-def test_shardmap_vjp_detects(tmp_path):
-    r = lint(tmp_path, {"mod.py": _ISLAND_BAD}, select=["shardmap-vjp"])
-    assert names(r) == ["shardmap-vjp"]
-    assert "invoked inside shard_map island 'body'" in \
-        r.findings[0].message
-
-
-def test_shardmap_vjp_allows_sanctioned_shapes(tmp_path):
-    src = """\
-    import jax
-    from cxxnet_tpu.ops.fused import island
-
-    @jax.custom_vjp
-    def op(x):
-        return x
-
-    def row_local(x, spmd):
-        # all specs batch-sharded: transpose is exact (LRN pattern)
-        return island(spmd, lambda xl: op(xl),
-                      in_batch=(True,), out_batch=True)(x)
-
-    @jax.custom_vjp
-    def mesh_op(x, spmd):
-        # outer custom_vjp intercepts AD (_epi_bias_mesh pattern)
-        return island(spmd, lambda xl: op(xl),
-                      in_batch=(True, False), out_batch=True)(x)
-    """
-    r = lint(tmp_path, {"mod.py": src}, select=["shardmap-vjp"])
-    assert r.findings == []
-
-
-def test_shardmap_vjp_suppression(tmp_path):
-    src = _ISLAND_BAD.replace(
-        "return op(x)",
-        "return op(x)  # graftlint: disable=shardmap-vjp "
-        "(driver env runs jax>=0.9 where this transposes fine)")
-    r = lint(tmp_path, {"mod.py": src}, select=["shardmap-vjp"])
-    assert r.findings == [] and len(r.suppressed) == 1
-
-
 # -- atomic-io ----------------------------------------------------------------
 
 _DURABLE_BAD = """\
@@ -566,7 +509,7 @@ def test_cli_contract(tmp_path):
     r2 = subprocess.run([sys.executable, cli, "--list-passes"],
                         capture_output=True, text=True)
     assert r2.returncode == 0
-    for name in ("trace-purity", "shardmap-vjp", "atomic-io",
+    for name in ("trace-purity", "atomic-io",
                  "signal-safety", "thread-shutdown",
                  "config-namespace", "dead-symbol"):
         assert name in r2.stdout

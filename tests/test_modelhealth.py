@@ -174,11 +174,10 @@ def test_health_off_jaxpr_identity():
     assert t_on != t_off and len(t_on) > len(t_off)
 
 
-@pytest.mark.parametrize("extra", ["", "fused_kernels = 1\n"])
+@pytest.mark.parametrize("extra", ["", "compute_dtype = bfloat16\n"])
 def test_health_on_training_parity(extra):
     """health=1 must not change the training trajectory — losses and
-    params bit-identical to the off run (fused path included: the
-    acceptance's fused_kernels x health coexistence pin)."""
+    params bit-identical to the off run, under either compute dtype."""
     tra = make_trainer("health = 1\n" + extra)
     trb = make_trainer(extra)
     b = make_batch()
@@ -347,8 +346,8 @@ def test_dp_mesh_fleet_consistent_stats():
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices")
     b = make_batch()
-    tr1 = make_trainer("health = 1\nfused_kernels = 0\n", ndev=1)
-    tr2 = make_trainer("health = 1\nfused_kernels = 0\n", ndev=2)
+    tr1 = make_trainer("health = 1\n", ndev=1)
+    tr2 = make_trainer("health = 1\n", ndev=2)
     tr1.update(b)
     tr2.update(b)
     h1 = jax.device_get(tr1.last_health_handle)

@@ -5,8 +5,8 @@ config-snapshot chunking + hash check, torn-ledger-tail tolerance
 (regression: a SIGKILLed writer tears the final line mid-UTF-8),
 reconstruction error taxonomy, config-drift loudness, report hints.
 
-E2E tier (tier-1, not quick): one in-process chaos run per path (std /
-fused) — injected ``device.step`` NaN in a NAMED layer, sentinel trip,
+E2E tier (tier-1, not quick): one in-process chaos run per compute dtype
+— injected ``device.step`` NaN in a NAMED layer, sentinel trip,
 rollback two rounds back (save_period=2 leaves the previous round
 unsaved, so the replay window spans a COMPLETE comparable round) —
 then time-travel back into the trip:
@@ -447,11 +447,11 @@ def test_replay_cli_inprocess(chaos_std, capsys):
     assert "layer=fc2 kind=param" in out
 
 
-def test_replay_fused_path(tmp_path):
-    """The fused-kernels dispatch replays bit-exactly too (ISSUE-18
-    acceptance: std AND fused paths)."""
+def test_replay_bfloat16_path(tmp_path):
+    """A bfloat16 run replays bit-exactly too (ISSUE-18 acceptance: the
+    std path under both compute dtypes)."""
     td = str(tmp_path)
-    ledger, trip, roll = _chaos_run(td, extra="fused_kernels = 1\n")
+    ledger, trip, roll = _chaos_run(td, extra="compute_dtype = bfloat16\n")
     plan = reconstruct(ledger, incident=0)
     res = execute(plan, failpoints_on=False)
     assert res.verdict == "bit_exact", res.report(plan)

@@ -210,16 +210,14 @@ def _toy_step_table(extra=""):
     return tr, tp.scope_table(profiler.step_hlo_text())
 
 
-@pytest.mark.parametrize("fused", ["0", "1"])
-def test_train_step_names_every_op_it_traces(fused):
-    tr, table = _toy_step_table(f"fused_kernels = {fused}\n")
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_train_step_names_every_op_it_traces(dtype):
+    tr, table = _toy_step_table(f"compute_dtype = {dtype}\n")
     assert profiler.step_scope_table() == table     # memoised, the same
     seen = {}
     for name, scope in table.items():
         if not scope.startswith("jit("):
-            # an argument's copy carries the argument's name; and the
-            # Pallas INTERPRETER (CPU only) restarts the stack at the
-            # kernel's name for the ops it expands a kernel into
+            # an argument's copy carries the argument's name
             continue
         phase, layer, kind = tp.classify(scope)
         if phase == "other":
@@ -231,19 +229,11 @@ def test_train_step_names_every_op_it_traces(fused):
     for layer in ("cv1", "bn1", "fc1"):
         assert layer in seen["forward"] and layer in seen["backward"]
     assert set(seen["optimizer"]) == {"optimizer"}
-    if fused == "1":
-        assert "sgd_apply" in seen["optimizer"]["optimizer"]
-        assert "bn_act" in seen["forward"]["bn1"]
-        assert "bn_act" in seen["backward"]["bn1"]
-        # the selection log and the trace use the same words
-        logged = {what for kind, what in tr.net.fused_log.values()
-                  if kind == "fused"}
-        traced = {k for by in seen.values() for ks in by.values()
-                  for k in ks if k}
-        assert traced == logged
-    else:
-        assert not any(k for by in seen.values() for ks in by.values()
-                       for k in ks)
+    # no op of the step sits under a ``fused.<kind>`` scope, and the
+    # selection log agrees: a convnet's ops choose no implementation
+    assert not any(k for by in seen.values() for ks in by.values()
+                   for k in ks)
+    assert tr.net.fused_log == {}
 
 
 @pytest.mark.parametrize("period,do_update", [(1, True), (2, False),
@@ -255,7 +245,7 @@ def test_step_builders_share_one_optimizer_scope(period, do_update):
     import jax.numpy as jnp
     from cxxnet_tpu.config import parse_config_string
     from cxxnet_tpu.trainer import Trainer, _apply_grads
-    tr = Trainer(parse_config_string(TOY + "fused_kernels = 0\n"))
+    tr = Trainer(parse_config_string(TOY))
     tr.init_model()
     zeros = jax.tree_util.tree_map(jnp.zeros_like, tr.params)
 

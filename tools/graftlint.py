@@ -2,9 +2,9 @@
 """graftlint — project-invariant static analysis for cxxnet_tpu.
 
 Mechanizes the review-hardening checklist (doc/tasks.md "Static
-analysis"): trace purity, custom_vjp x shard_map islands, durable-write
-atomicity, signal-handler safety, thread shutdown, config-namespace
-typos, dead symbols. Stdlib-only; jax is NOT imported.
+analysis"): trace purity, durable-write atomicity, signal-handler
+safety, thread shutdown, config-namespace typos, dead symbols.
+Stdlib-only; jax is NOT imported.
 
 Usage:
     python tools/graftlint.py --all              # the tier-1 gate

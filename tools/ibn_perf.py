@@ -9,9 +9,7 @@ What it measures, on whatever backend is attached:
   table (cxxnet_tpu.telemetry.traceparse — ROADMAP item 1's "attribute
   the ~72% non-MXU step time");
 * the byte calibration: measured trace bytes (TPU memory counters)
-  vs XLA cost_analysis vs the doc/bytes_audit.md-style analytic model;
-* on accelerators, the fused_kernels=1 vs 0 A/B (chained-dispatch
-  slope timing, bench.py's method).
+  vs XLA cost_analysis vs the doc/bytes_audit.md-style analytic model.
 
 Run on a TPU-attached host for the numbers of record:
     python tools/ibn_perf.py
@@ -24,7 +22,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -50,24 +47,10 @@ def measure():
         c["hbm_bytes_per_step"],
         att.get("measured_bytes_per_step"), analytic["total"])
     fold = bench.input_fold_entry(tr, c, image, classes, batch)
-    ab = None
-    if on_accel:
-        # the headline trainer (fused_kernels = auto) is the reference
-        # path; the rerun forces the kernels
-        tr_fus = bench.make_trainer(scale, image, classes, batch,
-                                    platform,
-                                    overrides=(("fused_kernels", "1"),))
-        c_fus = bench.compute_bench(tr_fus, image, classes, batch,
-                                    max(3, steps // 2))
-        ab = {"fused_ms": c_fus["per_step_ms"],
-              "ref_ms": c["per_step_ms"],
-              "speedup": c["per_step_ms"] / c_fus["per_step_ms"],
-              "fused_bytes": c_fus["hbm_bytes_per_step"],
-              "ref_bytes": c["hbm_bytes_per_step"]}
     chip = jax.devices()[0].device_kind
     return {"chip": chip, "platform": platform, "scale": scale,
             "image": image, "batch": batch, "c": c, "att": att,
-            "calib": calib, "fold": fold, "ab": ab}
+            "calib": calib, "fold": fold}
 
 
 def render(m) -> str:
@@ -86,8 +69,7 @@ def render(m) -> str:
         "",
         f"Backend: **{m['platform']} / {m['chip']}**, flagship scale "
         f"{m['scale']}, image {m['image']}, batch {m['batch']}, "
-        f"compute dtype {c['compute_dtype']}, fused_kernels="
-        f"{int(c['fused_kernels'])}, per-step "
+        f"compute dtype {c['compute_dtype']}, per-step "
         f"{c['per_step_ms']:.2f} ms (chained-dispatch slope).",
         "",
     ]
@@ -164,30 +146,10 @@ def render(m) -> str:
             f"{calib['analytic_vs_cost_ratio']}); the measured column "
             "fills in on a TPU capture.")
     lines.append("")
-    if m["ab"]:
-        ab = m["ab"]
-        lines += [
-            "## Fused-kernel A/B (same artifact, same method)",
-            "",
-            f"fused_kernels=1: {ab['fused_ms']:.2f} ms/step, "
-            f"{ab['fused_bytes']:.3e} B/step; fused_kernels=0: "
-            f"{ab['ref_ms']:.2f} ms/step, {ab['ref_bytes']:.3e} B/step "
-            f"-> **speedup {ab['speedup']:.3f}x** on-chip.",
-            "",
-        ]
-    else:
-        lines += [
-            "## Fused-kernel A/B",
-            "",
-            "Skipped on the CPU backend (interpret-mode kernels time "
-            "the interpreter, not the optimization); the on-chip A/B "
-            "is `bench.py`'s `fused_ab` entry.",
-            "",
-        ]
     fold = m["fold"]
     if "error" not in fold:
         lines += [
-            "## Second wave: input fold + stem padding + fused pooling",
+            "## Input fold + stem padding",
             "",
             f"`input_fold` (uint8 batch normalized in-step, no fp32 "
             f"round-trip of the input): folded step "
@@ -198,11 +160,8 @@ def render(m) -> str:
             f"**{fold['bytes_saved_per_step']:.3e} B/step saved** "
             "(cost-analysis accounting; the measured carrier is "
             "`e2e_u8_images_per_sec_per_chip`). Stem channel padding "
-            "(`stem_pad`, RGB->4) and the fused pooling kernels "
-            "(`ops/fused_pool.py`: non-overlapping + global windows, "
-            "first-match max backward — no select-and-scatter) ride "
-            "the same `fused_kernels`/graph-plan machinery with "
-            "per-op fallbacks.",
+            "(`stem_pad`, RGB->4) is a graph-level plan "
+            "(`graph.stem_pad_plan`).",
             "",
         ]
     return "\n".join(lines)

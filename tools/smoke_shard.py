@@ -1,22 +1,19 @@
 #!/usr/bin/env python
-"""CPU smoke: fused kernels x meshes + rule-driven sharding (ISSUE 9).
+"""CPU smoke: rule-driven sharding on a dp x tp mesh.
 
-On 8 faked CPU devices, runs a fused dp=4 x tp=2 round of the reduced
-Inception-BN flagship through the RULE-DRIVEN partition specs with the
-Pallas kernels in interpret mode, asserting the whole tentpole chain:
+On 8 faked CPU devices, runs a dp=4 x tp=2 round of the reduced
+Inception-BN flagship through the RULE-DRIVEN partition specs,
+asserting:
 
-  1. the trainer keeps fused_kernels=1 ON for the mesh (no silent
-     reference fallback) and binds the island context;
-  2. the compiled step's jaxpr carries the fused pallas_calls UNDER
-     shard_map (GSPMD never sees a bare opaque custom call);
-  3. psum'd fused-BN moments == unsharded global moments (sync-BN),
-     bit-for-bit in fp32 on exact-sum data;
-  4. params place per the rule table (a planned conv weight is
+  1. params place per the rule table (a planned conv weight is
      model-sharded on the mesh);
-  5. a 5-step fused mesh run tracks the single-device fused run.
+  2. the traced step holds no Pallas kernel (GSPMD shards XLA's own
+     code; sync-BN is the partitioner's all-reduce);
+  3. the flagship's first-step loss on the mesh matches one device;
+  4. a 5-step mesh run tracks the single-device run.
 
-~2-4 min on CPU (interpret-mode kernels). Wired into the verify
-recipe (.claude/skills/verify/SKILL.md "sharding rules").
+Wired into the verify recipe (.claude/skills/verify/SKILL.md
+"sharding rules").
 """
 
 import os
@@ -33,17 +30,12 @@ from cxxnet_tpu.parallel import force_cpu_devices  # noqa: E402
 
 force_cpu_devices(8)
 
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from gen_inception_bn import generate  # noqa: E402
 
 from cxxnet_tpu.config import parse_config_string  # noqa: E402
 from cxxnet_tpu.io.data import DataBatch  # noqa: E402
-from cxxnet_tpu.ops.fused import FusedSpmd  # noqa: E402
-from cxxnet_tpu.ops.fused_norm import (bn_act_reference,  # noqa: E402
-                                       fused_bn_act)
 from cxxnet_tpu.parallel import make_mesh_context  # noqa: E402
 from cxxnet_tpu.trainer import Trainer  # noqa: E402
 
@@ -58,7 +50,7 @@ def main() -> int:
         # batch-8 BN training is chaotic enough at eta=0.01 that even
         # two CORRECT configurations (e.g. jnp dp8 vs jnp dp4xtp2)
         # drift ~1e-2 by step 3 from float association alone
-        ("fused_kernels", "1"), ("eta", "0.001")]
+        ("eta", "0.001")]
     rng = np.random.RandomState(0)
     data = (rng.randint(0, 32, (8, 64, 64, 3)) * 0.25).astype(np.float32)
     label = rng.randint(0, 8, (8, 1)).astype(np.float32)
@@ -66,17 +58,11 @@ def main() -> int:
     def batch():
         return DataBatch(data=data.copy(), label=label.copy())
 
-    # -- 1. dp x tp mesh keeps the fused gate open ----------------------
     ctx = make_mesh_context(devices=jax.devices()[:8], model_parallel=2)
     tr = Trainer(cfg, mesh_ctx=ctx)
     tr.init_model()
-    assert tr.net._fused_now(), "mesh cleared the fused gate"
-    assert tr.net.fused_spmd is not None
-    print(f"smoke_shard: dp={ctx.data_parallel} x "
-          f"tp={ctx.model_parallel} mesh keeps fused_kernels=1 "
-          "(island mode)")
 
-    # -- 4. rule-driven placement: planned weights are model-sharded ----
+    # -- 1. rule-driven placement: planned weights are model-sharded ----
     pspecs = tr.net.param_pspecs()
     sharded = [(name, tuple(spec)) for name, sub in pspecs.items()
                for key, spec in (sub.items()
@@ -92,42 +78,13 @@ def main() -> int:
           f"model-sharded leaves (e.g. {probe_name}/wmat "
           f"{tuple(pspecs[probe_name]['wmat'])})")
 
-    # -- 2. pallas under shard_map in the step jaxpr --------------------
-    mask = tr._mask(batch())
-    staged = tr.stage_batch(batch())
-    step = tr._get_train_step(True, staged)
-    rngk = jax.random.fold_in(tr._base_key, 0)
-    # trace the jitted step: the jaxpr must carry the fused
-    # pallas_calls inside shard_map regions (in interpret mode the
-    # LOWERED module inlines the interpreter, so the jaxpr — where
-    # pallas_call is still a primitive — is the right probe)
-    jx = str(jax.make_jaxpr(step)(
-        tr.params, tr.opt_state, tr.net_state, {}, staged.data,
-        staged.label, mask, (), rngk, tr._sched_scalars()))
-    assert "shard_map" in jx, "no shard_map region in the traced step"
-    inner = jx[jx.index("shard_map"):]
-    assert "pallas_call" in inner, \
-        "no pallas_call under shard_map in the traced step"
-    print("smoke_shard: traced train step carries pallas_calls under "
-          "shard_map")
+    # -- 2. no kernel in the traced step --------------------------------
+    step, args = tr._train_step_call(tr.stage_batch(batch()))
+    jx = str(jax.make_jaxpr(step)(*args))
+    assert "pallas_call" not in jx, "a Pallas kernel in the mesh step"
+    print("smoke_shard: traced dp x tp train step holds no pallas_call")
 
-    # -- 3. psum'd fused-BN moments == global moments (bit parity) ------
-    spmd = FusedSpmd(mesh=ctx.mesh, batch_axis=ctx.data_axis)
-    xbn = jnp.asarray((rng.randint(0, 64, (16, 4, 8, 8)) * 0.125)
-                      .astype(np.float32))
-    gamma = jnp.asarray(np.linspace(0.5, 1.5, 8), np.float32)
-    beta = jnp.zeros((8,), jnp.float32)
-    xs = jax.device_put(xbn, NamedSharding(ctx.mesh, P("data")))
-    _, mean, var = jax.jit(lambda x, g, b: fused_bn_act(
-        x, g, b, 1e-5, act="relu", spmd=spmd))(xs, gamma, beta)
-    _, mean_ref, var_ref = bn_act_reference(xbn, gamma, beta, 1e-5,
-                                            act="relu")
-    assert np.array_equal(np.asarray(mean), np.asarray(mean_ref))
-    assert np.array_equal(np.asarray(var), np.asarray(var_ref))
-    print("smoke_shard: fused sync-BN moments == global moments "
-          "(fp32 bit parity)")
-
-    # -- 5a. flagship: first-step loss parity vs single device ---------
+    # -- 3. flagship: first-step loss parity vs single device ---------
     # (5-step trajectories of THIS model diverge ~1e-1 between two
     # CORRECT configs — e.g. pure-jnp dp8 vs single drifts 0.19 by
     # step 3 from GSPMD reduction association alone — so the flagship
@@ -140,10 +97,10 @@ def main() -> int:
     tr1.update(batch())
     d0 = abs(float(tr.last_loss) - float(tr1.last_loss))
     assert d0 < 1e-3, (float(tr.last_loss), float(tr1.last_loss))
-    print(f"smoke_shard: flagship fused step-1 loss parity ok "
+    print(f"smoke_shard: flagship step-1 loss parity ok "
           f"(d={d0:.1e})")
 
-    # -- 5b. 5-step parity vs the single-device fused run ---------------
+    # -- 4. 5-step parity vs the single-device run ----------------------
     conv_cfg = parse_config_string("""
 netconfig=start
 layer[0->1] = conv:c1
@@ -166,7 +123,6 @@ batch_size = 8
 eta = 0.05
 eval_train = 0
 compute_dtype = float32
-fused_kernels = 1
 """)
     cdata = (rng.randint(0, 16, (8, 8, 8, 3)) * 0.25).astype(np.float32)
     clabel = rng.randint(0, 4, (8, 1)).astype(np.float32)
@@ -184,10 +140,9 @@ fused_kernels = 1
     losses_1 = crun(1)
     for i, (a, b) in enumerate(zip(losses_m, losses_1)):
         assert abs(a - b) < 5e-3, (i, losses_m, losses_1)
-    print(f"smoke_shard: 5-step fused dp x tp parity ok "
+    print(f"smoke_shard: 5-step dp x tp parity ok "
           f"(mesh {losses_m[-1]:.4f} vs single {losses_1[-1]:.4f})")
-    print("smoke_shard ok: fused kernels x meshes x rule-driven "
-          "sharding")
+    print("smoke_shard ok: rule-driven sharding on a dp x tp mesh")
     return 0
 
 
