@@ -38,18 +38,28 @@ the partial sum on — one chip's share of an expert-parallel group; no
 code stands in for the other chips or their exchange. Every held pair
 is computed whatever the imbalance: pairs are sorted by expert and the
 experts' products run as grouped matrix products over the groups' true
-sizes (``jax.lax.ragged_dot``), on a buffer with room for
-every pair the held experts can get (positions x min(topk, held)), so
-the step is one executable whatever the routing. After a training
-step's routing ``b_i <- b_i - bias_update_rate * sign(load_i - mean
-load)`` over all experts; there is no auxiliary loss. The layer state
-also carries ``stats``: pairs held, pairs routed elsewhere, pairs
+sizes (``jax.lax.ragged_dot``), on a buffer that is the smallest rung
+that holds the step's held pairs; the last rung has room for every pair
+the held experts can get (positions x min(topk, held)), so no pair is
+dropped and the step is one executable whatever the routing. The ladder
+(:func:`buffer_ladder`) comes from the layer's own sizes — twice the
+balanced share of the pairs, then doubling — and the rung is chosen on
+the device from the held pairs' count; a layer that holds half the
+experts or more has one rung and traces no conditional. After a
+training step's routing ``b_i <- b_i - bias_update_rate * sign(load_i -
+mean load)`` over all experts; there is no auxiliary loss. The layer
+state also carries ``stats``: pairs held, pairs routed elsewhere, pairs
 dropped (0 by construction), the largest held expert's load over the
-mean load of all experts, and max |b| — the trainer adds them to the telemetry
-registry when it drains the train metric.
+mean load of all experts, max |b| and the rows of the rung taken — the
+trainer adds them to the telemetry registry when it drains the train
+metric (``cxxnet_moe_*``; ``cxxnet_moe_buffer_rows{layer}`` and
+``cxxnet_moe_full_buffer_steps_total`` say how often the ladder
+engages).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -60,54 +70,140 @@ from .seq import _seq, _unseq, swiglu
 
 #: the order of a sigmoid-routed layer's ``stats`` vector
 MOE_STATS = ("pairs_held", "pairs_elsewhere", "pairs_dropped",
-             "load_max_over_mean", "sel_bias_absmax")
+             "load_max_over_mean", "sel_bias_absmax", "buffer_rows")
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inv):
-    """``x[perm]`` for a permutation ``perm`` whose inverse is ``inv``:
-    the backward is the gather ``g[inv]``, not a scatter-add."""
-    return x[perm]
+def buffer_ladder(n, k, held, x):
+    """The sizes the expert block's buffers can take, smallest first:
+    with the balanced share ``S = n k held / x`` of the ``n k`` pairs,
+    ``2S, 4S, 8S, ..`` (a balanced load scatters around ``S``, which
+    itself would be missed every other step), each rounded up to whole
+    tiles of 8 rows, and last the room for every pair the held experts
+    can get, ``n min(k, held)``. One rung where ``2S`` reaches it
+    (``held >= x / 2``: the uncut layer)."""
+    full = n * min(k, held)
+    rungs, m = [], 2 * n * k * held // x
+    while (rung := -(-m // 8) * 8) < full:
+        rungs.append(rung)
+        m *= 2
+    return tuple(rungs) + (full,)
 
 
-def _permute_fwd(x, perm, inv):
-    return x[perm], inv
+def _sum_slots(table, inv, n):
+    """Rows of ``table`` (M, E), sorted pairs, back at their positions
+    and summed over each position's slots in float32: (n, E). ``inv``
+    (n K,) is a pair's row among the sorted; a pair whose row is past
+    the table is not held (held pairs sort first) and reads zero."""
+    back = jnp.take(table, inv, axis=0, mode="fill", fill_value=0)
+    return jnp.sum(back.reshape(n, -1, table.shape[1])
+                   .astype(jnp.float32), axis=1)
 
 
-def _permute_bwd(inv, g):
-    return g[inv], None, None
+def _rung_inputs(m, n_held, xf, gate, order):
+    """What a rung of ``m`` rows reads of the routing: the rows'
+    positions, which rows are live, their positions' rows of ``xf`` and
+    their gates (0 on a row that is not live)."""
+    rows = order[:m]
+    pos = rows // gate.shape[1]
+    live = jnp.arange(m) < n_held
+    with jax.named_scope("moe.experts"):
+        xs = xf[pos]
+    with jax.named_scope("moe.combine"):
+        g_rows = jnp.where(live, gate.reshape(-1)[rows], 0.0)
+    return pos, live, xs, g_rows
 
 
-_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+def _rung_products(xs, g_rows, w_gate, w_up, w_down, sizes, live):
+    """The held experts' SwiGLU of the sorted rows under their gates."""
+    with jax.named_scope("moe.experts"):
+        ys = grouped_swiglu(xs, w_gate, w_up, w_down, sizes, live)
+    with jax.named_scope("moe.combine"):
+        return (ys.astype(jnp.float32) * g_rows[:, None]).astype(xs.dtype)
 
 
-@jax.custom_vjp
-def _rows_of_pairs(xf, order, inv):
-    """Row ``order[m] // K`` of ``xf`` (N, E) for the first ``M`` sorted
-    pairs ``order`` (M,) of the permutation of the N*K (position, slot)
-    pairs whose inverse is ``inv`` (N*K,). The backward brings the rows' gradients back to
-    pair order with the inverse permutation and adds each position's K
-    slots: two dense passes where a gather's own transpose is a
-    scatter-add over rows that repeat."""
-    return xf[order // (inv.shape[0] // xf.shape[0])]
+def _rung_fwd(m, n_held, xf, gate, order, inv, sizes, *weights):
+    _, live, xs, g_rows = _rung_inputs(m, n_held, xf, gate, order)
+    ys = _rung_products(xs, g_rows, *weights, sizes, live)
+    with jax.named_scope("moe.combine"):
+        return _sum_slots(ys, inv, xf.shape[0])
 
 
-def _rows_fwd(xf, order, inv):
-    return _rows_of_pairs(xf, order, inv), (order, inv, xf.shape[0])
+def _rung_bwd(m, res, g):
+    """One rung's backward from the routing and the weights alone: it
+    rebuilds its gate and up products, so no rung hands another a
+    residual. Every transposed gather is a gather: the cotangent's rows
+    come by position, the rows' gradients go back through ``inv``."""
+    n_held, xf, gate, order, inv, sizes, *weights = res
+    pos, live, xs, g_rows = _rung_inputs(m, n_held, xf, gate, order)
+    _, vjp = jax.vjp(lambda *a: _rung_products(*a, sizes, live),
+                     xs, g_rows, *weights)
+    with jax.named_scope("moe.combine"):
+        d_ys = g.astype(xs.dtype)[pos]
+    d_xs, d_g_rows, *d_w = vjp(d_ys)
+    with jax.named_scope("moe.experts"):
+        d_xf = _sum_slots(d_xs, inv, xf.shape[0]).astype(xf.dtype)
+    with jax.named_scope("moe.combine"):
+        d_gate = jnp.take(d_g_rows, inv, mode="fill", fill_value=0)
+    return (d_xf, d_gate.reshape(gate.shape), *d_w)
 
 
-def _rows_bwd(res, g):
-    order, inv, n = res
-    pairs, m = inv.shape[0], order.shape[0]
-    if m < pairs:
-        g = jnp.concatenate(
-            [g, jnp.zeros((pairs - m, g.shape[1]), g.dtype)], axis=0)
-    back = g[inv].reshape(n, pairs // n, g.shape[1])
-    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(rungs, n_held, xf, gate, order, inv, sizes, w_gate, w_up,
+                 w_down):
+    """The routed part of a sigmoid-routed layer on this chip, (N, E) in
+    float32: rows ``xf`` (N, E) under gates ``gate`` (N, K), the pairs
+    sorted by ``order`` (inverse ``inv``) with the held ones first,
+    ``sizes`` (held,) to a held expert and ``n_held`` in all. The rows'
+    gather, the three grouped products, the gates and the sum over slots
+    run on a buffer of the first of ``rungs`` that holds ``n_held``
+    pairs, chosen on the device; the last has room for every pair.
+
+    One differentiation rule around both conditionals: differentiated as
+    it stands, a ``lax.switch`` makes every branch emit zeros for every
+    other branch's residuals — the last rung's full-size buffers, written
+    on every step. Here the forward's residuals are its own inputs."""
+    return _held_fwd(rungs, n_held, xf, gate, order, inv, sizes, w_gate,
+                     w_up, w_down)[0]
 
 
-_rows_of_pairs.defvjp(_rows_fwd, _rows_bwd)
+def _rung_taken(rungs, n_held):
+    """The index of the first rung that holds ``n_held`` pairs."""
+    return jnp.sum(n_held > jnp.asarray(rungs[:-1], jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _branches(branch, rungs):
+    """``branch`` at each rung, the same functions at every call: JAX
+    keeps a branch's trace by the function, so a net's expert blocks of
+    one shape trace their rungs once (5 s of the cell's set-up)."""
+    return tuple(functools.partial(branch, m) for m in rungs)
+
+
+def _on_rung(rungs, n_held, branch, *operands):
+    if len(rungs) == 1:
+        return branch(rungs[0], *operands)
+    return lax.switch(_rung_taken(rungs, n_held), _branches(branch, rungs),
+                      *operands)
+
+
+def _held_fwd(rungs, n_held, *args):
+    return _on_rung(rungs, n_held, _rung_fwd, n_held, *args), \
+        (n_held, *args)
+
+
+def _held_bwd(rungs, res, g):
+    d_xf, d_gate, *d_w = _on_rung(rungs, res[0], _rung_bwd, res, g)
+    if len(rungs) > 1:
+        # XLA moves a conditional's users into its branches: the weight
+        # gradients' casts to the parameters' float32, which then stand
+        # alone in every branch and write twice the bytes, where outside
+        # they fuse into the optimizer's update (2.4 ms a step and
+        # 0.3 GB of the 680 M-parameter cell; PERF.md section 6, PR 31)
+        d_w = lax.optimization_barrier(d_w)
+    return (None, d_xf, d_gate, None, None, None, *d_w)
+
+
+held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def grouped_swiglu(xs, w_gate, w_up, w_down, group_sizes, live):
@@ -280,27 +376,15 @@ class MoELayer(Layer):
             sizes = lax.dynamic_slice_in_dim(load, first, held) \
                 .astype(jnp.int32)
             n_held = jnp.sum(sizes)
-            # room for every pair the held experts can get
-            M = N * min(K, held)
-            rows = order[:M]
-            live = (jnp.arange(M) < n_held)
             # the inverse permutation: a pair's row among the sorted
             inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
                 jnp.arange(N * K, dtype=jnp.int32))
         with jax.named_scope("moe.experts"):
             note_grouped("ragged_dot")
-            w = lambda nm: params[nm]["wmat"].astype(cd)
-            ys = grouped_swiglu(_rows_of_pairs(xf, rows, inv), w("g"),
-                                w("h"), w("o"), sizes, live)   # (M, E)
-        with jax.named_scope("moe.combine"):
-            g_rows = jnp.where(live, gate.reshape(N * K)[rows], 0.0)
-            ys = ys.astype(jnp.float32) * g_rows[:, None]
-            if M < N * K:
-                ys = jnp.concatenate(
-                    [ys, jnp.zeros((N * K - M, E), ys.dtype)], axis=0)
-            # back to (position, slot) order
-            out = jnp.sum(_permute_rows(ys.astype(cd), inv, order)
-                          .reshape(N, K, E).astype(jnp.float32), axis=1)
+            weights = [params[nm]["wmat"].astype(cd) for nm in "gho"]
+        rungs = buffer_ladder(N, K, held, X)
+        out = held_experts(rungs, n_held, xf, gate, order, inv, sizes,
+                           *weights)
         if self.shared_expert:
             with jax.named_scope("moe.shared"):
                 sp = params["shared"]
@@ -316,11 +400,13 @@ class MoELayer(Layer):
                 new_bias = sel_bias - self.bias_update_rate * jnp.sign(
                     load - jnp.mean(load))
             held_f = n_held.astype(jnp.float32)
-            computed = jnp.minimum(held_f, float(M))
+            computed = jnp.minimum(held_f, float(rungs[-1]))
+            taken = jnp.asarray(rungs, jnp.float32)[
+                _rung_taken(rungs, n_held)]
             stats = jnp.stack([
                 computed, float(N * K) - held_f, held_f - computed,
                 jnp.max(sizes).astype(jnp.float32) / (N * K / X),
-                jnp.max(jnp.abs(new_bias))])
+                jnp.max(jnp.abs(new_bias)), taken])
         return [_unseq(out)], {"sel_bias": lax.stop_gradient(new_bias),
                                "stats": lax.stop_gradient(stats)}
 

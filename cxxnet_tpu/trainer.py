@@ -2444,21 +2444,36 @@ class Trainer:
         """One drained step's sigmoid-routed moe ``stats`` (layers/
         moe.MOE_STATS, by layer) into the telemetry registry: the three
         pair counters summed over the layers and the steps counted (a
-        mean's two halves), the two gauges by layer, and the pairs held
-        at this step alone (what a reader of a trace's last steps
-        wants while the routing drifts)."""
-        from .layers.moe import MOE_STATS
+        mean's two halves), the gauges by layer — the rung of its buffer
+        ladder the layer took among them — the steps on which some
+        layer took its ladder's last rung, and the pairs held at this
+        step alone (what a reader of a trace's last steps wants while
+        the routing drifts)."""
+        from .layers.moe import MOE_STATS, buffer_ladder
         from .telemetry.registry import get_registry
         reg = get_registry()
+        stats = jax.device_get(stats)
         total = np.zeros(3)
-        for layer, vec in jax.device_get(stats).items():
-            v = dict(zip(MOE_STATS, np.asarray(vec, np.float64)))
-            total += [v["pairs_held"], v["pairs_elsewhere"],
-                      v["pairs_dropped"]]
-            for g in ("load_max_over_mean", "sel_bias_absmax"):
+        full = False
+        for layer in self.net.layers:
+            if layer.name not in stats:
+                continue
+            v = dict(zip(MOE_STATS, np.asarray(stats[layer.name],
+                                               np.float64)))
+            pairs = [v["pairs_held"], v["pairs_elsewhere"],
+                     v["pairs_dropped"]]
+            total += pairs
+            full |= v["buffer_rows"] >= buffer_ladder(
+                int(sum(pairs)) // layer.topk, layer.topk,
+                layer.expert_held, layer.num_expert)[-1]
+            for g in MOE_STATS[3:]:
                 reg.gauge("cxxnet_moe_" + g, "sigmoid-routed moe: " + g
                           + " at the last drained step",
-                          labels=("layer",)).labels(layer).set(v[g])
+                          labels=("layer",)).labels(layer.name).set(v[g])
+        reg.counter("cxxnet_moe_full_buffer_steps_total",
+                    "drained steps on which some sigmoid-routed moe layer "
+                    "took the last rung of its buffer ladder").inc(
+                        float(full))
         for kind, n in zip(("held", "elsewhere", "dropped"), total):
             reg.counter(f"cxxnet_moe_pairs_{kind}_total",
                         "sigmoid-routed moe: (position, expert) pairs "

@@ -314,6 +314,50 @@ def test_latent_attention_flash_compiles_at_the_cell_s_widths(one_chip):
     assert _kernels(text) == 2          # forward, backward
 
 
+def test_the_held_experts_ladder_compiles_at_the_cell_s_sizes(one_chip):
+    """An expert layer of ``joyai_ep16_train_8k`` — 8192 positions of
+    2048, top-8 of 256 with 16 held, experts 768 wide — forward and
+    gradient under ``jax.checkpoint``: the chip's compiler takes the
+    four rungs' grouped kernels, the rebuilt forward's switch is dead
+    code (two conditionals, not three), and what crosses a conditional
+    is sized by the positions and the weights alone — no rung's buffer,
+    and the weights' gradients still in bf16 there (their casts to
+    float32 stay outside, where they fuse into the optimizer's
+    update)."""
+    import re
+    from cxxnet_tpu.graph import LayerSpec
+    from cxxnet_tpu.layers import ApplyCtx, create_layer
+    from cxxnet_tpu.layers.moe import buffer_ladder
+    n, e, f, x, held, k = 8192, 2048, 768, 256, 16, 8
+    rungs = buffer_ladder(n, k, held, x)
+    assert rungs == (8192, 16384, 32768, 65536)
+    layer = create_layer(LayerSpec("moe", "L", [0], [1], [
+        ("router", "sigmoid"), ("num_expert", str(x)), ("topk", str(k)),
+        ("nhidden", str(f)), ("shared_expert", "1"),
+        ("expert_first", "80"), ("expert_held", str(held))]), [])
+    sd = lambda t: jax.tree_util.tree_map(
+        lambda a: (tuple(a.shape), a.dtype), t)
+    params = sd(jax.eval_shape(
+        lambda key: layer.init_params(key, [(e, n, 1)]),
+        jax.random.PRNGKey(0)))
+    state = sd(jax.eval_shape(lambda: layer.init_state([(e, n, 1)])))
+
+    @jax.checkpoint
+    def fn(p, st, xs):
+        return layer.apply(p, st, [xs], ApplyCtx(
+            train=True, compute_dtype=BF16))[0][0]
+    text = _compile(fn, one_chip, [params, state, ((1, n, 1, e), BF16)],
+                    grad_argnums=(0, 2))
+    results = re.findall(r"^\s*%\S+ = (\(.*?\)) conditional\(", text, re.M)
+    assert len(results) == 2
+    for shapes in results:
+        leading = {int(d) for d in re.findall(r"\[(\d+)[,\]]", shapes)}
+        assert not leading & (set(rungs[1:]) | {n * k}), shapes
+    backward = max(results, key=len)
+    assert backward.count(f"bf16[{held},") == 3, backward
+    assert _kernels(text) % len(rungs) == 0
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_paged_decode_attention_compiles(one_chip, dtype):
     """serve/lm's decode step: plain XLA (no kernel), still the chip's

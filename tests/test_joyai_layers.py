@@ -213,6 +213,181 @@ def test_no_pair_is_dropped_under_planted_skew(skew):
         assert np.asarray(new["stats"])[3] > 5.0    # load max over mean
 
 
+# -- the ladder of buffer sizes (PR 31) -----------------------------------------
+
+#: experts 4 and 5 of 16 held, top-3 over 64 positions: a balanced share
+#: of 24 pairs, so rungs of 48 and 96 rows under the 128 that every pair
+#: the two can get fits in
+LADDER = (48, 96, 128)
+
+
+def _planted_bias(scores, n_held):
+    """A selection bias under which exactly ``n_held`` of the 64 x 3
+    pairs go to experts 4 and 5: expert 4 at every position or at none,
+    expert 5 at the positions whose score is nearest the chosen."""
+    everywhere = n_held >= B * S
+    want = n_held - B * S if everywhere else n_held
+    others = np.sort(np.delete(scores, [4, 5], axis=1), axis=1)
+    # the score expert 5 has to pass: the others' second largest where
+    # expert 4 takes a place, their third largest where it takes none
+    need = np.sort(others[:, -2 if everywhere else -3] - scores[:, 5])
+    edges = np.concatenate([[need[0] - 1.0], need, [need[-1] + 1.0]])
+    bias = np.zeros(16, np.float32)
+    bias[4] = 10.0 if everywhere else -10.0
+    bias[5] = 0.5 * (edges[want] + edges[want + 1])
+    return jnp.asarray(bias)
+
+
+@pytest.fixture(scope="module")
+def laddered():
+    """One layer of three rungs, its jitted value-and-gradient, and the
+    same layer held to its last rung."""
+    from cxxnet_tpu.layers import moe
+    layer = moe_layer(first=4, held=2)
+    p = layer.init_params(jax.random.PRNGKey(15), [(E, S, 1)])
+    x = x_node(16)
+    assert moe.buffer_ladder(B * S, 3, 2, 16) == LADDER
+
+    def jitted():       # a function of its own: jit caches by function
+        def run(p, bias, x):
+            def loss(p, x):
+                y, new = moe_apply(layer, p, bias, x)
+                return jnp.sum(y ** 2), (y, new["stats"])
+            return jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x)
+        return jax.jit(run)
+    fn, last = jitted(), jitted()
+    with jax.default_matmul_precision("highest"):
+        scores = np.asarray(jax.nn.sigmoid(
+            seq(x).reshape(B * S, E) @ p["router"]["wmat"]))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "buffer_ladder", lambda *a: LADDER[-1:])
+            last(p, jnp.zeros((16,)), x)        # traced on one rung
+    return {"layer": layer, "p": p, "x": x, "fn": fn, "last": last,
+            "scores": scores}
+
+
+@pytest.mark.parametrize("n_held,rung", [
+    (20, 48), (48, 48), (49, 96), (80, 96), (96, 96), (97, 128),
+    (128, 128)])
+def test_each_rung_is_the_last_rungs_and_the_references(laddered, n_held,
+                                                        rung):
+    """The routing planted through the bias so that each rung is taken
+    in turn, the held pairs exactly a rung's rows and one more: output,
+    input gradient and every weight's gradient (``g``, ``h``, ``o``, the
+    router, the shared expert) are the plain reference's and the last
+    rung's own, no pair is dropped, the rung is the smallest that holds
+    the pairs, and one executable serves every case."""
+    p, x = laddered["p"], laddered["x"]
+    bias = _planted_bias(laddered["scores"], n_held)
+    c = dict(C, n_routed_experts=2, expert_first=4)
+    with jax.default_matmul_precision("highest"):
+        (_, (y, stats)), grads = laddered["fn"](p, bias, x)
+        (_, (y_last, stats_last)), grads_last = laddered["last"](p, bias, x)
+        want = lambda p, x: ref.experts(p, bias, seq(x), c)[0]
+        close(y, want(p, x))
+        tree_close(grads, jax.grad(
+            lambda p, x: jnp.sum(want(p, x) ** 2), (0, 1))(p, x), 5e-5)
+    close(y, y_last, 1e-6)
+    tree_close(grads, grads_last, 1e-6)
+    assert laddered["fn"]._cache_size() == 1
+    assert laddered["last"]._cache_size() == 1
+    held, elsewhere, dropped, _, _, rows = np.asarray(stats)
+    assert (held, elsewhere, dropped, rows) \
+        == (n_held, B * S * 3 - n_held, 0, rung)
+    assert np.asarray(stats_last)[5] == LADDER[-1]
+    assert np.array_equal(np.asarray(stats)[:5], np.asarray(stats_last)[:5])
+
+
+def _conditionals(jaxpr):
+    """Every ``cond`` equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conditionals(sub)
+
+
+def _buffers_across(conds):
+    """The conditionals' results that are as long as a rung of
+    ``LADDER`` or as all the pairs: a buffer that left its branch."""
+    sized = set(LADDER) | {B * S * 3}
+    return [v.aval.shape for eqn in conds for v in eqn.outvars
+            if v.aval.shape and v.aval.shape[0] in sized]
+
+
+def test_no_buffer_of_a_rung_crosses_a_conditional(laddered):
+    """Differentiated as it stands a ``lax.switch`` has every branch
+    emit zeros for every other branch's residuals — the last rung's
+    buffers on every step. Under the layer's ``jax.checkpoint`` the
+    gradient's conditionals hand on nothing a rung sizes; the bare
+    switch, differentiated, does (the check can see the trap)."""
+    from cxxnet_tpu.layers import moe
+    layer, p, x = laddered["layer"], laddered["p"], laddered["x"]
+    bias = jnp.zeros((16,))
+    loss = jax.checkpoint(
+        lambda p, x: jnp.sum(moe_apply(layer, p, bias, x)[0] ** 2))
+    conds = list(_conditionals(
+        jax.make_jaxpr(jax.grad(loss, (0, 1)))(p, x).jaxpr))
+    assert len(conds) >= 2          # the forward's and the backward's
+    assert all(len(eqn.params["branches"]) == len(LADDER) for eqn in conds)
+    assert _buffers_across(conds) == []
+
+    def bare(xf, gate, w):
+        order = jnp.arange(B * S * 3, dtype=jnp.int32)
+        sizes = jnp.array([30, 30], jnp.int32)
+        return jnp.sum(moe._on_rung(
+            LADDER, jnp.sum(sizes), moe._rung_fwd, jnp.sum(sizes), xf, gate,
+            order, order, sizes, *w) ** 2)
+    w = [p[k]["wmat"] for k in "gho"]
+    trapped = list(_conditionals(jax.make_jaxpr(jax.grad(bare, (0, 2)))(
+        seq(x).reshape(B * S, E), jnp.ones((B * S, 3)), w).jaxpr))
+    assert (LADDER[-1], E) in _buffers_across(trapped)
+
+
+def test_a_ladder_of_one_rung_traces_no_conditional():
+    """``held = X`` (2S reaches the room for every pair): the code is
+    the one buffer's, forward and backward."""
+    from cxxnet_tpu.layers import moe
+    assert moe.buffer_ladder(B * S, 3, 16, 16) == (B * S * 3,)
+    assert moe.buffer_ladder(B * S, 3, 8, 16) == (B * S * 3,)
+    assert moe.buffer_ladder(8192, 8, 16, 256) \
+        == (8192, 16384, 32768, 65536)
+    layer = moe_layer()
+    p = layer.init_params(jax.random.PRNGKey(5), [(E, S, 1)])
+    text = str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(moe_apply(
+        layer, p, jnp.zeros((16,)), x)[0] ** 2), (0, 1)))(p, x_node(7)))
+    assert "cond[" not in text and "ragged_dot" in text
+
+
+def test_the_rung_taken_reaches_the_registry(laddered, monkeypatch):
+    """``Trainer._count_moe`` fed the six-entry ``stats``: the new gauge
+    by layer, the steps on which a layer took its last rung, and the
+    five older entries where they were (in a registry of the test's own:
+    the process's is another test's to count in)."""
+    import types
+    from cxxnet_tpu.telemetry import registry
+    from cxxnet_tpu.trainer import Trainer
+    reg = registry.MetricRegistry()
+    monkeypatch.setattr(registry, "get_registry", lambda: reg)
+    me = types.SimpleNamespace(net=types.SimpleNamespace(
+        layers=[laddered["layer"]]))
+    counters = ["cxxnet_moe_pairs_held_total",
+                "cxxnet_moe_pairs_elsewhere_total",
+                "cxxnet_moe_pairs_dropped_total", "cxxnet_moe_steps_total",
+                "cxxnet_moe_full_buffer_steps_total",
+                "cxxnet_moe_pairs_held_last_step"]
+    gauges = ["cxxnet_moe_buffer_rows", "cxxnet_moe_load_max_over_mean",
+              "cxxnet_moe_sel_bias_absmax"]
+
+    def read():
+        return [reg.get(n).value for n in counters], \
+            [dict(reg.get(n).samples())[("L",)].value for n in gauges]
+    Trainer._count_moe(me, {"L": jnp.array([40., 152., 0., 1.5, .25, 48.])})
+    assert read() == ([40., 152., 0., 1., 0., 40.], [48., 1.5, .25])
+    Trainer._count_moe(me, {"L": jnp.array([100., 92., 0., 4., .5, 128.])})
+    assert read() == ([140., 244., 0., 2., 1., 100.], [128., 4., .5])
+
+
 def test_the_shares_add_up_to_the_whole_layer():
     """Four chips of four experts each: the routed parts all the shares
     give, with the shared expert — which every chip computes alike —
