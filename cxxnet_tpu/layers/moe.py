@@ -10,7 +10,9 @@ mesh 'model' axis; under pjit, GSPMD lowers the dispatch/combine einsums to
 the expert all-to-all over ICI.
 
 Config (sequence node (E,S,1) -> (E,S,1)):
-  ``num_expert``, ``topk`` (1 or 2), ``nhidden`` (expert inner dim),
+  ``num_expert``, ``topk`` (1 or 2 under this capacity router; any
+  number up to ``num_expert`` under the two no-drop routers below),
+  ``nhidden`` (expert inner dim),
   ``capacity_factor`` (default 1.25), ``act`` (gelu/relu),
   ``moe_loss_coef`` (load-balance aux loss weight, default 0.01),
   ``no_drop`` (1 = dense all-expert evaluation, no token ever dropped —
@@ -47,7 +49,15 @@ balanced share of the pairs, then doubling — and the rung is chosen on
 the device from the held pairs' count; a layer that holds half the
 experts or more has one rung and traces no conditional. After a
 training step's routing ``b_i <- b_i - bias_update_rate * sign(load_i -
-mean load)`` over all experts; there is no auxiliary loss. The layer
+mean load)`` over all experts; there is no auxiliary loss.
+
+``router = softmax_nodrop`` is the same path under another score
+function and nothing else: ``s = softmax(x W_r)`` over all experts in
+float32, chosen = top-``topk`` of ``s`` itself — no selection bias, so
+no ``sel_bias`` in the layer's state and nothing updated after a step —
+and the same ``g_i``, held share, shared expert, sort, ladder and
+``stats`` (``router = softmax`` alone stays the capacity router above).
+The layer
 state also carries ``stats``: pairs held, pairs routed elsewhere, pairs
 dropped (0 by construction), the largest held expert's load over the
 mean load of all experts, max |b| and the rows of the rung taken — the
@@ -68,7 +78,13 @@ from jax import lax
 from .base import Layer, register_layer
 from .seq import _seq, _unseq, swiglu
 
-#: the order of a sigmoid-routed layer's ``stats`` vector
+#: the score function of each no-drop router (``router = <key>``) and
+#: whether the choice adds a selection bias that is layer state
+_NO_DROP_ROUTERS = {"sigmoid": (jax.nn.sigmoid, True),
+                    "softmax_nodrop": (
+                        functools.partial(jax.nn.softmax, axis=-1), False)}
+
+#: the order of a no-drop layer's ``stats`` vector
 MOE_STATS = ("pairs_held", "pairs_elsewhere", "pairs_dropped",
              "load_max_over_mean", "sel_bias_absmax", "buffer_rows")
 
@@ -150,7 +166,7 @@ def _rung_bwd(m, res, g):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def held_experts(rungs, n_held, xf, gate, order, inv, sizes, w_gate, w_up,
                  w_down):
-    """The routed part of a sigmoid-routed layer on this chip, (N, E) in
+    """The routed part of a no-drop layer on this chip, (N, E) in
     float32: rows ``xf`` (N, E) under gates ``gate`` (N, K), the pairs
     sorted by ``order`` (inverse ``inv``) with the held ones first,
     ``sizes`` (held,) to a held expert and ``n_held`` in all. The rows'
@@ -258,7 +274,7 @@ class MoELayer(Layer):
         elif name == "no_drop":
             self.no_drop = int(val)
         elif name == "router":
-            if val not in ("softmax", "sigmoid"):
+            if val != "softmax" and val not in _NO_DROP_ROUTERS:
                 raise ValueError(f"unknown moe router {val!r}")
             self.router = val
         elif name in ("shared_expert", "expert_first", "expert_held"):
@@ -279,7 +295,8 @@ class MoELayer(Layer):
         self.routed_scaling_factor = 1.0
         self.bias_update_rate = 0.001
         super().__init__(spec, global_cfg)
-        if self.router == "sigmoid":
+        self.no_drop_router = self.router in _NO_DROP_ROUTERS
+        if self.no_drop_router:
             self.act = "swiglu"
             self.expert_held = self.expert_held or self.num_expert
             if not 1 <= self.topk <= self.num_expert:
@@ -292,10 +309,11 @@ class MoELayer(Layer):
                     f"among {self.num_expert}")
         elif self.topk not in (1, 2):
             raise ValueError("moe: topk must be 1 or 2 under the softmax "
-                             "(capacity) router; router = sigmoid takes "
-                             "any")
+                             "(capacity) router; router = sigmoid and "
+                             "router = softmax_nodrop take any")
         elif self.act == "swiglu":
-            raise ValueError("moe: act = swiglu needs router = sigmoid")
+            raise ValueError("moe: act = swiglu needs router = sigmoid or "
+                             "softmax_nodrop")
 
     def infer_shapes(self, in_shapes):
         self.check_n(in_shapes, 1, 1)
@@ -306,7 +324,7 @@ class MoELayer(Layer):
         f = self.hp.num_hidden or 4 * e
         x = self.num_expert
         kr, k1, k2 = jax.random.split(key, 3)
-        if self.router == "sigmoid":
+        if self.no_drop_router:
             held = self.expert_held
             k1, k3, ks = jax.random.split(k1, 3)
             w = self.hp.init_weight
@@ -330,7 +348,7 @@ class MoELayer(Layer):
         }
 
     def param_pspecs(self):
-        if self.router == "sigmoid":
+        if self.no_drop_router:
             # one chip's share: the held experts are whole on this chip
             return {}
         # experts sharded over 'model' (expert parallelism); router replicated
@@ -338,31 +356,36 @@ class MoELayer(Layer):
                 "o": {"wmat": ("model", None, None), "bias": ("model", None)}}
 
     def init_state(self, in_shapes):
-        if self.router == "sigmoid":
-            return {"sel_bias": jnp.zeros((self.num_expert,), jnp.float32),
-                    "stats": jnp.zeros((len(MOE_STATS),), jnp.float32)}
+        if self.no_drop_router:
+            st = {"stats": jnp.zeros((len(MOE_STATS),), jnp.float32)}
+            if _NO_DROP_ROUTERS[self.router][1]:
+                st["sel_bias"] = jnp.zeros((self.num_expert,), jnp.float32)
+            return st
         return {"_aux_loss": jnp.zeros((), jnp.float32)}
 
-    def _apply_sigmoid(self, params, state, inputs, ctx):
-        """``router = sigmoid``: see the module's header."""
+    def _apply_no_drop(self, params, state, inputs, ctx):
+        """``router = sigmoid`` / ``softmax_nodrop``: see the module's
+        header."""
         from ..ops.fused import note_grouped
         if ctx.seq_axis is not None:
-            raise ValueError("moe: router = sigmoid has no "
+            raise ValueError(f"moe: router = {self.router} has no "
                              "sequence-parallel path")
+        score_fn, biased = _NO_DROP_ROUTERS[self.router]
         cd = ctx.compute_dtype
         x = _seq(inputs[0]).astype(cd)
         B, T, E = x.shape
         N, X, K = B * T, self.num_expert, self.topk
         first, held = self.expert_first, self.expert_held
         xf = x.reshape(N, E)
-        sel_bias = state["sel_bias"]
+        sel_bias = state["sel_bias"] if biased else None
         with jax.named_scope("moe.route"):
             logits = jnp.einsum(
                 "ne,ex->nx", xf.astype(jnp.float32),
                 params["router"]["wmat"].astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
-            score = jax.nn.sigmoid(logits)                     # (N, X)
-            _, idx = lax.top_k(score + lax.stop_gradient(sel_bias), K)
+            score = score_fn(logits)                           # (N, X)
+            _, idx = lax.top_k(score + lax.stop_gradient(sel_bias)
+                               if biased else score, K)
             gate = jnp.take_along_axis(score, idx, axis=1)     # (N, K)
             gate = gate / (jnp.sum(gate, axis=1, keepdims=True) + 1e-20)
             gate = gate * self.routed_scaling_factor
@@ -396,7 +419,7 @@ class MoELayer(Layer):
             return [_unseq(out)], {}
         with jax.named_scope("moe.route"):
             new_bias = sel_bias
-            if ctx.train and self.bias_update_rate:
+            if biased and ctx.train and self.bias_update_rate:
                 new_bias = sel_bias - self.bias_update_rate * jnp.sign(
                     load - jnp.mean(load))
             held_f = n_held.astype(jnp.float32)
@@ -406,13 +429,15 @@ class MoELayer(Layer):
             stats = jnp.stack([
                 computed, float(N * K) - held_f, held_f - computed,
                 jnp.max(sizes).astype(jnp.float32) / (N * K / X),
-                jnp.max(jnp.abs(new_bias)), taken])
-        return [_unseq(out)], {"sel_bias": lax.stop_gradient(new_bias),
-                               "stats": lax.stop_gradient(stats)}
+                jnp.max(jnp.abs(new_bias)) if biased else 0.0, taken])
+        new_state = {"stats": lax.stop_gradient(stats)}
+        if biased:
+            new_state["sel_bias"] = lax.stop_gradient(new_bias)
+        return [_unseq(out)], new_state
 
     def apply(self, params, state, inputs, ctx):
-        if self.router == "sigmoid":
-            return self._apply_sigmoid(params, state, inputs, ctx)
+        if self.no_drop_router:
+            return self._apply_no_drop(params, state, inputs, ctx)
         x = _seq(inputs[0]).astype(ctx.compute_dtype)   # (B, T, E)
         B, T, E = x.shape
         X = self.num_expert

@@ -1,5 +1,5 @@
-"""Sequence / transformer layers: embed, layernorm, mha, ffn, seqfc, add,
-lmloss.
+"""Sequence / transformer layers: embed, layernorm, rmsnorm, mha, mla, gqa,
+ffn, seqfc, add, lmloss.
 
 TPU-idiomatic extension beyond the reference (which has no sequence axis —
 fixed image tensors, /root/reference/src/layer/layer.h:33-39; SURVEY §5
@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (attention_reference, chunked_attention,
-                             flash_attention, rope, rope_interleaved)
+                             flash_attention, flash_tiles, rope,
+                             rope_frequencies, rope_interleaved,
+                             rope_partial)
 from .base import Layer, Shape3, register_layer
 from .loss import LossLayerBase
 
@@ -307,6 +309,20 @@ class MultiHeadAttentionLayer(Layer, _SeqLinearMixin):
         return [_unseq(y)], state
 
 
+def flash_block(positions: int, largest: int = 1024) -> int:
+    """The square block the flash kernel takes at ``positions``: the
+    largest of ``largest``, .., 256, 128 that divides them, the whole
+    row where there are fewer than 128, else 0 (no kernel)."""
+    if positions < 128:
+        return positions
+    b = largest
+    while b >= 128:
+        if positions % b == 0:
+            return b
+        b //= 2
+    return 0
+
+
 @register_layer("mla")
 class LatentAttentionLayer(Layer):
     """Multi-head latent attention (the DeepSeek-V2/V3 family's), causal,
@@ -398,8 +414,7 @@ class LatentAttentionLayer(Layer):
     def _attend(self, q, k, v):
         from ..ops.fused import note_attention
         impl, S = self.attn_impl, q.shape[1]
-        blk = next((b for b in (1024, 512, 256, 128) if S % b == 0),
-                   S if S < 128 else 0)
+        blk = flash_block(S)
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" and blk \
                 else "ref"
@@ -435,6 +450,180 @@ class LatentAttentionLayer(Layer):
         with jax.named_scope("mla.attend"):
             o = self._attend(q, k, v)
         with jax.named_scope("mla.proj"):
+            y = jnp.einsum("bshd,hde->bse", o, w("o"))
+        return [_unseq(y)], state
+
+
+@register_layer("gqa")
+class GroupedQueryAttentionLayer(Layer):
+    """Causal self-attention with grouped key/value heads, a head size
+    of its own, an optional window, rotary on part of the head with a
+    plain or YaRN table, and a per-head output gate, on a sequence node
+    (E,S,1) -> (E,S,1). With x a position's vector, no bias anywhere:
+
+      q = x W_q (``nhead`` heads of ``head_dim``); k = x W_k, v = x W_v
+      (``nkvhead`` heads); rotary on q and k (below)
+      query head h attends key/value head h // (nhead / nkvhead)
+      scores = q.k / sqrt(head_dim), causal; ``window = W`` lets
+      position i see j with i - W < j <= i (0: every j <= i)
+      o_h = softmax(scores) v
+      ``head_gate = 1``: o_h <- sigmoid(x w_g[:, h]) o_h, one scalar a
+      head and position from the layer's input (the head-wise form of
+      arXiv:2505.06708)
+      y = concat_h(o_h) W_o
+
+    The layer knows only the heads the conf states: a chip's share of a
+    layer's heads is a conf with fewer of them, and ``W_o`` then gives
+    the held heads' partial sum.
+
+    Rotary, on halves (``rotate_half``), over the first ``rotary_dim``
+    features of a head (default: all; the others pass through) at
+    ``rope_theta``; ``rope_type = yarn`` takes YaRN's frequency table
+    (``ops.attention.rope_frequencies``) from ``rope_factor``,
+    ``rope_original_max_position``, ``rope_beta_fast``,
+    ``rope_beta_slow``, and cos and sin times ``rope_attention_factor``.
+
+    ``attn_impl`` in {auto, ref, flash}, as ``mla`` has it: ``ref`` runs
+    on XLA's dots (the tests' oracle), ``flash`` is the Pallas kernel —
+    k and v are read as they stand by the query heads of a group, and
+    with a window the tiles outside the band are neither computed nor
+    fetched — at square blocks of the largest of 1024 (a full layer;
+    PERF.md section 6, PR 28/29) or 512 (a window layer; section 6,
+    PR 32), 256, 128 that divides the positions. ``auto`` is the kernel
+    on a TPU where such a block exists, else ``ref``. Under
+    ``remat = 1`` the model keeps the kernel's output and logsumexp and
+    rebuilds only ``gqa.proj``."""
+    has_params = True
+
+    _INT = ("nhead", "nkvhead", "head_dim", "window", "head_gate",
+            "rotary_dim")
+    _FLOAT = ("rope_theta", "rope_factor", "rope_original_max_position",
+              "rope_beta_fast", "rope_beta_slow", "rope_attention_factor")
+
+    def set_param(self, name, val):
+        if name in self._INT:
+            setattr(self, name, int(val))
+        elif name in self._FLOAT:
+            setattr(self, name, float(val))
+        elif name == "rope_type":
+            if val not in ("default", "yarn"):
+                raise ValueError(f"unknown gqa rope_type {val!r}")
+            self.rope_type = val
+        elif name == "attn_impl":
+            if val not in ("auto", "ref", "flash"):
+                raise ValueError(f"unknown gqa attn_impl {val!r}")
+            self.attn_impl = val
+
+    def __init__(self, spec, global_cfg):
+        self.nhead = self.nkvhead = self.head_dim = 0
+        self.window = self.head_gate = self.rotary_dim = 0
+        self.attn_impl = "auto"
+        self.rope_type = "default"
+        self.rope_theta = 10000.0
+        self.rope_factor = self.rope_attention_factor = 1.0
+        self.rope_original_max_position = 0.0
+        self.rope_beta_fast, self.rope_beta_slow = 32.0, 1.0
+        super().__init__(spec, global_cfg)
+        for k in ("nhead", "head_dim"):
+            if getattr(self, k) <= 0:
+                raise ValueError(f"gqa layer {spec.name!r} needs {k}")
+        self.nkvhead = self.nkvhead or self.nhead
+        self.rotary_dim = self.rotary_dim or self.head_dim
+        if self.nhead % self.nkvhead:
+            raise ValueError(f"gqa {spec.name!r}: {self.nhead} query heads "
+                             f"over {self.nkvhead} key/value heads")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(f"gqa {spec.name!r}: rotary_dim must be even "
+                             "and at most head_dim")
+        if self.window < 0:
+            raise ValueError(f"gqa {spec.name!r}: window must be >= 0")
+        yarn = None
+        if self.rope_type == "yarn":
+            if self.rope_original_max_position <= 0:
+                raise ValueError(f"gqa {spec.name!r}: rope_type = yarn "
+                                 "needs rope_original_max_position")
+            yarn = (self.rope_factor, self.rope_original_max_position,
+                    self.rope_beta_fast, self.rope_beta_slow,
+                    self.rope_attention_factor)
+        self.rope_freqs, self.rope_mscale = rope_frequencies(
+            self.rotary_dim, self.rope_theta, yarn)
+
+    def _block(self, positions):
+        """The kernel's square block at ``positions``, 0 where none
+        divides them."""
+        return flash_block(positions, 512 if self.window else 1024)
+
+    def infer_shapes(self, in_shapes):
+        self.check_n(in_shapes, 1, 1)
+        S = in_shapes[0][1]
+        blk = self._block(S)
+        if blk:
+            from ..telemetry.registry import get_registry
+            done, total = flash_tiles(S, blk, self.window or None)
+            for what, n in (("executed", done), ("total", total)):
+                get_registry().gauge(
+                    "cxxnet_attn_tiles_" + what, "gqa: score tiles a head "
+                    f"of the flash kernel's forward ({what}; of the square "
+                    "at the layer's blocks)",
+                    labels=("layer",)).labels(self.name).set(float(n))
+        return [in_shapes[0]]
+
+    def init_params(self, key, in_shapes):
+        e = in_shapes[0][0]
+        h, hkv, d = self.nhead, self.nkvhead, self.head_dim
+        ks = jax.random.split(key, 5)
+        w = self.hp.init_weight
+        p = {"q": {"wmat": w(ks[0], (e, h, d), e, h * d)},
+             "k": {"wmat": w(ks[1], (e, hkv, d), e, hkv * d)},
+             "v": {"wmat": w(ks[2], (e, hkv, d), e, hkv * d)},
+             "o": {"wmat": w(ks[3], (h, d, e), h * d, e)}}
+        if self.head_gate:
+            p["gate"] = {"wmat": w(ks[4], (e, h), e, h)}
+        return p
+
+    def param_pspecs(self):
+        qkv = {"wmat": (None, "model", None)}
+        return {"q": qkv, "k": qkv, "v": qkv,
+                "gate": {"wmat": (None, "model")},
+                "o": {"wmat": ("model", None, None)}}
+
+    def _attend(self, q, k, v):
+        from ..ops.fused import note_attention
+        impl, S = self.attn_impl, q.shape[1]
+        window = self.window or None
+        blk = self._block(S)
+        if impl == "auto":
+            impl = "flash" if jax.default_backend() == "tpu" and blk \
+                else "ref"
+        note_attention("gqa.flash_window" if impl == "flash" and window
+                       else "gqa." + impl)
+        if impl == "ref":
+            return attention_reference(q, k, v, causal=True, window=window)
+        if not blk:
+            raise ValueError(f"gqa {self.name!r}: no flash block divides "
+                             f"{S} positions")
+        return flash_attention(q, k, v, True, None, blk, blk, None, window)
+
+    def apply(self, params, state, inputs, ctx):
+        if ctx.seq_axis is not None:
+            raise ValueError("gqa has no sequence-parallel path")
+        cd = ctx.compute_dtype
+        x = _seq(inputs[0]).astype(cd)
+        w = lambda nm: params[nm]["wmat"].astype(cd)
+        with jax.named_scope("gqa.proj"):
+            q, k, v = (jnp.einsum("bse,ehd->bshd", x, w(nm))
+                       for nm in ("q", "k", "v"))
+            q, k = (rope_partial(a, self.rope_freqs, self.rope_mscale)
+                    for a in (q, k))
+        with jax.named_scope("gqa.attend.window" if self.window
+                             else "gqa.attend.full"):
+            o = self._attend(q, k, v)
+        if self.head_gate:
+            with jax.named_scope("gqa.gate"):
+                g = jax.nn.sigmoid(jnp.einsum(
+                    "bse,eh->bsh", x, w("gate")).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * g[..., None]).astype(cd)
+        with jax.named_scope("gqa.proj"):
             y = jnp.einsum("bshd,hde->bse", o, w("o"))
         return [_unseq(y)], state
 

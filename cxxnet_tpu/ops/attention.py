@@ -29,12 +29,21 @@ taking (batch, seq, heads, head_dim) arrays:
 
 Masking convention: ``causal=True`` masks strictly-future positions.
 Fully-masked rows produce zeros (guarded divide), so ragged/padded
-sequences are safe.
+sequences are safe. ``window = W`` (causal only) lets position ``i`` see
+``j`` with ``i - W < j <= i``: the position itself and the ``W - 1``
+before it.
+
+Grouped key/value heads: k and v may carry fewer heads than q, ``H_kv``
+dividing ``H``; query head ``h`` then reads key/value head ``h // G``
+with ``G = H / H_kv``. No implementation repeats k or v in memory: the
+jnp forms contract q as ``(.., H_kv, G, D)``, the kernels' index maps
+hand G consecutive query heads the same k/v tile.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -141,18 +150,111 @@ def rope_interleaved(x: jax.Array, theta: float, offset=0) -> jax.Array:
     return out.reshape(B, S, H, D).astype(x.dtype)
 
 
+def rope_frequencies(rot_dim: int, theta: float, yarn=None):
+    """``(the rot_dim / 2 pairs' frequencies as a tuple of floats, the
+    factor cos and sin carry)`` of a rotary over ``rot_dim`` features at
+    base ``theta``. ``yarn = None`` is the plain table ``f_i =
+    theta^(-2i / rot_dim)`` with factor 1. ``yarn = (factor,
+    original_max_position, beta_fast, beta_slow, attention_factor)`` is
+    YaRN's (arXiv:2309.00071, as transformers computes it): with
+    ``dim(n) = rot_dim ln(original / (2 pi n)) / (2 ln theta)``, ``low =
+    floor(dim(beta_fast))`` and ``high = ceil(dim(beta_slow))`` (clamped
+    to the table), pair ``i`` keeps ``f_i`` below ``low``, takes ``f_i /
+    factor`` above ``high`` and the ramp's mix between; cos and sin are
+    scaled by ``attention_factor``. Host arithmetic in float64: the table
+    is a constant of the layer."""
+    half = rot_dim // 2
+    f = [theta ** (-2.0 * i / rot_dim) for i in range(half)]
+    if yarn is None:
+        return tuple(f), 1.0
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+
+    def dim_of(turns):
+        return rot_dim * math.log(original / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), rot_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = [min(max((i - low) / (high - low), 0.0), 1.0)
+            for i in range(half)]
+    return tuple(fi * (1.0 - r) + fi / factor * r
+                 for fi, r in zip(f, ramp)), float(attention_factor)
+
+
+def rope_partial(x: jax.Array, freqs, mscale: float = 1.0,
+                 offset=0) -> jax.Array:
+    """Rotary on the FIRST ``2 len(freqs)`` features of (B, S, H, D), on
+    halves (feature ``i`` of the rotated part pairs with ``i +
+    len(freqs)``: ``rotate_half``), pair ``i`` turned by ``pos *
+    freqs[i]``, cos and sin times ``mscale``; the other features pass
+    through untouched (``partial_rotary_factor``). ``freqs`` is
+    :func:`rope_frequencies`'s table. With the whole head rotated, the
+    plain table and ``mscale`` 1 this is :func:`rope`."""
+    half = len(freqs)
+    if 2 * half > x.shape[-1]:
+        raise ValueError(f"rope_partial: {2 * half} rotated features in a "
+                         f"head of {x.shape[-1]}")
+    pos = jnp.arange(x.shape[1], dtype=jnp.float32) \
+        + jnp.asarray(offset, jnp.float32)
+    ang = pos[:, None] * jnp.asarray(freqs, jnp.float32)[None, :]
+    cos = (jnp.cos(ang) * mscale)[None, :, None, :]
+    sin = (jnp.sin(ang) * mscale)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                            x[..., 2 * half:]], axis=-1).astype(x.dtype)
+
+
+def _group(q: jax.Array, k: jax.Array) -> int:
+    """Query heads per key/value head of (B, S, H, D) / (B, S, H_kv, D)."""
+    H, Hkv = q.shape[2], k.shape[2]
+    if H % Hkv:
+        raise ValueError(f"attention: {H} query heads over {Hkv} key/value "
+                         "heads")
+    return H // Hkv
+
+
+def _keep(q_pos, k_pos, window):
+    """Causal keep-mask of broadcastable position arrays, inside
+    ``window`` where one is given."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, k_pos > q_pos - window)
+    return keep
+
+
+def _check_window(causal, window):
+    if window is not None and (not causal or window < 1):
+        raise ValueError("attention: a window needs causal = True and at "
+                         "least one position")
+
+
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = False,
-                        scale: Optional[float] = None) -> jax.Array:
-    """Plain softmax attention. q,k,v: (B, S, H, D) -> (B, S, H, D)."""
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * _scale(q, scale)
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
+    """Plain softmax attention. q: (B, S, H, D), k,v: (B, S, H_kv, D)
+    -> (B, S, H, Dv)."""
+    _check_window(causal, window)
+    B, Sq, H, D = q.shape
+    G = _group(q, k)
+    if G == 1:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * _scale(q, scale)
+    else:
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, Sq, H // G, G, D),
+                       k, preferred_element_type=jnp.float32) \
+            * _scale(q, scale)
     if causal:
-        qi = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        ki = lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        s = jnp.where(qi >= ki, s, _NEG)
+        qi = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2)
+        ki = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+        s = jnp.where(_keep(qi, ki, window), s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype)).astype(q.dtype)
+    if G == 1:
+        return jnp.einsum("bhqk,bkhd->bqhd", p,
+                          v.astype(p.dtype)).astype(q.dtype)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(p.dtype))
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -225,19 +327,19 @@ def gather_kv_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _online_block_update(acc, m, l, q, kb, vb, q_pos, k_pos, scale, causal,
-                         k_valid_upto=None):
+                         k_valid_upto=None, window=None):
     """One online-softmax accumulation step against key/value block (kb, vb).
 
     acc: (B,H,Sq,D) f32, m/l: (B,H,Sq) f32; q: (B,Sq,H,D);
     kb/vb: (B,Sk,H,D); q_pos: (Sq,), k_pos: (Sk,) global positions.
     ``k_valid_upto`` masks key positions >= that bound (block tail padding)
-    independently of the causal mask.
+    independently of the causal mask; ``window`` narrows the causal mask.
     """
     s = jnp.einsum("bqhd,bkhd->bhqk", q, kb,
                    preferred_element_type=jnp.float32) * scale
     mask = None
     if causal:
-        mask = q_pos[:, None] >= k_pos[None, :]
+        mask = _keep(q_pos[:, None], k_pos[None, :], window)
     if k_valid_upto is not None:
         valid = (k_pos < k_valid_upto)[None, :]
         mask = valid if mask is None else jnp.logical_and(mask, valid)
@@ -259,20 +361,30 @@ def _online_block_update(acc, m, l, q, kb, vb, q_pos, k_pos, scale, causal,
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool = False, scale: Optional[float] = None,
-                      block_k: int = 128) -> jax.Array:
-    """Online-softmax attention scanning over k/v blocks (B,S,H,D)."""
+                      block_k: int = 128,
+                      window: Optional[int] = None) -> jax.Array:
+    """Online-softmax attention scanning over k/v blocks. q: (B, S, H,
+    D), k,v: (B, S, H_kv, D). Grouped heads ride the query axis: the G
+    query heads of a key/value head are G queries at the same position,
+    so one scan over k and v as they stand serves them all."""
+    _check_window(causal, window)
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    G = _group(q, k)
+    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     sc = _scale(q, scale)
+    q_pos = jnp.arange(Sq)
+    if G > 1:
+        q = q.reshape(B, Sq, Hkv, G, D).transpose(0, 1, 3, 2, 4) \
+            .reshape(B, Sq * G, Hkv, D)
+        q_pos = jnp.repeat(q_pos, G)
     block_k = min(block_k, Sk)
     nb = -(-Sk // block_k)
     pad = nb * block_k - Sk
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kb = k.reshape(B, nb, block_k, H, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nb, block_k, H, D).transpose(1, 0, 2, 3, 4)
-    q_pos = jnp.arange(Sq)
+    kb = k.reshape(B, nb, block_k, Hkv, D).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, nb, block_k, Hkv, Dv).transpose(1, 0, 2, 3, 4)
 
     def step(carry, blk):
         acc, m, l = carry
@@ -280,46 +392,91 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         k_pos = j * block_k + jnp.arange(block_k)
         acc, m, l = _online_block_update(
             acc, m, l, q, kj, vj, q_pos, k_pos, sc, causal,
-            k_valid_upto=Sk if pad else None)
+            k_valid_upto=Sk if pad else None, window=window)
         return (acc, m, l), None
 
-    acc0 = jnp.zeros((B, H, Sq, D), jnp.float32)
-    m0 = jnp.full((B, H, Sq), _NEG, jnp.float32)
-    l0 = jnp.zeros((B, H, Sq), jnp.float32)
+    acc0 = jnp.zeros((B, Hkv, Sq * G, Dv), jnp.float32)
+    m0 = jnp.full((B, Hkv, Sq * G), _NEG, jnp.float32)
+    l0 = jnp.zeros((B, Hkv, Sq * G), jnp.float32)
     (acc, m, l), _ = lax.scan(step, (acc0, m0, l0),
                               (jnp.arange(nb), kb, vb))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B, Hkv, Sq G, Dv)
+    out = out.reshape(B, Hkv, Sq, G, Dv).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
 # -- Pallas flash attention ---------------------------------------------------
 
-def _block_causal_mask(qi, kj, block_q, block_k, transposed=False):
+def _block_causal_mask(qi, kj, block_q, block_k, transposed=False,
+                       window=None):
     """Causal keep-mask for one (q-block, k-block) tile — shared by the
     forward and the backward kernel so the masking convention cannot
     drift between them. ``transposed``: the (block_k, block_q) tile the
-    backward holds."""
+    backward holds. ``window`` narrows it to the band."""
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     qpos = qi * block_q + lax.broadcasted_iota(
         jnp.int32, shape, 1 if transposed else 0)
     kpos = kj * block_k + lax.broadcasted_iota(
         jnp.int32, shape, 0 if transposed else 1)
-    return qpos >= kpos
+    return _keep(qpos, kpos, window)
+
+
+def _at_least_0(x):
+    """``max(x, 0)`` of a Python int or a traced one alike (the bands
+    below are taken by the wrappers in Python and by the kernels and
+    their index maps on the device)."""
+    return x * (x > 0)
+
+
+def _band_k(qi, block_q, block_k, window):
+    """``(first, last)`` k-block the band of q-block ``qi`` touches."""
+    return (_at_least_0(qi * block_q - (window - 1)) // block_k,
+            (qi * block_q + block_q - 1) // block_k)
+
+
+def _band_q(kj, block_q, block_k, window, nq):
+    """``(first, last)`` q-block whose band touches k-block ``kj``."""
+    last = (kj * block_k + block_k - 1 + window - 1) // block_q
+    return (kj * block_k) // block_q, nq - 1 - _at_least_0(nq - 1 - last)
+
+
+def flash_tiles(positions: int, block: int, window: Optional[int] = None):
+    """``(score tiles the causal kernels execute, tiles of the square)``
+    for one head at square blocks of ``block``, forward (the backward
+    executes the same tiles): the tiles at or below the diagonal, inside
+    the band where there is a window."""
+    n = positions // block
+    if window is None:
+        return n * (n + 1) // 2, n * n
+    done = 0
+    for i in range(n):
+        first, last = _band_k(i, block, block, window)
+        done += last - first + 1
+    return done, n * n
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
-                      scale, causal, block_q, block_k):
+                      scale, causal, block_q, block_k, window=None):
     """One (batch*head, q-block, k-block) grid cell. K/V truly stream: each
     cell sees only one (block_k, D) K/V tile in VMEM; the online-softmax
     accumulators persist in VMEM scratch across the (innermost, sequential)
     k-block grid dimension, so VMEM residency is O(block) not O(S).
     Also emits the per-row logsumexp — the statistic the fused backward
     kernels rebuild the softmax from without a second online pass.
+    With a ``window`` the innermost dimension runs over the k-blocks of
+    the q-block's band only (``_band_k``): the cell's k-block is the
+    band's first plus the grid index, and cells past the band's last
+    (the count is the widest band's) do nothing and fetch nothing new.
     """
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
+    if window is not None:
+        first, last = _band_k(qi, block_q, block_k, window)
+        kb_idx = first + kj
+    else:
+        kb_idx = kj
 
     @pl.when(kj == 0)
     def _init():
@@ -336,7 +493,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = lax.dot_general(q, kb, _NT,
                             preferred_element_type=jnp.float32) * scale
         if causal:
-            mask = _block_causal_mask(qi, kj, block_q, block_k)
+            mask = _block_causal_mask(qi, kb_idx, block_q, block_k,
+                                      window=window)
             s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -349,7 +507,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
         m_ref[:, 0] = m_new
 
-    if causal:
+    if window is not None:
+        @pl.when(kb_idx <= last)
+        def _in_band():
+            compute()
+    elif causal:
         # skip tiles strictly above the causal diagonal
         @pl.when(kj * block_k <= qi * block_q + block_q - 1)
         def _guarded():
@@ -364,30 +526,57 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(l_fin)
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   with_lse: bool = False):
-    B, Sq, H, D = q.shape
-    Sk, Dv = k.shape[1], v.shape[-1]
+def _heads_flat(a):
+    """(B, S, H, D) -> (B*H, S, D): one grid row per (batch, head)."""
+    B, S, H, D = a.shape
+    return a.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+
+
+def _blocks(Sq, Sk, block_q, block_k, causal, window):
+    _check_window(causal, window)
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     if Sq % block_q or Sk % block_k:
         raise ValueError(
             f"flash_attention: seq lengths ({Sq},{Sk}) must be divisible by "
             f"blocks ({block_q},{block_k})")
-    # (B,S,H,D) -> (B*H, S, D): one grid row per (batch, head)
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, Dv)
+    if window is not None and Sq != Sk:
+        raise ValueError("flash_attention: a window needs as many keys as "
+                         "queries")
+    return block_q, block_k
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   with_lse: bool = False, window=None):
+    B, Sq, H, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[-1]
+    G = _group(q, k)
+    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+    nq, nk = Sq // block_q, Sk // block_k
+    qt, kt, vt = _heads_flat(q), _heads_flat(k), _heads_flat(v)
     kern = functools.partial(
         _flash_fwd_kernel, scale=_scale(q, scale), causal=causal,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, window=window)
+    # G consecutive query heads (grid rows) read one key/value head
+    kv_row = (lambda b: b) if G == 1 else (lambda b: b // G)
+    if window is None:
+        kv_map = lambda b, i, j: (kv_row(b), j, 0)
+    else:
+        # the innermost dimension counts the k-blocks of a band, the
+        # widest's many; past a band's last block the index stands still
+        nk = max(last - first + 1 for first, last in (
+            _band_k(i, block_q, block_k, window) for i in range(nq)))
+
+        def kv_map(b, i, j):
+            first, last = _band_k(i, block_q, block_k, window)
+            return kv_row(b), jnp.minimum(first + j, last), 0
     out, lse = pl.pallas_call(
         kern,
-        grid=(B * H, Sq // block_q, Sk // block_k),
+        grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_k, Dv), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
@@ -410,13 +599,20 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     return (out, lse) if with_lse else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention (B,S,H,D): Pallas forward, fused Pallas backward.
-    v may be narrower or wider than q and k. The backward is one kernel
+    v may be narrower or wider than q and k; k and v may carry fewer
+    heads than q (grouped: query head ``h`` reads key/value head ``h //
+    G``, from k and v as they stand); ``window`` (causal only) keeps the
+    ``window`` positions up to and including the query's own, and tiles
+    wholly outside the band are neither computed nor fetched. With as
+    many key/value heads as query heads and no window the kernels are
+    the plain causal ones. The backward is one kernel
     (``flash_bwd``, :func:`_flash_backward`) at the forward's blocks: it
     needs q, k, v, the cotangent, and the forward's output and
     logsumexp, which are saved under the names ``FLASH_RESIDUALS`` — a
@@ -427,12 +623,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     pairtest spirit, SURVEY §4).
     """
     return _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                          use_interpret(interpret))
+                          use_interpret(interpret), window=window)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                      scale, causal, block_q, block_k):
+                      scale, causal, block_q, block_k, window=None):
     """The whole backward of one (batch*head, k-block, q-block) grid cell:
     the score tile, its softmax P (rebuilt from the saved logsumexp, no
     second online pass), dP and dS = P o (dP - delta) are made ONCE and
@@ -448,10 +644,20 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     accumulate in VMEM scratch across it; dQ is the float32 block of the
     head's WHOLE row, resident in VMEM while the head's tiles run (its
     index map is constant within a head), added to in place at the
-    q-block's rows and written back once a head."""
+    q-block's rows and written back once a head. With a ``window`` the
+    innermost dimension runs over the q-blocks whose band touches the
+    k-block only (``_band_q``), as the forward's runs over a band's
+    k-blocks. dK and dV are a QUERY head's: where heads are grouped the
+    caller sums a group's."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    if window is not None:
+        first, last = _band_q(kj, block_q, block_k, window,
+                              dq_ref.shape[1] // block_q)
+        qb_idx = first + qi
+    else:
+        qb_idx = qi
 
     @pl.when(jnp.logical_and(kj == 0, qi == 0))
     def _init_dq():
@@ -473,19 +679,24 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             # explicit zeroing: fully-masked rows carry a sentinel lse,
             # where exp(s - lse) would NOT vanish on its own
-            pt = jnp.where(_block_causal_mask(qi, kj, block_q, block_k,
-                                              transposed=True), pt, 0.0)
+            pt = jnp.where(_block_causal_mask(qb_idx, kj, block_q, block_k,
+                                              transposed=True,
+                                              window=window), pt, 0.0)
         dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
         dpt = lax.dot_general(vb, do, _NT,
                               preferred_element_type=jnp.float32)
         dst = (pt * (dpt - delta_ref[0]) * scale).astype(q.dtype)
         dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        rows = pl.ds(pl.multiple_of(qb_idx * block_q, block_q), block_q)
         dq_ref[0, rows, :] += lax.dot_general(
             dst, kb, _TN, preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        @pl.when(qb_idx <= last)
+        def _in_band():
+            compute()
+    elif causal:
         # only q blocks at or below the diagonal contribute to this k tile
         @pl.when(qi * block_q + block_q - 1 >= kj * block_k)
         def _guarded():
@@ -506,18 +717,23 @@ _BWD_VMEM_LIMIT = 96 * 2 ** 20
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret):
+                    interpret, window=None):
     """Fused Pallas backward, ONE kernel (``flash_bwd``): every executed
     tile rebuilds its softmax once from the forward's logsumexp and
     feeds dq, dk and dv from it. ``out`` is (B, Sq, H, Dv) as the
     forward returned it, ``lse`` the lane-dense (B*H, Sq) the forward
     rule holds; dq leaves the kernel in float32 (it is summed in place
     across the k-blocks) and is rounded on the way back to (B, Sq, H, D).
+    Where G query heads share a key/value head the kernel's dk and dv
+    are a query head's, in float32, and the group's are summed here
+    (one pass over them, under a millisecond a layer at the cell's
+    shapes; PERF.md section 6, PR 32).
     """
     B, Sq, H, D = q.shape
-    Sk, Dv = k.shape[1], v.shape[-1]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
+    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    G = _group(q, k)
+    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+    nq, nk = Sq // block_q, Sk // block_k
     # a head's float32 dq row (lanes padded to 128s, the output's two
     # pipeline buffers) beside the tile's float32 intermediates
     lanes = -(-D // 128) * 128
@@ -529,31 +745,46 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             f"tiles need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} "
             f"the kernel may use: shorten the sequence or shard it")
     sc = _scale(q, scale)
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, Dv)
-    dot = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
+    qt, kt, vt, dot = (_heads_flat(a) for a in (q, k, v, g))
     # delta_i = rowsum(dO_i * O_i): elementwise where both already lie,
     # then one small transpose to the kernel's lane-dense rows
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Sq)
 
     # grid (bh, k-block j, q-block i), the q-blocks innermost
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    v_spec = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
-    o_spec = pl.BlockSpec((1, block_q, Dv), lambda b, j, i: (b, i, 0))
-    r_spec = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
+    kv_row = (lambda b: b) if G == 1 else (lambda b: b // G)
+    if window is None:
+        q_blk = lambda j, i: i
+    else:
+        nq = max(last - first + 1 for first, last in (
+            _band_q(j, block_q, block_k, window, nq) for j in range(nk)))
+
+        def q_blk(j, i, _nq=Sq // block_q):
+            first, last = _band_q(j, block_q, block_k, window, _nq)
+            return jnp.minimum(first + i, last)
+    q_spec = pl.BlockSpec((1, block_q, D),
+                          lambda b, j, i: (b, q_blk(j, i), 0))
+    k_spec = pl.BlockSpec((1, block_k, D),
+                          lambda b, j, i: (kv_row(b), j, 0))
+    v_spec = pl.BlockSpec((1, block_k, Dv),
+                          lambda b, j, i: (kv_row(b), j, 0))
+    o_spec = pl.BlockSpec((1, block_q, Dv),
+                          lambda b, j, i: (b, q_blk(j, i), 0))
+    r_spec = pl.BlockSpec((1, 1, block_q),
+                          lambda b, j, i: (b, 0, q_blk(j, i)))
     dq_spec = pl.BlockSpec((1, Sq, D), lambda b, j, i: (b, 0, 0))
+    dk_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    dv_spec = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
+    part = (lambda a: a.dtype) if G == 1 else (lambda a: jnp.float32)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=sc, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(B * H, Sk // block_k, Sq // block_q),
+                          block_q=block_q, block_k=block_k, window=window),
+        grid=(B * H, nk, nq),
         in_specs=[q_spec, k_spec, v_spec, o_spec, r_spec, r_spec],
-        out_specs=[dq_spec, k_spec, v_spec],
+        out_specs=[dq_spec, dk_spec, dv_spec],
         out_shape=[out_struct((B * H, Sq, D), jnp.float32, qt, kt, vt, dot),
-                   out_struct((B * H, Sk, D), k.dtype, qt, kt, vt, dot),
-                   out_struct((B * H, Sk, Dv), v.dtype, qt, kt, vt, dot)],
+                   out_struct((B * H, Sk, D), part(k), qt, kt, vt, dot),
+                   out_struct((B * H, Sk, Dv), part(v), qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -562,7 +793,12 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         interpret=interpret, name="flash_bwd",
     )(qt, kt, vt, dot, lse.reshape(B * H, 1, Sq), delta)
 
-    unflat = lambda a, S: a.reshape(B, H, S, -1).transpose(0, 2, 1, 3)
+    unflat = lambda a, S: a.reshape(B, -1, S, a.shape[-1]).transpose(
+        0, 2, 1, 3)
+    if G > 1:
+        # a key/value head's gradient: the sum over its G query heads
+        dk, dv = (jnp.sum(a.reshape(B * Hkv, G, Sk, -1), axis=1).astype(
+            like.dtype) for a, like in ((dk, k), (dv, v)))
     return unflat(dq.astype(q.dtype), Sq), unflat(dk, Sk), unflat(dv, Sk)
 
 
@@ -572,19 +808,23 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              use_interpret(interpret), with_lse=True)
+                              use_interpret(interpret), with_lse=True,
+                              window=window)
     out = checkpoint_name(out, FLASH_RESIDUALS[0])
     # held lane-dense, (B*H, Sq): a trailing 1 may be padded to 128 lanes
     lse = checkpoint_name(lse.reshape(lse.shape[:2]), FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, window,
+                    res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                           block_q, block_k, use_interpret(interpret))
+                           block_q, block_k, use_interpret(interpret),
+                           window=window)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -2,9 +2,9 @@
 chooses one took.
 
 Two kinds of site choose today, both from what the code observes, never
-from an option: a ``mha`` / latent attention layer (``attn_impl = auto``
-decides per backend and sequence length — ops/attention.py) and a
-``moe`` layer's grouped product. Every other op has one implementation,
+from an option: an attention layer (``mha``, ``mla``, ``gqa``:
+``attn_impl = auto`` decides per backend and sequence length —
+ops/attention.py) and a ``moe`` layer's grouped product. Every other op has one implementation,
 XLA's own (PERF.md section 6, PR 26 and PR 30: the Pallas suite that
 lived beside this file lost every benchmark cell and left the tree).
 
@@ -49,9 +49,12 @@ def _record(kind: str, what: str) -> None:
 
 
 def note_attention(impl: str) -> None:
-    """Record which attention implementation the mha layer being
+    """Record which attention implementation the attention layer being
     traced selected (``attn_impl = auto`` decides per backend and
-    sequence length)."""
+    sequence length): ``ref`` / ``chunked`` / ``flash`` / ``ring`` /
+    ``gather_kv`` for ``mha``, ``mla.<impl>`` for latent attention,
+    ``gqa.<impl>`` for grouped-query attention, whose kernel with a
+    window is ``gqa.flash_window``."""
     _record("attention", impl)
 
 

@@ -2441,8 +2441,9 @@ class Trainer:
         TRACER.add_complete("train.metric_drain", t0, t1, cat="train")
 
     def _count_moe(self, stats) -> None:
-        """One drained step's sigmoid-routed moe ``stats`` (layers/
-        moe.MOE_STATS, by layer) into the telemetry registry: the three
+        """One drained step's no-drop moe ``stats`` (layers/
+        moe.MOE_STATS, by layer: ``router = sigmoid`` and ``router =
+        softmax_nodrop`` alike) into the telemetry registry: the three
         pair counters summed over the layers and the steps counted (a
         mean's two halves), the gauges by layer — the rung of its buffer
         ladder the layer took among them — the steps on which some
@@ -2467,22 +2468,22 @@ class Trainer:
                 int(sum(pairs)) // layer.topk, layer.topk,
                 layer.expert_held, layer.num_expert)[-1]
             for g in MOE_STATS[3:]:
-                reg.gauge("cxxnet_moe_" + g, "sigmoid-routed moe: " + g
+                reg.gauge("cxxnet_moe_" + g, "no-drop moe: " + g
                           + " at the last drained step",
                           labels=("layer",)).labels(layer.name).set(v[g])
         reg.counter("cxxnet_moe_full_buffer_steps_total",
-                    "drained steps on which some sigmoid-routed moe layer "
+                    "drained steps on which some no-drop moe layer "
                     "took the last rung of its buffer ladder").inc(
                         float(full))
         for kind, n in zip(("held", "elsewhere", "dropped"), total):
             reg.counter(f"cxxnet_moe_pairs_{kind}_total",
-                        "sigmoid-routed moe: (position, expert) pairs "
+                        "no-drop moe: (position, expert) pairs "
                         f"{kind}, summed over layers and drained "
                         "steps").inc(float(n))
         reg.counter("cxxnet_moe_steps_total",
                     "train steps whose moe stats were drained").inc()
         reg.gauge("cxxnet_moe_pairs_held_last_step",
-                  "sigmoid-routed moe: pairs held at the last drained "
+                  "no-drop moe: pairs held at the last drained "
                   "step, summed over layers").set(float(total[0]))
 
     def train_metric_report(self, name: str = "train") -> str:

@@ -314,6 +314,27 @@ def test_latent_attention_flash_compiles_at_the_cell_s_widths(one_chip):
     assert _kernels(text) == 2          # forward, backward
 
 
+# -- benchmarks/configs/laguna_s_2_1.conf (grouped heads, a window, at 8k) -------
+
+@pytest.mark.parametrize("heads, window, block", [
+    pytest.param(24, None, 1024, id="full"),
+    pytest.param(36, 512, 512, id="window"),
+    pytest.param(36, 512, 256, id="window256")])
+def test_grouped_query_flash_compiles_at_the_cell_s_widths(
+        one_chip, heads, window, block):
+    """One row of 8192 positions, heads of 128, 4 key/value heads read
+    by 24 (a full layer) or 36 (a window layer, band 512) query heads:
+    the shapes ``gqa`` hands the kernel in ``laguna_ep32_train_8k``. The
+    index maps divide a grid row by the group and, with a window, clamp
+    a band's block: a map the chip's compiler refuses fails here."""
+    fn = lambda q, k, v: flash_attention(q, k, v, True, None, block, block,
+                                         False, window)
+    text = _compile(fn, one_chip, [((1, 8192, heads, 128), BF16)]
+                    + [((1, 8192, 4, 128), BF16)] * 2,
+                    grad_argnums=(0, 1, 2))
+    assert _kernels(text) == 2          # forward, backward
+
+
 def test_the_held_experts_ladder_compiles_at_the_cell_s_sizes(one_chip):
     """An expert layer of ``joyai_ep16_train_8k`` — 8192 positions of
     2048, top-8 of 256 with 16 held, experts 768 wide — forward and

@@ -1,34 +1,146 @@
 #!/usr/bin/env python3
-"""Write the net conf of a JoyAI-LLM-Flash-shaped model (the DeepSeek-V3
-family: latent attention, one leading dense layer, sigmoid-routed
-experts with a shared one, one multi-token-prediction module) in this
-repo's dialect, from the published config's own keys.
+"""Write the net conf of a published sequence model in this repo's
+dialect, from the published config's own keys. Two families:
+
+* JoyAI-LLM-Flash's (the DeepSeek-V3 family: latent attention, one
+  leading dense layer, sigmoid-routed experts with a shared one, one
+  multi-token-prediction module) — any file without ``model_type``
+  ``laguna``;
+* ``model_type`` ``laguna`` (poolside's Laguna family: grouped-query
+  attention whose layers are full or windowed by ``layer_types``, with
+  head counts by layer, per-head output gates and a rotary by layer
+  type, plain or YaRN, over part of the head; leading dense layers by
+  ``mlp_only_layers``; softmax top-k experts without drops with a shared
+  one).
 
     python tools/gen_joyai_conf.py benchmarks/configs/joyai_llm_flash.json
+    python tools/gen_joyai_conf.py benchmarks/configs/laguna_s_2_1.json
 
-reads the keys of that JSON (the model's ``config.json`` names plus
-``expert_first`` / ``expert_held``, ``mtp_loss_weight``,
-``bias_update_rate`` and the conf's training pairs under ``train``) and
-prints the conf. ``benchmarks/configs/joyai_llm_flash.conf`` and
-``tests/benchmarks/data/joyai_toy/configs/joyai_toy.conf`` are its
-output; nothing reads this file at run time.
+reads the keys of that JSON (the model's ``config.json`` names plus the
+held experts' ``expert_first`` and published count, for the first family
+``mtp_loss_weight`` and ``bias_update_rate``, and the conf's training
+pairs under ``train``) and prints the conf.
+``benchmarks/configs/joyai_llm_flash.conf``,
+``benchmarks/configs/laguna_s_2_1.conf`` and the toy confs under
+``tests/benchmarks/data/*_toy/configs/`` are its output; nothing reads
+this file at run time.
 """
 
 import json
 import sys
 
+_HEADER = ["# written by tools/gen_joyai_conf.py from the keys of the",
+           "# configuration file beside it; edit that, not this",
+           "netconfig=start"]
+
+
+def _tail(c: dict, metrics) -> list:
+    """What follows the net: the input's shape, the label and the
+    training pairs, then the train metrics."""
+    return ["netconfig=end", "", f"input_shape = 1,1,{c['positions']}",
+            f"label_vec[0,{c['positions']}) = label",
+            *(f"{k} = {v}" for k, v in c["train"].items()), *metrics]
+
 
 def conf(c: dict) -> str:
+    if c.get("model_type") == "laguna":
+        return conf_laguna(c)
+    return conf_joyai(c)
+
+
+def conf_laguna(c: dict) -> str:
+    if not c["norm_topk_prob"]:
+        raise ValueError("norm_topk_prob is false: the moe kind has the "
+                         "renormalised gates only")
+    if c["gating"] != "per-head":
+        raise ValueError(f"gating {c['gating']!r}: the gqa kind has the "
+                         "per-head gate only")
+    if c["moe_router_logit_softcapping"] or c[
+            "moe_apply_router_weight_on_input"] or c["attention_bias"]:
+        raise ValueError("a capped router logit, the gate on the expert's "
+                         "input and attention biases are not written")
+    shared, rest = divmod(c["shared_expert_intermediate_size"],
+                          c["moe_intermediate_size"])
+    if rest:
+        raise ValueError("the shared expert is not a whole number of "
+                         "routed experts wide")
+    E, V, eps = c["hidden_size"], c["vocab_size"], c["rms_norm_eps"]
+    out = _HEADER + ["layer[0->e0] = embed:tok_embed",
+                     f"  nhidden = {E}", f"  vocab_size = {V}"]
+
+    def norm(src, dst, name):
+        out.extend([f"layer[{src}->{dst}] = rmsnorm:{name}",
+                    f"  eps = {eps}"])
+
+    def attn(src, dst, name, kind, heads):
+        window = {"full_attention": 0,
+                  "sliding_attention": c["sliding_window"]}[kind]
+        r = c["rope_parameters"][kind]
+        rotary = int(round(c["head_dim"] * r["partial_rotary_factor"]))
+        out.extend([
+            f"layer[{src}->{dst}] = gqa:{name}",
+            f"  nhead = {heads}",
+            f"  nkvhead = {c['num_key_value_heads']}",
+            f"  head_dim = {c['head_dim']}",
+            f"  window = {window}",
+            "  head_gate = 1",
+            f"  rotary_dim = {rotary}",
+            f"  rope_theta = {r['rope_theta']}",
+            f"  rope_type = {r['rope_type']}"])
+        if r["rope_type"] == "yarn":
+            out.extend([
+                f"  rope_factor = {r['factor']}",
+                "  rope_original_max_position = "
+                f"{r['original_max_position_embeddings']}",
+                f"  rope_beta_fast = {r['beta_fast']}",
+                f"  rope_beta_slow = {r['beta_slow']}",
+                f"  rope_attention_factor = {r['attention_factor']}"])
+        elif r["rope_type"] != "default":
+            raise ValueError(f"rope_type {r['rope_type']!r}")
+
+    x = "e0"
+    for i in range(c["num_hidden_layers"]):
+        p = f"b{i}"
+        norm(x, f"{p}n1", f"{p}_ln1")
+        attn(f"{p}n1", f"{p}a", f"{p}_attn", c["layer_types"][i],
+             c["num_attention_heads_per_layer"][i])
+        out.append(f"layer[{x},{p}a->{p}r1] = add:{p}_res1")
+        norm(f"{p}r1", f"{p}n2", f"{p}_ln2")
+        if i in c["mlp_only_layers"] or (i + 1) % c["decoder_sparse_step"]:
+            out.extend([f"layer[{p}n2->{p}f] = ffn:{p}_ffn",
+                        "  act = swiglu",
+                        f"  nhidden = {c['intermediate_size']}"])
+        else:
+            out.extend([
+                f"layer[{p}n2->{p}f] = moe:{p}_moe",
+                "  router = softmax_nodrop",
+                f"  num_expert = {c['num_experts_published']}",
+                f"  topk = {c['num_experts_per_tok']}",
+                f"  nhidden = {c['moe_intermediate_size']}",
+                f"  shared_expert = {shared}",
+                "  routed_scaling_factor = "
+                f"{c['moe_routed_scaling_factor']}",
+                f"  expert_first = {c['expert_first']}",
+                f"  expert_held = {c['num_experts']}"])
+        out.append(f"layer[{p}r1,{p}f->{p}r2] = add:{p}_res2")
+        x = f"{p}r2"
+    norm(x, "hN", "final_norm")
+    out.extend(["layer[hN->lg] = seqfc:lm_head", f"  nhidden = {V}",
+                "  no_bias = 1",
+                "layer[lg->lg] = lmloss:loss_main"])
+    out.extend(_tail(c, ["metric[label,lg] = seq_error",
+                         "metric[label,lg] = seq_logloss"]))
+    return "\n".join(out) + "\n"
+
+
+def conf_joyai(c: dict) -> str:
     for key in ("norm_topk_prob", "rope_interleave"):
         if not c[key]:
             raise ValueError(f"{key} is false: the moe and mla kinds have "
                              "the published form only")
     E, V = c["hidden_size"], c["vocab_size"]
-    out = ["# written by tools/gen_joyai_conf.py from the keys of the",
-           "# configuration file beside it; edit that, not this",
-           "netconfig=start",
-           "layer[0->e0] = embed:tok_embed",
-           f"  nhidden = {E}", f"  vocab_size = {V}"]
+    out = _HEADER + ["layer[0->e0] = embed:tok_embed",
+                     f"  nhidden = {E}", f"  vocab_size = {V}"]
 
     def attn(src, dst, name):
         out.extend([
@@ -99,14 +211,9 @@ def conf(c: dict) -> str:
         out.extend(["layer[mN->mlg] = share[lm_head]:mtp_head",
                     "layer[mlg->mlg] = lmloss:loss_mtp", "  shift = 1",
                     f"  grad_scale = {c['mtp_loss_weight']}"])
-    out.append("netconfig=end")
-    out.append("")
-    out.append(f"input_shape = 1,1,{c['positions']}")
-    out.append(f"label_vec[0,{c['positions']}) = label")
-    out.extend(f"{k} = {v}" for k, v in c["train"].items())
-    out.append("metric[label,lg] = seq_error")
-    out.append("metric[label,lg] = seq_logloss")
-    out.append("metric[label,mlg] = seq_logloss")
+    out.extend(_tail(c, ["metric[label,lg] = seq_error",
+                         "metric[label,lg] = seq_logloss",
+                         "metric[label,mlg] = seq_logloss"]))
     return "\n".join(out) + "\n"
 
 
