@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (attention_reference, chunked_attention,
-                             flash_attention, flash_tiles, rope,
+                             flash_attention, flash_tile_classes,
+                             flash_tiles, rope,
                              rope_frequencies, rope_interleaved,
                              rope_partial)
 from .base import Layer, Shape3, register_layer
@@ -323,6 +324,38 @@ def flash_block(positions: int, largest: int = 1024) -> int:
     return 0
 
 
+def publish_flash_tiles(layer: str, positions: int, block: int,
+                        window=None) -> None:
+    """What the causal flash kernel does for one head of ``layer`` at
+    square blocks of ``block`` (0: no kernel, nothing published), as
+    gauges set while the net is built, from shapes: the score tiles its
+    forward executes and the square's (``flash_tiles``), how many of the
+    executed an edge crosses (the only ones that build a mask, taken by
+    sub-tiles of ``cxxnet_attn_subtile``), and the score pairs that
+    reach the MXU over the pairs attended (``flash_tile_classes``)."""
+    if not block:
+        return
+    from ..telemetry.registry import get_registry
+    done, total = flash_tiles(positions, block, window)
+    cls = flash_tile_classes(positions, block, window)
+    for what, value, text in (
+            ("tiles_executed", done, "score tiles a head of the flash "
+             "kernel's forward executes, at the layer's blocks"),
+            ("tiles_total", total, "score tiles of a head's whole square, "
+             "at the layer's blocks"),
+            ("tiles_masked", cls["edge"], "executed score tiles the causal "
+             "diagonal or the band's trailing edge crosses: taken by "
+             "sub-tiles, the only ones a mask is built in"),
+            ("subtile", cls["subtile"], "side of the square sub-tiles an "
+             "edge tile is taken by"),
+            ("pairs_multiplied_over_attended",
+             cls["pairs_multiplied"] / cls["pairs_attended"], "score pairs "
+             "the flash kernel multiplies over the pairs the mask keeps")):
+        get_registry().gauge("cxxnet_attn_" + what, text,
+                             labels=("layer",)).labels(layer).set(
+                                 float(value))
+
+
 @register_layer("mla")
 class LatentAttentionLayer(Layer):
     """Multi-head latent attention (the DeepSeek-V2/V3 family's), causal,
@@ -386,6 +419,8 @@ class LatentAttentionLayer(Layer):
 
     def infer_shapes(self, in_shapes):
         self.check_n(in_shapes, 1, 1)
+        S = in_shapes[0][1]
+        publish_flash_tiles(self.name, S, flash_block(S))
         return [in_shapes[0]]
 
     def init_params(self, key, in_shapes):
@@ -557,15 +592,7 @@ class GroupedQueryAttentionLayer(Layer):
         self.check_n(in_shapes, 1, 1)
         S = in_shapes[0][1]
         blk = self._block(S)
-        if blk:
-            from ..telemetry.registry import get_registry
-            done, total = flash_tiles(S, blk, self.window or None)
-            for what, n in (("executed", done), ("total", total)):
-                get_registry().gauge(
-                    "cxxnet_attn_tiles_" + what, "gqa: score tiles a head "
-                    f"of the flash kernel's forward ({what}; of the square "
-                    "at the layer's blocks)",
-                    labels=("layer",)).labels(self.name).set(float(n))
+        publish_flash_tiles(self.name, S, blk, self.window or None)
         return [in_shapes[0]]
 
     def init_params(self, key, in_shapes):

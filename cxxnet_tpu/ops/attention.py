@@ -407,17 +407,19 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 # -- Pallas flash attention ---------------------------------------------------
 
-def _block_causal_mask(qi, kj, block_q, block_k, transposed=False,
-                       window=None):
-    """Causal keep-mask for one (q-block, k-block) tile — shared by the
-    forward and the backward kernel so the masking convention cannot
-    drift between them. ``transposed``: the (block_k, block_q) tile the
-    backward holds. ``window`` narrows it to the band."""
-    shape = (block_k, block_q) if transposed else (block_q, block_k)
-    qpos = qi * block_q + lax.broadcasted_iota(
+def _tile_mask(off, rows, cols, window=None, transposed=False):
+    """Causal keep-mask of a (rows, cols) tile of scores whose first
+    query stands ``off`` positions after its first key (the mask reads
+    only the distance between a query and a key) — shared by the forward
+    and the backward kernel so the masking convention cannot drift
+    between them. ``off`` is a Python int for a sub-tile of the kernels'
+    table and a traced scalar for a whole unsquare tile. ``transposed``:
+    the (cols, rows) tile the backward holds. ``window`` narrows it to
+    the band."""
+    shape = (cols, rows) if transposed else (rows, cols)
+    qpos = off + lax.broadcasted_iota(
         jnp.int32, shape, 1 if transposed else 0)
-    kpos = kj * block_k + lax.broadcasted_iota(
-        jnp.int32, shape, 0 if transposed else 1)
+    kpos = lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
     return _keep(qpos, kpos, window)
 
 
@@ -455,6 +457,168 @@ def flash_tiles(positions: int, block: int, window: Optional[int] = None):
     return done, n * n
 
 
+#: the smallest square an edge tile is taken apart into (PERF.md section
+#: 6, PR 33: on the chip sub-tiles of 128 lost to the whole tile at
+#: every shape swept, and 256 under blocks of 512 lost too)
+_MIN_SUBTILE = 256
+
+
+def _subtile(block: int) -> int:
+    """The side of the square sub-tiles the kernels take an EDGE tile by
+    (a tile the causal diagonal or a band's trailing edge crosses), from
+    the block alone: a quarter of the block where that is at least
+    ``_MIN_SUBTILE`` (and whole lane tiles), else the block — the tile
+    is then its own one sub-tile and is masked whole. Blocks of 1024 go
+    by 256s (10 of an edge tile's 16 sub-tiles multiplied); blocks of
+    512 and less are not taken apart."""
+    quarter = block // 4
+    if block % 4 == 0 and quarter >= _MIN_SUBTILE and quarter % 128 == 0:
+        return quarter
+    return block
+
+
+def _pairs_kept(off: int, side: int, window: Optional[int]) -> str:
+    """What the causal mask keeps of a ``side`` x ``side`` square of
+    scores whose first query stands ``off`` positions after its first
+    key: ``"all"``, ``"none"`` or ``"some"`` of its pairs. Python ints:
+    the kernels' tables are made of it while they are traced."""
+    nearest, farthest = off - (side - 1), off + (side - 1)   # of q - k
+    if farthest < 0 or (window is not None and nearest >= window):
+        return "none"
+    if nearest >= 0 and (window is None or farthest < window):
+        return "all"
+    return "some"
+
+
+def _edge_tiles(block: int, window: Optional[int]) -> dict:
+    """The kernels' table at square blocks: ``{offset: q sub-rows}`` for
+    every offset ``q-block's first position - k-block's`` at which an
+    edge crosses the tile (0, the diagonal; the one or two multiples of
+    the block at which a band's trailing edge falls). A q sub-row is
+    ``(a, parts)`` in units of :func:`_subtile`: the sub-tiles of row
+    ``a`` that keep a pair, in order, as ``(first k sub-column, how
+    many, mask)`` — neighbours that keep every pair are one part with
+    ``mask`` ``None``, a sub-tile an edge crosses is a part of its own
+    with its offset as ``mask``, for :func:`_tile_mask`; sub-tiles that
+    keep no pair are in no part, and a row of none is left out. An
+    executed tile at any other offset keeps every pair."""
+    sub = _subtile(block)
+    n = block // sub
+    table = {}
+    for off in range(0, block if window is None else window + block, block):
+        if _pairs_kept(off, block, window) != "some":
+            continue
+        rows = []
+        for a in range(n):
+            parts = []
+            for b in range(n):
+                e = off + (a - b) * sub
+                kept = _pairs_kept(e, sub, window)
+                if kept == "some":
+                    parts.append((b, 1, e))
+                elif kept == "all" and parts and parts[-1][2] is None \
+                        and sum(parts[-1][:2]) == b:
+                    parts[-1] = (parts[-1][0], parts[-1][1] + 1, None)
+                elif kept == "all":
+                    parts.append((b, 1, None))
+            if parts:
+                rows.append((a, tuple(parts)))
+        table[off] = tuple(rows)
+    return table
+
+
+def flash_tile_classes(positions: int, block: int,
+                       window: Optional[int] = None) -> dict:
+    """What one head of the causal kernels does at square blocks of
+    ``block``, forward (the backward does the same), by class of tile:
+
+    * ``interior``: executed tiles that keep every pair — multiplied
+      whole, no mask built;
+    * ``edge``: executed tiles an edge crosses, taken by sub-tiles of
+      ``subtile`` (:func:`_subtile`): ``sub_plain`` of those keep every
+      pair (no mask), ``sub_masked`` are crossed by an edge (the only
+      masks built), ``sub_skipped`` keep no pair and are not multiplied;
+    * ``pairs_multiplied``: score pairs that reach the MXU (interior
+      tiles whole, the edge tiles' sub-tiles but the skipped), against
+      ``pairs_attended``, the pairs the mask keeps.
+
+    ``interior + edge`` is :func:`flash_tiles`'s first count."""
+    n, sub = positions // block, _subtile(block)
+    table = _edge_tiles(block, window)
+    out = dict.fromkeys(("interior", "edge", "sub_plain", "sub_masked",
+                         "sub_skipped"), 0)
+    for i in range(n):
+        first = 0 if window is None else _band_k(i, block, block, window)[0]
+        for j in range(first, i + 1):
+            subs = table.get((i - j) * block)
+            if subs is None:
+                out["interior"] += 1
+                continue
+            parts = [part for _, parts in subs for part in parts]
+            masked = sum(1 for _, _, mask in parts if mask is not None)
+            plain = sum(n for _, n, mask in parts if mask is None)
+            out["edge"] += 1
+            out["sub_masked"] += masked
+            out["sub_plain"] += plain
+            out["sub_skipped"] += (block // sub) ** 2 - masked - plain
+    w = positions if window is None else min(window, positions)
+    out["subtile"] = sub
+    out["pairs_multiplied"] = out["interior"] * block * block + (
+        out["sub_plain"] + out["sub_masked"]) * sub * sub
+    out["pairs_attended"] = w * (w + 1) // 2 + (positions - w) * w
+    return out
+
+
+def _kept_parts(body, run, off, block_q, block_k, causal, window,
+                transposed=False):
+    """Both kernels' one way through a tile: ``body(rows, parts)`` runs
+    for each stretch ``rows`` of the q-block that keeps a pair, with the
+    ``parts`` of the k-block it keeps them in, in order, as ``(cols,
+    mask)`` — ``rows`` and ``cols`` ``pl.ds`` slices, ``mask`` ``None``
+    where every pair of the part is kept and else its keep-mask. ``run``
+    says the cell's tile is one the kernel executes (at or below the
+    diagonal, inside the band), ``off`` is ``q-block's first position -
+    k-block's``, both traced.
+
+    At square blocks the tile is classified by ``off``: an interior tile
+    is one part with no mask; an edge tile goes by the q sub-rows of
+    :func:`_edge_tiles`, whose sub-tiles without a kept pair are in no
+    part and of which only those an edge crosses carry a mask, of the
+    sub-tile's shape. Blocks that are not square are one masked part,
+    whatever ``off``."""
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+    if not causal:
+        body(whole_q, ((whole_k, None),))
+        return
+    if block_q != block_k:
+        @pl.when(run)
+        def _whole():
+            body(whole_q, ((whole_k, _tile_mask(
+                off, block_q, block_k, window, transposed)),))
+        return
+    sub = _subtile(block_q)
+    interior = off >= block_q - 1
+    if window is not None:
+        interior = jnp.logical_and(interior, off + block_q <= window)
+    if window is None or any(_pairs_kept(d, block_q, window) == "all"
+                             for d in range(0, window, block_q)):
+
+        @pl.when(jnp.logical_and(run, interior))
+        def _interior():
+            body(whole_q, ((whole_k, None),))
+
+    for edge_off, sub_rows in _edge_tiles(block_q, window).items():
+
+        @pl.when(jnp.logical_and(run, off == edge_off))
+        def _edge(sub_rows=sub_rows):
+            for a, parts in sub_rows:
+                body(pl.ds(a * sub, sub), tuple(
+                    (pl.ds(b * sub, n * sub),
+                     None if mask is None else _tile_mask(
+                         mask, sub, sub, window, transposed))
+                    for b, n, mask in parts))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       scale, causal, block_q, block_k, window=None):
@@ -468,6 +632,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     the q-block's band only (``_band_k``): the cell's k-block is the
     band's first plus the grid index, and cells past the band's last
     (the count is the widest band's) do nothing and fetch nothing new.
+    A causal tile goes by what it keeps (:func:`_kept_parts`): whole
+    and without a mask where every pair is kept, else by its q sub-rows,
+    each taking the parts it keeps together in one step of the online
+    softmax.
     """
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -484,40 +652,43 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def compute():
+    def attend(rows, parts):
         # operands go to the MXU in their own dtype (a bf16 product
         # upcast to float32 first costs several passes), sums in float32
-        q = q_ref[0]                              # (block_q, D)
-        kb = k_ref[0]                             # (block_k, D)
-        vb = v_ref[0]                             # (block_k, Dv)
-        s = lax.dot_general(q, kb, _NT,
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            mask = _block_causal_mask(qi, kb_idx, block_q, block_k,
-                                      window=window)
-            s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+        q = q_ref[0, rows, :]                     # (rows, D)
+        s = []
+        for cols, mask in parts:                  # kb: (cols, D)
+            sc = lax.dot_general(q, k_ref[0, cols, :], _NT,
+                                 preferred_element_type=jnp.float32) * scale
+            s.append(sc if mask is None else jnp.where(mask, sc, _NEG))
+        # ONE step of the online softmax over the row's parts together:
+        # the running statistics are (rows, 1) columns, and each pass
+        # over them costs as much as a pass over 128 columns of scores
+        m_prev = m_ref[rows, 0]
+        m_new = m_prev
+        for sc in s:
+            m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+        l_new = l_ref[rows, 0] * corr
+        acc = acc_ref[rows, :] * corr[:, None]
+        for (cols, mask), sc in zip(parts, s):
+            p = jnp.exp(sc - m_new[:, None])
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            l_new = l_new + jnp.sum(p, axis=-1)
+            vb = v_ref[0, cols, :]                # (cols, Dv)
+            acc = acc + jnp.dot(p.astype(vb.dtype), vb,
+                                preferred_element_type=jnp.float32)
+        l_ref[rows, 0] = l_new
+        acc_ref[rows, :] = acc
+        m_ref[rows, 0] = m_new
 
-    if window is not None:
-        @pl.when(kb_idx <= last)
-        def _in_band():
-            compute()
-    elif causal:
-        # skip tiles strictly above the causal diagonal
-        @pl.when(kj * block_k <= qi * block_q + block_q - 1)
-        def _guarded():
-            compute()
-    else:
-        compute()
+    # tiles past the band's last block, or strictly above the causal
+    # diagonal, are skipped; an executed tile goes by what it keeps
+    run = kb_idx <= last if window is not None else \
+        kj * block_k <= qi * block_q + block_q - 1
+    _kept_parts(attend, run, qi * block_q - kb_idx * block_k,
+                block_q, block_k, causal, window)
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -546,16 +717,21 @@ def _blocks(Sq, Sk, block_q, block_k, causal, window):
     return block_q, block_k
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   with_lse: bool = False, window=None):
-    B, Sq, H, D = q.shape
-    Sk, Dv = k.shape[1], v.shape[-1]
-    G = _group(q, k)
-    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+# The two calls of the kernels are jitted and inlined: a kernel's body
+# is then traced once per shape and set of options, not once per layer
+# and differentiation pass (every layer of a net calls with the same,
+# and a step traces a layer's forward twice); inlined, the call leaves
+# no trace of its own in the step, and run alone it is the one
+# computation a ``pallas_call`` always was.
+@functools.partial(jax.jit, static_argnums=tuple(range(3, 10)), inline=True)
+def _forward_call(qt, kt, vt, G, scale, causal, block_q, block_k, interpret,
+                  window):
+    """``flash_fwd`` on (B*H, S, D) rows, ``G`` consecutive query rows
+    reading one key/value row: ``(out, logsumexp (B*H, Sq, 1))``."""
+    (BH, Sq, D), Sk, Dv = qt.shape, kt.shape[1], vt.shape[-1]
     nq, nk = Sq // block_q, Sk // block_k
-    qt, kt, vt = _heads_flat(q), _heads_flat(k), _heads_flat(v)
     kern = functools.partial(
-        _flash_fwd_kernel, scale=_scale(q, scale), causal=causal,
+        _flash_fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, window=window)
     # G consecutive query heads (grid rows) read one key/value head
     kv_row = (lambda b: b) if G == 1 else (lambda b: b // G)
@@ -570,9 +746,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         def kv_map(b, i, j):
             first, last = _band_k(i, block_q, block_k, window)
             return kv_row(b), jnp.minimum(first + j, last), 0
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kern,
-        grid=(B * H, nq, nk),
+        grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), kv_map),
@@ -585,8 +761,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            out_struct((B * H, Sq, Dv), q.dtype, qt, kt, vt),
-            out_struct((B * H, Sq, 1), jnp.float32, qt, kt, vt),
+            out_struct((BH, Sq, Dv), qt.dtype, qt, kt, vt),
+            out_struct((BH, Sq, 1), jnp.float32, qt, kt, vt),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),  # acc
@@ -595,6 +771,16 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         ],
         interpret=interpret, name="flash_fwd",
     )(qt, kt, vt)
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   with_lse: bool = False, window=None):
+    B, Sq, H, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[-1]
+    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+    out, lse = _forward_call(
+        _heads_flat(q), _heads_flat(k), _heads_flat(v), _group(q, k),
+        _scale(q, scale), causal, block_q, block_k, interpret, window)
     out = out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
     return (out, lse) if with_lse else out
 
@@ -647,8 +833,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q-block's rows and written back once a head. With a ``window`` the
     innermost dimension runs over the q-blocks whose band touches the
     k-block only (``_band_q``), as the forward's runs over a band's
-    k-blocks. dK and dV are a QUERY head's: where heads are grouped the
-    caller sums a group's."""
+    k-blocks. A causal tile goes by what it keeps, as the forward's
+    (:func:`_kept_parts`): each part rebuilds its own P and adds to its
+    own rows of dV, dK and dQ. dK and dV are a QUERY head's: where heads
+    are grouped the caller sums a group's."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -668,41 +856,40 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def compute():
-        q = q_ref[0]                              # (block_q, D)
-        kb = k_ref[0]                             # (block_k, D)
-        vb = v_ref[0]                             # (block_k, Dv)
-        do = do_ref[0]                            # (block_q, Dv)
+    def grads(rows, parts):
+        for cols, mask in parts:
+            part_grads(rows, cols, mask)
+
+    def part_grads(rows, cols, mask):
+        q = q_ref[0, rows, :]                     # (rows, D)
+        kb = k_ref[0, cols, :]                    # (cols, D)
+        vb = v_ref[0, cols, :]                    # (cols, Dv)
+        do = do_ref[0, rows, :]                   # (rows, Dv)
         st = lax.dot_general(kb, q, _NT,
                              preferred_element_type=jnp.float32) * scale
-        pt = jnp.exp(st - lse_ref[0])             # lse: (1, block_q)
-        if causal:
+        pt = jnp.exp(st - lse_ref[0, :, rows])    # lse: (1, rows)
+        if mask is not None:
             # explicit zeroing: fully-masked rows carry a sentinel lse,
             # where exp(s - lse) would NOT vanish on its own
-            pt = jnp.where(_block_causal_mask(qb_idx, kj, block_q, block_k,
-                                              transposed=True,
-                                              window=window), pt, 0.0)
-        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
+            pt = jnp.where(mask, pt, 0.0)
+        dv_acc[cols, :] += jnp.dot(pt.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
         dpt = lax.dot_general(vb, do, _NT,
                               preferred_element_type=jnp.float32)
-        dst = (pt * (dpt - delta_ref[0]) * scale).astype(q.dtype)
-        dk_acc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
-        rows = pl.ds(pl.multiple_of(qb_idx * block_q, block_q), block_q)
-        dq_ref[0, rows, :] += lax.dot_general(
+        dst = (pt * (dpt - delta_ref[0, :, rows]) * scale).astype(q.dtype)
+        dk_acc[cols, :] += jnp.dot(dst, q,
+                                   preferred_element_type=jnp.float32)
+        at = pl.ds(pl.multiple_of(qb_idx * block_q + rows.start, rows.size),
+                   rows.size)
+        dq_ref[0, at, :] += lax.dot_general(
             dst, kb, _TN, preferred_element_type=jnp.float32)
 
-    if window is not None:
-        @pl.when(qb_idx <= last)
-        def _in_band():
-            compute()
-    elif causal:
-        # only q blocks at or below the diagonal contribute to this k tile
-        @pl.when(qi * block_q + block_q - 1 >= kj * block_k)
-        def _guarded():
-            compute()
-    else:
-        compute()
+    # only q blocks inside the k-block's band, or at or below the
+    # diagonal, contribute to this k tile
+    run = qb_idx <= last if window is not None else \
+        qi * block_q + block_q - 1 >= kj * block_k
+    _kept_parts(grads, run, qb_idx * block_q - kj * block_k,
+                block_q, block_k, causal, window, transposed=True)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -716,41 +903,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _BWD_VMEM_LIMIT = 96 * 2 ** 20
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret, window=None):
-    """Fused Pallas backward, ONE kernel (``flash_bwd``): every executed
-    tile rebuilds its softmax once from the forward's logsumexp and
-    feeds dq, dk and dv from it. ``out`` is (B, Sq, H, Dv) as the
-    forward returned it, ``lse`` the lane-dense (B*H, Sq) the forward
-    rule holds; dq leaves the kernel in float32 (it is summed in place
-    across the k-blocks) and is rounded on the way back to (B, Sq, H, D).
-    Where G query heads share a key/value head the kernel's dk and dv
-    are a query head's, in float32, and the group's are summed here
-    (one pass over them, under a millisecond a layer at the cell's
-    shapes; PERF.md section 6, PR 32).
-    """
-    B, Sq, H, D = q.shape
-    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
-    G = _group(q, k)
-    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 13)), inline=True)
+def _backward_call(qt, kt, vt, dot, lse, delta, G, scale, causal, block_q,
+                   block_k, interpret, window):
+    """``flash_bwd`` on (B*H, S, D) rows (``lse`` and ``delta`` lane-dense,
+    (B*H, 1, Sq)): ``(dq in float32, dk, dv)``, dk and dv a QUERY row's
+    — in float32 where ``G`` of them share a key/value row."""
+    (BH, Sq, D), Sk, Dv = qt.shape, kt.shape[1], vt.shape[-1]
     nq, nk = Sq // block_q, Sk // block_k
-    # a head's float32 dq row (lanes padded to 128s, the output's two
-    # pipeline buffers) beside the tile's float32 intermediates
-    lanes = -(-D // 128) * 128
-    need = 2 * Sq * lanes * 4 + 8 * block_q * block_k * 4
-    if need > _BWD_VMEM_LIMIT:
-        raise ValueError(
-            f"flash_attention backward: a head's float32 dq row "
-            f"({Sq} x {lanes} lanes, twice) and its ({block_q},{block_k}) "
-            f"tiles need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} "
-            f"the kernel may use: shorten the sequence or shard it")
-    sc = _scale(q, scale)
-    qt, kt, vt, dot = (_heads_flat(a) for a in (q, k, v, g))
-    # delta_i = rowsum(dO_i * O_i): elementwise where both already lie,
-    # then one small transpose to the kernel's lane-dense rows
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Sq)
-
     # grid (bh, k-block j, q-block i), the q-blocks innermost
     kv_row = (lambda b: b) if G == 1 else (lambda b: b // G)
     if window is None:
@@ -776,23 +936,60 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dk_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
     dv_spec = pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0))
     part = (lambda a: a.dtype) if G == 1 else (lambda a: jnp.float32)
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, scale=sc, causal=causal,
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window),
-        grid=(B * H, nk, nq),
+        grid=(BH, nk, nq),
         in_specs=[q_spec, k_spec, v_spec, o_spec, r_spec, r_spec],
         out_specs=[dq_spec, dk_spec, dv_spec],
-        out_shape=[out_struct((B * H, Sq, D), jnp.float32, qt, kt, vt, dot),
-                   out_struct((B * H, Sk, D), part(k), qt, kt, vt, dot),
-                   out_struct((B * H, Sk, Dv), part(v), qt, kt, vt, dot)],
+        out_shape=[out_struct((BH, Sq, D), jnp.float32, qt, kt, vt, dot),
+                   out_struct((BH, Sk, D), part(kt), qt, kt, vt, dot),
+                   out_struct((BH, Sk, Dv), part(vt), qt, kt, vt, dot)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret, name="flash_bwd",
-    )(qt, kt, vt, dot, lse.reshape(B * H, 1, Sq), delta)
+    )(qt, kt, vt, dot, lse, delta)
 
+
+def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
+                    interpret, window=None):
+    """Fused Pallas backward, ONE kernel (``flash_bwd``): every executed
+    tile rebuilds its softmax once from the forward's logsumexp and
+    feeds dq, dk and dv from it. ``out`` is (B, Sq, H, Dv) as the
+    forward returned it, ``lse`` the lane-dense (B*H, Sq) the forward
+    rule holds; dq leaves the kernel in float32 (it is summed in place
+    across the k-blocks) and is rounded on the way back to (B, Sq, H, D).
+    Where G query heads share a key/value head the kernel's dk and dv
+    are a query head's, in float32, and the group's are summed here
+    (one pass over them, under a millisecond a layer at the cell's
+    shapes; PERF.md section 6, PR 32).
+    """
+    B, Sq, H, D = q.shape
+    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    G = _group(q, k)
+    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
+    # a head's float32 dq row (lanes padded to 128s, the output's two
+    # pipeline buffers) beside the tile's float32 intermediates
+    lanes = -(-D // 128) * 128
+    need = 2 * Sq * lanes * 4 + 8 * block_q * block_k * 4
+    if need > _BWD_VMEM_LIMIT:
+        raise ValueError(
+            f"flash_attention backward: a head's float32 dq row "
+            f"({Sq} x {lanes} lanes, twice) and its ({block_q},{block_k}) "
+            f"tiles need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} "
+            f"the kernel may use: shorten the sequence or shard it")
+    qt, kt, vt, dot = (_heads_flat(a) for a in (q, k, v, g))
+    # delta_i = rowsum(dO_i * O_i): elementwise where both already lie,
+    # then one small transpose to the kernel's lane-dense rows
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Sq)
+
+    dq, dk, dv = _backward_call(
+        qt, kt, vt, dot, lse.reshape(B * H, 1, Sq), delta, G,
+        _scale(q, scale), causal, block_q, block_k, interpret, window)
     unflat = lambda a, S: a.reshape(B, -1, S, a.shape[-1]).transpose(
         0, 2, 1, 3)
     if G > 1:

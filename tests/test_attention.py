@@ -125,6 +125,150 @@ def test_flash_backward_cases(sq, sk, d, dv, causal, block_q, block_k,
         np.testing.assert_allclose(f32(a), b, atol=atol)
 
 
+def _held_to_the_reference(sq, h, hkv, d, dv, window, block_q, block_k):
+    """Output, dq, dk and dv of the kernels (interpreter) against
+    autodiff of ``attention_reference``, float32, a random cotangent."""
+    rng = np.random.RandomState(sq + d + (window or 0))
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+    q, k, v, g = (mk(1, sq, h, d), mk(1, sq, hkv, d), mk(1, sq, hkv, dv),
+                  mk(1, sq, h, dv))
+    out_r, vjp_r = jax.vjp(lambda a, b, c: attention_reference(
+        a, b, c, causal=True, window=window), q, k, v)
+    out_f, vjp_f = jax.vjp(lambda a, b, c: flash_attention(
+        a, b, c, True, None, block_q, block_k, True, window), q, k, v)
+    np.testing.assert_allclose(out_f, out_r, atol=5e-5)
+    for a, b in zip(vjp_f(g), vjp_r(g)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("h,hkv,d,dv,window", [
+    (2, 2, 16, 16, None),       # the diagonal's tiles alone have an edge
+    (2, 2, 16, 16, 1024),       # a band of one block: every tile an edge tile
+    (2, 2, 16, 16, 1200),       # the trailing edge falls at two offsets
+    (2, 2, 16, 16, 2400),       # wider than two blocks: interior tiles too
+    (2, 2, 16, 16, 100),        # narrower than a sub-tile
+    (4, 2, 16, 16, None),       # two query heads a key/value head
+    (6, 2, 16, 16, 1024),
+    (2, 2, 24, 16, None),       # v narrower than q.k
+    (2, 2, 24, 16, 1200),
+], ids=["causal", "window_is_block", "window_1200", "window_2400",
+        "window_100", "grouped", "grouped_window", "v_narrower",
+        "v_narrower_window"])
+def test_flash_edge_tiles_go_by_sub_tiles(h, hkv, d, dv, window):
+    """3072 positions at square blocks of 1024, the smallest block that
+    is taken apart, three blocks a row: the interior tiles run without a
+    mask, and an edge tile is multiplied by its sub-tiles of 256 — some
+    left out, some without a mask, some masked at the sub-tile's
+    shape."""
+    from cxxnet_tpu.ops.attention import _edge_tiles, flash_tile_classes
+    cls = flash_tile_classes(3072, 1024, window)
+    assert cls["subtile"] == 256 and cls["sub_skipped"] > 0
+    assert cls["pairs_multiplied"] < (cls["interior"] + cls["edge"]) * 1024 ** 2
+    assert 0 in _edge_tiles(1024, window)
+    _held_to_the_reference(3072, h, hkv, d, dv, window, 1024, 1024)
+
+
+@pytest.mark.parametrize("block_q,block_k,window", [
+    (1024, 512, None), (512, 1024, None), (1024, 512, 1200),
+    (512, 1024, 1200)])
+def test_flash_unsquare_blocks_take_the_whole_tile_body(
+        monkeypatch, block_q, block_k, window):
+    """Blocks that are not square never consult the table of sub-tiles:
+    every executed tile is masked whole, as before, and agrees."""
+    from cxxnet_tpu.ops import attention
+
+    def no_table(*a):
+        raise AssertionError("an unsquare tile was classified")
+    monkeypatch.setattr(attention, "_edge_tiles", no_table)
+    _held_to_the_reference(2048, 2, 2, 16, 16, window, block_q, block_k)
+
+
+@pytest.mark.parametrize("positions,block,window", [
+    (3072, 1024, None), (3072, 1024, 1024), (3072, 1024, 1200),
+    (3072, 1024, 2400), (3072, 1024, 100), (3072, 1024, 1),
+    (3072, 1024, 3072), (3072, 1024, 5000), (4096, 2048, 700),
+    (2048, 512, 512), (1024, 256, 300), (1024, 128, 100),
+    (1024, 128, None), (96, 32, 20), (48, 16, 16), (1024, 1024, None),
+    (1024, 1024, 200)],
+    ids=lambda v: str(v))
+def test_flash_tile_classes_against_the_mask_itself(positions, block, window):
+    """The counting function against brute force: ``_keep`` over the
+    whole square, cut into the kernels' tiles and sub-tiles. A sub-tile
+    counted as skipped holds no kept pair, one counted as mask-free
+    holds only kept pairs, and the multiplied pairs follow."""
+    from cxxnet_tpu.ops.attention import (_edge_tiles, _keep, _subtile,
+                                          flash_tile_classes, flash_tiles)
+    pos = np.arange(positions)
+    keep = np.asarray(_keep(pos[:, None], pos[None, :], window))
+    sub, table = _subtile(block), _edge_tiles(block, window)
+    assert block % sub == 0 and (block < 1024) == (sub == block)
+    want = dict.fromkeys(("interior", "edge", "sub_plain", "sub_masked",
+                          "sub_skipped"), 0)
+    for i in range(0, positions, block):
+        for j in range(0, positions, block):
+            tile = keep[i:i + block, j:j + block]
+            if not tile.any():
+                continue                    # never executed
+            if tile.all():
+                want["interior"] += 1
+                assert i - j not in table
+                continue
+            want["edge"] += 1
+            listed = {(a, b): mask for a, parts in table[i - j]
+                      for first, n, mask in parts
+                      for b in range(first, first + n)}
+            assert all(mask is None or n == 1 for _, parts in table[i - j]
+                       for _, n, mask in parts)
+            for a in range(block // sub):
+                for b in range(block // sub):
+                    part = tile[a * sub:(a + 1) * sub, b * sub:(b + 1) * sub]
+                    kind = "sub_plain" if part.all() else \
+                        "sub_masked" if part.any() else "sub_skipped"
+                    want[kind] += 1
+                    # the kernels' own table says the same of this sub-tile
+                    assert ((a, b) in listed) == bool(part.any())
+                    if part.any():
+                        assert (listed[(a, b)] is None) == bool(part.all())
+                        assert part.all() or \
+                            listed[(a, b)] == i - j + (a - b) * sub
+    got = flash_tile_classes(positions, block, window)
+    assert {k: got[k] for k in want} == want
+    assert got["subtile"] == sub
+    assert want["interior"] + want["edge"] == \
+        flash_tiles(positions, block, window)[0]
+    assert got["pairs_attended"] == int(keep.sum())
+    assert got["pairs_multiplied"] == want["interior"] * block ** 2 + (
+        want["sub_plain"] + want["sub_masked"]) * sub ** 2
+    assert got["pairs_multiplied"] >= got["pairs_attended"]
+
+
+def test_flash_tile_classes_at_the_cells_shapes():
+    """8192 positions: a causal head at blocks of 1024 and a band of 512
+    at blocks of 512, as the benchmark's two sequence cells run them."""
+    from cxxnet_tpu.ops.attention import flash_tile_classes, flash_tiles
+    assert flash_tiles(8192, 1024) == (36, 64)
+    assert flash_tiles(8192, 512, 512) == (31, 256)
+    full = flash_tile_classes(8192, 1024)
+    assert (full["interior"], full["edge"]) == (28, 8)
+    assert full["pairs_attended"] == 8192 * 8193 // 2
+    band = flash_tile_classes(8192, 512, 512)
+    assert (band["interior"], band["edge"]) == (0, 31)
+    assert band["pairs_attended"] == 512 * 513 // 2 + (8192 - 512) * 512
+    # blocks of 1024 go by sub-tiles of 256: 10 of a diagonal tile's 16
+    assert (full["subtile"], full["sub_plain"], full["sub_masked"],
+            full["sub_skipped"]) == (256, 48, 32, 48)
+    assert full["pairs_multiplied"] == (28 * 16 + 80) * 256 ** 2
+    assert full["pairs_multiplied"] / full["pairs_attended"] == \
+        pytest.approx(1.0311, abs=1e-4)          # 1.1249 with the tiles whole
+    # blocks of 512 are not taken apart: every tile masked whole
+    assert (band["subtile"], band["sub_plain"], band["sub_masked"],
+            band["sub_skipped"]) == (512, 0, 31, 0)
+    assert band["pairs_multiplied"] == 31 * 512 ** 2
+    assert band["pairs_multiplied"] / band["pairs_attended"] == \
+        pytest.approx(1.9999, abs=1e-4)
+
+
 def test_flash_residuals_outlive_checkpoint():
     """Under ``jax.checkpoint`` with the model's policy the kernel's
     output and logsumexp are kept, so the gradient holds the forward

@@ -319,18 +319,49 @@ def test_latent_attention_flash_compiles_at_the_cell_s_widths(one_chip):
 @pytest.mark.parametrize("heads, window, block", [
     pytest.param(24, None, 1024, id="full"),
     pytest.param(36, 512, 512, id="window"),
-    pytest.param(36, 512, 256, id="window256")])
+    pytest.param(36, 512, 256, id="window256"),
+    pytest.param(36, 768, 512, id="window768")])
 def test_grouped_query_flash_compiles_at_the_cell_s_widths(
         one_chip, heads, window, block):
     """One row of 8192 positions, heads of 128, 4 key/value heads read
     by 24 (a full layer) or 36 (a window layer, band 512) query heads:
     the shapes ``gqa`` hands the kernel in ``laguna_ep32_train_8k``. The
     index maps divide a grid row by the group and, with a window, clamp
-    a band's block: a map the chip's compiler refuses fails here."""
+    a band's block: a map the chip's compiler refuses fails here. A band
+    of 768 at blocks of 512 has its trailing edge at two offsets: four
+    bodies a kernel, the most the tile classes give."""
     fn = lambda q, k, v: flash_attention(q, k, v, True, None, block, block,
                                          False, window)
     text = _compile(fn, one_chip, [((1, 8192, heads, 128), BF16)]
                     + [((1, 8192, 4, 128), BF16)] * 2,
+                    grad_argnums=(0, 1, 2))
+    assert _kernels(text) == 2          # forward, backward
+
+
+@pytest.mark.parametrize("sub", (128, 256, 512))
+@pytest.mark.parametrize("heads, kv_heads, d, window, block", [
+    pytest.param(32, 32, 192, None, 1024, id="mla"),
+    pytest.param(36, 4, 128, 512, 512, id="gqa_window")])
+def test_the_edge_tiles_compile_at_every_sub_tile_of_the_sweep(
+        one_chip, monkeypatch, heads, kv_heads, d, window, block, sub):
+    """The two shapes the sequence cells run, forward and backward, with
+    an edge tile taken by sub-tiles of 128, 256 and 512 (PERF.md section
+    6, PR 33, has the chip's sweep; ``_subtile`` holds the rule it set):
+    static slices of the resident q, k, v and cotangent tiles, of the
+    accumulators' rows and of the logsumexp's lanes, which the chip's
+    compiler refuses where they do not align. At the shapes run the
+    split engages: some sub-tiles are left out, some carry no mask."""
+    from cxxnet_tpu.ops import attention
+    monkeypatch.setattr(attention, "_subtile", lambda b: min(sub, b))
+    cls = attention.flash_tile_classes(8192, block, window)
+    assert cls["subtile"] == min(sub, block)
+    if sub < block:
+        assert cls["sub_skipped"] and cls["sub_masked"]
+    fn = lambda q, k, v: flash_attention(q, k, v, True, None, block, block,
+                                         False, window)
+    text = _compile(fn, one_chip, [((1, 8192, heads, d), BF16),
+                                   ((1, 8192, kv_heads, d), BF16),
+                                   ((1, 8192, kv_heads, 128), BF16)],
                     grad_argnums=(0, 1, 2))
     assert _kernels(text) == 2          # forward, backward
 

@@ -128,6 +128,32 @@ def test_mla_forward_and_gradients_are_the_references(impl):
     tree_close(g1, g2, 5e-5)
 
 
+def test_mla_publishes_the_kernels_tile_gauges():
+    """As the net is built, from shapes: 2048 positions take blocks of
+    1024 — three executed tiles of four, the diagonal's two taken by
+    sub-tiles of 256 (10 of 16 each), the one below them whole."""
+    from cxxnet_tpu.ops.attention import flash_tile_classes
+    from cxxnet_tpu.telemetry.registry import get_registry
+    layer = make("mla", MLA_CFG)
+    layer.name = "mla_gauges"
+    assert layer.infer_shapes([(E, 2048, 1)]) == [(E, 2048, 1)]
+    read = lambda what: dict(
+        (tuple(labels), child.value) for labels, child in
+        get_registry().get("cxxnet_attn_" + what).samples())[
+            ("mla_gauges",)]
+    cls = flash_tile_classes(2048, 1024)
+    assert (read("tiles_executed"), read("tiles_total")) == (3, 4)
+    assert read("tiles_masked") == cls["edge"] == 2
+    assert read("subtile") == cls["subtile"] == 256
+    assert (cls["sub_plain"], cls["sub_masked"], cls["sub_skipped"]) == (
+        12, 8, 12)
+    assert read("pairs_multiplied_over_attended") == pytest.approx(
+        cls["pairs_multiplied"] / cls["pairs_attended"])
+    assert cls["pairs_multiplied"] == 1024 ** 2 + (
+        cls["sub_plain"] + cls["sub_masked"]) * cls["subtile"] ** 2
+    assert 1.0 < read("pairs_multiplied_over_attended") < 3 / 2
+
+
 def test_mla_rotary_is_on_interleaved_pairs():
     """Against the reference's own two forms: the program agrees with
     the interleaved one, and the halves form is another function."""

@@ -24,8 +24,8 @@ import pytest
 
 from cxxnet_tpu.graph import LayerSpec
 from cxxnet_tpu.layers import ApplyCtx, create_layer
-from cxxnet_tpu.ops.attention import (flash_tiles, rope, rope_frequencies,
-                                      rope_partial)
+from cxxnet_tpu.ops.attention import (flash_tile_classes, flash_tiles, rope,
+                                      rope_frequencies, rope_partial)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOY = os.path.join(ROOT, "tests", "benchmarks", "data", "laguna_toy")
@@ -223,6 +223,23 @@ def test_the_selection_log_and_the_tile_gauges():
              for labels in [(what,) + tuple(labels)]}
     assert tiles[("executed", "a")] == 6 and tiles[("total", "a")] == 9
     assert tiles[("executed", "b")] == 5 and tiles[("total", "b")] == 9
+    # a block of 128 is its own one sub-tile: the diagonal's three tiles
+    # are masked whole, the three below it run without a mask; under the
+    # window every executed tile has an edge. The pairs multiplied are
+    # then the executed tiles', over the pairs the mask keeps
+    read = lambda what, layer: dict(
+        (tuple(labels), child.value) for labels, child in
+        get_registry().get("cxxnet_attn_" + what).samples())[(layer,)]
+    assert read("tiles_masked", "a") == 3 and read("tiles_masked", "b") == 5
+    assert read("subtile", "a") == read("subtile", "b") == 128
+    assert read("pairs_multiplied_over_attended", "a") == pytest.approx(
+        6 * 128 ** 2 / (384 * 385 // 2))
+    assert read("pairs_multiplied_over_attended", "b") == pytest.approx(
+        5 * 128 ** 2 / (7 * 8 // 2 + (384 - 7) * 7))
+    for layer, window in (("a", None), ("b", 7)):
+        cls = flash_tile_classes(384, 128, window)
+        assert read("tiles_masked", layer) == cls["edge"]
+        assert read("tiles_executed", layer) == cls["interior"] + cls["edge"]
     assert flash_tiles(8192, 1024) == (36, 64)
     assert flash_tiles(8192, 512, 512) == (31, 256)
 
