@@ -22,12 +22,17 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.attention import (attention_reference, chunked_attention,
-                             flash_attention, flash_tile_classes,
-                             flash_tiles, rope,
+from ..ops.attention import (SELECT_RESIDUAL, attention_reference,
+                             chunked_attention, flash_attention,
+                             flash_attention_select, flash_tile_classes,
+                             flash_tiles, head_sum_probs,
+                             head_sum_probs_reference, index_scores,
+                             index_scores_reference, rope,
                              rope_frequencies, rope_interleaved,
-                             rope_partial)
+                             rope_partial, rope_sections, select_tiles,
+                             select_topk)
 from .base import Layer, Shape3, register_layer
 from .loss import LossLayerBase
 
@@ -489,15 +494,28 @@ class LatentAttentionLayer(Layer):
         return [_unseq(y)], state
 
 
+#: the order of a sparse ``gqa`` layer's ``dsa_stats`` vector: the pairs
+#: its selection keeps (all rows), the indexer's loss ``L_I`` (unweighted),
+#: and the score tiles a head's kernels executed and the square's, at the
+#: layer's blocks, a row (0 where no kernel block divides the positions)
+DSA_STATS = ("selected_pairs", "index_loss", "tiles_executed",
+             "tiles_total")
+
+
 @register_layer("gqa")
 class GroupedQueryAttentionLayer(Layer):
     """Causal self-attention with grouped key/value heads, a head size
     of its own, an optional window, rotary on part of the head with a
-    plain or YaRN table, and a per-head output gate, on a sequence node
-    (E,S,1) -> (E,S,1). With x a position's vector, no bias anywhere:
+    plain or YaRN table or by three position rows, an optional RMS norm
+    on each head's q and k, a per-head output gate, and an optional
+    learned indexer that picks the keys a query attends, on a sequence
+    node (E,S,1) -> (E,S,1). With x a position's vector, no bias
+    anywhere:
 
       q = x W_q (``nhead`` heads of ``head_dim``); k = x W_k, v = x W_v
-      (``nkvhead`` heads); rotary on q and k (below)
+      (``nkvhead`` heads); ``qk_norm = 1``: each head's q and k through
+      an RMS norm over its ``head_dim`` features (``eps``), one gain
+      vector for q and one for k; rotary on q and k (below)
       query head h attends key/value head h // (nhead / nkvhead)
       scores = q.k / sqrt(head_dim), causal; ``window = W`` lets
       position i see j with i - W < j <= i (0: every j <= i)
@@ -517,29 +535,60 @@ class GroupedQueryAttentionLayer(Layer):
     (``ops.attention.rope_frequencies``) from ``rope_factor``,
     ``rope_original_max_position``, ``rope_beta_fast``,
     ``rope_beta_slow``, and cos and sin times ``rope_attention_factor``.
+    ``mrope_section = a,b,c`` (the pairs of the rotated part, in three
+    contiguous sections) lets the layer take a SECOND input node, (3,S,1):
+    a token's temporal, height and width position, and pair i turns by
+    the row of its section (``ops.attention.rope_sections``); with no
+    second input the three rows are the token's index, which is text and
+    the plain rotary.
+
+    The indexer (``index_topk = K > 0``; DeepSeek sparse attention,
+    arXiv:2512.02556 section 2.1, over grouped-query heads), on the
+    layer's input DETACHED: ``qI = x W_qI`` (``index_heads`` heads of
+    ``index_head_dim``), ``kI = LayerNorm(x W_kI)`` (one head), both
+    rotated whole at ``rope_theta`` by the temporal position; ``w = x W_w
+    / sqrt(index_heads index_head_dim)``; ``I[t,s] = sum_j w[t,j]
+    relu(qI[t,j] . kI[s])`` in float32. Query t attends the K keys s <= t
+    of largest I (all of them while t < K; a tie to the lower s), chosen
+    exactly (``ops.attention.select_topk``), the same set forward, in the
+    rebuilt forward under ``remat`` (the set is kept, not made again) and
+    backward. The indexer learns from the main attention alone: with
+    ``p[t,s] = sum_h a_h[t,s] / nhead`` detached, ``L_I = mean_t KL(p ||
+    softmax over the selected set of I)``, and ``index_loss_coef L_I``
+    joins the objective through the state's ``_aux_loss`` (0: no loss is
+    built and the indexer's leaves get no gradient). Nothing but the
+    indexer's leaves gets a gradient from it. The state's ``dsa_stats``
+    (``DSA_STATS``) count the pairs selected, ``L_I``, and the score
+    tiles the kernels executed of a head's square.
 
     ``attn_impl`` in {auto, ref, flash}, as ``mla`` has it: ``ref`` runs
     on XLA's dots (the tests' oracle), ``flash`` is the Pallas kernel —
-    k and v are read as they stand by the query heads of a group, and
-    with a window the tiles outside the band are neither computed nor
-    fetched — at square blocks of the largest of 1024 (a full layer;
-    PERF.md section 6, PR 28/29) or 512 (a window layer; section 6,
-    PR 32), 256, 128 that divides the positions. ``auto`` is the kernel
-    on a TPU where such a block exists, else ``ref``. Under
-    ``remat = 1`` the model keeps the kernel's output and logsumexp and
-    rebuilds only ``gqa.proj``."""
+    k and v are read as they stand by the query heads of a group, with a
+    window the tiles outside the band are neither computed nor fetched,
+    and under a selection (``flash_attention_select``) neither are the
+    tiles without a selected pair — at square blocks of the largest of
+    1024 (a full or sparse layer; PERF.md section 6, PR 28/29) or 512 (a
+    window layer; section 6, PR 32), 256, 128 that divides the
+    positions. ``auto`` is the kernel on a TPU where such a block
+    exists, else ``ref``. Under ``remat = 1`` the model keeps the
+    kernel's output and logsumexp, and a selection, and rebuilds the
+    rest."""
     has_params = True
 
     _INT = ("nhead", "nkvhead", "head_dim", "window", "head_gate",
-            "rotary_dim")
+            "rotary_dim", "qk_norm", "index_heads", "index_head_dim",
+            "index_topk")
     _FLOAT = ("rope_theta", "rope_factor", "rope_original_max_position",
-              "rope_beta_fast", "rope_beta_slow", "rope_attention_factor")
+              "rope_beta_fast", "rope_beta_slow", "rope_attention_factor",
+              "eps", "index_loss_coef")
 
     def set_param(self, name, val):
         if name in self._INT:
             setattr(self, name, int(val))
         elif name in self._FLOAT:
             setattr(self, name, float(val))
+        elif name == "mrope_section":
+            self.mrope_section = tuple(int(v) for v in val.split(","))
         elif name == "rope_type":
             if val not in ("default", "yarn"):
                 raise ValueError(f"unknown gqa rope_type {val!r}")
@@ -552,6 +601,11 @@ class GroupedQueryAttentionLayer(Layer):
     def __init__(self, spec, global_cfg):
         self.nhead = self.nkvhead = self.head_dim = 0
         self.window = self.head_gate = self.rotary_dim = 0
+        self.qk_norm = 0
+        self.eps = 1e-6
+        self.mrope_section = ()
+        self.index_heads = self.index_head_dim = self.index_topk = 0
+        self.index_loss_coef = 1.0
         self.attn_impl = "auto"
         self.rope_type = "default"
         self.rope_theta = 10000.0
@@ -582,6 +636,20 @@ class GroupedQueryAttentionLayer(Layer):
                     self.rope_attention_factor)
         self.rope_freqs, self.rope_mscale = rope_frequencies(
             self.rotary_dim, self.rope_theta, yarn)
+        if self.mrope_section and (
+                len(self.mrope_section) != 3 or yarn is not None
+                or 2 * sum(self.mrope_section) != self.rotary_dim):
+            raise ValueError(f"gqa {spec.name!r}: mrope_section is three "
+                             "counts of pairs that make up rotary_dim, "
+                             "under the plain table")
+        if self.index_topk:
+            if self.window or self.index_heads <= 0 \
+                    or self.index_head_dim <= 0 or self.index_head_dim % 2:
+                raise ValueError(
+                    f"gqa {spec.name!r}: an indexer needs index_heads and "
+                    "an even index_head_dim, and no window")
+            self.index_freqs, _ = rope_frequencies(self.index_head_dim,
+                                                   self.rope_theta)
 
     def _block(self, positions):
         """The kernel's square block at ``positions``, 0 where none
@@ -589,10 +657,19 @@ class GroupedQueryAttentionLayer(Layer):
         return flash_block(positions, 512 if self.window else 1024)
 
     def infer_shapes(self, in_shapes):
-        self.check_n(in_shapes, 1, 1)
+        self.check_n(in_shapes, len(in_shapes), 1)
         S = in_shapes[0][1]
-        blk = self._block(S)
-        publish_flash_tiles(self.name, S, blk, self.window or None)
+        if len(in_shapes) > 1 and (
+                not self.mrope_section or len(in_shapes) > 2
+                or tuple(in_shapes[1]) != (3, S, 1)):
+            raise ValueError(
+                f"gqa {self.name!r}: a second input is the positions' "
+                f"three rows, a (3,{S},1) node, under mrope_section")
+        if not self.index_topk:
+            # a sparse layer's tiles depend on what it selects: they are
+            # counted as it runs (``dsa_stats``), not as it is built
+            publish_flash_tiles(self.name, S, self._block(S),
+                                self.window or None)
         return [in_shapes[0]]
 
     def init_params(self, key, in_shapes):
@@ -606,7 +683,24 @@ class GroupedQueryAttentionLayer(Layer):
              "o": {"wmat": w(ks[3], (h, d, e), h * d, e)}}
         if self.head_gate:
             p["gate"] = {"wmat": w(ks[4], (e, h), e, h)}
+        if self.qk_norm:
+            p["qnorm"] = {"gamma": jnp.ones((d,), jnp.float32)}
+            p["knorm"] = {"gamma": jnp.ones((d,), jnp.float32)}
+        if self.index_topk:
+            j, di = self.index_heads, self.index_head_dim
+            ki = [jax.random.fold_in(key, 5 + n) for n in range(3)]
+            p["iq"] = {"wmat": w(ki[0], (e, j, di), e, j * di)}
+            p["ik"] = {"wmat": w(ki[1], (e, di), e, di)}
+            p["iknorm"] = {"gamma": jnp.ones((di,), jnp.float32),
+                           "beta": jnp.zeros((di,), jnp.float32)}
+            p["iw"] = {"wmat": w(ki[2], (e, j), e, j)}
         return p
+
+    def init_state(self, in_shapes):
+        if not self.index_topk:
+            return {}
+        return {"dsa_stats": jnp.zeros((len(DSA_STATS),), jnp.float32),
+                "_aux_loss": jnp.zeros((), jnp.float32)}
 
     def param_pspecs(self):
         qkv = {"wmat": (None, "model", None)}
@@ -614,14 +708,20 @@ class GroupedQueryAttentionLayer(Layer):
                 "gate": {"wmat": (None, "model")},
                 "o": {"wmat": ("model", None, None)}}
 
-    def _attend(self, q, k, v):
-        from ..ops.fused import note_attention
-        impl, S = self.attn_impl, q.shape[1]
-        window = self.window or None
-        blk = self._block(S)
+    def _impl(self, positions):
+        """``(flash | ref, the kernel's block)``: ``auto`` is the kernel
+        on a TPU where a block divides the positions."""
+        impl, blk = self.attn_impl, self._block(positions)
         if impl == "auto":
             impl = "flash" if jax.default_backend() == "tpu" and blk \
                 else "ref"
+        return impl, blk
+
+    def _attend(self, q, k, v):
+        from ..ops.fused import note_attention
+        S = q.shape[1]
+        window = self.window or None
+        impl, blk = self._impl(S)
         note_attention("gqa.flash_window" if impl == "flash" and window
                        else "gqa." + impl)
         if impl == "ref":
@@ -631,20 +731,123 @@ class GroupedQueryAttentionLayer(Layer):
                              f"{S} positions")
         return flash_attention(q, k, v, True, None, blk, blk, None, window)
 
+    def _attend_sparse(self, q, k, v, select, want_probs):
+        """The main attention over the selected pairs -> ``(o, the
+        head-summed distribution over them or None)``."""
+        from ..ops.fused import note_attention
+        S = q.shape[1]
+        impl, blk = self._impl(S)
+        note_attention("gqa.flash_sparse" if impl == "flash"
+                       else "gqa.ref_sparse")
+        if impl == "ref":
+            return (attention_reference(q, k, v, causal=True, select=select),
+                    jax.lax.stop_gradient(head_sum_probs_reference(
+                        q, k, select)) if want_probs else None)
+        if not blk:
+            raise ValueError(f"gqa {self.name!r}: no flash block divides "
+                             f"{S} positions")
+        o, lse = flash_attention_select(q, k, v, select, None, blk, blk)
+        return o, head_sum_probs(q, k, lse, select, None, blk) \
+            if want_probs else None
+
+    def _index(self, params, x, pos, cd):
+        """The indexer's scores (B, S, S) float32 from the layer's
+        detached input."""
+        x = jax.lax.stop_gradient(x)
+        w = lambda nm: params[nm]["wmat"].astype(cd)
+        qi = jnp.einsum("bse,ejd->bsjd", x, w("iq"))
+        ki = jnp.einsum("bse,ed->bsd", x, w("ik")).astype(jnp.float32)
+        mu = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mu), axis=-1, keepdims=True)
+        ki = (ki - mu) * jax.lax.rsqrt(var + self.eps) \
+            * params["iknorm"]["gamma"] + params["iknorm"]["beta"]
+        ki = ki.astype(cd)[:, :, None, :]
+        if pos is None:
+            qi, ki = (rope_partial(a, self.index_freqs) for a in (qi, ki))
+        else:                  # the whole head by the temporal row
+            rows = jnp.broadcast_to(pos[:, :1], (pos.shape[0], 1,
+                                                 pos.shape[2]))
+            half = (self.index_head_dim // 2,)
+            qi, ki = (rope_sections(a, self.index_freqs, rows, half)
+                      for a in (qi, ki))
+        wt = jnp.einsum("bse,ej->bsj", x, w("iw"),
+                        preferred_element_type=jnp.float32) \
+            * (self.index_heads * self.index_head_dim) ** -0.5
+        blk = flash_block(x.shape[1], 512)
+        if self._impl(x.shape[1])[0] == "ref" or not blk:
+            return index_scores_reference(qi, ki[:, :, 0], wt)
+        return index_scores(qi, ki[:, :, 0], wt, blk)
+
+    def select(self, params, x):
+        """The selection (B, S, S) int8 the layer makes for its normed
+        input ``x`` (B, S, E) at text positions, products in ``x``'s
+        dtype: the function ``apply`` runs, for a caller that holds an
+        input of its own (the benchmark's reference does)."""
+        return select_topk(self._index(params, x, None, x.dtype),
+                           self.index_topk).astype(jnp.int8)
+
+    def rotate(self, a, pos=None):
+        """The main heads' rotary on (B, S, H, head_dim): by the three
+        position rows ``pos`` (B, 3, S), or by the token's index."""
+        if pos is None:
+            return rope_partial(a, self.rope_freqs, self.rope_mscale)
+        return rope_sections(a, self.rope_freqs, pos, self.mrope_section)
+
+    def _index_loss(self, scores, select, probs):
+        """``mean_t KL(p[t] || softmax of I[t] over the selected set)``."""
+        keep = select != 0
+        logz = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1,
+                                keepdims=True)
+        some = keep & (probs > 0)
+        logp = jnp.log(jnp.where(some, probs, 1.0))
+        return jnp.mean(jnp.sum(jnp.where(
+            some, probs * (logp - (scores - logz)), 0.0), axis=-1))
+
     def apply(self, params, state, inputs, ctx):
         if ctx.seq_axis is not None:
             raise ValueError("gqa has no sequence-parallel path")
         cd = ctx.compute_dtype
         x = _seq(inputs[0]).astype(cd)
         w = lambda nm: params[nm]["wmat"].astype(cd)
+        pos = None
+        if len(inputs) > 1:          # (b, S, 1, 3) -> the rows (b, 3, S)
+            pos = inputs[1].reshape(x.shape[0], x.shape[1], 3) \
+                .transpose(0, 2, 1)
         with jax.named_scope("gqa.proj"):
             q, k, v = (jnp.einsum("bse,ehd->bshd", x, w(nm))
                        for nm in ("q", "k", "v"))
-            q, k = (rope_partial(a, self.rope_freqs, self.rope_mscale)
-                    for a in (q, k))
-        with jax.named_scope("gqa.attend.window" if self.window
-                             else "gqa.attend.full"):
-            o = self._attend(q, k, v)
+            if self.qk_norm:
+                q, k = (rms_normalize(a, params[nm]["gamma"],
+                                      self.eps).astype(cd)
+                        for a, nm in ((q, "qnorm"), (k, "knorm")))
+            q, k = self.rotate(q, pos), self.rotate(k, pos)
+        if self.index_topk:
+            with jax.named_scope("gqa.index"):
+                scores = self._index(params, x, pos, cd)
+            with jax.named_scope("gqa.select"):
+                select = checkpoint_name(
+                    select_topk(scores, self.index_topk).astype(jnp.int8),
+                    SELECT_RESIDUAL)
+            learn = bool(ctx.train and self.index_loss_coef)
+            with jax.named_scope("gqa.attend.sparse"):
+                o, probs = self._attend_sparse(q, k, v, select, learn)
+            with jax.named_scope("gqa.index_loss"):
+                loss = self._index_loss(scores, select, probs) if learn \
+                    else jnp.zeros((), jnp.float32)
+            with jax.named_scope("gqa.select"):
+                blk = self._block(x.shape[1])
+                tiles = jnp.sum(select_tiles(select, blk, blk)[0] > 0) \
+                    / x.shape[0] if blk else 0.0
+                stats = jnp.stack([
+                    jnp.sum(select, dtype=jnp.float32), loss,
+                    jnp.asarray(tiles, jnp.float32),
+                    jnp.float32((x.shape[1] // blk) ** 2 if blk else 0)])
+            state = {"dsa_stats": jax.lax.stop_gradient(stats),
+                     "_aux_loss": self.index_loss_coef * loss}
+        else:
+            with jax.named_scope("gqa.attend.window" if self.window
+                                 else "gqa.attend.full"):
+                o = self._attend(q, k, v)
         if self.head_gate:
             with jax.named_scope("gqa.gate"):
                 g = jax.nn.sigmoid(jnp.einsum(
@@ -653,6 +856,20 @@ class GroupedQueryAttentionLayer(Layer):
         with jax.named_scope("gqa.proj"):
             y = jnp.einsum("bshd,hde->bse", o, w("o"))
         return [_unseq(y)], state
+
+
+@register_layer("dsa")
+class SparseAttentionLayer(GroupedQueryAttentionLayer):
+    """``gqa`` with its indexer on, under a kind of its own name: a conf
+    that means a selected set names ``dsa``, and a program that has no
+    such attention refuses the conf as the net is built (``gqa`` takes
+    the same keys, but ``set_param`` passes over a key it does not know,
+    and an older ``gqa`` would train a dense net from it)."""
+
+    def __init__(self, spec, global_cfg):
+        super().__init__(spec, global_cfg)
+        if self.index_topk <= 0:
+            raise ValueError(f"dsa layer {spec.name!r} needs index_topk")
 
 
 def swiglu(x, w_gate, w_up, w_down):

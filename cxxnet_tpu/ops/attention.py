@@ -205,6 +205,31 @@ def rope_partial(x: jax.Array, freqs, mscale: float = 1.0,
                             x[..., 2 * half:]], axis=-1).astype(x.dtype)
 
 
+def rope_sections(x: jax.Array, freqs, pos: jax.Array,
+                  sections) -> jax.Array:
+    """Rotary whose pairs read DIFFERENT position rows (the multimodal
+    rotary of the Qwen2-VL family, ``mrope_section``), on halves over the
+    first ``2 len(freqs)`` features of (B, S, H, D): ``pos`` is (B, 3, S)
+    — a token's temporal, height and width position — and pair ``i``
+    turns by ``pos[c(i)] * freqs[i]`` with ``c(i)`` the section ``i``
+    falls in, the sections CONTIGUOUS: the first ``sections[0]`` pairs
+    read row 0, the next ``sections[1]`` row 1, the rest row 2. With the
+    three rows equal to the token's index this is :func:`rope_partial`
+    (text)."""
+    half = len(freqs)
+    if sum(sections) != half or len(sections) != pos.shape[1]:
+        raise ValueError(f"rope_sections: sections {tuple(sections)} over "
+                         f"{half} pairs and {pos.shape[1]} position rows")
+    row = [c for c, n in enumerate(sections) for _ in range(n)]
+    p = pos.astype(jnp.float32)[:, jnp.asarray(row), :]     # (B, half, S)
+    ang = p.transpose(0, 2, 1) * jnp.asarray(freqs, jnp.float32)
+    cos = jnp.cos(ang)[:, :, None, :]
+    sin = jnp.sin(ang)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                            x[..., 2 * half:]], axis=-1).astype(x.dtype)
+
+
 def _group(q: jax.Array, k: jax.Array) -> int:
     """Query heads per key/value head of (B, S, H, D) / (B, S, H_kv, D)."""
     H, Hkv = q.shape[2], k.shape[2]
@@ -232,9 +257,11 @@ def _check_window(causal, window):
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = False,
                         scale: Optional[float] = None,
-                        window: Optional[int] = None) -> jax.Array:
+                        window: Optional[int] = None,
+                        select: Optional[jax.Array] = None) -> jax.Array:
     """Plain softmax attention. q: (B, S, H, D), k,v: (B, S, H_kv, D)
-    -> (B, S, H, Dv)."""
+    -> (B, S, H, Dv). ``select`` (B, Sq, Sk), boolean or integer: the
+    pairs every head may attend, beside ``causal`` and ``window``."""
     _check_window(causal, window)
     B, Sq, H, D = q.shape
     G = _group(q, k)
@@ -249,6 +276,9 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         qi = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2)
         ki = lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
         s = jnp.where(_keep(qi, ki, window), s, _NEG)
+    if select is not None:
+        s = jnp.where((select != 0).reshape(
+            (B,) + (1,) * (s.ndim - 3) + select.shape[1:]), s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     if G == 1:
         return jnp.einsum("bhqk,bkhd->bqhd", p,
@@ -327,13 +357,14 @@ def gather_kv_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _online_block_update(acc, m, l, q, kb, vb, q_pos, k_pos, scale, causal,
-                         k_valid_upto=None, window=None):
+                         k_valid_upto=None, window=None, select=None):
     """One online-softmax accumulation step against key/value block (kb, vb).
 
     acc: (B,H,Sq,D) f32, m/l: (B,H,Sq) f32; q: (B,Sq,H,D);
     kb/vb: (B,Sk,H,D); q_pos: (Sq,), k_pos: (Sk,) global positions.
     ``k_valid_upto`` masks key positions >= that bound (block tail padding)
-    independently of the causal mask; ``window`` narrows the causal mask.
+    independently of the causal mask; ``window`` narrows the causal mask;
+    ``select`` (B, Sq, Sk-block) boolean keeps the selected pairs only.
     """
     s = jnp.einsum("bqhd,bkhd->bhqk", q, kb,
                    preferred_element_type=jnp.float32) * scale
@@ -345,6 +376,10 @@ def _online_block_update(acc, m, l, q, kb, vb, q_pos, k_pos, scale, causal,
         mask = valid if mask is None else jnp.logical_and(mask, valid)
     if mask is not None:
         mask = mask[None, None]
+    if select is not None:
+        select = select[:, None]
+        mask = select if mask is None else jnp.logical_and(mask, select)
+    if mask is not None:
         s = jnp.where(mask, s, _NEG)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # exp under the new running max; explicitly zero masked entries so a
@@ -362,11 +397,14 @@ def _online_block_update(acc, m, l, q, kb, vb, q_pos, k_pos, scale, causal,
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool = False, scale: Optional[float] = None,
                       block_k: int = 128,
-                      window: Optional[int] = None) -> jax.Array:
+                      window: Optional[int] = None,
+                      select: Optional[jax.Array] = None) -> jax.Array:
     """Online-softmax attention scanning over k/v blocks. q: (B, S, H,
     D), k,v: (B, S, H_kv, D). Grouped heads ride the query axis: the G
     query heads of a key/value head are G queries at the same position,
-    so one scan over k and v as they stand serves them all."""
+    so one scan over k and v as they stand serves them all. ``select``
+    (B, Sq, Sk): the pairs every head may attend, a block of keys at a
+    time."""
     _check_window(causal, window)
     B, Sq, H, D = q.shape
     G = _group(q, k)
@@ -377,6 +415,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q = q.reshape(B, Sq, Hkv, G, D).transpose(0, 1, 3, 2, 4) \
             .reshape(B, Sq * G, Hkv, D)
         q_pos = jnp.repeat(q_pos, G)
+        if select is not None:
+            select = jnp.repeat(select, G, axis=1)
     block_k = min(block_k, Sk)
     nb = -(-Sk // block_k)
     pad = nb * block_k - Sk
@@ -385,21 +425,25 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(B, nb, block_k, Hkv, D).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(B, nb, block_k, Hkv, Dv).transpose(1, 0, 2, 3, 4)
+    blocks = (jnp.arange(nb), kb, vb)
+    if select is not None:
+        sel = jnp.pad(select != 0, ((0, 0), (0, 0), (0, pad)))
+        blocks += (sel.reshape(B, -1, nb, block_k).transpose(2, 0, 1, 3),)
 
     def step(carry, blk):
         acc, m, l = carry
-        j, kj, vj = blk
+        j, kj, vj, *sj = blk
         k_pos = j * block_k + jnp.arange(block_k)
         acc, m, l = _online_block_update(
             acc, m, l, q, kj, vj, q_pos, k_pos, sc, causal,
-            k_valid_upto=Sk if pad else None, window=window)
+            k_valid_upto=Sk if pad else None, window=window,
+            select=sj[0] if sj else None)
         return (acc, m, l), None
 
     acc0 = jnp.zeros((B, Hkv, Sq * G, Dv), jnp.float32)
     m0 = jnp.full((B, Hkv, Sq * G), _NEG, jnp.float32)
     l0 = jnp.zeros((B, Hkv, Sq * G), jnp.float32)
-    (acc, m, l), _ = lax.scan(step, (acc0, m0, l0),
-                              (jnp.arange(nb), kb, vb))
+    (acc, m, l), _ = lax.scan(step, (acc0, m0, l0), blocks)
     out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B, Hkv, Sq G, Dv)
     out = out.reshape(B, Hkv, Sq, G, Dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, Sq, H, Dv).astype(q.dtype)
@@ -619,6 +663,50 @@ def _kept_parts(body, run, off, block_q, block_k, causal, window,
                     for b, n, mask in parts))
 
 
+def _fwd_attend(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale):
+    """The forward kernels' one step of the online softmax: ``attend(rows,
+    parts)`` takes the rows' scores against the ``parts`` of the k-block
+    (``(cols, mask)``, ``mask`` ``None`` where every pair is kept)
+    together."""
+    def attend(rows, parts):
+        # operands go to the MXU in their own dtype (a bf16 product
+        # upcast to float32 first costs several passes), sums in float32
+        q = q_ref[0, rows, :]                     # (rows, D)
+        s = []
+        for cols, mask in parts:                  # kb: (cols, D)
+            sc = lax.dot_general(q, k_ref[0, cols, :], _NT,
+                                 preferred_element_type=jnp.float32) * scale
+            s.append(sc if mask is None else jnp.where(mask, sc, _NEG))
+        # ONE step of the online softmax over the row's parts together:
+        # the running statistics are (rows, 1) columns, and each pass
+        # over them costs as much as a pass over 128 columns of scores
+        m_prev = m_ref[rows, 0]
+        m_new = m_prev
+        for sc in s:
+            m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1))
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[rows, 0] * corr
+        acc = acc_ref[rows, :] * corr[:, None]
+        for (cols, mask), sc in zip(parts, s):
+            p = jnp.exp(sc - m_new[:, None])
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            l_new = l_new + jnp.sum(p, axis=-1)
+            vb = v_ref[0, cols, :]                # (cols, Dv)
+            acc = acc + jnp.dot(p.astype(vb.dtype), vb,
+                                preferred_element_type=jnp.float32)
+        l_ref[rows, 0] = l_new
+        acc_ref[rows, :] = acc
+        m_ref[rows, 0] = m_new
+    return attend
+
+
+def _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref):
+    l_fin = jnp.maximum(l_ref[:, 0], 1e-30)
+    o_ref[0] = (acc_ref[...] / l_fin[:, None]).astype(o_ref.dtype)
+    lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(l_fin)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       scale, causal, block_q, block_k, window=None):
@@ -652,36 +740,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def attend(rows, parts):
-        # operands go to the MXU in their own dtype (a bf16 product
-        # upcast to float32 first costs several passes), sums in float32
-        q = q_ref[0, rows, :]                     # (rows, D)
-        s = []
-        for cols, mask in parts:                  # kb: (cols, D)
-            sc = lax.dot_general(q, k_ref[0, cols, :], _NT,
-                                 preferred_element_type=jnp.float32) * scale
-            s.append(sc if mask is None else jnp.where(mask, sc, _NEG))
-        # ONE step of the online softmax over the row's parts together:
-        # the running statistics are (rows, 1) columns, and each pass
-        # over them costs as much as a pass over 128 columns of scores
-        m_prev = m_ref[rows, 0]
-        m_new = m_prev
-        for sc in s:
-            m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1))
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[rows, 0] * corr
-        acc = acc_ref[rows, :] * corr[:, None]
-        for (cols, mask), sc in zip(parts, s):
-            p = jnp.exp(sc - m_new[:, None])
-            if mask is not None:
-                p = jnp.where(mask, p, 0.0)
-            l_new = l_new + jnp.sum(p, axis=-1)
-            vb = v_ref[0, cols, :]                # (cols, Dv)
-            acc = acc + jnp.dot(p.astype(vb.dtype), vb,
-                                preferred_element_type=jnp.float32)
-        l_ref[rows, 0] = l_new
-        acc_ref[rows, :] = acc
-        m_ref[rows, 0] = m_new
+    attend = _fwd_attend(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale)
 
     # tiles past the band's last block, or strictly above the causal
     # diagonal, are skipped; an executed tile goes by what it keeps
@@ -692,9 +751,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(kj == nk - 1)
     def _finish():
-        l_fin = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_fin[:, None]).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(l_fin)
+        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
 
 
 def _heads_flat(a):
@@ -812,6 +869,42 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           use_interpret(interpret), window=window)
 
 
+def _bwd_part_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, dk_acc, dv_acc, scale, q_first):
+    """The backward kernels' work on one part of a tile, held
+    transposed: ``grads(rows, parts)`` rebuilds each part's P from the
+    saved logsumexp and adds to its rows of dV, dK and dQ. ``q_first``
+    is the q-block's first position in the head's resident dq row."""
+    def part_grads(rows, cols, mask):
+        q = q_ref[0, rows, :]                     # (rows, D)
+        kb = k_ref[0, cols, :]                    # (cols, D)
+        vb = v_ref[0, cols, :]                    # (cols, Dv)
+        do = do_ref[0, rows, :]                   # (rows, Dv)
+        st = lax.dot_general(kb, q, _NT,
+                             preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0, :, rows])    # lse: (1, rows)
+        if mask is not None:
+            # explicit zeroing: fully-masked rows carry a sentinel lse,
+            # where exp(s - lse) would NOT vanish on its own
+            pt = jnp.where(mask, pt, 0.0)
+        dv_acc[cols, :] += jnp.dot(pt.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(vb, do, _NT,
+                              preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0, :, rows]) * scale).astype(q.dtype)
+        dk_acc[cols, :] += jnp.dot(dst, q,
+                                   preferred_element_type=jnp.float32)
+        at = pl.ds(pl.multiple_of(q_first + rows.start, rows.size),
+                   rows.size)
+        dq_ref[0, at, :] += lax.dot_general(
+            dst, kb, _TN, preferred_element_type=jnp.float32)
+
+    def grads(rows, parts):
+        for cols, mask in parts:
+            part_grads(rows, cols, mask)
+    return grads
+
+
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       scale, causal, block_q, block_k, window=None):
@@ -856,33 +949,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def grads(rows, parts):
-        for cols, mask in parts:
-            part_grads(rows, cols, mask)
-
-    def part_grads(rows, cols, mask):
-        q = q_ref[0, rows, :]                     # (rows, D)
-        kb = k_ref[0, cols, :]                    # (cols, D)
-        vb = v_ref[0, cols, :]                    # (cols, Dv)
-        do = do_ref[0, rows, :]                   # (rows, Dv)
-        st = lax.dot_general(kb, q, _NT,
-                             preferred_element_type=jnp.float32) * scale
-        pt = jnp.exp(st - lse_ref[0, :, rows])    # lse: (1, rows)
-        if mask is not None:
-            # explicit zeroing: fully-masked rows carry a sentinel lse,
-            # where exp(s - lse) would NOT vanish on its own
-            pt = jnp.where(mask, pt, 0.0)
-        dv_acc[cols, :] += jnp.dot(pt.astype(do.dtype), do,
-                                   preferred_element_type=jnp.float32)
-        dpt = lax.dot_general(vb, do, _NT,
-                              preferred_element_type=jnp.float32)
-        dst = (pt * (dpt - delta_ref[0, :, rows]) * scale).astype(q.dtype)
-        dk_acc[cols, :] += jnp.dot(dst, q,
-                                   preferred_element_type=jnp.float32)
-        at = pl.ds(pl.multiple_of(qb_idx * block_q + rows.start, rows.size),
-                   rows.size)
-        dq_ref[0, at, :] += lax.dot_general(
-            dst, kb, _TN, preferred_element_type=jnp.float32)
+    grads = _bwd_part_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, dk_acc, dv_acc, scale, qb_idx * block_q)
 
     # only q blocks inside the k-block's band, or at or below the
     # diagonal, contribute to this k tile
@@ -967,12 +1035,20 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     (one pass over them, under a millisecond a layer at the cell's
     shapes; PERF.md section 6, PR 32).
     """
-    B, Sq, H, D = q.shape
-    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
-    G = _group(q, k)
-    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, causal, window)
-    # a head's float32 dq row (lanes padded to 128s, the output's two
-    # pipeline buffers) beside the tile's float32 intermediates
+    block_q, block_k = _bwd_blocks(q, k, block_q, block_k, causal, window)
+    dq, dk, dv = _backward_call(
+        *_bwd_operands(q, k, v, out, lse, g), _group(q, k),
+        _scale(q, scale), causal, block_q, block_k, interpret, window)
+    return _bwd_results(dq, dk, dv, q, k, v)
+
+
+def _bwd_blocks(q, k, block_q, block_k, causal, window):
+    """The backward kernels' blocks, checked against what they may hold
+    in VMEM: a head's float32 dq row (lanes padded to 128s, the output's
+    two pipeline buffers) beside the tile's float32 intermediates."""
+    Sq, D = q.shape[1], q.shape[3]
+    block_q, block_k = _blocks(Sq, k.shape[1], block_q, block_k, causal,
+                               window)
     lanes = -(-D // 128) * 128
     need = 2 * Sq * lanes * 4 + 8 * block_q * block_k * 4
     if need > _BWD_VMEM_LIMIT:
@@ -981,21 +1057,34 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             f"({Sq} x {lanes} lanes, twice) and its ({block_q},{block_k}) "
             f"tiles need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} "
             f"the kernel may use: shorten the sequence or shard it")
-    qt, kt, vt, dot = (_heads_flat(a) for a in (q, k, v, g))
-    # delta_i = rowsum(dO_i * O_i): elementwise where both already lie,
-    # then one small transpose to the kernel's lane-dense rows
+    return block_q, block_k
+
+
+def _bwd_operands(q, k, v, out, lse, g):
+    """What both backward kernels read, as they read it: q, k, v and the
+    cotangent a row a head, the logsumexp and ``delta_i = rowsum(dO_i *
+    O_i)`` lane-dense (B*H, 1, Sq)."""
+    B, Sq, H, _ = q.shape
+    # delta: elementwise where both already lie, then one small
+    # transpose to the kernel's lane-dense rows
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Sq)
+    return (*(_heads_flat(a) for a in (q, k, v, g)),
+            lse.reshape(B * H, 1, Sq), delta)
 
-    dq, dk, dv = _backward_call(
-        qt, kt, vt, dot, lse.reshape(B * H, 1, Sq), delta, G,
-        _scale(q, scale), causal, block_q, block_k, interpret, window)
+
+def _bwd_results(dq, dk, dv, q, k, v):
+    """The backward kernels' (B*H, S, D) outputs as the gradients of q,
+    k and v: dq rounded from float32, and where G query heads share a
+    key/value head their float32 dk and dv summed."""
+    B, Sq, Sk, Hkv = q.shape[0], q.shape[1], k.shape[1], k.shape[2]
     unflat = lambda a, S: a.reshape(B, -1, S, a.shape[-1]).transpose(
         0, 2, 1, 3)
-    if G > 1:
+    if _group(q, k) > 1:
         # a key/value head's gradient: the sum over its G query heads
-        dk, dv = (jnp.sum(a.reshape(B * Hkv, G, Sk, -1), axis=1).astype(
-            like.dtype) for a, like in ((dk, k), (dv, v)))
+        dk, dv = (jnp.sum(a.reshape(B * Hkv, -1, Sk, a.shape[-1]),
+                          axis=1).astype(like.dtype)
+                  for a, like in ((dk, k), (dv, v)))
     return unflat(dq.astype(q.dtype), Sq), unflat(dk, Sk), unflat(dv, Sk)
 
 
@@ -1025,3 +1114,474 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, window,
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# -- attention over keys a learned scorer picks -------------------------------
+#
+# DeepSeek sparse attention (arXiv:2512.02556, section 2.1) in its training
+# form: an indexer scores every causal pair, each query keeps its ``topk``
+# best, and the main attention runs over the kept pairs alone. The pieces:
+# the indexer's score (``index_scores``), the exact selection
+# (``select_topk``), the kernels above under a selection operand
+# (``flash_attention_select``), and the head-summed attention distribution
+# over the selected set that the indexer is trained on (``head_sum_probs``).
+
+
+def _index_scores_rows(qi, ki, w):
+    """``I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s])`` for a stretch
+    of queries: qi (B, T, J, d), ki (B, S, d), w (B, T, J) float32 ->
+    (B, T, S) float32. The products run in the operands' dtype with
+    float32 sums; the relu, the weights and the sum over the indexer's
+    heads are float32."""
+    s = jnp.einsum("btjd,bsd->bjts", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * w.transpose(0, 2, 1)[..., None], axis=1)
+
+
+def index_scores_reference(qi: jax.Array, ki: jax.Array, w: jax.Array,
+                           chunk: int = 512) -> jax.Array:
+    """The indexer's scores of every pair, (B, S, S) float32, on XLA's
+    dots, ``chunk`` queries at a time where that divides the positions
+    (the products of all the indexer's heads against all keys are held a
+    chunk at a time, never positions x positions x heads). Differentiable;
+    the kernel's backward too."""
+    B, S = qi.shape[:2]
+    if S <= chunk or S % chunk:
+        return _index_scores_rows(qi, ki, w)
+    n = S // chunk
+    rows = jax.checkpoint(lambda a: _index_scores_rows(a[0], ki, a[1]))
+    out = lax.map(rows, (
+        qi.reshape(B, n, chunk, *qi.shape[2:]).swapaxes(0, 1),
+        w.reshape(B, n, chunk, w.shape[-1]).swapaxes(0, 1)))
+    return out.swapaxes(0, 1).reshape(B, S, ki.shape[1])
+
+
+def _index_scores_kernel(q_ref, k_ref, w_ref, o_ref, *, block):
+    """One (batch, q-block, k-block) tile of the indexer's scores: the
+    heads' products one after another on the MXU, each through its relu
+    and the query's weight into the float32 tile. A tile above the
+    diagonal is filled with the mask's value and multiplies nothing."""
+    qi, kj = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kj > qi)
+    def _above():
+        o_ref[0] = jnp.full(o_ref.shape[1:], _NEG, jnp.float32)
+
+    @pl.when(kj <= qi)
+    def _tile():
+        kb = k_ref[0]                               # (block, d)
+        wb = w_ref[0]                               # (block, J) float32
+        acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
+        for j in range(q_ref.shape[1]):
+            sc = lax.dot_general(q_ref[0, j], kb, _NT,
+                                 preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(sc, 0.0) * wb[:, j:j + 1]
+        o_ref[0] = acc
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4), inline=True)
+def _index_scores_call(qi, ki, w, block, interpret):
+    B, S, J, d = qi.shape
+    n = S // block
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, block=block),
+        grid=(B, n, n),
+        in_specs=[
+            pl.BlockSpec((1, J, block, d), lambda b, i, j: (b, 0, i, 0)),
+            # a tile above the diagonal fetches nothing new
+            pl.BlockSpec((1, block, d),
+                         lambda b, i, j: (b, jnp.minimum(i, j), 0)),
+            pl.BlockSpec((1, block, J), lambda b, i, j: (b, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block, block), lambda b, i, j: (b, i, j)),
+        out_shape=out_struct((B, S, S), jnp.float32, qi, ki, w),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="index_scores",
+    )(qi.transpose(0, 2, 1, 3), ki, w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array,
+                 block: int = 512,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """The indexer's scores as a Pallas kernel (``index_scores``), causal:
+    qi (B, S, J, d), ki (B, S, d), w (B, S, J) float32 -> (B, S, S)
+    float32, pairs above the diagonal at the mask's value. ``block``
+    divides the positions. The backward is XLA's, through
+    :func:`index_scores_reference` a chunk of queries at a time."""
+    return _index_scores_call(qi, ki, w, block, use_interpret(interpret))
+
+
+def _index_scores_fwd(qi, ki, w, block, interpret):
+    return index_scores(qi, ki, w, block, interpret), (qi, ki, w)
+
+
+def _index_scores_bwd(block, interpret, res, g):
+    return jax.vjp(index_scores_reference, *res)[1](g)
+
+
+index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
+
+
+def select_topk(scores: jax.Array, topk: int) -> jax.Array:
+    """The EXACT selection of each query's ``topk`` largest ``scores``
+    over its causal keys ``s <= t``, (B, S, S) float32 -> boolean (B, S,
+    S): every causal key while ``t < topk``, and a tie at the last place
+    goes to the lower ``s``, as ``lax.top_k`` breaks it. No sort: the
+    ``topk``-th largest value of a row is found bit by bit on the scores'
+    order-preserving integer image (32 counting passes over the square),
+    and the ties' cut the same way over the positions (one pass a bit of
+    the row's length). Nothing here has a derivative."""
+    scores = lax.stop_gradient(scores)
+    B, S, Sk = scores.shape
+    t = lax.broadcasted_iota(jnp.int32, (1, S, 1), 1)
+    s = lax.broadcasted_iota(jnp.int32, (1, 1, Sk), 2)
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    # float order as unsigned order: a negative float's magnitude bits
+    # are flipped, and the sign bit is flipped throughout
+    u = lax.bitcast_convert_type(
+        bits ^ ((bits >> 31) | jnp.int32(-2 ** 31)), jnp.uint32)
+    u = jnp.where(s <= t, jnp.maximum(u, jnp.uint32(1)), jnp.uint32(0))
+    want = jnp.minimum(t[..., 0] + 1, topk)                  # (1, S)
+
+    def value_bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= want, cand, tau)
+    tau = lax.fori_loop(0, 32, value_bit, jnp.zeros((B, S), jnp.uint32))
+    above = u > tau[..., None]
+    tied = u == tau[..., None]
+    need = want - jnp.sum(above, axis=-1, dtype=jnp.int32)   # >= 1
+    nbits = max(Sk, 1).bit_length()
+
+    def place_bit(i, cut):
+        cand = cut | (jnp.int32(1) << (nbits - 1 - i))
+        n = jnp.sum(tied & (s < cand[..., None]), axis=-1, dtype=jnp.int32)
+        return jnp.where((cand <= Sk) & (n < need), cand, cut)
+    # the largest ``cut`` with fewer than ``need`` ties before it: the
+    # ties up to and including position ``cut`` are the ``need`` lowest
+    cut = lax.fori_loop(0, nbits, place_bit, jnp.zeros((B, S), jnp.int32))
+    return above | (tied & (s <= cut[..., None]))
+
+
+def select_tiles(select: jax.Array, block_q: int, block_k: int):
+    """The kernels' table of a selection (B, Sq, Sk) at blocks of
+    ``(block_q, block_k)``, two flat int32 arrays over (batch, q-block,
+    k-block): a tile's class — 0 no pair selected (neither computed nor
+    fetched), 1 some (masked by the selection's tile), 2 all (multiplied
+    whole, no mask) — and the k-block a cell FETCHES: its own where it is
+    executed, else the last executed before it (the first of the row
+    where none is), so that a skipped tile moves nothing."""
+    B, Sq, Sk = select.shape
+    nq, nk = Sq // block_q, Sk // block_k
+    n = jnp.sum((select != 0).reshape(B, nq, block_q, nk, block_k),
+                axis=(2, 4), dtype=jnp.int32)
+    cls = jnp.where(n == 0, 0, jnp.where(n == block_q * block_k, 2, 1))
+    j = lax.broadcasted_iota(jnp.int32, cls.shape, 2)
+    last = lax.cummax(jnp.where(cls > 0, j, -1), axis=2)
+    first = jnp.argmax(cls > 0, axis=2).astype(jnp.int32)[..., None]
+    fetch = jnp.where(last < 0, first, last)
+    return cls.reshape(-1).astype(jnp.int32), fetch.reshape(-1)
+
+
+def _flash_fwd_select_kernel(cls_ref, fetch_ref, q_ref, k_ref, v_ref, sel_ref,
+                             o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                             scale, block_q, block_k, heads):
+    """``_flash_fwd_kernel`` under a selection operand: the tile's class
+    comes from the table (:func:`select_tiles`, scalar-prefetched), a
+    tile without a selected pair is skipped and fetches nothing (the
+    index maps read the table's ``fetch``), a tile whose every pair is
+    selected is multiplied whole, and any other is masked by the
+    selection's own tile. The selection carries causality."""
+    b, qi, kj = (pl.program_id(a) for a in range(3))
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+    cls = cls_ref[(b // heads * nq + qi) * nk + kj]
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    attend = _fwd_attend(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, scale)
+
+    @pl.when(cls == 2)
+    def _all():
+        attend(whole_q, ((whole_k, None),))
+
+    @pl.when(cls == 1)
+    def _some():
+        attend(whole_q, ((whole_k, sel_ref[0].astype(jnp.float32) > 0.0),))
+
+    @pl.when(kj == nk - 1)
+    def _finish():
+        _fwd_finish(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 12)), inline=True)
+def _forward_select_call(cls, fetch, qt, kt, vt, sel, heads, G, scale,
+                         block_q, block_k, interpret):
+    (BH, Sq, D), Sk, Dv = qt.shape, kt.shape[1], vt.shape[-1]
+    nq, nk = Sq // block_q, Sk // block_k
+    at = lambda b, i, j, fetch: fetch[(b // heads * nq + i) * nk + j]
+    kv_map = lambda b, i, j, cls, fetch: (b // G, at(b, i, j, fetch), 0)
+    return pl.pallas_call(
+        functools.partial(_flash_fwd_select_kernel, scale=scale,
+                          block_q=block_q, block_k=block_k, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BH, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, block_k, D), kv_map),
+                pl.BlockSpec((1, block_k, Dv), kv_map),
+                pl.BlockSpec((1, block_q, block_k),
+                             lambda b, i, j, cls, fetch: (
+                                 b // heads, i, at(b, i, j, fetch))),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, Dv), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+        out_shape=[out_struct((BH, Sq, Dv), qt.dtype, qt, kt, vt),
+                   out_struct((BH, Sq, 1), jnp.float32, qt, kt, vt)],
+        interpret=interpret, name="flash_fwd_select",
+    )(cls, fetch, qt, kt, vt, sel)
+
+
+def _flash_bwd_select_kernel(cls_ref, fetch_ref, q_ref, k_ref, v_ref, do_ref,
+                             lse_ref, delta_ref, sel_ref, dq_ref, dk_ref,
+                             dv_ref, dk_acc, dv_acc, *, scale, block_q,
+                             block_k, heads):
+    """``_flash_bwd_kernel`` under a selection operand, the table and the
+    selection TRANSPOSED as the tile is held: (batch, k-block, q-block)."""
+    b, kj, qi = (pl.program_id(a) for a in range(3))
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
+    cls = cls_ref[(b // heads * nk + kj) * nq + qi]
+
+    @pl.when(jnp.logical_and(kj == 0, qi == 0))
+    def _init_dq():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    grads = _bwd_part_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, dk_acc, dv_acc, scale, qi * block_q)
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+
+    @pl.when(cls == 2)
+    def _all():
+        grads(whole_q, ((whole_k, None),))
+
+    @pl.when(cls == 1)
+    def _some():
+        grads(whole_q, ((whole_k, sel_ref[0].astype(jnp.float32) > 0.0),))
+
+    @pl.when(qi == nq - 1)
+    def _finish():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(9, 15)), inline=True)
+def _backward_select_call(cls, fetch, qt, kt, vt, dot, lse, delta, sel_t,
+                          heads, G, scale, block_q, block_k, interpret):
+    (BH, Sq, D), Sk, Dv = qt.shape, kt.shape[1], vt.shape[-1]
+    nq, nk = Sq // block_q, Sk // block_k
+    at = lambda b, j, i, fetch: fetch[(b // heads * nk + j) * nq + i]
+    q_map = lambda b, j, i, cls, fetch: (b, at(b, j, i, fetch), 0)
+    r_map = lambda b, j, i, cls, fetch: (b, 0, at(b, j, i, fetch))
+    kv_map = lambda b, j, i, *_: (b // G, j, 0)
+    own = lambda b, j, i, *_: (b, j, 0)
+    part = (lambda a: a.dtype) if G == 1 else (lambda a: jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_select_kernel, scale=scale,
+                          block_q=block_q, block_k=block_k, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BH, nk, nq),
+            in_specs=[pl.BlockSpec((1, block_q, D), q_map),
+                      pl.BlockSpec((1, block_k, D), kv_map),
+                      pl.BlockSpec((1, block_k, Dv), kv_map),
+                      pl.BlockSpec((1, block_q, Dv), q_map),
+                      pl.BlockSpec((1, 1, block_q), r_map),
+                      pl.BlockSpec((1, 1, block_q), r_map),
+                      pl.BlockSpec((1, block_k, block_q),
+                                   lambda b, j, i, cls, fetch: (
+                                       b // heads, j, at(b, j, i, fetch)))],
+            out_specs=[pl.BlockSpec((1, Sq, D), lambda b, j, i, *_: (b, 0, 0)),
+                       pl.BlockSpec((1, block_k, D), own),
+                       pl.BlockSpec((1, block_k, Dv), own)],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, Dv), jnp.float32)]),
+        out_shape=[out_struct((BH, Sq, D), jnp.float32, qt, kt, vt, dot),
+                   out_struct((BH, Sk, D), part(kt), qt, kt, vt, dot),
+                   out_struct((BH, Sk, Dv), part(vt), qt, kt, vt, dot)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="flash_bwd_select",
+    )(cls, fetch, qt, kt, vt, dot, lse, delta, sel_t)
+
+
+#: the selection as the kernels read it (int8, 1 a selected pair), by the
+#: name a ``jax.checkpoint`` policy keeps it under: ``model.py`` under
+#: ``remat = 1`` keeps it beside ``FLASH_RESIDUALS``, so that the rebuilt
+#: forward neither scores for nor makes the selection a second time and
+#: the backward masks by the very set the forward attended
+SELECT_RESIDUAL = "dsa_select"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def flash_attention_select(q: jax.Array, k: jax.Array, v: jax.Array,
+                           select: jax.Array, scale: Optional[float] = None,
+                           block_q: int = 128, block_k: int = 128,
+                           interpret: Optional[bool] = None):
+    """:func:`flash_attention` over the pairs ``select`` keeps: (B, Sq,
+    Sk) int8, 1 a pair every head may attend, which carries causality
+    (``select_topk``'s set lies under the diagonal). Grouped key/value
+    heads as there. A tile without a selected pair is neither computed
+    nor fetched, one wholly selected is multiplied without a mask
+    (:func:`select_tiles`), forward and backward; the backward is the one
+    kernel at the forward's blocks, reading the selection transposed.
+    Returns ``(out, logsumexp (B*H, Sq))``, which carry
+    ``FLASH_RESIDUALS``' names; the logsumexp is there for
+    :func:`head_sum_probs` and takes no cotangent."""
+    out, lse = _flash_select_forward(q, k, v, select, scale, block_q,
+                                     block_k, use_interpret(interpret))
+    return out, lse.reshape(lse.shape[:2])
+
+
+def _flash_select_forward(q, k, v, select, scale, block_q, block_k,
+                          interpret):
+    B, Sq, H, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[-1]
+    block_q, block_k = _blocks(Sq, Sk, block_q, block_k, True, None)
+    cls, fetch = select_tiles(select, block_q, block_k)
+    out, lse = _forward_select_call(
+        cls, fetch, _heads_flat(q), _heads_flat(k), _heads_flat(v), select,
+        H, _group(q, k), _scale(q, scale), block_q, block_k, interpret)
+    return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3), lse
+
+
+def _flash_select_fwd_rule(q, k, v, select, scale, block_q, block_k,
+                           interpret):
+    out, lse = _flash_select_forward(q, k, v, select, scale, block_q,
+                                     block_k, use_interpret(interpret))
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse.reshape(lse.shape[:2]), FLASH_RESIDUALS[1])
+    return (out, lse), (q, k, v, select, out, lse)
+
+
+def _flash_select_bwd_rule(scale, block_q, block_k, interpret, res, g):
+    q, k, v, select, out, lse = res
+    block_q, block_k = _bwd_blocks(q, k, block_q, block_k, True, None)
+    sel_t = select.transpose(0, 2, 1)
+    dq, dk, dv = _backward_select_call(
+        *select_tiles(sel_t, block_k, block_q),
+        # the logsumexp's cotangent is dropped: it feeds a detached target
+        *_bwd_operands(q, k, v, out, lse, g[0]), sel_t, q.shape[2],
+        _group(q, k), _scale(q, scale), block_q, block_k,
+        use_interpret(interpret))
+    return (*_bwd_results(dq, dk, dv, q, k, v), None)
+
+
+flash_attention_select.defvjp(_flash_select_fwd_rule, _flash_select_bwd_rule)
+
+
+def head_sum_probs_reference(q, k, select, scale=None) -> jax.Array:
+    """``sum_h softmax_h over the selected set / H``, (B, Sq, Sk) float32:
+    the main attention's distribution summed over its heads, on XLA's
+    dots (positions x positions x heads in memory: the tests' size)."""
+    B, Sq, H, D = q.shape
+    G = _group(q, k)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, Sq, H // G, G, D), k,
+                   preferred_element_type=jnp.float32) * _scale(q, scale)
+    keep = (select != 0)[:, None, None]
+    p = jax.nn.softmax(jnp.where(keep, s, _NEG), axis=-1)
+    return jnp.sum(jnp.where(keep, p, 0.0), axis=(1, 2)) / H
+
+
+def _head_sum_kernel(cls_ref, q_ref, k_ref, lse_ref, sel_ref, o_ref, *,
+                     scale, heads):
+    """One (batch, q-block, k-block, head) cell, the heads innermost: the
+    head's probabilities of the tile, rebuilt from its logsumexp, are
+    added into the float32 tile, which stays in VMEM across the heads;
+    the last head's cell masks it by the selection and divides."""
+    b, qi, kj, h = (pl.program_id(a) for a in range(4))
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+    cls = cls_ref[(b * nq + qi) * nk + kj]
+
+    @pl.when(h == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(cls > 0)
+    def _add():
+        sc = lax.dot_general(q_ref[0], k_ref[0], _NT,
+                             preferred_element_type=jnp.float32) * scale
+        o_ref[0] += jnp.exp(sc - lse_ref[0])
+
+    @pl.when(jnp.logical_and(cls > 0, h == heads - 1))
+    def _finish():
+        o_ref[0] = jnp.where(sel_ref[0].astype(jnp.float32) > 0.0,
+                             o_ref[0] * (1.0 / heads), 0.0)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 10)), inline=True)
+def _head_sum_call(cls, qt, kt, lse, sel, heads, G, scale, block, interpret):
+    (BH, Sq, D), Sk = qt.shape, kt.shape[1]
+    B, nq, nk = BH // heads, Sq // block, Sk // block
+    return pl.pallas_call(
+        functools.partial(_head_sum_kernel, scale=scale, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, nq, nk, heads),
+            in_specs=[
+                pl.BlockSpec((1, block, D),
+                             lambda b, i, j, h, *_: (b * heads + h, i, 0)),
+                pl.BlockSpec((1, block, D), lambda b, i, j, h, *_: (
+                    (b * heads + h) // G, j, 0)),
+                pl.BlockSpec((1, block, 1),
+                             lambda b, i, j, h, *_: (b * heads + h, i, 0)),
+                pl.BlockSpec((1, block, block),
+                             lambda b, i, j, h, *_: (b, i, j)),
+            ],
+            out_specs=pl.BlockSpec((1, block, block),
+                                   lambda b, i, j, h, *_: (b, i, j))),
+        out_shape=out_struct((B, Sq, Sk), jnp.float32, qt, kt, lse),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="head_sum_probs",
+    )(cls, qt, kt, lse, sel)
+
+
+def head_sum_probs(q: jax.Array, k: jax.Array, lse: jax.Array,
+                   select: jax.Array, scale: Optional[float] = None,
+                   block: int = 128,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """The main attention's distribution summed over its heads, ``sum_h
+    a_h[t, s] / H`` over the selected set, (B, Sq, Sk) float32, as a
+    Pallas kernel (``head_sum_probs``): a head's probabilities are rebuilt
+    a tile at a time from ``lse`` — the logsumexp (B*H, Sq) the selection
+    kernel's forward emitted — and summed in VMEM, so nothing of positions
+    x positions x heads is ever in memory. ``select`` as
+    :func:`flash_attention_select` takes it. No derivative: the indexer's
+    target is detached."""
+    q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
+    B, Sq, H, D = q.shape
+    Sk = k.shape[1]
+    block, _ = _blocks(Sq, Sk, block, block, True, None)
+    cls, _ = select_tiles(select, block, block)
+    out = _head_sum_call(
+        cls, _heads_flat(q), _heads_flat(k), lse.reshape(B * H, Sq, 1),
+        select, H, _group(q, k), _scale(q, scale), block,
+        use_interpret(interpret))
+    return lax.stop_gradient(out)

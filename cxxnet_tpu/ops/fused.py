@@ -2,7 +2,7 @@
 chooses one took.
 
 Two kinds of site choose today, both from what the code observes, never
-from an option: an attention layer (``mha``, ``mla``, ``gqa``:
+from an option: an attention layer (``mha``, ``mla``, ``gqa`` / ``dsa``:
 ``attn_impl = auto`` decides per backend and sequence length —
 ops/attention.py) and a ``moe`` layer's grouped product. Every other op has one implementation,
 XLA's own (PERF.md section 6, PR 26 and PR 30: the Pallas suite that
@@ -54,7 +54,9 @@ def note_attention(impl: str) -> None:
     sequence length): ``ref`` / ``chunked`` / ``flash`` / ``ring`` /
     ``gather_kv`` for ``mha``, ``mla.<impl>`` for latent attention,
     ``gqa.<impl>`` for grouped-query attention, whose kernel with a
-    window is ``gqa.flash_window``."""
+    window is ``gqa.flash_window`` and, over the keys its indexer
+    selects (the kind ``dsa``), ``gqa.flash_sparse`` (XLA's dots there:
+    ``gqa.ref_sparse``)."""
     _record("attention", impl)
 
 

@@ -52,6 +52,7 @@ _REDUCED = "!reduced:"
 #: layers' ``stats`` vectors ride, by layer name: fetched with the train
 #: metric, one step late, at no sync of their own
 _MOE = "!moe"
+_DSA = "!dsa"
 
 
 def _collect_nodes(res, needed, reduce=None, drop_top=False):
@@ -70,9 +71,11 @@ def _collect_nodes(res, needed, reduce=None, drop_top=False):
     return nodes
 
 
-def _moe_stats(net_state):
-    return {name: st["stats"] for name, st in net_state.items()
-            if isinstance(st, dict) and "stats" in st}
+def _layer_stats(net_state, key="stats"):
+    """{layer: its state's vector under ``key``}: ``stats`` of the
+    no-drop moe layers, ``dsa_stats`` of the sparse attention layers."""
+    return {name: st[key] for name, st in net_state.items()
+            if isinstance(st, dict) and key in st}
 
 
 def _fold_input(data, net):
@@ -1604,9 +1607,12 @@ class Trainer:
                                 extra_data=extra, rng=rng, train=True,
                                 capture_nodes=capture, health=health_on)
                 nodes = _collect_nodes(res, needed, reduce_keys, drop_top)
-                moe = _moe_stats(res.state)
+                moe = _layer_stats(res.state)
                 if moe and not chain:
                     nodes[_MOE] = moe
+                dsa = _layer_stats(res.state, "dsa_stats")
+                if dsa and not chain:
+                    nodes[_DSA] = dsa
                 aux = (res.state, nodes)
                 return res.loss, aux + ((res.health,) if health_on
                                         else ())
@@ -2310,7 +2316,7 @@ class Trainer:
         node_labels = {}
         reduced = set()
         for key, arr in nodes.items():
-            if key == _MOE:
+            if key in (_MOE, _DSA):
                 continue
             rows, idx = self._local_rows(arr)
             keep = idx < n_real          # drop tail padding rows
@@ -2436,6 +2442,8 @@ class Trainer:
             self._add_metric(self.train_metric, nodes, batch)
             if _MOE in nodes:
                 self._count_moe(nodes[_MOE])
+            if _DSA in nodes:
+                self._count_dsa(nodes[_DSA])
         t1 = time.perf_counter()
         self.last_drain_s = t1 - t0
         TRACER.add_complete("train.metric_drain", t0, t1, cat="train")
@@ -2485,6 +2493,38 @@ class Trainer:
         reg.gauge("cxxnet_moe_pairs_held_last_step",
                   "no-drop moe: pairs held at the last drained "
                   "step, summed over layers").set(float(total[0]))
+
+    def _count_dsa(self, stats) -> None:
+        """One drained step's sparse-attention ``dsa_stats`` (layers/
+        seq.DSA_STATS, by layer) into the telemetry registry as gauges of
+        the last drained step: the pairs the selection kept, the
+        indexer's loss, and — under the names the other attention kinds
+        publish as their nets are built — the score tiles a head's
+        kernels executed and the square's, where a tile without a
+        selected pair shows as one not executed."""
+        from .layers.seq import DSA_STATS
+        from .telemetry.registry import get_registry
+        reg = get_registry()
+        for layer, vec in jax.device_get(stats).items():
+            v = dict(zip(DSA_STATS, np.asarray(vec, np.float64)))
+            for name, key, text in (
+                    ("cxxnet_dsa_selected_pairs", "selected_pairs",
+                     "sparse attention: (query, key) pairs the indexer's "
+                     "selection kept at the last drained step"),
+                    ("cxxnet_dsa_index_loss", "index_loss",
+                     "sparse attention: the indexer's loss L_I at the "
+                     "last drained step"),
+                    ("cxxnet_attn_tiles_executed", "tiles_executed",
+                     "score tiles a head of the flash kernel's forward "
+                     "executes, at the layer's blocks"),
+                    ("cxxnet_attn_tiles_total", "tiles_total",
+                     "score tiles of a head's whole square, at the "
+                     "layer's blocks")):
+                reg.gauge(name, text, labels=("layer",)).labels(layer).set(
+                    v[key])
+        reg.counter("cxxnet_dsa_steps_total",
+                    "train steps whose sparse-attention stats were "
+                    "drained").inc()
 
     def train_metric_report(self, name: str = "train") -> str:
         self._drain_pending_metric()

@@ -366,6 +366,47 @@ def test_the_edge_tiles_compile_at_every_sub_tile_of_the_sweep(
     assert _kernels(text) == 2          # forward, backward
 
 
+# -- benchmarks/configs/keye_vl_2_0_30b_a3b.conf (selected keys, at 8k) ----------
+
+def test_sparse_attention_kernels_compile_at_the_cell_s_widths(one_chip):
+    """One row of 8192 positions, 32 query heads of 128 over 4 key/value
+    heads, the selection an int8 a pair, blocks of 1024: what ``dsa``
+    hands ``flash_attention_select`` in ``keye_ep16_train_8k``. The
+    tiles' table reaches the index maps by scalar prefetch and the int8
+    tile is compared as float32: either refused by the chip's compiler
+    fails here."""
+    from cxxnet_tpu.ops.attention import flash_attention_select
+    fn = lambda q, k, v, sel: flash_attention_select(
+        q, k, v, sel, None, 1024, 1024, False)[0]
+    text = _compile(fn, one_chip, [((1, 8192, 32, 128), BF16)]
+                    + [((1, 8192, 4, 128), BF16)] * 2
+                    + [((1, 8192, 8192), jnp.int8)], grad_argnums=(0, 1, 2))
+    assert _kernels(text) == 2          # forward, backward
+
+
+def test_the_indexer_s_kernels_compile_at_the_cell_s_widths(one_chip):
+    """The indexer's scores (16 heads of 64 against one key head, tiles
+    of 512) and the head-summed distribution over the selected set (32
+    heads innermost, a float32 tile of 1024 x 1024 resident across
+    them), and the exact selection, which is XLA's own loops."""
+    from cxxnet_tpu.ops.attention import (head_sum_probs, index_scores,
+                                          select_topk)
+    text = _compile(lambda qi, ki, w: index_scores(qi, ki, w, 512, False),
+                    one_chip, [((1, 8192, 16, 64), BF16),
+                               ((1, 8192, 64), BF16), ((1, 8192, 16), F32)])
+    assert _kernels(text) == 1
+    text = _compile(
+        lambda q, k, lse, sel: head_sum_probs(q, k, lse, sel, None, 1024,
+                                              False),
+        one_chip, [((1, 8192, 32, 128), BF16), ((1, 8192, 4, 128), BF16),
+                   ((32, 8192), F32), ((1, 8192, 8192), jnp.int8)])
+    assert _kernels(text) == 1
+    text = _compile(lambda s: select_topk(s, 2048).astype(jnp.int8),
+                    one_chip, [((1, 8192, 8192), F32)])
+    _xla_only(text)
+    assert "sort" not in text
+
+
 def test_the_held_experts_ladder_compiles_at_the_cell_s_sizes(one_chip):
     """An expert layer of ``joyai_ep16_train_8k`` — 8192 positions of
     2048, top-8 of 256 with 16 held, experts 768 wide — forward and

@@ -83,6 +83,11 @@ CASES = {
             "  nkvhead = 2\n  head_dim = 6\n  window = 5\n"
             "  head_gate = 1\n  rotary_dim = 4\n  rope_type = yarn\n"
             "  rope_factor = 4\n  rope_original_max_position = 8\n"),
+    "dsa": (f"1,1,12", f"layer[+1] = embed\n  nhidden = 8\n"
+            f"  vocab_size = {SEQ_V}\nlayer[+1] = dsa\n  nhead = 4\n"
+            "  nkvhead = 2\n  head_dim = 6\n  qk_norm = 1\n"
+            "  mrope_section = 1,1,1\n  index_heads = 2\n"
+            "  index_head_dim = 4\n  index_topk = 5\n"),
     "label_ids": (f"1,1,12", f"layer[+1] = label_ids\nlayer[+1] = embed\n"
                   f"  nhidden = 8\n  vocab_size = {SEQ_V}\n"),
 }
@@ -108,7 +113,7 @@ def test_layer_forward_and_grad(ltype):
     rng = np.random.RandomState(0)
     c, y, x = (int(v) for v in shape.split(","))
     if ltype in ("embed", "posembed", "layernorm", "mha", "ffn", "moe",
-                 "seqfc", "add", "lmloss", "rmsnorm", "mla", "gqa",
+                 "seqfc", "add", "lmloss", "rmsnorm", "mla", "gqa", "dsa",
                  "label_ids"):
         data = jnp.asarray(rng.randint(0, SEQ_V, (4, 1, 1, x))
                            .astype(np.float32))

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Write the net conf of a published sequence model in this repo's
-dialect, from the published config's own keys. Two families:
+dialect, from the published config's own keys. Three families:
 
 * JoyAI-LLM-Flash's (the DeepSeek-V3 family: latent attention, one
   leading dense layer, sigmoid-routed experts with a shared one, one
@@ -11,17 +11,25 @@ dialect, from the published config's own keys. Two families:
   head counts by layer, per-head output gates and a rotary by layer
   type, plain or YaRN, over part of the head; leading dense layers by
   ``mlp_only_layers``; softmax top-k experts without drops with a shared
-  one).
+  one);
+* ``model_type`` ``KeyeVL2`` (Kwai-Keye's Keye-VL-2.0 language model:
+  grouped-query attention over the keys a learned indexer picks,
+  ``sa_config``, as the kind ``dsa``; an RMS norm on each head's q and k;
+  a rotary by ``rope_scaling.mrope_section``; all layers alike, softmax
+  top-k experts without drops and without a shared one).
 
     python tools/gen_joyai_conf.py benchmarks/configs/joyai_llm_flash.json
     python tools/gen_joyai_conf.py benchmarks/configs/laguna_s_2_1.json
+    python tools/gen_joyai_conf.py benchmarks/configs/keye_vl_2_0_30b_a3b.json
 
 reads the keys of that JSON (the model's ``config.json`` names plus the
 held experts' ``expert_first`` and published count, for the first family
-``mtp_loss_weight`` and ``bias_update_rate``, and the conf's training
-pairs under ``train``) and prints the conf.
+``mtp_loss_weight`` and ``bias_update_rate``, for the third
+``index_loss_coef``, and the conf's training pairs under ``train``) and
+prints the conf.
 ``benchmarks/configs/joyai_llm_flash.conf``,
-``benchmarks/configs/laguna_s_2_1.conf`` and the toy confs under
+``benchmarks/configs/laguna_s_2_1.conf``,
+``benchmarks/configs/keye_vl_2_0_30b_a3b.conf`` and the toy confs under
 ``tests/benchmarks/data/*_toy/configs/`` are its output; nothing reads
 this file at run time.
 """
@@ -45,7 +53,71 @@ def _tail(c: dict, metrics) -> list:
 def conf(c: dict) -> str:
     if c.get("model_type") == "laguna":
         return conf_laguna(c)
+    if c.get("model_type") == "KeyeVL2":
+        return conf_keye(c)
     return conf_joyai(c)
+
+
+def conf_keye(c: dict) -> str:
+    if not c["norm_topk_prob"]:
+        raise ValueError("norm_topk_prob is false: the moe kind has the "
+                         "renormalised gates only")
+    if c["attention_bias"] or c["mlp_only_layers"] \
+            or c["decoder_sparse_step"] != 1 or c["use_sliding_window"] \
+            or c["tie_word_embeddings"]:
+        raise ValueError("attention biases, dense layers among the expert "
+                         "layers, a window and a tied head are not written")
+    rope, sa = c["rope_scaling"], c["sa_config"]
+    if rope["rope_type"] != "default" or sa["indexer_num_kv_heads"] != 1:
+        raise ValueError("a scaled rotary and an indexer with several key "
+                         "heads are not written")
+    E, V, eps = c["hidden_size"], c["vocab_size"], c["rms_norm_eps"]
+    out = _HEADER + ["layer[0->e0] = embed:tok_embed",
+                     f"  nhidden = {E}", f"  vocab_size = {V}"]
+
+    def norm(src, dst, name):
+        out.extend([f"layer[{src}->{dst}] = rmsnorm:{name}",
+                    f"  eps = {eps}"])
+
+    x = "e0"
+    for i in range(c["num_hidden_layers"]):
+        p = f"b{i}"
+        norm(x, f"{p}n1", f"{p}_ln1")
+        out.extend([
+            f"layer[{p}n1->{p}a] = dsa:{p}_attn",
+            f"  nhead = {c['num_attention_heads']}",
+            f"  nkvhead = {c['num_key_value_heads']}",
+            f"  head_dim = {c['head_dim']}",
+            "  qk_norm = 1",
+            f"  eps = {eps}",
+            f"  rope_theta = {c['rope_theta']}",
+            "  mrope_section = "
+            + ",".join(str(n) for n in rope["mrope_section"]),
+            f"  index_heads = {sa['indexer_num_heads']}",
+            f"  index_head_dim = {sa['indexer_head_dim']}",
+            f"  index_topk = {sa['topk']}",
+            f"  index_loss_coef = {c['index_loss_coef']}"])
+        out.append(f"layer[{x},{p}a->{p}r1] = add:{p}_res1")
+        norm(f"{p}r1", f"{p}n2", f"{p}_ln2")
+        out.extend([
+            f"layer[{p}n2->{p}f] = moe:{p}_moe",
+            "  router = softmax_nodrop",
+            f"  num_expert = {c['num_experts_published']}",
+            f"  topk = {c['num_experts_per_tok']}",
+            f"  nhidden = {c['moe_intermediate_size']}",
+            "  shared_expert = 0",
+            "  routed_scaling_factor = 1",
+            f"  expert_first = {c['expert_first']}",
+            f"  expert_held = {c['num_experts']}"])
+        out.append(f"layer[{p}r1,{p}f->{p}r2] = add:{p}_res2")
+        x = f"{p}r2"
+    norm(x, "hN", "final_norm")
+    out.extend(["layer[hN->lg] = seqfc:lm_head", f"  nhidden = {V}",
+                "  no_bias = 1",
+                "layer[lg->lg] = lmloss:loss_main"])
+    out.extend(_tail(c, ["metric[label,lg] = seq_error",
+                         "metric[label,lg] = seq_logloss"]))
+    return "\n".join(out) + "\n"
 
 
 def conf_laguna(c: dict) -> str:
