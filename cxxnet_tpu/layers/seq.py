@@ -502,6 +502,26 @@ DSA_STATS = ("selected_pairs", "index_loss", "tiles_executed",
              "tiles_total")
 
 
+@jax.custom_vjp
+def _gradients_together(tree):
+    """The identity, whose backward hands all of ``tree``'s cotangents on
+    at once (``optimization_barrier``): none is used before the last is
+    made. A sparse ``gqa`` layer passes its input and its indexer's
+    leaves through it. The indexer is detached from the layer's input,
+    so nothing upstream waits for its backward, and the chip's scheduler
+    is then free to put all layers' indexer backwards off to the step's
+    end, each holding its rebuilt positions x positions scores and
+    distribution until then (2.4 GB at eight layers of 8192 positions;
+    PERF.md section 6, PR 35); tied, a layer's indexer has its gradients
+    before the backward goes on to the layer before."""
+    return tree
+
+
+_gradients_together.defvjp(
+    lambda tree: (tree, None),
+    lambda _, grads: (jax.lax.optimization_barrier(grads),))
+
+
 @register_layer("gqa")
 class GroupedQueryAttentionLayer(Layer):
     """Causal self-attention with grouped key/value heads, a head size
@@ -557,7 +577,14 @@ class GroupedQueryAttentionLayer(Layer):
     softmax over the selected set of I)``, and ``index_loss_coef L_I``
     joins the objective through the state's ``_aux_loss`` (0: no loss is
     built and the indexer's leaves get no gradient). Nothing but the
-    indexer's leaves gets a gradient from it. The state's ``dsa_stats``
+    indexer's leaves gets a gradient from it, and it reaches them
+    through the scores' own backward: under ``flash`` one kernel
+    (``index_scores_bwd``, every causal tile once, at the score kernel's
+    blocks of 512), under ``ref`` XLA's derivative of
+    ``index_scores_reference``; and the backward hands the layer's
+    input's gradient on only together with theirs
+    (``_gradients_together``), so a layer's indexer is done with before
+    the layer before it is begun. The state's ``dsa_stats``
     (``DSA_STATS``) count the pairs selected, ``L_I``, and the score
     tiles the kernels executed of a head's square.
 
@@ -808,6 +835,11 @@ class GroupedQueryAttentionLayer(Layer):
             raise ValueError("gqa has no sequence-parallel path")
         cd = ctx.compute_dtype
         x = _seq(inputs[0]).astype(cd)
+        learn = bool(self.index_topk and ctx.train and self.index_loss_coef)
+        if learn:
+            x, mine = _gradients_together((x, {
+                nm: params[nm] for nm in ("iq", "ik", "iknorm", "iw")}))
+            params = {**params, **mine}
         w = lambda nm: params[nm]["wmat"].astype(cd)
         pos = None
         if len(inputs) > 1:          # (b, S, 1, 3) -> the rows (b, 3, S)
@@ -828,7 +860,6 @@ class GroupedQueryAttentionLayer(Layer):
                 select = checkpoint_name(
                     select_topk(scores, self.index_topk).astype(jnp.int8),
                     SELECT_RESIDUAL)
-            learn = bool(ctx.train and self.index_loss_coef)
             with jax.named_scope("gqa.attend.sparse"):
                 o, probs = self._attend_sparse(q, k, v, select, learn)
             with jax.named_scope("gqa.index_loss"):

@@ -1125,6 +1125,10 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # (``select_topk``), the kernels above under a selection operand
 # (``flash_attention_select``), and the head-summed attention distribution
 # over the selected set that the indexer is trained on (``head_sum_probs``).
+# The score, the attention and the distribution are kernels (the selection
+# is XLA's counting loops); the score and the attention each have ONE
+# backward kernel (``index_scores_bwd``, ``flash_bwd_select``), the
+# selection and the distribution no derivative.
 
 
 def _index_scores_rows(qi, ki, w):
@@ -1143,8 +1147,10 @@ def index_scores_reference(qi: jax.Array, ki: jax.Array, w: jax.Array,
     """The indexer's scores of every pair, (B, S, S) float32, on XLA's
     dots, ``chunk`` queries at a time where that divides the positions
     (the products of all the indexer's heads against all keys are held a
-    chunk at a time, never positions x positions x heads). Differentiable;
-    the kernel's backward too."""
+    chunk at a time, never positions x positions x heads). Differentiable
+    by XLA, a chunk at a time under ``jax.checkpoint``: the ``ref`` path
+    and the oracle the kernels, forward and backward, are tested
+    against."""
     B, S = qi.shape[:2]
     if S <= chunk or S % chunk:
         return _index_scores_rows(qi, ki, w)
@@ -1208,9 +1214,136 @@ def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array,
     """The indexer's scores as a Pallas kernel (``index_scores``), causal:
     qi (B, S, J, d), ki (B, S, d), w (B, S, J) float32 -> (B, S, S)
     float32, pairs above the diagonal at the mask's value. ``block``
-    divides the positions. The backward is XLA's, through
-    :func:`index_scores_reference` a chunk of queries at a time."""
+    divides the positions. The backward is one kernel too
+    (``index_scores_bwd``, :func:`_index_scores_bwd_kernel`) at the
+    forward's block; it needs qi, ki, w and the cotangent, nothing of the
+    forward's output."""
     return _index_scores_call(qi, ki, w, block, use_interpret(interpret))
+
+
+def _index_scores_bwd_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref,
+                             dw_ref, dq_acc, dw_acc, *, block):
+    """The whole backward of one (batch, q-block, k-block) tile of the
+    indexer's scores: per head the product q_j . k is made ONCE, into a
+    float32 tile, and through the relu's mask feeds all three gradients,
+
+      a_j = g o w_j o [q_j . k > 0]
+      dq_j += a_j K    dK += a_j^T Q_j    dw_j += rowsum(g o relu(q_j . k))
+
+    The tile is held TRANSPOSED, (block k, block q), as ``flash_bwd``
+    holds its own: dK's product contracts its lanes as they stand and
+    only dq's contracts its rows, the query's weight arrives lane-dense
+    as a (1, block) row and dw's sum runs down the sublanes; the
+    cotangent's tile is turned once a tile, for all heads. The k-blocks
+    are the innermost, sequential grid dimension: a q-block's dq and dw
+    accumulate in VMEM scratch across it and are written once; dK — ONE
+    key head — is the float32 block of the batch row's WHOLE length,
+    resident in VMEM while the row's tiles run (its index map is
+    constant within a row), added to in place once a tile. A tile above
+    the diagonal is neither fetched nor multiplied (the forward fills it
+    with a constant); a tile on the diagonal zeroes the cotangent above
+    it, whatever the caller left there, and goes by what it keeps
+    (:func:`_kept_parts`). ``a_j`` is rounded to the operands' dtype
+    before its two products, as ``flash_bwd`` rounds dS; the relu, the
+    weights, dw and every sum are float32."""
+    qi, kj = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jnp.logical_and(qi == 0, kj == 0))
+    def _init_dk():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    def part_grads(rows, cols, mask):
+        kb = k_ref[0, cols, :]                      # (cols, d)
+        g = g_ref[0, rows, cols]                    # (rows, cols) float32
+        if mask is not None:
+            # before the turn: the chip's compiler folds the mask's
+            # constant corners away, and a turned piece left without a
+            # use is refused
+            g = jnp.where(mask, g, 0.0)
+        gt = g.T
+        dk = 0.0
+        for j in range(q_ref.shape[1]):
+            q = q_ref[0, j, rows, :]                # (rows, d)
+            st = lax.dot_general(kb, q, _NT,
+                                 preferred_element_type=jnp.float32)
+            ht = jnp.where(st > 0.0, gt, 0.0)
+            at = (ht * w_ref[0, pl.ds(j, 1), rows]).astype(q.dtype)
+            dk += jnp.dot(at, q, preferred_element_type=jnp.float32)
+            dq_acc[j, rows, :] += lax.dot_general(
+                at, kb, _TN, preferred_element_type=jnp.float32)
+            dw_acc[pl.ds(j, 1), rows] += jnp.sum(ht * st, axis=0,
+                                                 keepdims=True)
+        at_k = pl.ds(pl.multiple_of(kj * block + cols.start,
+                                    math.gcd(block, cols.start)), cols.size)
+        dk_ref[0, at_k, :] += dk
+
+    def grads(rows, parts):
+        for cols, mask in parts:
+            part_grads(rows, cols, mask)
+
+    _kept_parts(grads, kj <= qi, (qi - kj) * block, block, block, True, None)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[0] = dw_acc[...]
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5), inline=True)
+def _index_scores_bwd_call(qi, ki, w, g, block, interpret):
+    """``index_scores_bwd``: the gradients of qi, ki and w, each in its
+    own dtype, from the float32 cotangent ``g`` (B, S, S)."""
+    B, S, J, d = qi.shape
+    n = S // block
+    lanes = -(-d // 128) * 128
+    # dK's row twice (the output's two pipeline buffers), a q-block's
+    # operand, result (two buffers each) and float32 sum, the
+    # cotangent's tile twice and the tile's float32 intermediates
+    need = 2 * S * lanes * 4 + J * block * lanes * (
+        4 * qi.dtype.itemsize + 4) + 8 * block * block * 4
+    if need > _BWD_VMEM_LIMIT:
+        raise ValueError(
+            f"index_scores backward: the key head's float32 gradient row "
+            f"({S} x {lanes} lanes, twice), {J} heads' blocks of {block} "
+            f"and their tiles need {need} bytes of VMEM, over the "
+            f"{_BWD_VMEM_LIMIT} the kernel may use: shorten the sequence "
+            f"or shard it")
+    q_spec = pl.BlockSpec((1, J, block, d), lambda b, i, j: (b, 0, i, 0))
+    w_spec = pl.BlockSpec((1, J, block), lambda b, i, j: (b, 0, i))
+    operands = (qi.transpose(0, 2, 1, 3), ki, w.transpose(0, 2, 1),
+                g.astype(jnp.float32))
+    dq, dk, dw = pl.pallas_call(
+        functools.partial(_index_scores_bwd_kernel, block=block),
+        grid=(B, n, n),
+        in_specs=[
+            q_spec,
+            # a tile above the diagonal fetches nothing new
+            pl.BlockSpec((1, block, d),
+                         lambda b, i, j: (b, jnp.minimum(i, j), 0)),
+            w_spec,
+            pl.BlockSpec((1, block, block),
+                         lambda b, i, j: (b, i, jnp.minimum(i, j))),
+        ],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, S, d), lambda b, i, j: (b, 0, 0)),
+                   w_spec],
+        out_shape=[out_struct((B, J, S, d), qi.dtype, *operands),
+                   out_struct((B, S, d), jnp.float32, *operands),
+                   out_struct((B, J, S), jnp.float32, *operands)],
+        scratch_shapes=[pltpu.VMEM((J, block, d), jnp.float32),
+                        pltpu.VMEM((J, block), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="index_scores_bwd",
+    )(*operands)
+    return (dq.transpose(0, 2, 1, 3), dk.astype(ki.dtype),
+            dw.transpose(0, 2, 1).astype(w.dtype))
 
 
 def _index_scores_fwd(qi, ki, w, block, interpret):
@@ -1218,7 +1351,7 @@ def _index_scores_fwd(qi, ki, w, block, interpret):
 
 
 def _index_scores_bwd(block, interpret, res, g):
-    return jax.vjp(index_scores_reference, *res)[1](g)
+    return _index_scores_bwd_call(*res, g, block, use_interpret(interpret))
 
 
 index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)

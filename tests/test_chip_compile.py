@@ -407,6 +407,23 @@ def test_the_indexer_s_kernels_compile_at_the_cell_s_widths(one_chip):
     assert "sort" not in text
 
 
+def test_the_indexer_s_backward_kernel_compiles_at_the_cell_s_widths(one_chip):
+    """The gradient of the indexer's scores at the cell's widths: two
+    kernels, ``index_scores`` and ``index_scores_bwd`` — the key head's
+    whole float32 gradient row resident (8192 x 128 lanes, two buffers),
+    sixteen heads' blocks of 512 beside it, the cotangent's tile turned
+    in the kernel — inside the VMEM limit the kernel asks for, which is
+    over the compiler's default."""
+    from cxxnet_tpu.ops.attention import _BWD_VMEM_LIMIT, index_scores
+    text = _compile(lambda qi, ki, w: index_scores(qi, ki, w, 512, False),
+                    one_chip, [((1, 8192, 16, 64), BF16),
+                               ((1, 8192, 64), BF16), ((1, 8192, 16), F32)],
+                    grad_argnums=(0, 1, 2))
+    assert _kernels(text) == 2          # forward, backward
+    # the backward's custom call carries the scoped limit it asked for
+    assert text.count('"size":"%d"' % _BWD_VMEM_LIMIT) == 1
+
+
 def test_the_held_experts_ladder_compiles_at_the_cell_s_sizes(one_chip):
     """An expert layer of ``joyai_ep16_train_8k`` — 8192 positions of
     2048, top-8 of 256 with 16 held, experts 768 wide — forward and
