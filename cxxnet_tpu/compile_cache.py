@@ -26,7 +26,10 @@ Observability (the ``cxxnet_compile_cache`` tag): enabling lands a
 ``compile_cache`` ledger event and a ``cxxnet_compile_cache_info{dir}``
 info-gauge; every persistent-cache hit counts into
 ``cxxnet_compile_cache_hits_total`` AND lands a
-``compile_cache`` ledger event with ``hit=true``. That pairing is what
+``compile_cache`` ledger event with ``hit=true`` (both from the
+process's one compile instrument, ``telemetry.anomaly.
+install_compile_counter``, which also marks the load's
+``compile.backend`` span ``cached``). That pairing is what
 lets the PR-7 recompile-storm detector's operator distinguish
 cold-start from storm: real XLA builds for a window are (compile
 events - cache-hit events): where the ``backend_compile`` duration
@@ -45,7 +48,6 @@ from .telemetry.registry import REGISTRY
 
 _LOCK = threading.Lock()
 _ENABLED_DIR = ""
-_HIT_LISTENER_INSTALLED = False
 
 
 #: what the environment may set to place the cache (JAX's own variable)
@@ -105,7 +107,8 @@ def enable_compile_cache(configured: str = "", silent: bool = True) -> str:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     with _LOCK:
         _ENABLED_DIR = cache_dir
-    installed = _install_hit_listener()
+    from .telemetry.anomaly import install_compile_counter
+    installed = install_compile_counter()
     REGISTRY.gauge(
         "cxxnet_compile_cache_info",
         "Persistent compile cache identity (constant 1)",
@@ -123,23 +126,3 @@ def cache_dir() -> str:
     with _LOCK:
         return _ENABLED_DIR
 
-
-def _install_hit_listener() -> bool:
-    """Count ``/jax/compilation_cache/cache_hits`` monitoring events
-    into ``cxxnet_compile_cache_hits_total``. Idempotent."""
-    global _HIT_LISTENER_INSTALLED
-    if _HIT_LISTENER_INSTALLED:
-        return True
-    from jax import monitoring
-    c = REGISTRY.counter(
-        "cxxnet_compile_cache_hits_total",
-        "Persistent-compile-cache hits (executables NOT recompiled)")
-
-    def _on_event(event: str, **kw) -> None:
-        if event.endswith("compilation_cache/cache_hits"):
-            c.inc()
-            LEDGER.event("compile_cache", hit=True)
-
-    monitoring.register_event_listener(_on_event)
-    _HIT_LISTENER_INSTALLED = True
-    return True

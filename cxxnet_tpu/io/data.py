@@ -11,11 +11,13 @@ padding (SURVEY §7 "dynamic batch tail").
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Type
 
 import numpy as np
 
 from ..config import ConfigPairs
+from ..telemetry.trace import TRACER
 
 
 @dataclasses.dataclass
@@ -48,6 +50,9 @@ class DataIter:
     #: never drift from the code: dist_shardable_sources() derives the
     #: allowed set from the registry.
     supports_dist_shard = False
+    #: whether ``__iter__`` has handed out its first batch: the making of
+    #: that one is set-up, and is recorded as ``setup.input``
+    _first_made = False
 
     def __init__(self, cfg: ConfigPairs):
         self.cfg = cfg
@@ -68,7 +73,18 @@ class DataIter:
         raise NotImplementedError
 
     def __iter__(self):
-        self.before_first()
+        if self._first_made:
+            self.before_first()
+        else:
+            t0 = time.perf_counter()
+            self.before_first()
+            b = self.next()
+            self._first_made = True
+            TRACER.add_complete("setup.input", t0, time.perf_counter(),
+                                cat="setup")
+            if b is None:
+                return
+            yield b
         while True:
             b = self.next()
             if b is None:
@@ -181,6 +197,7 @@ def create_iterator(cfg: ConfigPairs) -> DataIter:
     the previous one; every other pair is passed to all iterators in the
     chain (each ignores settings it does not understand)."""
     from . import proc, iter_imgrec, iter_img  # noqa: F401  (populate registry)
+    t0 = time.perf_counter()
     kinds = [v for k, v in cfg if k == "iter"]
     params = [(k, v) for k, v in cfg if k != "iter"]
     it: Optional[DataIter] = None
@@ -201,4 +218,8 @@ def create_iterator(cfg: ConfigPairs) -> DataIter:
     if any(k == "test_skipread" and str(v).strip() == "1"
            for k, v in params):
         it = SkipReadIterator(it)
+    # what the chain's init() did (a synthetic source makes its rows
+    # here) is set-up, as its first batch is (``__iter__``)
+    TRACER.add_complete("setup.input", t0, time.perf_counter(),
+                        cat="setup")
     return it

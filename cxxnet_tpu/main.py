@@ -27,6 +27,7 @@ from .graph import global_param
 from .io.data import DataBatch, close_chain, create_iterator
 from .resilience import SentinelAbort, TrainingSentinel, counters, failpoints
 from .telemetry import TelemetrySession
+from .telemetry.anomaly import install_compile_counter
 from .telemetry.disttrace import DISTTRACE, set_trace_identity
 from .telemetry.ledger import LEDGER, config_hash, plan_config_snapshot
 from .telemetry.trace import NULL_SPAN, TRACER
@@ -79,8 +80,24 @@ def _text_out(path: str):
     return _open_out(path, "w")
 
 
+def _round_spans(first: int, end: int):
+    """``range(first, end)``, recording a ``train.round`` span around
+    the loop's body for each round (from the yield to the request for
+    the next: a ``continue`` included, a raise left out)."""
+    for r in range(first, end):
+        t0 = time.perf_counter()
+        yield r
+        TRACER.add_complete("train.round", t0, time.perf_counter(),
+                            cat="setup", args={"round": r})
+
+
 class LearnTask:
     def __init__(self, cfg: ConfigPairs):
+        # set-up's own span (setup.task) and the compile instrument's
+        # (compile.*) land once the session below has set what the
+        # tracer keeps
+        t_setup = time.perf_counter()
+        install_compile_counter()
         self.cfg = cfg
         self.global_cfg, self.sections = split_sections(cfg)
         gp = lambda n, d: global_param(self.global_cfg, n, d)
@@ -297,6 +314,8 @@ class LearnTask:
             **snap_fields)
         for ch in snap_chunks:
             LEDGER.event("config_chunk", **ch)
+        TRACER.add_complete("setup.task", t_setup, time.perf_counter(),
+                            cat="setup")
 
     # -- iterators ---------------------------------------------------------
     def _make_iter(self, pairs: ConfigPairs):
@@ -364,6 +383,10 @@ class LearnTask:
 
     # -- model init --------------------------------------------------------
     def _init_model(self) -> None:
+        with TRACER.span("setup.weights", cat="setup"):
+            self._init_or_restore()
+
+    def _init_or_restore(self) -> None:
         tr = self.trainer
         if self.continue_training:
             latest = self._agree_latest(want_blob=True)
@@ -1014,7 +1037,7 @@ class LearnTask:
             return DISTTRACE.span("train.step", cat="train",
                                   args={"round": round_no,
                                         "steps": steps})
-        for r in range(self.start_counter, end_round):
+        for r in _round_spans(self.start_counter, end_round):
             tr.start_round(r)
             self._cur_round = r      # the grace checkpoint's round label
             batch_count = 0

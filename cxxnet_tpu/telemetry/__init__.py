@@ -12,8 +12,12 @@ One registry, one tracer, every subsystem a client:
   A ``task = train`` run keeps its own ``cat="train"`` spans
   (``train.data_wait`` / ``h2d_stage`` / ``step_dispatch`` /
   ``metric_drain`` / ``device_block``) in that ring WITHOUT the knob,
-  governed by ``telemetry_steptime`` (default 1; 0 keeps nothing):
-  about half a microsecond a span, and there after the session closed.
+  and its ``cat="setup"`` spans (``setup.task`` / ``setup.weights`` /
+  ``setup.input``, ``train.round``, and ``compile.trace`` /
+  ``compile.lower`` / ``compile.backend`` from the compile instrument,
+  :func:`.anomaly.install_compile_counter`), governed by
+  ``telemetry_steptime`` (default 1; 0 keeps nothing): about half a
+  microsecond a span, and there after the session closed.
 * :mod:`.steptime` — :class:`StepTimeProbe`, the amortized-sync
   data-wait / dispatch / drain / device breakdown with the input-bound
   vs compute-bound verdict in the round log (``dispatch_ms`` is the
@@ -107,15 +111,16 @@ class TelemetrySession:
         if cfg.ledger_path:
             LEDGER.enable(cfg.ledger_path, self.run_id, host=self.host)
         if cfg.ledger_path or cfg.fleet_dir:
-            # compile events feed the ledger + the storm detector
-            install_compile_counter()
+            # compile events (the task driver's compile instrument,
+            # anomaly.install_compile_counter) feed the storm detector
             self.storm = RecompileStormDetector(
                 window_s=cfg.storm_window_s,
                 threshold=cfg.storm_threshold)
-        # the train loop's own spans stay in the tracer's ring without
+        # the train loop's own spans and the set-up's (setup.*,
+        # train.round, compile.*) stay in the tracer's ring without
         # telemetry_trace (telemetry/trace.py "Two levels"): the same
         # knob that governs the step-time probe governs them
-        TRACER.keep(("train",) if cfg.steptime else ())
+        TRACER.keep(("train", "setup") if cfg.steptime else ())
         if cfg.trace_path:
             TRACER.enable(capacity=cfg.trace_capacity)
             # the distributed layer rides the same knob: cross-process

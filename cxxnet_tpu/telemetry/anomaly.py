@@ -23,8 +23,9 @@ thin stateful shell), never wired ad hoc into the train loop:
   can usually still run this thread and append a line, which is exactly
   why the ledger transport is a local file append and not a collective.
 * :class:`RecompileStormDetector` — compile events (counted process-
-  wide from jax.monitoring's ``backend_compile`` duration events, plus
-  the serve compile-cache misses) arriving faster than
+  wide by :func:`install_compile_counter` from jax.monitoring's
+  ``backend_compile`` duration events, plus the serve compile-cache
+  misses) arriving faster than
   ``threshold`` per ``window_s`` AFTER the first ``grace`` warmup
   compiles => a recompile storm: some shape/constant is churning the
   jit cache and the run is burning its step budget on the compiler.
@@ -308,14 +309,39 @@ class HangWatchdog:
 
 _COMPILE_COUNTER_INSTALLED = False
 
+#: jax's duration events of one executable's making (jax/_src/dispatch.py),
+#: by the phase each times: the jaxpr's trace, its lowering to an MLIR
+#: module, and the backend compile — an XLA build, or a load from the
+#: persistent cache, which jax times under the same event
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: timed inside a backend compile only where the persistent cache served
+#: the executable (jax/_src/compiler.py, beside its ``cache_hits`` event)
+CACHE_SERVED_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
 
 def install_compile_counter() -> bool:
-    """Count every XLA backend compile in this process into
-    ``cxxnet_compiles_total`` (and the ledger, when enabled) via
-    jax.monitoring's duration events — the only hook that sees jit
-    cache misses wherever they happen (trainer step fns, serve engine,
-    eval). Idempotent; returns False when this jax has no monitoring
-    listener API."""
+    """The process's one compile instrument: a jax.monitoring duration
+    listener — the only hook that sees jit cache misses wherever they
+    happen (trainer step fns, serve engine, eval). For each event of
+    :data:`COMPILE_PHASES` it
+
+    * adds the seconds to ``cxxnet_compile_seconds_total{phase}``;
+    * records a ``compile.<phase>`` span, ``cat="setup"``, with
+      ``args={"fn": <jax's name of the function>}``. jax calls back as
+      its timer exits, so the span ends now on ``perf_counter`` and
+      starts ``duration`` earlier: the timeline of every other span;
+    * on a backend compile, counts ``cxxnet_compiles_total`` and lands a
+      ``compile`` ledger event (when the ledger is on), and the span
+      carries ``"cached"``: whether the persistent cache served it.
+
+    A persistent-cache load counts ``cxxnet_compile_cache_hits_total``
+    and lands a ``compile_cache`` ledger event with ``hit=true``, so
+    (compiles - hits) is what XLA built. Idempotent; returns False when
+    this jax has no monitoring listener API."""
     global _COMPILE_COUNTER_INSTALLED
     if _COMPILE_COUNTER_INSTALLED:
         return True
@@ -324,16 +350,42 @@ def install_compile_counter() -> bool:
         register = monitoring.register_event_duration_secs_listener
     except Exception:
         return False
-    c = REGISTRY.counter("cxxnet_compiles_total",
-                         "XLA backend compiles observed in this process")
+    from .trace import TRACER
+    compiles = REGISTRY.counter(
+        "cxxnet_compiles_total",
+        "XLA backend compiles observed in this process")
+    hits = REGISTRY.counter(
+        "cxxnet_compile_cache_hits_total",
+        "Persistent-compile-cache hits (executables NOT recompiled)")
+    seconds = REGISTRY.counter(
+        "cxxnet_compile_seconds_total",
+        "Seconds spent making executables, by phase: trace (jaxpr), "
+        "lower (to MLIR), backend (an XLA build or a cache load)",
+        labels=("phase",))
+    phases = {event: ("compile." + phase, seconds.labels(phase))
+              for event, phase in COMPILE_PHASES.items()}
+    served = threading.local()      # a cache load, inside its compile
 
     def _on_event(event: str, duration: float, **kw) -> None:
-        # one backend_compile duration event per executable build;
-        # the sibling trace/lowering events would double count
-        if event.endswith("backend_compile_duration") \
-                or event.endswith("backend_compile"):
-            c.inc()
+        if event == CACHE_SERVED_EVENT:
+            hits.inc()
+            LEDGER.event("compile_cache", hit=True)
+            served.hit = True
+            return
+        phase = phases.get(event)
+        if phase is None:
+            return
+        t1 = time.perf_counter()
+        name, spent = phase
+        spent.inc(max(float(duration), 0.0))
+        args = {"fn": str(kw.get("fun_name", ""))}
+        if name == "compile.backend":
+            compiles.inc()
             LEDGER.event("compile", seconds=round(float(duration), 4))
+            args["cached"] = getattr(served, "hit", False)
+            served.hit = False
+        TRACER.add_complete(name, t1 - float(duration), t1, cat="setup",
+                            args=args)
 
     try:
         register(_on_event)

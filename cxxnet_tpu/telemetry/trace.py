@@ -10,9 +10,11 @@ bounded ring buffer and exported as Chrome trace-event JSON
 Two levels of recording. ``enable()`` (the ``telemetry_trace=path``
 knob) records every span of every category, feeds the distributed-trace
 sink and is what ``dump`` exports. Without it the tracer records only
-the categories named by ``keep()``: a ``task = train`` run keeps its
-``cat="train"`` spans by default (``telemetry_steptime``; ``0`` keeps
-nothing), for whoever reads a profiler dump afterwards
+the categories named by ``keep()``: a run of the task driver keeps its
+``cat="train"`` spans and its ``cat="setup"`` spans (``setup.*``,
+``train.round``, the ``compile.*`` spans of telemetry/anomaly's compile
+instrument) by default (``telemetry_steptime``; ``0`` keeps nothing),
+for whoever reads a profiler dump afterwards
 (telemetry/traceparse.attribute_profile, the benchmark's layer metrics).
 
 Design constraints, in order:

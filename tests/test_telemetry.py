@@ -268,10 +268,13 @@ def _train(extra=""):
 
 def test_default_train_run_keeps_the_loops_spans():
     """No telemetry_trace: the ring still holds the loop's own spans,
-    and only those — after the session has closed."""
+    and only those beside the set-up's — after the session has closed."""
     evs = _train()
     assert {e["name"] for e in evs} >= _LOOP_SPANS
-    assert {e.get("cat") for e in evs} == {"train"}
+    assert {e["name"] for e in evs if e.get("cat") == "train"} \
+        == _LOOP_SPANS
+    assert {e.get("cat") for e in evs} == {"train", "setup"}
+    evs = [e for e in evs if e.get("cat") == "train"]
     steps = [e for e in evs if e["name"] == "train.step_dispatch"]
     assert len(steps) == 2 * (256 // 16)
     # the drain is its own span, after the enqueue and outside it
