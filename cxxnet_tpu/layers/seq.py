@@ -31,8 +31,8 @@ from ..ops.attention import (SELECT_RESIDUAL, attention_reference,
                              head_sum_probs_reference, index_scores,
                              index_scores_reference, rope,
                              rope_frequencies, rope_interleaved,
-                             rope_partial, rope_sections, select_tiles,
-                             select_topk)
+                             rope_partial, rope_sections,
+                             select_rows_block, select_tiles, select_topk)
 from .base import Layer, Shape3, register_layer
 from .loss import LossLayerBase
 
@@ -570,7 +570,10 @@ class GroupedQueryAttentionLayer(Layer):
     / sqrt(index_heads index_head_dim)``; ``I[t,s] = sum_j w[t,j]
     relu(qI[t,j] . kI[s])`` in float32. Query t attends the K keys s <= t
     of largest I (all of them while t < K; a tie to the lower s), chosen
-    exactly (``ops.attention.select_topk``), the same set forward, in the
+    exactly (``ops.attention.select_topk``: under ``flash`` one kernel,
+    ``select_rows``, a block of rows resident in VMEM through all the
+    counting passes; under ``ref`` XLA's passes over the whole square,
+    ``select_topk_reference``), the same set forward, in the
     rebuilt forward under ``remat`` (the set is kept, not made again) and
     backward. The indexer learns from the main attention alone: with
     ``p[t,s] = sum_h a_h[t,s] / nhead`` detached, ``L_I = mean_t KL(p ||
@@ -805,13 +808,22 @@ class GroupedQueryAttentionLayer(Layer):
             return index_scores_reference(qi, ki[:, :, 0], wt)
         return index_scores(qi, ki[:, :, 0], wt, blk)
 
+    def _select(self, scores):
+        """The selection (B, S, S) int8 of the indexer's scores: the
+        kernel (``select_rows``) under ``flash`` where a row block
+        divides the positions, else XLA's counting passes."""
+        from ..ops.fused import note_select
+        S = scores.shape[1]
+        kernel = self._impl(S)[0] == "flash" and select_rows_block(S) > 0
+        note_select("gqa.select_rows" if kernel else "gqa.select_ref")
+        return select_topk(scores, self.index_topk, kernel)
+
     def select(self, params, x):
         """The selection (B, S, S) int8 the layer makes for its normed
         input ``x`` (B, S, E) at text positions, products in ``x``'s
         dtype: the function ``apply`` runs, for a caller that holds an
         input of its own (the benchmark's reference does)."""
-        return select_topk(self._index(params, x, None, x.dtype),
-                           self.index_topk).astype(jnp.int8)
+        return self._select(self._index(params, x, None, x.dtype))
 
     def rotate(self, a, pos=None):
         """The main heads' rotary on (B, S, H, head_dim): by the three
@@ -857,9 +869,8 @@ class GroupedQueryAttentionLayer(Layer):
             with jax.named_scope("gqa.index"):
                 scores = self._index(params, x, pos, cd)
             with jax.named_scope("gqa.select"):
-                select = checkpoint_name(
-                    select_topk(scores, self.index_topk).astype(jnp.int8),
-                    SELECT_RESIDUAL)
+                select = checkpoint_name(self._select(scores),
+                                         SELECT_RESIDUAL)
             with jax.named_scope("gqa.attend.sparse"):
                 o, probs = self._attend_sparse(q, k, v, select, learn)
             with jax.named_scope("gqa.index_loss"):

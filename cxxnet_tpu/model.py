@@ -85,9 +85,10 @@ class Network:
         # recipe for memory-bound models (no reference analog; the closest
         # is temp_col_max's memory/compute staging, SURVEY §5)
         self.remat = bool(int(global_param(cfg, "remat", "0")))
-        # site -> which implementation it took (ops.fused.SelectionLog):
-        # written while apply() is traced, printed once by the trainer
-        self.fused_log: Dict[str, Tuple[str, str]] = {}
+        # (site, kind) -> which implementation it took
+        # (ops.fused.SelectionLog): written while apply() is traced,
+        # printed once by the trainer
+        self.fused_log: Dict[Tuple[str, str], str] = {}
         self._tp_plan_logged = False
         # rule-driven sharding (parallel/rules.py): the validated
         # config namespace (partition_rules / fsdp_*), custom rules

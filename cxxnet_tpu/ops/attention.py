@@ -1122,11 +1122,12 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # form: an indexer scores every causal pair, each query keeps its ``topk``
 # best, and the main attention runs over the kept pairs alone. The pieces:
 # the indexer's score (``index_scores``), the exact selection
-# (``select_topk``), the kernels above under a selection operand
+# (``select_rows``), the kernels above under a selection operand
 # (``flash_attention_select``), and the head-summed attention distribution
 # over the selected set that the indexer is trained on (``head_sum_probs``).
-# The score, the attention and the distribution are kernels (the selection
-# is XLA's counting loops); the score and the attention each have ONE
+# Each is a kernel with an XLA form beside it, its oracle and the ``ref``
+# path (``select_topk_reference``: XLA's counting loops); the score and
+# the attention each have ONE
 # backward kernel (``index_scores_bwd``, ``flash_bwd_select``), the
 # selection and the distribution no derivative.
 
@@ -1357,7 +1358,7 @@ def _index_scores_bwd(block, interpret, res, g):
 index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
 
 
-def select_topk(scores: jax.Array, topk: int) -> jax.Array:
+def select_topk_reference(scores: jax.Array, topk: int) -> jax.Array:
     """The EXACT selection of each query's ``topk`` largest ``scores``
     over its causal keys ``s <= t``, (B, S, S) float32 -> boolean (B, S,
     S): every causal key while ``t < topk``, and a tie at the last place
@@ -1365,7 +1366,9 @@ def select_topk(scores: jax.Array, topk: int) -> jax.Array:
     ``topk``-th largest value of a row is found bit by bit on the scores'
     order-preserving integer image (32 counting passes over the square),
     and the ties' cut the same way over the positions (one pass a bit of
-    the row's length). Nothing here has a derivative."""
+    the row's length). XLA's loops, each pass a read of the whole square:
+    the ``ref`` path and the oracle :func:`select_rows` is tested against.
+    Nothing here has a derivative."""
     scores = lax.stop_gradient(scores)
     B, S, Sk = scores.shape
     t = lax.broadcasted_iota(jnp.int32, (1, S, 1), 1)
@@ -1396,6 +1399,184 @@ def select_topk(scores: jax.Array, topk: int) -> jax.Array:
     # ties up to and including position ``cut`` are the ``need`` lowest
     cut = lax.fori_loop(0, nbits, place_bit, jnp.zeros((B, S), jnp.int32))
     return above | (tied & (s <= cut[..., None]))
+
+
+#: the most rows of the scores a step of ``select_rows`` holds in VMEM,
+#: the rows a group of its passes runs over, and the columns a step of a
+#: pass reads (PERF.md section 6 has the sweep on the chip that set them)
+SELECT_ROWS, _SELECT_GROUP, _SELECT_WIDTH = 128, 64, 512
+
+
+def _select_rows_kernel(s_ref, o_ref, img_ref, tau_ref, cut_ref, *, topk,
+                        group, width):
+    """One (batch, row block) step of :func:`select_rows`: the block's
+    causal columns are made into the order-preserving integer image once
+    (signed: a negative float's magnitude bits flipped; a non-causal key
+    the least int32, a causal one above it), a group of rows at a time
+    runs :func:`select_topk_reference`'s passes over the image in VMEM —
+    each pass counts a row's hits lane-wise over the group's causal
+    chunks and sums the lanes once — and the int8 block is written once
+    from each row's threshold and cut. A block under ``topk`` keeps every
+    causal key and makes no pass; a group whose rows' thresholds need
+    every tie makes no position pass."""
+    R, S = img_ref.shape
+    t0 = pl.program_id(1) * R
+    lo = jnp.int32(-2 ** 31)
+    cols = lambda c: pl.ds(pl.multiple_of(c * width, width), width)
+    lanes = lambda c, n: c * width + lax.broadcasted_iota(
+        jnp.int32, (n, width), 1)
+    # the chunks that hold a causal key of the block's first ``n`` rows
+    reach = lambda n: (t0 + n + width - 1) // width
+
+    def image(c, carry):
+        bits = lax.bitcast_convert_type(s_ref[0, :, cols(c)], jnp.int32)
+        v = bits ^ ((bits >> 31) & jnp.int32(2 ** 31 - 1))
+        t = t0 + lax.broadcasted_iota(jnp.int32, (R, width), 0)
+        img_ref[:, cols(c)] = jnp.where(lanes(c, R) <= t,
+                                        jnp.maximum(v, lo + 1), lo)
+        return carry
+    lax.fori_loop(0, reach(R), image, 0)
+    # every causal key, none cut: what a row under ``topk`` keeps
+    tau_ref[...] = jnp.full(tau_ref.shape, lo + 1)
+    cut_ref[...] = jnp.full(cut_ref.shape, S)
+
+    def passes(g, carry):
+        rows = pl.ds(pl.multiple_of(g * group, group), group)
+        t = t0 + g * group + lax.broadcasted_iota(jnp.int32, (group, 1), 0)
+        want = jnp.minimum(t + 1, topk)
+        zero = jnp.zeros((group, 1), jnp.int32)
+
+        def count(hit):
+            def chunk(c, acc):
+                return acc + jnp.where(hit(img_ref[rows, cols(c)], c), 1, 0)
+            acc = lax.fori_loop(0, reach((g + 1) * group), chunk,
+                                jnp.zeros((group, width), jnp.int32))
+            return jnp.sum(acc, axis=1, keepdims=True)
+
+        # a row's threshold is broadcast over a chunk once a pass, not
+        # once a chunk
+        wide = lambda a: jnp.broadcast_to(a, (group, width))
+
+        def value_bit(i, carry):
+            tau, n_tau = carry
+            cand = tau | (jnp.int32(1) << (31 - i))
+            at = wide(cand ^ lo)
+            n = count(lambda x, c: x >= at)
+            take = n >= want
+            return jnp.where(take, cand, tau), jnp.where(take, n, n_tau)
+        tau, n_tau = lax.fori_loop(0, 32, value_bit, (zero, zero))
+        ts = tau ^ lo
+        tau_ref[rows, :] = ts
+
+        # a row with more keys at its threshold than it keeps cuts them
+        # by position: the lowest ``need``
+        @pl.when(jnp.any(n_tau != want))
+        def _ties():
+            at = wide(ts)
+            need = want - count(lambda x, c: x > at)
+            nbits = S.bit_length()
+
+            def place_bit(i, cut):
+                cand = cut | (jnp.int32(1) << (nbits - 1 - i))
+                below = wide(cand)
+                n = count(lambda x, c: (x == at) & (lanes(c, group) < below))
+                return jnp.where((cand <= S) & (n < need), cand, cut)
+            cut_ref[rows, :] = lax.fori_loop(0, nbits, place_bit, zero)
+        return carry
+
+    @pl.when(t0 + R > topk)
+    def _select():
+        lax.fori_loop(0, R // group, passes, 0)
+
+    def write(c, carry):
+        x, ts = img_ref[:, cols(c)], tau_ref[...]
+        keep = (x > ts) | ((x == ts) & (lanes(c, R) <= cut_ref[...]))
+        o_ref[0, :, cols(c)] = keep.astype(o_ref.dtype)
+        return carry
+    lax.fori_loop(0, reach(R), write, 0)
+
+    def clear(c, carry):
+        o_ref[0, :, cols(c)] = jnp.zeros((R, width), o_ref.dtype)
+        return carry
+    lax.fori_loop(reach(R), S // width, clear, 0)
+
+
+def _select_rows_vmem(rows: int, positions: int) -> int:
+    """VMEM bytes a step of :func:`select_rows` holds: the float32 rows
+    and the int8 set twice each (the pipeline's buffers), the integer
+    image, and each row's threshold and cut a 128-lane row."""
+    return rows * positions * (2 * 4 + 2 + 4) + 2 * rows * 128 * 4
+
+
+def select_rows_block(positions: int) -> int:
+    """The rows a step of :func:`select_rows` takes at ``positions``: the
+    whole square where there are at most ``SELECT_ROWS``, else the
+    largest of ``SELECT_ROWS``, .., 64, 32 that divides them and fits
+    the kernel's VMEM, on rows of whole 128-lane chunks; 0 where none
+    does (no kernel)."""
+    if positions <= SELECT_ROWS:
+        return positions
+    rows = SELECT_ROWS if positions % 128 == 0 else 0
+    while rows >= 32 and (positions % rows or _select_rows_vmem(
+            rows, positions) > _BWD_VMEM_LIMIT):
+        rows //= 2
+    return rows if rows >= 32 else 0
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3), inline=True)
+def _select_rows_call(scores, topk, rows, interpret):
+    B, S, _ = scores.shape
+    need = _select_rows_vmem(rows, S)
+    if need > _BWD_VMEM_LIMIT:
+        raise ValueError(
+            f"select_rows: {rows} rows of {S} scores, their image and set "
+            f"need {need} bytes of VMEM, over the {_BWD_VMEM_LIMIT} the "
+            f"kernel may use: take fewer rows")
+    # a block under ``topk`` keeps its causal keys whatever they score:
+    # it fetches the first block that selects, which that one then reuses
+    first = min(topk // rows, S // rows - 1)
+    return pl.pallas_call(
+        functools.partial(_select_rows_kernel, topk=topk,
+                          group=math.gcd(rows, _SELECT_GROUP),
+                          width=math.gcd(S, _SELECT_WIDTH)),
+        grid=(B, S // rows),
+        in_specs=[pl.BlockSpec((1, rows, S),
+                               lambda b, i: (b, jnp.maximum(i, first), 0))],
+        out_specs=pl.BlockSpec((1, rows, S), lambda b, i: (b, i, 0)),
+        out_shape=out_struct((B, S, S), jnp.int8, scores),
+        scratch_shapes=[pltpu.VMEM((rows, S), jnp.int32),
+                        pltpu.VMEM((rows, 1), jnp.int32),
+                        pltpu.VMEM((rows, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="select_rows",
+    )(scores)
+
+
+def select_rows(scores: jax.Array, topk: int, rows: int,
+                interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`select_topk_reference`'s set, bit for bit, as int8 (1 a
+    selected pair) from ONE Pallas kernel (``select_rows``): ``rows``
+    rows of the scores at a time (:func:`select_rows_block`) stay in
+    VMEM through all the counting passes, so the square is read from
+    memory once and the set written once, where the reference reads it
+    once a pass. No derivative."""
+    scores = lax.stop_gradient(scores).astype(jnp.float32)
+    return _select_rows_call(scores, topk, rows, use_interpret(interpret))
+
+
+def select_topk(scores: jax.Array, topk: int,
+                kernel: Optional[bool] = None) -> jax.Array:
+    """The exact selection as int8: :func:`select_rows` where ``kernel``
+    (``None``: on a TPU backend) and a row block divides the positions,
+    else :func:`select_topk_reference`'s."""
+    rows = select_rows_block(scores.shape[1])
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    if kernel and rows:
+        return select_rows(scores, topk, rows)
+    return select_topk_reference(scores, topk).astype(jnp.int8)
 
 
 def select_tiles(select: jax.Array, block_q: int, block_k: int):

@@ -1,10 +1,12 @@
 """The model's selection log: which implementation each site that
 chooses one took.
 
-Two kinds of site choose today, both from what the code observes, never
-from an option: an attention layer (``mha``, ``mla``, ``gqa`` / ``dsa``:
-``attn_impl = auto`` decides per backend and sequence length —
-ops/attention.py) and a ``moe`` layer's grouped product. Every other op has one implementation,
+Three kinds of choice are made today, all from what the code observes,
+never from an option: an attention layer's (``mha``, ``mla``, ``gqa`` /
+``dsa``: ``attn_impl = auto`` decides per backend and sequence length —
+ops/attention.py), a ``dsa`` layer's selection of the keys its indexer
+picks (the same decision, a second entry of the same site), and a
+``moe`` layer's grouped product. Every other op has one implementation,
 XLA's own (PERF.md section 6, PR 26 and PR 30: the Pallas suite that
 lived beside this file lost every benchmark cell and left the tree).
 
@@ -25,16 +27,18 @@ from typing import Dict, Tuple
 _SITE: contextvars.ContextVar = contextvars.ContextVar(
     "cxxnet_selection_site", default=None)
 
-#: site name -> ("attention", implementation) | ("grouped", product)
-SelectionLog = Dict[str, Tuple[str, str]]
+#: (site name, kind) -> what the site took of that kind; the kinds are
+#: ``attention``, ``select`` and ``grouped``
+SelectionLog = Dict[Tuple[str, str], str]
 
 
 @contextlib.contextmanager
 def selection_site(log: SelectionLog, name: str):
-    """Route :func:`note_attention` / :func:`note_grouped` calls made
-    while tracing site ``name`` (a layer) into ``log``, which the model
-    owns. Keyed by site, so a retrace overwrites its own entry instead
-    of counting twice."""
+    """Route :func:`note_attention` / :func:`note_select` /
+    :func:`note_grouped` calls made while tracing site ``name`` (a layer)
+    into ``log``, which the model owns. Keyed by site and kind, so a
+    site keeps one entry of each kind it reports and a retrace
+    overwrites its own entry instead of counting twice."""
     token = _SITE.set((log, name))
     try:
         yield
@@ -45,7 +49,7 @@ def selection_site(log: SelectionLog, name: str):
 def _record(kind: str, what: str) -> None:
     site = _SITE.get()
     if site is not None:
-        site[0][site[1]] = (kind, what)
+        site[0][(site[1], kind)] = what
 
 
 def note_attention(impl: str) -> None:
@@ -60,6 +64,13 @@ def note_attention(impl: str) -> None:
     _record("attention", impl)
 
 
+def note_select(impl: str) -> None:
+    """Record how the ``dsa`` layer being traced selects the keys its
+    indexer picks: ``gqa.select_rows`` (the kernel) or ``gqa.select_ref``
+    (XLA's counting passes, ``select_topk_reference``)."""
+    _record("select", impl)
+
+
 def note_grouped(impl: str) -> None:
     """Record which grouped matrix product the moe layer being traced
     runs its held experts on (``ragged_dot``: XLA's own)."""
@@ -67,10 +78,10 @@ def note_grouped(impl: str) -> None:
 
 
 def selection_counts(log: SelectionLog):
-    """{kind: Counter(what)} over the log's sites (kind: ``attention``
-    / ``grouped``)."""
+    """{kind: Counter(what)} over the log's sites, the kinds in their
+    names' order (``attention``, ``grouped``, ``select``)."""
     by = collections.defaultdict(collections.Counter)
-    for kind, what in log.values():
+    for (_, kind), what in sorted(log.items(), key=lambda e: e[0][1]):
         by[kind][what] += 1
     return by
 

@@ -386,11 +386,17 @@ def test_sparse_attention_kernels_compile_at_the_cell_s_widths(one_chip):
 
 def test_the_indexer_s_kernels_compile_at_the_cell_s_widths(one_chip):
     """The indexer's scores (16 heads of 64 against one key head, tiles
-    of 512) and the head-summed distribution over the selected set (32
+    of 512), the head-summed distribution over the selected set (32
     heads innermost, a float32 tile of 1024 x 1024 resident across
-    them), and the exact selection, which is XLA's own loops."""
-    from cxxnet_tpu.ops.attention import (head_sum_probs, index_scores,
-                                          select_topk)
+    them), and the exact selection: one kernel, ``select_rows`` — 128
+    rows of 8192 scores, their integer image and their int8 set resident
+    — inside the VMEM limit it asks for; its oracle,
+    ``select_topk_reference``, is XLA's own loops, no sort."""
+    from cxxnet_tpu.ops.attention import (_BWD_VMEM_LIMIT, SELECT_ROWS,
+                                          _select_rows_vmem, head_sum_probs,
+                                          index_scores, select_rows,
+                                          select_rows_block,
+                                          select_topk_reference)
     text = _compile(lambda qi, ki, w: index_scores(qi, ki, w, 512, False),
                     one_chip, [((1, 8192, 16, 64), BF16),
                                ((1, 8192, 64), BF16), ((1, 8192, 16), F32)])
@@ -401,7 +407,13 @@ def test_the_indexer_s_kernels_compile_at_the_cell_s_widths(one_chip):
         one_chip, [((1, 8192, 32, 128), BF16), ((1, 8192, 4, 128), BF16),
                    ((32, 8192), F32), ((1, 8192, 8192), jnp.int8)])
     assert _kernels(text) == 1
-    text = _compile(lambda s: select_topk(s, 2048).astype(jnp.int8),
+    assert select_rows_block(8192) == SELECT_ROWS
+    assert _select_rows_vmem(SELECT_ROWS, 8192) <= _BWD_VMEM_LIMIT
+    text = _compile(lambda s: select_rows(s, 2048, SELECT_ROWS, False),
+                    one_chip, [((1, 8192, 8192), F32)])
+    assert _kernels(text) == 1
+    assert text.count('"size":"%d"' % _BWD_VMEM_LIMIT) == 1
+    text = _compile(lambda s: select_topk_reference(s, 2048).astype(jnp.int8),
                     one_chip, [((1, 8192, 8192), F32)])
     _xla_only(text)
     assert "sort" not in text

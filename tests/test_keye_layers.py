@@ -588,8 +588,8 @@ def test_toy_net_under_remat_keeps_the_selection_and_the_kernels_output():
     """Loss and gradients under ``remat = 1`` are those under ``remat =
     0``; the rebuilt layers run neither the selection nor the attention's
     forward a second time (two layers: two ``flash_fwd_select``, two
-    ``flash_bwd_select``, and the counting loops of two selections), and
-    the selection log names the sparse kernel."""
+    ``flash_bwd_select``, and two selection kernels), and the selection
+    log names the sparse kernel and the selection kernel."""
     rng = np.random.RandomState(3)
     toks = jnp.asarray(rng.randint(0, 64, (2, 1, 1, 32)), jnp.float32)
     label = jnp.asarray(rng.randint(0, 64, (2, 32)), jnp.float32)
@@ -614,10 +614,11 @@ def test_toy_net_under_remat_keeps_the_selection_and_the_kernels_output():
                 r"custom_vjp_call\[\s*name=index_scores", text)) == 4
             assert bwd == 2
             assert text.count("name=head_sum_probs") == 4
-            assert text.count("scan[") == 4        # two loops a selection
+            assert text.count("name=select_rows") == 2
             from cxxnet_tpu.ops.fused import selection_counts
-            assert selection_counts(net.fused_log)["attention"] \
-                == {"gqa.flash_sparse": 2}
+            by = selection_counts(net.fused_log)
+            assert by["attention"] == {"gqa.flash_sparse": 2}
+            assert by["select"] == {"gqa.select_rows": 2}
     close(out[1][0], out[0][0], 1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(out[1][1]),
                     jax.tree_util.tree_leaves(out[0][1])):
