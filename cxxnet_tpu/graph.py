@@ -41,7 +41,7 @@ KNOWN_LAYER_TYPES = {
     # sequence/transformer extensions (no reference analog; SURVEY §5
     # long-context is N/A there — first-class here)
     "embed", "layernorm", "mha", "ffn", "seqfc", "add", "lmloss", "moe",
-    "posembed", "rmsnorm", "mla", "gqa", "dsa", "label_ids",
+    "posembed", "rmsnorm", "mla", "gqa", "dsa", "shortconv", "label_ids",
     # user-plugin layers (the reference's Caffe-adapter plugin spirit,
     # src/plugin/caffe_adapter-inl.hpp: embed foreign layer code in the
     # graph — here a user Python/JAX Layer subclass)

@@ -1,5 +1,5 @@
 """Sequence / transformer layers: embed, layernorm, rmsnorm, mha, mla, gqa,
-ffn, seqfc, add, lmloss.
+shortconv, ffn, seqfc, add, lmloss.
 
 TPU-idiomatic extension beyond the reference (which has no sequence axis —
 fixed image tensors, /root/reference/src/layer/layer.h:33-39; SURVEY §5
@@ -912,6 +912,77 @@ class SparseAttentionLayer(GroupedQueryAttentionLayer):
         super().__init__(spec, global_cfg)
         if self.index_topk <= 0:
             raise ValueError(f"dsa layer {spec.name!r} needs index_topk")
+
+
+@register_layer("shortconv")
+class ShortConvLayer(Layer):
+    """The gated short convolution of the LFM2 family, which mixes
+    positions in place of attention, causal, on a sequence node (E,S,1)
+    -> (E,S,1). With x a position's vector, no bias anywhere:
+
+      [B ; C ; x~] = x W_in        W_in (E, 3, E): B, C, x~ in that order
+      u[t] = B[t] * x~[t]
+      v[t] = sum_{i<L} w[i] * u[t - (L-1) + i]      u[<0] = 0; w (L, E)
+      z[t] = C[t] * v[t]
+      y    = z W_out               W_out (E, E)
+
+    ``conv_L_cache`` = L, the taps of the depthwise convolution (3 in the
+    published configurations). The two projections run on the MXU under
+    the scope ``shortconv.proj``; the gates and the convolution — L
+    shifted multiply-adds over the channels in float32, which XLA fuses
+    into one pass — under ``shortconv.mix``. The taps' gradient is a
+    reduction over the positions. Under ``remat = 1`` the layer keeps
+    nothing but its input and is rebuilt whole in the backward pass."""
+    has_params = True
+
+    def set_param(self, name, val):
+        if name == "conv_L_cache":
+            self.taps = int(val)
+
+    def __init__(self, spec, global_cfg):
+        self.taps = 3
+        super().__init__(spec, global_cfg)
+        if self.taps < 1:
+            raise ValueError(f"shortconv {spec.name!r}: conv_L_cache must "
+                             "be at least 1")
+
+    def infer_shapes(self, in_shapes):
+        self.check_n(in_shapes, 1, 1)
+        return [in_shapes[0]]
+
+    def init_params(self, key, in_shapes):
+        e = in_shapes[0][0]
+        ks = jax.random.split(key, 3)
+        w = self.hp.init_weight
+        return {"in_proj": {"wmat": w(ks[0], (e, 3, e), e, 3 * e)},
+                "conv": {"wmat": w(ks[1], (self.taps, e), self.taps, 1)},
+                "out_proj": {"wmat": w(ks[2], (e, e), e, e)}}
+
+    def apply(self, params, state, inputs, ctx):
+        if ctx.seq_axis is not None:
+            raise ValueError("shortconv has no sequence-parallel path")
+        cd = ctx.compute_dtype
+        x = _seq(inputs[0]).astype(cd)
+        S = x.shape[1]
+        with jax.named_scope("shortconv.proj"):
+            bcx = jnp.einsum("bse,ekf->bskf", x,
+                             params["in_proj"]["wmat"].astype(cd))
+        with jax.named_scope("shortconv.mix"):
+            gate_b, gate_c, xt = (bcx[:, :, i].astype(jnp.float32)
+                                  for i in range(3))
+            u = gate_b * xt
+            w = params["conv"]["wmat"].astype(jnp.float32)
+            v = 0.0
+            for i in range(self.taps):
+                lag = self.taps - 1 - i
+                shifted = u if not lag else jnp.pad(
+                    u, ((0, 0), (lag, 0), (0, 0)))[:, :S]
+                v = v + w[i] * shifted
+            z = (gate_c * v).astype(cd)
+        with jax.named_scope("shortconv.proj"):
+            y = jnp.einsum("bsf,fe->bse", z,
+                           params["out_proj"]["wmat"].astype(cd))
+        return [_unseq(y)], state
 
 
 def swiglu(x, w_gate, w_up, w_down):

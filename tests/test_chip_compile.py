@@ -436,6 +436,51 @@ def test_the_indexer_s_backward_kernel_compiles_at_the_cell_s_widths(one_chip):
     assert text.count('"size":"%d"' % _BWD_VMEM_LIMIT) == 1
 
 
+# -- benchmarks/configs/lfm2_8b_a1b.conf (heads of 64, short convolutions) -------
+
+def test_grouped_query_flash_compiles_at_a_head_of_64(one_chip):
+    """One row of 8192 positions, 32 query heads of 64 over 8 key/value
+    heads, blocks of 1024: what ``gqa`` hands the kernels in
+    ``lfm2_ep4_train_8k``. A head of 64 is half the lanes: the tiles'
+    last dimension is the array's whole, and the backward's resident dq
+    row is padded to 128 lanes in its VMEM count."""
+    fn = lambda q, k, v: flash_attention(q, k, v, True, None, 1024, 1024,
+                                         False)
+    text = _compile(fn, one_chip, [((1, 8192, 32, 64), BF16)]
+                    + [((1, 8192, 8, 64), BF16)] * 2,
+                    grad_argnums=(0, 1, 2))
+    assert _kernels(text) == 2          # forward, backward
+
+
+def test_the_short_convolution_compiles_at_the_cell_s_widths(one_chip):
+    """A ``shortconv`` layer of ``lfm2_ep4_train_8k`` — 8192 positions of
+    2048, 3 taps — forward and gradient under ``jax.checkpoint``: XLA's
+    own code, and both projections' products carry the scope
+    ``shortconv.proj`` in the compiled text."""
+    from cxxnet_tpu.graph import LayerSpec
+    from cxxnet_tpu.layers import ApplyCtx, create_layer
+    from cxxnet_tpu.telemetry.traceparse import scope_table
+    n, e = 8192, 2048
+    layer = create_layer(LayerSpec("shortconv", "b0_conv", [0], [1], [
+        ("conv_L_cache", "3")]), [])
+    params = jax.tree_util.tree_map(
+        lambda a: (tuple(a.shape), a.dtype), jax.eval_shape(
+            lambda key: layer.init_params(key, [(e, n, 1)]),
+            jax.random.PRNGKey(0)))
+
+    @jax.checkpoint
+    def fn(p, xs):
+        with jax.named_scope("b0_conv"):
+            return layer.apply(p, {}, [xs], ApplyCtx(
+                train=True, compute_dtype=BF16))[0][0]
+    text = _compile(fn, one_chip, [params, ((1, n, 1, e), BF16)],
+                    grad_argnums=(0, 1))
+    _xla_only(text)
+    scopes = scope_table(text)
+    dots = [op for op in scopes.values() if "dot_general" in op]
+    assert dots and all("shortconv.proj" in op for op in dots), dots
+
+
 def test_the_held_experts_ladder_compiles_at_the_cell_s_sizes(one_chip):
     """An expert layer of ``joyai_ep16_train_8k`` — 8192 positions of
     2048, top-8 of 256 with 16 held, experts 768 wide — forward and

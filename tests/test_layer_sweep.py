@@ -88,6 +88,9 @@ CASES = {
             "  nkvhead = 2\n  head_dim = 6\n  qk_norm = 1\n"
             "  mrope_section = 1,1,1\n  index_heads = 2\n"
             "  index_head_dim = 4\n  index_topk = 5\n"),
+    "shortconv": (f"1,1,12", f"layer[+1] = embed\n  nhidden = 8\n"
+                  f"  vocab_size = {SEQ_V}\nlayer[+1] = shortconv\n"
+                  "  conv_L_cache = 3\n"),
     "label_ids": (f"1,1,12", f"layer[+1] = label_ids\nlayer[+1] = embed\n"
                   f"  nhidden = 8\n  vocab_size = {SEQ_V}\n"),
 }
@@ -114,7 +117,7 @@ def test_layer_forward_and_grad(ltype):
     c, y, x = (int(v) for v in shape.split(","))
     if ltype in ("embed", "posembed", "layernorm", "mha", "ffn", "moe",
                  "seqfc", "add", "lmloss", "rmsnorm", "mla", "gqa", "dsa",
-                 "label_ids"):
+                 "shortconv", "label_ids"):
         data = jnp.asarray(rng.randint(0, SEQ_V, (4, 1, 1, x))
                            .astype(np.float32))
     elif c == 1 and y == 1:

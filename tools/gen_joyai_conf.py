@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Write the net conf of a published sequence model in this repo's
-dialect, from the published config's own keys. Three families:
+dialect, from the published config's own keys. Four families:
 
 * JoyAI-LLM-Flash's (the DeepSeek-V3 family: latent attention, one
   leading dense layer, sigmoid-routed experts with a shared one, one
@@ -16,20 +16,29 @@ dialect, from the published config's own keys. Three families:
   grouped-query attention over the keys a learned indexer picks,
   ``sa_config``, as the kind ``dsa``; an RMS norm on each head's q and k;
   a rotary by ``rope_scaling.mrope_section``; all layers alike, softmax
-  top-k experts without drops and without a shared one).
+  top-k experts without drops and without a shared one);
+* ``model_type`` ``lfm2_moe`` (LiquidAI's LFM2 mixture-of-experts family:
+  ``layer_types`` sets each layer's operator, ``conv`` the gated short
+  convolution as the kind ``shortconv``, ``full_attention`` grouped-query
+  attention with an RMS norm on each head's q and k and the plain rotary
+  over the whole head; the first ``num_dense_layers`` take a SwiGLU
+  ``ffn``, the rest sigmoid top-k experts with a selection bias and
+  without a shared one).
 
     python tools/gen_joyai_conf.py benchmarks/configs/joyai_llm_flash.json
     python tools/gen_joyai_conf.py benchmarks/configs/laguna_s_2_1.json
     python tools/gen_joyai_conf.py benchmarks/configs/keye_vl_2_0_30b_a3b.json
+    python tools/gen_joyai_conf.py benchmarks/configs/lfm2_8b_a1b.json
 
 reads the keys of that JSON (the model's ``config.json`` names plus the
 held experts' ``expert_first`` and published count, for the first family
 ``mtp_loss_weight`` and ``bias_update_rate``, for the third
-``index_loss_coef``, and the conf's training pairs under ``train``) and
-prints the conf.
+``index_loss_coef``, for the fourth ``bias_update_rate``, and the conf's
+training pairs under ``train``) and prints the conf.
 ``benchmarks/configs/joyai_llm_flash.conf``,
 ``benchmarks/configs/laguna_s_2_1.conf``,
-``benchmarks/configs/keye_vl_2_0_30b_a3b.conf`` and the toy confs under
+``benchmarks/configs/keye_vl_2_0_30b_a3b.conf``,
+``benchmarks/configs/lfm2_8b_a1b.conf`` and the toy confs under
 ``tests/benchmarks/data/*_toy/configs/`` are its output; nothing reads
 this file at run time.
 """
@@ -55,7 +64,76 @@ def conf(c: dict) -> str:
         return conf_laguna(c)
     if c.get("model_type") == "KeyeVL2":
         return conf_keye(c)
+    if c.get("model_type") == "lfm2_moe":
+        return conf_lfm2(c)
     return conf_joyai(c)
+
+
+def conf_lfm2(c: dict) -> str:
+    if not c["norm_topk_prob"] or not c["use_expert_bias"]:
+        raise ValueError("norm_topk_prob or use_expert_bias is false: the "
+                         "sigmoid router has the renormalised, biased "
+                         "choice only")
+    if c["conv_bias"]:
+        raise ValueError("the shortconv kind has no bias")
+    kinds = c["layer_types"]
+    if len(kinds) != c["num_hidden_layers"]:
+        raise ValueError(f"{len(kinds)} layer_types for "
+                         f"{c['num_hidden_layers']} layers")
+    E, V, eps = c["hidden_size"], c["vocab_size"], c["norm_eps"]
+    heads = c["num_attention_heads"]
+    out = _HEADER + ["layer[0->e0] = embed:tok_embed",
+                     f"  nhidden = {E}", f"  vocab_size = {V}"]
+
+    def norm(src, dst, name):
+        out.extend([f"layer[{src}->{dst}] = rmsnorm:{name}",
+                    f"  eps = {eps}"])
+
+    x = "e0"
+    for i, kind in enumerate(kinds):
+        p = f"b{i}"
+        norm(x, f"{p}n1", f"{p}_ln1")
+        if kind == "conv":
+            out.extend([f"layer[{p}n1->{p}a] = shortconv:{p}_conv",
+                        f"  conv_L_cache = {c['conv_L_cache']}"])
+        elif kind == "full_attention":
+            out.extend([
+                f"layer[{p}n1->{p}a] = gqa:{p}_attn",
+                f"  nhead = {heads}",
+                f"  nkvhead = {c['num_key_value_heads']}",
+                f"  head_dim = {E // heads}",
+                "  qk_norm = 1",
+                f"  eps = {eps}",
+                f"  rope_theta = {c['rope_theta']}"])
+        else:
+            raise ValueError(f"layer_types {kind!r}")
+        out.append(f"layer[{x},{p}a->{p}r1] = add:{p}_res1")
+        norm(f"{p}r1", f"{p}n2", f"{p}_ln2")
+        if i < c["num_dense_layers"]:
+            out.extend([f"layer[{p}n2->{p}f] = ffn:{p}_ffn",
+                        "  act = swiglu",
+                        f"  nhidden = {c['intermediate_size']}"])
+        else:
+            out.extend([
+                f"layer[{p}n2->{p}f] = moe:{p}_moe",
+                "  router = sigmoid",
+                f"  num_expert = {c['num_experts_published']}",
+                f"  topk = {c['num_experts_per_tok']}",
+                f"  nhidden = {c['moe_intermediate_size']}",
+                "  shared_expert = 0",
+                f"  routed_scaling_factor = {c['routed_scaling_factor']}",
+                f"  expert_first = {c['expert_first']}",
+                f"  expert_held = {c['num_experts']}",
+                f"  bias_update_rate = {c['bias_update_rate']}"])
+        out.append(f"layer[{p}r1,{p}f->{p}r2] = add:{p}_res2")
+        x = f"{p}r2"
+    norm(x, "hN", "final_norm")
+    out.extend(["layer[hN->lg] = seqfc:lm_head", f"  nhidden = {V}",
+                "  no_bias = 1",
+                "layer[lg->lg] = lmloss:loss_main"])
+    out.extend(_tail(c, ["metric[label,lg] = seq_error",
+                         "metric[label,lg] = seq_logloss"]))
+    return "\n".join(out) + "\n"
 
 
 def conf_keye(c: dict) -> str:
