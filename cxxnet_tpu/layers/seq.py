@@ -18,17 +18,19 @@ flat nodes ``(1, 1, S)``.
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.attention import (SELECT_RESIDUAL, attention_reference,
-                             chunked_attention, flash_attention,
-                             flash_attention_select, flash_tile_classes,
-                             flash_tiles, head_sum_probs,
-                             head_sum_probs_reference, index_scores,
+from ..ops.attention import (INDEX_GRAD_RESIDUAL, SELECT_RESIDUAL,
+                             attention_reference, chunked_attention,
+                             flash_attention, flash_attention_select,
+                             flash_tile_classes, flash_tiles,
+                             head_sum_probs, head_sum_probs_reference,
+                             index_scores, index_scores_backward,
                              index_scores_reference, rope,
                              rope_frequencies, rope_interleaved,
                              rope_partial, rope_sections,
@@ -507,19 +509,74 @@ def _gradients_together(tree):
     """The identity, whose backward hands all of ``tree``'s cotangents on
     at once (``optimization_barrier``): none is used before the last is
     made. A sparse ``gqa`` layer passes its input and its indexer's
-    leaves through it. The indexer is detached from the layer's input,
-    so nothing upstream waits for its backward, and the chip's scheduler
-    is then free to put all layers' indexer backwards off to the step's
-    end, each holding its rebuilt positions x positions scores and
-    distribution until then (2.4 GB at eight layers of 8192 positions;
-    PERF.md section 6, PR 35); tied, a layer's indexer has its gradients
-    before the backward goes on to the layer before."""
+    leaves through it, so the leaves' gradients (the kept ones times the
+    cotangent: :func:`_index_learned`) are handed on with the input's,
+    before the backward goes on to the layer before. No positions x
+    positions square waits on the tie: the indexer's backward runs in the
+    forward pass. While the backward rebuilt the indexer's scores and
+    distribution, the untied scheduler put every layer's indexer backward
+    off to the step's end, each holding its two squares until then, and
+    with the tie the chip's compiler also picked another layout for them
+    (PERF.md section 6)."""
     return tree
 
 
 _gradients_together.defvjp(
     lambda tree: (tree, None),
     lambda _, grads: (jax.lax.optimization_barrier(grads),))
+
+
+#: a sparse ``gqa`` layer's indexer's leaves: all that its loss reaches
+_INDEX_LEAVES = ("iq", "ik", "iknorm", "iw")
+
+
+def _index_loss(scores, select, probs):
+    """``(L_I, dL_I/dI)``: ``L_I = mean_t KL(p[t] || softmax of I[t] over
+    the selected set)``, and its gradient in the scores from the same
+    float32 passes, ``(softmax over the selected set of I - p) / n``
+    over the ``n`` rows (the softmax times the row's sum of ``p``, which
+    is 1 but for rounding, as the plain derivative has it)."""
+    keep = select != 0
+    masked = jnp.where(keep, scores, -jnp.inf)
+    logz = jax.nn.logsumexp(masked, axis=-1, keepdims=True)
+    some = keep & (probs > 0)
+    logp = jnp.log(jnp.where(some, probs, 1.0))
+    rows = jnp.sum(jnp.where(some, probs * (logp - (scores - logz)), 0.0),
+                   axis=-1)
+    p = jnp.where(some, probs, 0.0)
+    grad = (jnp.exp(masked - logz) * jnp.sum(p, axis=-1, keepdims=True)
+            - p) / rows.size
+    return jnp.mean(rows), grad
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _index_learned(run, leaves, operands):
+    """The indexer's loss ``L_I``, ``run(leaves, operands, False)[0]``,
+    whose whole backward runs in the forward pass: there ``run(leaves,
+    operands, True)`` gives ``L_I`` with the leaves' gradients for a
+    cotangent of 1, which are kept (``INDEX_GRAD_RESIDUAL``), and the
+    backward hands the leaves the cotangent times them. The loss reaches
+    nothing but the leaves — ``operands`` (the layer's detached input,
+    the indexer's scores and their operands, the main attention's q, k
+    and logsumexp, the selection) take no gradient — so that is the
+    whole derivative, by linearity. ``remat`` keeps the gradients (2.2 M
+    float32 a layer at 16 heads of 64 over a width of 2048) and its
+    rebuilt forward makes nothing of the indexer: not the scores, not the
+    head-summed distribution, not the loss's float32 passes over the
+    positions x positions squares."""
+    return run(leaves, operands, False)[0]
+
+
+def _index_learned_fwd(run, leaves, operands):
+    loss, grads = run(leaves, operands, True)
+    return loss, checkpoint_name(grads, INDEX_GRAD_RESIDUAL)
+
+
+def _index_learned_bwd(run, grads, ct):
+    return jax.tree_util.tree_map(lambda g: ct * g, grads), None
+
+
+_index_learned.defvjp(_index_learned_fwd, _index_learned_bwd)
 
 
 @register_layer("gqa")
@@ -580,14 +637,17 @@ class GroupedQueryAttentionLayer(Layer):
     softmax over the selected set of I)``, and ``index_loss_coef L_I``
     joins the objective through the state's ``_aux_loss`` (0: no loss is
     built and the indexer's leaves get no gradient). Nothing but the
-    indexer's leaves gets a gradient from it, and it reaches them
-    through the scores' own backward: under ``flash`` one kernel
+    indexer's leaves gets a gradient from it, so its whole backward runs
+    in the forward pass, once (:func:`_index_learned`): the loss and its
+    gradient in the scores from the same float32 passes, then the
+    scores' own backward — under ``flash`` one kernel
     (``index_scores_bwd``, every causal tile once, at the score kernel's
     blocks of 512), under ``ref`` XLA's derivative of
-    ``index_scores_reference``; and the backward hands the layer's
-    input's gradient on only together with theirs
-    (``_gradients_together``), so a layer's indexer is done with before
-    the layer before it is begun. The state's ``dsa_stats``
+    ``index_scores_reference`` — and the projections'; the backward
+    scales the kept gradients by the loss's cotangent and hands them on
+    only together with the layer's input's (``_gradients_together``).
+    The selection log records ``index_grad: gqa.forward`` for such a
+    layer. The state's ``dsa_stats``
     (``DSA_STATS``) count the pairs selected, ``L_I``, and the score
     tiles the kernels executed of a head's square.
 
@@ -601,8 +661,8 @@ class GroupedQueryAttentionLayer(Layer):
     window layer; section 6, PR 32), 256, 128 that divides the
     positions. ``auto`` is the kernel on a TPU where such a block
     exists, else ``ref``. Under ``remat = 1`` the model keeps the
-    kernel's output and logsumexp, and a selection, and rebuilds the
-    rest."""
+    kernel's output and logsumexp, a selection and the indexer's
+    gradients, and rebuilds the rest: nothing of the indexer."""
     has_params = True
 
     _INT = ("nhead", "nkvhead", "head_dim", "window", "head_gate",
@@ -761,28 +821,44 @@ class GroupedQueryAttentionLayer(Layer):
                              f"{S} positions")
         return flash_attention(q, k, v, True, None, blk, blk, None, window)
 
-    def _attend_sparse(self, q, k, v, select, want_probs):
+    def _attend_sparse(self, q, k, v, select):
         """The main attention over the selected pairs -> ``(o, the
-        head-summed distribution over them or None)``."""
+        kernel's logsumexp or None under ``ref``)``."""
         from ..ops.fused import note_attention
         S = q.shape[1]
         impl, blk = self._impl(S)
         note_attention("gqa.flash_sparse" if impl == "flash"
                        else "gqa.ref_sparse")
         if impl == "ref":
-            return (attention_reference(q, k, v, causal=True, select=select),
-                    jax.lax.stop_gradient(head_sum_probs_reference(
-                        q, k, select)) if want_probs else None)
+            return attention_reference(q, k, v, causal=True,
+                                       select=select), None
         if not blk:
             raise ValueError(f"gqa {self.name!r}: no flash block divides "
                              f"{S} positions")
-        o, lse = flash_attention_select(q, k, v, select, None, blk, blk)
-        return o, head_sum_probs(q, k, lse, select, None, blk) \
-            if want_probs else None
+        return flash_attention_select(q, k, v, select, None, blk, blk)
+
+    def _index_block(self, positions):
+        """The score kernel's block at ``positions``; 0 where XLA's form
+        runs (``ref``, or no block of 512 or less divides them)."""
+        blk = flash_block(positions, 512)
+        return blk if self._impl(positions)[0] == "flash" else 0
 
     def _index(self, params, x, pos, cd):
         """The indexer's scores (B, S, S) float32 from the layer's
         detached input."""
+        return self._scores(*self._index_parts(params, x, pos, cd))
+
+    def _scores(self, qi, ki, wt):
+        """The indexer's scores from its parts: the kernel at its block,
+        else XLA's form."""
+        blk = self._index_block(qi.shape[1])
+        return index_scores(qi, ki, wt, blk) if blk \
+            else index_scores_reference(qi, ki, wt)
+
+    def _index_parts(self, params, x, pos, cd):
+        """What the indexer's scores are made of, from the layer's
+        detached input: ``qI`` (B, S, J, d) and ``kI`` (B, S, d) rotated,
+        in ``cd``, and the heads' weights ``w`` (B, S, J) float32."""
         x = jax.lax.stop_gradient(x)
         w = lambda nm: params[nm]["wmat"].astype(cd)
         qi = jnp.einsum("bse,ejd->bsjd", x, w("iq"))
@@ -803,10 +879,32 @@ class GroupedQueryAttentionLayer(Layer):
         wt = jnp.einsum("bse,ej->bsj", x, w("iw"),
                         preferred_element_type=jnp.float32) \
             * (self.index_heads * self.index_head_dim) ** -0.5
-        blk = flash_block(x.shape[1], 512)
-        if self._impl(x.shape[1])[0] == "ref" or not blk:
-            return index_scores_reference(qi, ki[:, :, 0], wt)
-        return index_scores(qi, ki[:, :, 0], wt, blk)
+        return qi, ki[:, :, 0], wt
+
+    def _learn(self, leaves, operands, with_grads):
+        """``(L_I, the leaves' gradients of L_I or None)``: the forward
+        rule of :func:`_index_learned`, which also makes the head-summed
+        target. ``operands`` = the layer's input in the products' dtype,
+        its position rows or None, the indexer's parts and scores (from
+        the detached leaves: the selection was made from them), the main
+        attention's q, k and logsumexp (None under ``ref``), and the
+        selection."""
+        x, pos, parts, scores, q, k, lse, select = operands
+        S = x.shape[1]
+        with jax.named_scope("gqa.attend.sparse"):
+            probs = head_sum_probs_reference(q, k, select) if lse is None \
+                else head_sum_probs(q, k, lse, select, None, self._block(S))
+        with jax.named_scope("gqa.index_loss"):
+            loss, d_scores = _index_loss(scores, select, probs)
+        if not with_grads:
+            return loss, None
+        with jax.named_scope("gqa.index"):
+            _, pull = jax.vjp(
+                lambda lv: self._index_parts(lv, x, pos, x.dtype), leaves)
+            blk = self._index_block(S)
+            d_parts = index_scores_backward(*parts, d_scores, blk) if blk \
+                else jax.vjp(index_scores_reference, *parts)[1](d_scores)
+            return loss, pull(d_parts)[0]
 
     def _select(self, scores):
         """The selection (B, S, S) int8 of the indexer's scores: the
@@ -832,16 +930,6 @@ class GroupedQueryAttentionLayer(Layer):
             return rope_partial(a, self.rope_freqs, self.rope_mscale)
         return rope_sections(a, self.rope_freqs, pos, self.mrope_section)
 
-    def _index_loss(self, scores, select, probs):
-        """``mean_t KL(p[t] || softmax of I[t] over the selected set)``."""
-        keep = select != 0
-        logz = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1,
-                                keepdims=True)
-        some = keep & (probs > 0)
-        logp = jnp.log(jnp.where(some, probs, 1.0))
-        return jnp.mean(jnp.sum(jnp.where(
-            some, probs * (logp - (scores - logz)), 0.0), axis=-1))
-
     def apply(self, params, state, inputs, ctx):
         if ctx.seq_axis is not None:
             raise ValueError("gqa has no sequence-parallel path")
@@ -849,9 +937,8 @@ class GroupedQueryAttentionLayer(Layer):
         x = _seq(inputs[0]).astype(cd)
         learn = bool(self.index_topk and ctx.train and self.index_loss_coef)
         if learn:
-            x, mine = _gradients_together((x, {
-                nm: params[nm] for nm in ("iq", "ik", "iknorm", "iw")}))
-            params = {**params, **mine}
+            x, leaves = _gradients_together(
+                (x, {nm: params[nm] for nm in _INDEX_LEAVES}))
         w = lambda nm: params[nm]["wmat"].astype(cd)
         pos = None
         if len(inputs) > 1:          # (b, S, 1, 3) -> the rows (b, 3, S)
@@ -867,15 +954,24 @@ class GroupedQueryAttentionLayer(Layer):
             q, k = self.rotate(q, pos), self.rotate(k, pos)
         if self.index_topk:
             with jax.named_scope("gqa.index"):
-                scores = self._index(params, x, pos, cd)
+                # learning, the leaves' gradients come from _index_learned
+                parts = self._index_parts(
+                    jax.lax.stop_gradient(params) if learn else params,
+                    x, pos, cd)
+                scores = self._scores(*parts)
             with jax.named_scope("gqa.select"):
                 select = checkpoint_name(self._select(scores),
                                          SELECT_RESIDUAL)
             with jax.named_scope("gqa.attend.sparse"):
-                o, probs = self._attend_sparse(q, k, v, select, learn)
-            with jax.named_scope("gqa.index_loss"):
-                loss = self._index_loss(scores, select, probs) if learn \
-                    else jnp.zeros((), jnp.float32)
+                o, lse = self._attend_sparse(q, k, v, select)
+            if learn:
+                from ..ops.fused import note_index_grad
+                note_index_grad("gqa.forward")
+                loss = _index_learned(
+                    self._learn, leaves, jax.lax.stop_gradient(
+                        (x, pos, parts, scores, q, k, lse, select)))
+            else:
+                loss = jnp.zeros((), jnp.float32)
             with jax.named_scope("gqa.select"):
                 blk = self._block(x.shape[1])
                 tiles = jnp.sum(select_tiles(select, blk, blk)[0] > 0) \

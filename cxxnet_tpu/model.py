@@ -24,19 +24,24 @@ from .config import ConfigPairs, Policy
 from .graph import NetGraph, global_param, policy_from_config
 from .layers import ApplyCtx, Layer, create_layer
 from .layers.base import Shape3, is_flat, to_nhwc
-from .ops.attention import FLASH_RESIDUALS, SELECT_RESIDUAL
+from .ops.attention import (FLASH_RESIDUALS, INDEX_GRAD_RESIDUAL,
+                            SELECT_RESIDUAL)
 from .ops.fused import selection_site
 
 #: ``remat = 1`` rebuilds a layer's activations in the backward pass but
 #: for the values named here: the flash kernel's output and logsumexp,
-#: which its backward needs and only the kernel's forward makes, and a
+#: which its backward needs and only the kernel's forward makes, a
 #: sparse layer's selection (one int8 a pair: kept, the rebuilt forward
 #: neither runs the selection again nor can pick another set than the
 #: forward attended; PERF.md section 6, PR 34 has the chip A/B against
-#: rebuilding it). A layer that never reaches the kernel has no such
-#: name and keeps nothing, as under a bare ``jax.checkpoint``
+#: rebuilding it), and that layer's indexer's gradients, made in the
+#: forward pass (its leaves' size: kept, the rebuilt forward makes
+#: neither the indexer's scores nor the head-summed distribution nor the
+#: loss's passes over those positions x positions squares). A layer
+#: that never reaches the kernel has no such name and keeps nothing, as
+#: under a bare ``jax.checkpoint``
 _REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    *FLASH_RESIDUALS, SELECT_RESIDUAL)
+    *FLASH_RESIDUALS, SELECT_RESIDUAL, INDEX_GRAD_RESIDUAL)
 
 Params = Dict[str, Dict[str, jax.Array]]
 NetState = Dict[str, Any]

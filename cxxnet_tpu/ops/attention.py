@@ -1128,8 +1128,9 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # Each is a kernel with an XLA form beside it, its oracle and the ``ref``
 # path (``select_topk_reference``: XLA's counting loops); the score and
 # the attention each have ONE
-# backward kernel (``index_scores_bwd``, ``flash_bwd_select``), the
-# selection and the distribution no derivative.
+# backward kernel (``index_scores_bwd``, ``flash_bwd_select``; the first
+# also alone, ``index_scores_backward``, for the layer that runs it in
+# the forward pass), the selection and the distribution no derivative.
 
 
 def _index_scores_rows(qi, ki, w):
@@ -1347,12 +1348,24 @@ def _index_scores_bwd_call(qi, ki, w, g, block, interpret):
             dw.transpose(0, 2, 1).astype(w.dtype))
 
 
+def index_scores_backward(qi: jax.Array, ki: jax.Array, w: jax.Array,
+                          g: jax.Array, block: int = 512,
+                          interpret: Optional[bool] = None):
+    """:func:`index_scores`' backward alone, ``(dqi, dki, dw)`` from the
+    scores' cotangent ``g`` (B, S, S): the one kernel
+    (``index_scores_bwd``), for a caller that holds the cotangent in the
+    forward pass and must not run the score kernel again to reach it
+    (the ``dsa`` layer's indexer: ``layers/seq.py:_index_learned``)."""
+    return _index_scores_bwd_call(qi, ki, w, g, block,
+                                  use_interpret(interpret))
+
+
 def _index_scores_fwd(qi, ki, w, block, interpret):
     return index_scores(qi, ki, w, block, interpret), (qi, ki, w)
 
 
 def _index_scores_bwd(block, interpret, res, g):
-    return _index_scores_bwd_call(*res, g, block, use_interpret(interpret))
+    return index_scores_backward(*res, g, block, interpret)
 
 
 index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
@@ -1751,6 +1764,11 @@ def _backward_select_call(cls, fetch, qt, kt, vt, dot, lse, delta, sel_t,
 #: the backward masks by the very set the forward attended
 SELECT_RESIDUAL = "dsa_select"
 
+#: a ``dsa`` layer's indexer's gradients for a cotangent of 1, made in the
+#: forward pass (``layers/seq.py:_index_learned``): kept, the rebuilt
+#: forward makes none of the indexer's loss, its scores or the head sum
+INDEX_GRAD_RESIDUAL = "dsa_index_grad"
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention_select(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1888,7 +1906,10 @@ def head_sum_probs(q: jax.Array, k: jax.Array, lse: jax.Array,
     kernel's forward emitted — and summed in VMEM, so nothing of positions
     x positions x heads is ever in memory. ``select`` as
     :func:`flash_attention_select` takes it. No derivative: the indexer's
-    target is detached."""
+    target is detached. The ``dsa`` layer calls it once a step, in the
+    forward pass, where the indexer's whole backward runs too and only
+    its gradients are kept (``INDEX_GRAD_RESIDUAL``): the forward that
+    ``remat`` rebuilds has no use for the distribution."""
     q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
     B, Sq, H, D = q.shape
     Sk = k.shape[1]

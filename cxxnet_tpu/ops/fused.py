@@ -6,7 +6,10 @@ never from an option: an attention layer's (``mha``, ``mla``, ``gqa`` /
 ``dsa``: ``attn_impl = auto`` decides per backend and sequence length —
 ops/attention.py), a ``dsa`` layer's selection of the keys its indexer
 picks (the same decision, a second entry of the same site), and a
-``moe`` layer's grouped product. Every other op has one implementation,
+``moe`` layer's grouped product. A fourth kind is no choice but a path
+taken: where a ``dsa`` layer's indexer learns, its gradients are made
+in the forward pass (``index_grad``), so a run's line shows how many
+layers took it. Every other op has one implementation,
 XLA's own (PERF.md section 6, PR 26 and PR 30: the Pallas suite that
 lived beside this file lost every benchmark cell and left the tree).
 
@@ -28,17 +31,18 @@ _SITE: contextvars.ContextVar = contextvars.ContextVar(
     "cxxnet_selection_site", default=None)
 
 #: (site name, kind) -> what the site took of that kind; the kinds are
-#: ``attention``, ``select`` and ``grouped``
+#: ``attention``, ``select``, ``grouped`` and ``index_grad``
 SelectionLog = Dict[Tuple[str, str], str]
 
 
 @contextlib.contextmanager
 def selection_site(log: SelectionLog, name: str):
     """Route :func:`note_attention` / :func:`note_select` /
-    :func:`note_grouped` calls made while tracing site ``name`` (a layer)
-    into ``log``, which the model owns. Keyed by site and kind, so a
-    site keeps one entry of each kind it reports and a retrace
-    overwrites its own entry instead of counting twice."""
+    :func:`note_grouped` / :func:`note_index_grad` calls made while
+    tracing site ``name`` (a layer) into ``log``, which the model owns.
+    Keyed by site and kind, so a site keeps one entry of each kind it
+    reports and a retrace overwrites its own entry instead of counting
+    twice."""
     token = _SITE.set((log, name))
     try:
         yield
@@ -71,6 +75,15 @@ def note_select(impl: str) -> None:
     _record("select", impl)
 
 
+def note_index_grad(where: str) -> None:
+    """Record where the ``dsa`` layer being traced makes its indexer's
+    gradients: ``gqa.forward``, in the forward pass, where they are kept
+    for the backward (``layers/seq.py:_index_learned``). A layer whose
+    indexer does not learn (not training, or ``index_loss_coef = 0``)
+    records nothing."""
+    _record("index_grad", where)
+
+
 def note_grouped(impl: str) -> None:
     """Record which grouped matrix product the moe layer being traced
     runs its held experts on (``ragged_dot``: XLA's own)."""
@@ -79,7 +92,8 @@ def note_grouped(impl: str) -> None:
 
 def selection_counts(log: SelectionLog):
     """{kind: Counter(what)} over the log's sites, the kinds in their
-    names' order (``attention``, ``grouped``, ``select``)."""
+    names' order (``attention``, ``grouped``, ``index_grad``,
+    ``select``)."""
     by = collections.defaultdict(collections.Counter)
     for (_, kind), what in sorted(log.items(), key=lambda e: e[0][1]):
         by[kind][what] += 1

@@ -6,7 +6,9 @@ selection (lengths under, at and over ``topk``; a planted tie); sparse
 interpreter and through XLA's dots, forward and gradients, at lengths
 that do and do not divide the block; the selection operand at the ops;
 q/k norm; the three-section rotary at grid positions against hand-computed
-angles; the indexer's loss and where gradients may flow; that ``gqa``
+angles; the indexer's loss and where gradients may flow, its gradients
+made in the forward pass against plain autodiff, and one score kernel
+and one head sum a layer under ``remat``; that ``gqa``
 without the new keys traces what it traced; the whole toy model over
 three Adam steps; and the test that ties a chip's share to the model: the
 sixteen expert shares' partial sums add up to the uncut expert layer."""
@@ -428,6 +430,80 @@ def test_the_indexer_learns_from_its_loss_alone():
             assert np.abs(np.asarray(b)).max() > 0, name
 
 
+def _plain_index_loss(scores, select, probs):
+    """The indexer's loss as plain autodiff differentiates it: ``mean_t
+    KL(p[t] || softmax of I[t] over the selected set)``."""
+    keep = select != 0
+    logz = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1,
+                            keepdims=True)
+    some = keep & (probs > 0)
+    logp = jnp.log(jnp.where(some, probs, 1.0))
+    return jnp.mean(jnp.sum(jnp.where(
+        some, probs * (logp - (scores - logz)), 0.0), axis=-1))
+
+
+def _plain_index_learned(layer):
+    """``seq._index_learned`` as plain autodiff through the loss: the
+    scores made again from the leaves, over the same target and set, and
+    the backward left to differentiate them (a cotangent reaches the
+    leaves through every step of the loss, not as a scale at its end)."""
+    def learned(run, leaves, operands):
+        x, pos, _, _, q, k, lse, select = operands
+        scores = layer._index(leaves, x, pos, x.dtype)
+        probs = A.head_sum_probs_reference(q, k, select) if lse is None \
+            else A.head_sum_probs(q, k, lse, select, None,
+                                  layer._block(x.shape[1]))
+        return _plain_index_loss(scores, select, probs)
+    return learned
+
+
+@pytest.mark.parametrize("remat", (0, 1))
+@pytest.mark.parametrize("impl", ("ref", "flash"))
+def test_the_indexer_gradients_made_in_the_forward_are_plain_autodiffs(
+        monkeypatch, impl, remat):
+    """The indexer's leaves' gradients, made in the forward pass and
+    scaled by the cotangent in the backward, are those of plain autodiff
+    through the loss, under ``remat`` (the model's policy) and without,
+    through XLA's scores and the kernels', at an ``index_loss_coef`` and
+    an objective's scale that make the cotangent neither 1 nor the
+    coefficient; the layer's input and every other leaf get bit for bit
+    the gradients they got."""
+    from cxxnet_tpu.layers import seq
+    from cxxnet_tpu.model import _REMAT_POLICY
+    S = 16
+    x, w = data(S, 11)
+    layer = dsa_layer(attn_impl=impl, index_loss_coef=0.3)
+    params = weights(layer, S, 11)
+
+    def gradients():
+        # traced anew each time: jax.checkpoint keeps a function's trace
+        def apply(p, x_):
+            y, new = run(layer, p, x_)
+            return y, new["_aux_loss"], new["dsa_stats"]
+        if remat:
+            apply = jax.checkpoint(apply, policy=_REMAT_POLICY)
+
+        def objective(p, x_):
+            y, aux, stats = apply(p, x_)
+            return -1.7 * (jnp.sum(y * w) + aux), stats
+        return jax.jit(jax.grad(objective, (0, 1), has_aux=True))(params, x)
+    got = gradients()
+    monkeypatch.setattr(seq, "_index_learned", _plain_index_learned(layer))
+    want = gradients()
+    close(got[1], want[1], 1e-6)            # L_I and the set's counts
+    assert float(got[1][1]) > 0
+    (g_params, g_x), (w_params, w_x) = got[0], want[0]
+    assert np.array_equal(np.asarray(g_x), np.asarray(w_x))
+    for name in params:
+        for a, b in zip(jax.tree_util.tree_leaves(g_params[name]),
+                        jax.tree_util.tree_leaves(w_params[name])):
+            if name in seq._INDEX_LEAVES:
+                assert np.abs(np.asarray(b)).max() > 0, name
+                close(a, b, 1e-5)
+            else:
+                assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+
 def test_the_kind_dsa_needs_its_indexer_and_gqa_takes_the_same_keys():
     with pytest.raises(ValueError, match="index_topk"):
         dsa_layer(topk=0)
@@ -572,6 +648,18 @@ def test_the_toy_model_trains_as_the_reference_does(ref):
     assert tiles["b0_attn"] == tiles["b1_attn"] == 1    # 32 positions
 
 
+def kernel_calls(text):
+    """How often a jaxpr's text calls the score kernel, its backward and
+    the head sum (the custom_vjp that wraps the score kernel under its
+    name is not a call)."""
+    bwd = text.count("name=index_scores_bwd")
+    return {"index_scores": text.count("name=index_scores") - bwd
+            - len(re.findall(r"custom_vjp_call\[\s*name=index_scores",
+                             text)),
+            "index_scores_bwd": bwd,
+            "head_sum_probs": text.count("name=head_sum_probs")}
+
+
 def _toy_net(remat, impl):
     from cxxnet_tpu.config import parse_config_string
     from cxxnet_tpu.graph import build_graph
@@ -584,12 +672,41 @@ def _toy_net(remat, impl):
     return Network(build_graph(cfg), cfg)
 
 
+@pytest.mark.parametrize("remat", (0, 1))
+def test_the_indexer_runs_once_a_step(remat):
+    """With ``remat`` (the model's policy) or without, the gradient's
+    program calls the score kernel, its backward and the head sum once:
+    the indexer's backward runs in the forward pass and its gradients are
+    kept, so the forward that ``remat`` rebuilds has nothing of the
+    indexer to make (it made both kernels again until the gradients were
+    kept). The selection log names the path."""
+    from cxxnet_tpu.model import _REMAT_POLICY
+    from cxxnet_tpu.ops.fused import selection_counts, selection_site
+    x, w = data(16, 5)
+    layer = dsa_layer(attn_impl="flash")
+    params = weights(layer, 16, 5)
+
+    def apply(p, x_):
+        y, new = run(layer, p, x_)
+        return jnp.sum(y * w) + new["_aux_loss"]
+    if remat:
+        apply = jax.checkpoint(apply, policy=_REMAT_POLICY)
+    log = {}
+    with selection_site(log, "attn"):
+        text = str(jax.make_jaxpr(jax.grad(apply))(params, x))
+    assert kernel_calls(text) == {"index_scores": 1, "index_scores_bwd": 1,
+                                  "head_sum_probs": 1}
+    assert selection_counts(log)["index_grad"] == {"gqa.forward": 1}
+
+
 def test_toy_net_under_remat_keeps_the_selection_and_the_kernels_output():
     """Loss and gradients under ``remat = 1`` are those under ``remat =
     0``; the rebuilt layers run neither the selection nor the attention's
-    forward a second time (two layers: two ``flash_fwd_select``, two
-    ``flash_bwd_select``, and two selection kernels), and the selection
-    log names the sparse kernel and the selection kernel."""
+    forward nor anything of the indexer a second time (two layers: two
+    ``flash_fwd_select``, two ``flash_bwd_select``, two selection
+    kernels, two score kernels with two backward kernels, two head
+    sums), and the selection log names the sparse kernel, the selection
+    kernel and where the indexer's gradients are made."""
     rng = np.random.RandomState(3)
     toks = jnp.asarray(rng.randint(0, 64, (2, 1, 1, 32)), jnp.float32)
     label = jnp.asarray(rng.randint(0, 64, (2, 32)), jnp.float32)
@@ -606,19 +723,19 @@ def test_toy_net_under_remat_keeps_the_selection_and_the_kernels_output():
             text = str(jax.make_jaxpr(jax.grad(loss))(params))
             assert text.count("name=flash_fwd_select") == 2
             assert text.count("name=flash_bwd_select") == 2
-            # the score kernel and the head sum run again in the rebuilt
-            # forward (the public function of the kernel's name wraps it)
-            # (and the forward's name begins the backward kernel's)
-            bwd = text.count("name=index_scores_bwd")
-            assert text.count("name=index_scores") - bwd - len(re.findall(
-                r"custom_vjp_call\[\s*name=index_scores", text)) == 4
-            assert bwd == 2
-            assert text.count("name=head_sum_probs") == 4
+            # the score kernel and the head sum run once, in the forward:
+            # the indexer's gradients are kept (the public function of
+            # the kernel's name wraps it, and the forward's name begins
+            # the backward kernel's)
+            assert kernel_calls(text) == {"index_scores": 2,
+                                          "index_scores_bwd": 2,
+                                          "head_sum_probs": 2}
             assert text.count("name=select_rows") == 2
             from cxxnet_tpu.ops.fused import selection_counts
             by = selection_counts(net.fused_log)
             assert by["attention"] == {"gqa.flash_sparse": 2}
             assert by["select"] == {"gqa.select_rows": 2}
+            assert by["index_grad"] == {"gqa.forward": 2}
     close(out[1][0], out[0][0], 1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(out[1][1]),
                     jax.tree_util.tree_leaves(out[0][1])):
